@@ -57,7 +57,9 @@ def main() -> None:
         make_ring_attention,
         make_ulysses_attention,
     )
+    from ray_shuffling_data_loader_tpu.utils import enable_compile_cache
 
+    enable_compile_cache()
     platform = jax.devices()[0].platform
     n_dev = len(jax.devices())
     mesh = Mesh(np.array(jax.devices()), ("sp",))

@@ -47,6 +47,9 @@ def main() -> None:
         dot_interaction,
         dot_interaction_reference,
     )
+    from ray_shuffling_data_loader_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     print(f"backend={jax.default_backend()} devices={jax.device_count()}")
     rng = np.random.default_rng(0)

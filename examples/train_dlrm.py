@@ -160,11 +160,9 @@ def main(argv=None) -> int:
 
     import jax
 
-    from ray_shuffling_data_loader_tpu.utils import force_platform_from_env
+    from ray_shuffling_data_loader_tpu.utils import enable_compile_cache
 
-    # Honor the user's platform choice even under TPU plugins that
-    # override JAX_PLATFORMS (the CPU smoke invocation depends on this).
-    force_platform_from_env()
+    enable_compile_cache()
 
     import jax.numpy as jnp
     import numpy as np
@@ -226,7 +224,7 @@ def main(argv=None) -> int:
             elif jax.local_devices()[0].platform == "cpu":
                 print(
                     "note: resident loader forced on the CPU backend "
-                    "(auto prefers map/reduce there — see BENCHLOG.md)"
+                    "(auto prefers map/reduce there)"
                 )
             else:
                 print(

@@ -73,12 +73,6 @@ def parse_args(argv=None):
         "--process-id", type=int, default=None, help=argparse.SUPPRESS
     )
     p.add_argument(
-        "--platform",
-        default=None,
-        help="Pin the JAX platform via the config API (e.g. 'cpu'; "
-        "JAX_PLATFORMS alone is overridden by experimental TPU plugins).",
-    )
-    p.add_argument(
         "--simulate-pod",
         type=int,
         default=None,
@@ -100,11 +94,9 @@ def parse_args(argv=None):
 def train_main(args) -> int:
     import jax
 
-    if args.platform:
-        # The config API, not JAX_PLATFORMS: experimental TPU plugins
-        # override the env var and would still try (and possibly hang on)
-        # accelerator bring-up in a CPU smoke run.
-        jax.config.update("jax_platforms", args.platform)
+    from ray_shuffling_data_loader_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
 
     # 1. Pod discovery. On Cloud TPU, initialize() needs no arguments.
     if args.coordinator:
@@ -322,12 +314,14 @@ def simulate_pod(args) -> int:
             str(args.batch_size),
             "--epochs",
             str(args.epochs),
-            "--platform",
-            args.platform or "cpu",
             "--loader",
             args.loader,
         ]
-        env = dict(os.environ, RSDL_ADVERTISE_HOST="127.0.0.1")
+        # The simulated pod is a CPU smoke: N processes cannot share the
+        # chips of one host. This launcher itself stays off JAX.
+        env = dict(
+            os.environ, RSDL_ADVERTISE_HOST="127.0.0.1", JAX_PLATFORMS="cpu"
+        )
         procs.append(subprocess.Popen(cmd, env=env))
     rc = 0
     for p in procs:

@@ -55,9 +55,9 @@ def main(argv=None) -> int:
 
     import jax
 
-    from ray_shuffling_data_loader_tpu.utils import force_platform_from_env
+    from ray_shuffling_data_loader_tpu.utils import enable_compile_cache
 
-    force_platform_from_env()
+    enable_compile_cache()
 
     import jax.numpy as jnp
     import numpy as np

@@ -123,8 +123,6 @@ KNOBS: Tuple[Knob, ...] = (
          "device-direct delivery kill switch"),
     Knob("RSDL_RESIDENT_BUDGET_GB", "float", "measured", "public",
          "HBM budget override for fits_device"),
-    Knob("RSDL_TPU_HBM_GB", "float", "16", "public",
-         "per-device HBM for plugins without memory_stats"),
     # -- kernels (ops) ------------------------------------------------------
     Knob("RSDL_FLASH_BWD", "enum", "pallas", "public",
          "flash-attention VJP route (pallas | xla)"),
@@ -223,9 +221,6 @@ KNOBS: Tuple[Knob, ...] = (
          "fsync-per-append toggle"),
     Knob("RSDL_RESUME", "enum", "off", "public",
          "resume mode (auto | redeliver)"),
-    # -- tests / tools (documented) -----------------------------------------
-    Knob("RSDL_TPU_TESTS", "flag", "off", "public",
-         "enable the TPU-gated test files"),
     # -- continuous profiling plane (ISSUE 17) ------------------------------
     Knob("RSDL_PROFILE", "flag", "off", "public",
          "cluster-wide wall-clock sampling profiler (every RSDL "

@@ -8,12 +8,13 @@ reference-file citations per op).
 
 Loading strategy:
 
-1. try a prebuilt ``librsdl_native.so`` next to this file;
-2. else build it once with ``g++ -O3 -shared -fPIC -pthread`` into a
-   per-user cache dir (no pip/cmake involved);
-3. else (no toolchain / build failure) every wrapper silently falls back
-   to an equivalent numpy expression — correctness never depends on the
-   native build, only throughput does.
+1. build the library once from the tracked source with ``g++ -O3 -shared
+   -fPIC -pthread`` into a per-user cache dir, under a name that carries
+   the source's digest (no pip/cmake involved; nothing prebuilt is ever
+   loaded in its place);
+2. else (no toolchain / build failure) every wrapper falls back to an
+   equivalent numpy expression — correctness never depends on the native
+   build, only throughput does. :func:`native_available` says which.
 
 Set ``RSDL_DISABLE_NATIVE=1`` to force the numpy paths (used by tests to
 compare both implementations).
@@ -158,7 +159,6 @@ def _declare(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.rsdl_group_rows_multi_mt.argtypes = [
         p, p, p, c_i64, p, c_i64, p, c_int, c_i64
     ]
-    lib.rsdl_abi_version.restype = c_int
     return lib
 
 
@@ -172,22 +172,9 @@ def _get_lib() -> Optional[ctypes.CDLL]:
         _load_attempted = True
         if os.environ.get("RSDL_DISABLE_NATIVE"):
             return None
-        # Lazy second candidate: only compile when no prebuilt .so loads.
-        for get_candidate in (
-            lambda: os.path.join(_HERE, _LIB_BASENAME),
-            _build_lib,
-        ):
-            candidate = get_candidate()
-            if candidate and os.path.exists(candidate):
-                try:
-                    lib = _declare(ctypes.CDLL(candidate))
-                    if lib.rsdl_abi_version() == 5:
-                        _lib = lib
-                        break
-                except (OSError, AttributeError):
-                    # Unloadable or stale/ABI-mismatched .so (e.g. a symbol
-                    # missing from an old build): keep the numpy fallbacks.
-                    continue
+        built = _build_lib()
+        if built is not None:
+            _lib = _declare(ctypes.CDLL(built))
         return _lib
 
 

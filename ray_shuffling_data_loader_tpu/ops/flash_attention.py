@@ -10,14 +10,14 @@ matmuls via ``lax.dot_general``, ``@pl.when`` for first/last-block
 prologue/epilogue, lane-padded VMEM scratch for the running max and
 normalizer).
 
-Scope: attention over ``[batch, seq, heads, head_dim]`` (batch/head
-partitionable on pod meshes via ``custom_partitioning``).
+Scope: attention over ``[batch, seq, heads, head_dim]`` (split over batch
+and heads on multi-device meshes with ``shard_map``, see :mod:`.placement`).
 It composes with the sequence-parallel schedules (the Ulysses local body
 and each ring hop are exactly this computation) but is wired as the
-standalone ``flash_attention`` op with an XLA fallback — same
-auto-policy shape as the DLRM interaction kernel (``ops/interaction.py``):
-Pallas on TPU backends, XLA reference elsewhere, interpret mode for
-CPU tests.
+standalone ``flash_attention`` op — same auto-policy as the DLRM
+interaction kernel (``ops/interaction.py``): Pallas on TPU backends, the
+XLA reference on backends Mosaic cannot target, interpret mode by
+explicit argument in CPU tests.
 
 Differentiability: the kernel carries an exact, memory-safe custom VJP.
 The forward emits its softmax row statistics (m, l) as outputs; the
@@ -40,6 +40,12 @@ import jax
 import jax.numpy as jnp
 
 
+from ray_shuffling_data_loader_tpu.ops.placement import (
+    DATA_AXIS,
+    MODEL_AXIS,
+    auto_pallas,
+    over_mesh,
+)
 from ray_shuffling_data_loader_tpu.ops.ring_attention import (
     NEG_INF,
     _chunked_attention_bwd,
@@ -139,8 +145,8 @@ def _flash_kernel(
         o_ref[0] = (
             acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
         ).astype(o_ref.dtype)
-        m_ref[0] = m_scr[:, 0]
-        l_ref[0] = l_scr[:, 0]
+        m_ref[0] = m_scr[:, :1]
+        l_ref[0] = l_scr[:, :1]
 
 
 def _flash_forward(
@@ -194,13 +200,13 @@ def _flash_forward(
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq), lambda bh, i, j: (bh, i)),
-            pl.BlockSpec((1, bq), lambda bh, i, j: (bh, i)),
+            pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tq_pad, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, tq_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b * h, tq_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, tq_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, tq_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),  # running max
@@ -216,7 +222,7 @@ def _flash_forward(
     out = jnp.transpose(out, (0, 2, 1, 3))
     if not return_stats:
         return out
-    return out, m[:, :t].reshape(b, h, t), l[:, :t].reshape(b, h, t)
+    return out, m[:, :t, 0].reshape(b, h, t), l[:, :t, 0].reshape(b, h, t)
 
 
 def _bwd_probs(q, k, m, l, ki, scale, causal, block_q, block_k, seq_len, qi):
@@ -242,11 +248,9 @@ def _bwd_probs(q, k, m, l, ki, scale, causal, block_q, block_k, seq_len, qi):
             )
             valid = valid & (q_pos >= k_pos)
         s = jnp.where(valid, s, NEG_INF)
-    mcol = m[:, None]
-    lcol = jnp.maximum(l[:, None], 1e-30)
-    p = jnp.exp(s - mcol) / lcol
+    p = jnp.exp(s - m) / jnp.maximum(l, 1e-30)
     # Fully-masked rows kept m at NEG_INF and must contribute nothing.
-    return jnp.where(mcol > NEG_INF / 2, p, 0.0)
+    return jnp.where(m > NEG_INF / 2, p, 0.0)
 
 
 def _flash_bwd_dkv_kernel(
@@ -308,7 +312,7 @@ def _flash_bwd_dkv_kernel(
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - d_ref[0][:, None])
+        ds = p * (dp - d_ref[0])
         dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
             ds,
             q,
@@ -372,7 +376,7 @@ def _flash_bwd_dq_kernel(
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - d_ref[0][:, None])
+        ds = p * (dp - d_ref[0])
         dq_scr[...] = dq_scr[...] + jax.lax.dot(
             ds,
             k.astype(jnp.float32),
@@ -413,10 +417,12 @@ def _flash_backward_pallas(
             x = jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
         return x
 
-    def rows_bh(x, t_pad, fill=0.0):  # [b, h, t] -> [bh, t_pad]
-        x = x.reshape(b * h, t)
+    def rows_bh(x, t_pad, fill=0.0):  # [b, h, t] -> [bh, t_pad, 1]
+        x = x.reshape(b * h, t, 1)
         if t_pad != t:
-            x = jnp.pad(x, ((0, 0), (0, t_pad - t)), constant_values=fill)
+            x = jnp.pad(
+                x, ((0, 0), (0, t_pad - t), (0, 0)), constant_values=fill
+            )
         return x
 
     qb = to_bh(q, tq_pad)
@@ -439,7 +445,7 @@ def _flash_backward_pallas(
 
     q_spec = pl.BlockSpec((1, bq, d), lambda bh, j, i: (bh, i, 0))
     kv_spec = pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))
-    row_spec = pl.BlockSpec((1, bq), lambda bh, j, i: (bh, i))
+    row_spec = pl.BlockSpec((1, bq, 1), lambda bh, j, i: (bh, i, 0))
     dkv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel,
@@ -473,7 +479,7 @@ def _flash_backward_pallas(
 
     q_spec2 = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
     kv_spec2 = pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0))
-    row_spec2 = pl.BlockSpec((1, bq), lambda bh, i, j: (bh, i))
+    row_spec2 = pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0))
     dqb = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel,
@@ -502,100 +508,51 @@ def _flash_backward_pallas(
     return from_bh(dqb, t), from_bh(dkb, t), from_bh(dvb, t)
 
 
-@functools.lru_cache(maxsize=None)
-def _partitioned_flash(
-    causal: bool,
-    block_q: int,
-    block_k: int,
-    interpret: bool,
-    return_stats: bool = False,
-):
-    """The flash kernel wrapped in ``custom_partitioning``: batch and
-    heads partition (the grid is over ``b·h``), sequence and head_dim
-    must be replicated (each tile reads full K/V rows) — so a dp×tp pod
-    mesh splits the ``pallas_call`` per device and the fused kernel fires
-    on pods, no model-layer ``shard_map`` plumbing. Sequence sharding is
-    the ring/Ulysses schedules' job, not this op's."""
-    from jax.experimental.custom_partitioning import custom_partitioning
-    from jax.sharding import NamedSharding, PartitionSpec as P
+# The kernels split over batch and heads. Sequence and head_dim stay whole
+# on every device (each tile reads full K/V rows) — sequence sharding is
+# the ring/Ulysses schedules' job, not this op's.
+_QKV_DIMS = (DATA_AXIS, None, MODEL_AXIS, None)  # [b, t, h, d]
+_STAT_DIMS = (DATA_AXIS, MODEL_AXIS, None)  # [b, h, t]
 
-    def _lower(q, k, v):
+
+def _sharded_flash(causal, block_q, block_k, interpret, return_stats=False):
+    """The forward kernel, split over the context mesh (:mod:`.placement`)."""
+
+    def run(q, k, v):
         return _flash_forward(
             q, k, v, causal, block_q, block_k, interpret,
             return_stats=return_stats,
         )
 
-    fn = custom_partitioning(_lower)
-
-    def partition(mesh, arg_infos, result_infos):
-        sh = arg_infos[0].sharding
-        spec = sh.spec if sh is not None else P()
-        b_ax = spec[0] if len(spec) > 0 else None
-        h_ax = spec[2] if len(spec) > 2 else None
-        io = NamedSharding(mesh, P(b_ax, None, h_ax, None))
-        stat = NamedSharding(mesh, P(b_ax, h_ax, None))
-        out_sh = (io, stat, stat) if return_stats else io
-        return mesh, _lower, out_sh, (io, io, io)
-
-    rule_out = (
-        "b t h d, b h t, b h t" if return_stats else "b t h d"
-    )
-    fn.def_partition(
-        partition=partition,
-        sharding_rule=f"b t h d, b s h d, b s h d -> {rule_out}",
-        need_replication_factors=("t", "d", "s"),
-    )
-    return fn
+    out_dims = [_QKV_DIMS]
+    if return_stats:
+        out_dims += [_STAT_DIMS, _STAT_DIMS]
+    return over_mesh(run, in_dims=[_QKV_DIMS] * 3, out_dims=out_dims)
 
 
-@functools.lru_cache(maxsize=None)
-def _partitioned_flash_bwd(
-    causal: bool, block_q: int, block_k: int, interpret: bool
-):
-    """The fused backward under the same batch/head partitioning rule."""
-    from jax.experimental.custom_partitioning import custom_partitioning
-    from jax.sharding import NamedSharding, PartitionSpec as P
+def _sharded_flash_bwd(causal, block_q, block_k, interpret):
+    """The fused backward under the same batch/head split."""
 
-    def _lower(q, k, v, out, m, l, ct):
+    def run(q, k, v, out, m, l, ct):
         return _flash_backward_pallas(
             q, k, v, out, m, l, ct, causal, block_q, block_k, interpret
         )
 
-    fn = custom_partitioning(_lower)
-
-    def partition(mesh, arg_infos, result_infos):
-        sh = arg_infos[0].sharding
-        spec = sh.spec if sh is not None else P()
-        b_ax = spec[0] if len(spec) > 0 else None
-        h_ax = spec[2] if len(spec) > 2 else None
-        io = NamedSharding(mesh, P(b_ax, None, h_ax, None))
-        stat = NamedSharding(mesh, P(b_ax, h_ax, None))
-        return (
-            mesh,
-            _lower,
-            (io, io, io),
-            (io, io, io, io, stat, stat, io),
-        )
-
-    fn.def_partition(
-        partition=partition,
-        sharding_rule=(
-            "b t h d, b s h d, b s h d, b t h d, b h t, b h t, b t h d "
-            "-> b t h d, b s h d, b s h d"
-        ),
-        need_replication_factors=("t", "d", "s"),
+    return over_mesh(
+        run,
+        in_dims=[_QKV_DIMS] * 4 + [_STAT_DIMS] * 2 + [_QKV_DIMS],
+        out_dims=[_QKV_DIMS] * 3,
     )
-    return fn
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_vjp(q, k, v, causal, block_q, block_k, interpret):
-    return _partitioned_flash(causal, block_q, block_k, interpret)(q, k, v)
+    return _sharded_flash(causal, block_q, block_k, interpret)(q, k, v)
 
 
 def _fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, m, l = _partitioned_flash(
-        causal, block_q, block_k, interpret, True
+    out, m, l = _sharded_flash(
+        causal, block_q, block_k, interpret, return_stats=True
     )(q, k, v)
     # ``out`` joins the residuals (the backward needs D = rowsum(ct*out))
     # along with the softmax statistics the fused backward consumes.
@@ -612,7 +569,7 @@ def _bwd(causal, block_q, block_k, interpret, res, ct):
         return _chunked_attention_bwd(
             q, k, v, out, ct, causal, max(block_k, 128)
         )
-    return _partitioned_flash_bwd(causal, block_q, block_k, interpret)(
+    return _sharded_flash_bwd(causal, block_q, block_k, interpret)(
         q, k, v, out, m, l, ct
     )
 
@@ -628,24 +585,20 @@ def flash_attention(
     use_pallas: Optional[bool] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused attention over ``[batch, seq, heads, head_dim]``.
 
-    ``use_pallas=None`` auto-selects the kernel on any TPU backend (the
-    ``custom_partitioning`` wrapper splits it batch/head-wise on pod
-    meshes — same policy as :func:`~.interaction.dot_interaction`) and
-    the XLA dense reference elsewhere; ``interpret=True`` runs the
-    kernel in interpreter mode (CPU tests).
+    ``use_pallas=None`` auto-selects the kernel on any TPU backend (split
+    batch/head-wise over the context mesh — same policy as
+    :func:`~.interaction.dot_interaction`) and the XLA dense reference
+    elsewhere. A kernel that does not compile
+    raises. ``interpret=True`` runs the kernel in the Pallas interpreter;
+    only tests on the CPU set it, and it is never derived from the
+    backend.
     """
     if use_pallas is None:
-        from ray_shuffling_data_loader_tpu.ops.interaction import (
-            _auto_pallas,
-        )
-
-        use_pallas = _auto_pallas()
+        use_pallas = auto_pallas()
     if not use_pallas:
         return attention_reference(q, k, v, causal=causal)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return _flash_vjp(q, k, v, causal, block_q, block_k, interpret)

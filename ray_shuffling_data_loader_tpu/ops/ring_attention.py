@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_shuffling_data_loader_tpu.ops.placement import auto_pallas
+
 NEG_INF = -1e30  # finite "minus infinity": avoids NaN from (-inf) - (-inf)
 
 
@@ -119,12 +121,15 @@ def _ring_mask(s, i, me, p, tq, tk):
     return jnp.where(mask[None, None], s, NEG_INF)
 
 
-def _ring_fwd_local(q, k, v, axis_name, causal, use_flash=None):
+def _ring_fwd_local(
+    q, k, v, axis_name, causal, use_flash=None, interpret=False
+):
     """Forward ring pass; returns ``(out, m, l)`` — the softmax statistics
     ride out as residuals for the backward ring.
 
     ``use_flash`` routes each hop's local block compute through the fused
-    Pallas flash kernel (``None`` = auto: on for TPU backends). The hop
+    Pallas flash kernel (``None`` = auto: on for TPU backends;
+    ``interpret`` is the kernel's explicit CPU-test switch). The hop
     is exactly the kernel's computation; its emitted (m, l) statistics
     merge into the ring accumulator in float32. Causal hops classify by
     the chunk's position: below the diagonal = plain kernel, on the
@@ -138,7 +143,7 @@ def _ring_fwd_local(q, k, v, axis_name, causal, use_flash=None):
     scale = 1.0 / math.sqrt(d)
     qf = q.astype(jnp.float32) * scale
     if use_flash is None:
-        use_flash = _use_flash_auto()
+        use_flash = auto_pallas()
 
     perm = [(j, (j + 1) % p) for j in range(p)]
 
@@ -146,8 +151,6 @@ def _ring_fwd_local(q, k, v, axis_name, causal, use_flash=None):
         from ray_shuffling_data_loader_tpu.ops.flash_attention import (
             _flash_forward,
         )
-
-        interpret = jax.default_backend() != "tpu"
 
         def _partial(causal_block):
             def run(q_, k_, v_):
@@ -211,8 +214,10 @@ def _ring_fwd_local(q, k, v, axis_name, causal, use_flash=None):
     return _accum_finish(o, l, q.dtype), m, l
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _ring_attention_local(q, k, v, axis_name, causal, use_flash=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _ring_attention_local(
+    q, k, v, axis_name, causal, use_flash=None, interpret=False
+):
     """Per-device ring attention (runs inside ``shard_map``); q/k/v are
     the local sequence chunks ``[batch, chunk, heads, head_dim]``.
 
@@ -222,16 +227,22 @@ def _ring_attention_local(q, k, v, axis_name, causal, use_flash=None):
     with the shard like the forward (plain scan autodiff would save every
     hop's rotated K/V chunks and probability blocks: O(T) + O(T²/p) per
     device; the advisor flagged exactly this)."""
-    out, _, _ = _ring_fwd_local(q, k, v, axis_name, causal, use_flash)
+    out, _, _ = _ring_fwd_local(
+        q, k, v, axis_name, causal, use_flash, interpret
+    )
     return out
 
 
-def _ring_vjp_fwd(q, k, v, axis_name, causal, use_flash=None):
-    out, m, l = _ring_fwd_local(q, k, v, axis_name, causal, use_flash)
+def _ring_vjp_fwd(
+    q, k, v, axis_name, causal, use_flash=None, interpret=False
+):
+    out, m, l = _ring_fwd_local(
+        q, k, v, axis_name, causal, use_flash, interpret
+    )
     return out, (q, k, v, out, m, l)
 
 
-def _ring_vjp_bwd(axis_name, causal, use_flash, res, ct):
+def _ring_vjp_bwd(axis_name, causal, use_flash, interpret, res, ct):
     q, k, v, out, m, l = res
     p = lax.psum(1, axis_name)
     me = lax.axis_index(axis_name)
@@ -458,10 +469,8 @@ def _seq_parallel_jit(
     sequence dimension (and optionally the batch dimension along
     ``batch_axis`` — composes with data parallelism), run the per-device
     ``body`` under ``shard_map``, jit with matching in/out shardings."""
-    from ray_shuffling_data_loader_tpu.jax_compat import shard_map
-
     spec = P(batch_axis, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -479,6 +488,7 @@ def make_ring_attention(
     causal: bool = False,
     batch_axis: Optional[str] = None,
     use_flash: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """Build a jitted ring-attention over ``mesh``'s ``axis_name``.
 
@@ -497,7 +507,7 @@ def make_ring_attention(
         axis_name,
         # Positional call: custom_vjp nondiff args resolve by position.
         lambda q, k, v: _ring_attention_local(
-            q, k, v, axis_name, causal, use_flash
+            q, k, v, axis_name, causal, use_flash, interpret
         ),
         batch_axis=batch_axis,
     )
@@ -523,15 +533,6 @@ def ring_attention(
 # ---------------------------------------------------------------------------
 
 
-def _use_flash_auto() -> bool:
-    """Local-attention lowering policy for the sequence-parallel bodies:
-    the fused Pallas flash kernel on a TPU backend (safe inside
-    ``shard_map`` — the kernel is per-device, the collectives stay XLA's),
-    the XLA blockwise path elsewhere (CPU tests run it compiled rather
-    than paying kernel-interpret overhead)."""
-    return jax.default_backend() == "tpu"
-
-
 def _ulysses_local(
     q: jax.Array,
     k: jax.Array,
@@ -540,6 +541,7 @@ def _ulysses_local(
     causal: bool,
     kv_chunk: int,
     use_flash: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """Per-device body: one ``all_to_all`` each way redistributes
     sequence↔heads, so this device attends over the FULL sequence for
@@ -554,13 +556,15 @@ def _ulysses_local(
     kh = lax.all_to_all(k, axis_name, split_axis=2, concat_axis=1, tiled=True)
     vh = lax.all_to_all(v, axis_name, split_axis=2, concat_axis=1, tiled=True)
     if use_flash is None:
-        use_flash = _use_flash_auto()
+        use_flash = auto_pallas()
     if use_flash:
         from ray_shuffling_data_loader_tpu.ops.flash_attention import (
             flash_attention,
         )
 
-        out = flash_attention(qh, kh, vh, causal=causal, use_pallas=True)
+        out = flash_attention(
+            qh, kh, vh, causal=causal, use_pallas=True, interpret=interpret
+        )
     else:
         out = blockwise_attention(qh, kh, vh, causal=causal, kv_chunk=kv_chunk)
     # [B, T, H/p, D] -> [B, Tl, H, D]: back to sequence shards.
@@ -575,6 +579,7 @@ def make_ulysses_attention(
     kv_chunk: int = 1024,
     batch_axis: Optional[str] = None,
     use_flash: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """All-to-all (Ulysses-style) sequence-parallel attention over
     ``mesh``'s ``axis_name`` — the second canonical long-context
@@ -595,6 +600,7 @@ def make_ulysses_attention(
             causal=causal,
             kv_chunk=kv_chunk,
             use_flash=use_flash,
+            interpret=interpret,
         ),
         batch_axis=batch_axis,
     )

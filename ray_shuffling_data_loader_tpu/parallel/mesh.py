@@ -20,8 +20,10 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-DATA_AXIS = "data"
-MODEL_AXIS = "model"
+from ray_shuffling_data_loader_tpu.ops.placement import (  # noqa: F401
+    DATA_AXIS,
+    MODEL_AXIS,
+)
 
 # Embedding tables at least this tall get their vocab dim sharded across
 # MODEL_AXIS; everything smaller replicates.

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_shuffling_data_loader_tpu.ops.placement import traced_in_mesh
 from ray_shuffling_data_loader_tpu.parallel.mesh import (
     DATA_AXIS,
     batch_sharding,
@@ -88,7 +89,10 @@ def init_state(
         params=param_shardings(shapes.params, mesh, **kwargs),
         opt_state=param_shardings(shapes.opt_state, mesh, **kwargs),
     )
-    state = jax.jit(_init, out_shardings=shardings)(rng)
+    # The mesh is in context for the model's own forward pass at init, as
+    # it is for the train step (ops that split themselves over it).
+    init = jax.jit(traced_in_mesh(mesh, _init), out_shardings=shardings)
+    state = init(rng)
     return state, shardings
 
 
@@ -134,7 +138,7 @@ def make_train_step(
     ``JaxShufflingDataset``); XLA derives the gradient all-reduce.
     """
     batch_in = batch_sharding(mesh, 1)
-    step_fn = make_step_body(model, optimizer)
+    step_fn = traced_in_mesh(mesh, make_step_body(model, optimizer))
 
     return jax.jit(
         step_fn,
@@ -285,8 +289,6 @@ def make_psum_train_step(
     optimizer state; pass ``False`` to keep reusing the input state
     object after the call.
     """
-    from ray_shuffling_data_loader_tpu.jax_compat import shard_map
-
     if grad_reduce not in ("mean", "adasum"):
         raise ValueError(
             f"grad_reduce must be 'mean' or 'adasum', got {grad_reduce!r}"
@@ -324,7 +326,7 @@ def make_psum_train_step(
 
     batch_spec = P(DATA_AXIS)
     rep = P()
-    sharded = shard_map(
+    sharded = jax.shard_map(
         per_device_step,
         mesh=mesh,
         in_specs=(rep, batch_spec, batch_spec),

@@ -54,6 +54,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_shuffling_data_loader_tpu import runtime
 from ray_shuffling_data_loader_tpu.jax_dataset import HostToDeviceStats
+from ray_shuffling_data_loader_tpu.ops.placement import traced_in_mesh
 
 # Rows per H2D piece: large enough to amortize transfer round-trips,
 # small enough that the staging buffer (piece_rows x n_cols x 4 B,
@@ -157,22 +158,24 @@ def _file_metadata(filenames: Sequence[str]):
 
 
 def packed_nbytes(num_rows: int, num_feature_columns: int) -> int:
-    """HBM residency of the packed buffer: features + label, 4 B each."""
-    return (num_feature_columns + 1) * 4 * num_rows
+    """Device residency of the packed ``[features + label, rows]`` int32
+    buffer. The TPU lays its last two dimensions out in (8, 128) tiles,
+    so the column count is held rounded up to 8 sublanes: 20 columns
+    occupy 24 rows' worth of memory."""
+    sublanes = -(-(num_feature_columns + 1) // 8) * 8
+    return sublanes * 4 * num_rows
 
 
-def _probe_device_alloc(dev, nbytes: int) -> bool:
-    """Can the device hold ``nbytes`` right now? Allocates zeros
-    ON-DEVICE (a compiled fill — no host->device transfer, so the probe
-    is cheap even over a slow tunnel) and frees them on return."""
-    try:
-        with jax.default_device(dev):
-            x = jnp.zeros((max(1, nbytes),), jnp.uint8)
-            x.block_until_ready()
-        del x
-        return True
-    except Exception:
-        return False
+def _local_memory_stats() -> Optional[Tuple[int, int]]:
+    """``(smallest bytes_limit, largest bytes_in_use)`` over this process's
+    devices, or None where the backend keeps no such count (the CPU)."""
+    stats = [dev.memory_stats() for dev in jax.local_devices()]
+    if not all(s and s.get("bytes_limit") for s in stats):
+        return None
+    return (
+        min(int(s["bytes_limit"]) for s in stats),
+        max(int(s.get("bytes_in_use", 0)) for s in stats),
+    )
 
 
 def device_memory_budget(
@@ -180,61 +183,25 @@ def device_memory_budget(
 ) -> Tuple[Optional[int], bool]:
     """Memory budget for the resident buffer: ``(bytes, per_device)``.
 
-    TPU backends expose ``bytes_limit`` via ``memory_stats`` — a
-    PER-DEVICE figure, so an N-way batch-axis mesh holds N x that.
-    Backends that don't (CPU) fall back to a fraction of host RAM, which
-    is a TOTAL figure: virtual CPU "devices" all share the same RAM, so
+    Accelerators report ``bytes_limit`` through ``memory_stats`` — a
+    PER-DEVICE figure, so an N-way batch-axis mesh holds N x that. The
+    CPU backend reports nothing and gets a fraction of host RAM, which is
+    a TOTAL figure: virtual CPU "devices" all share the same RAM, so
     sharding buys no extra capacity (``per_device=False``). ``(None, _)``
-    means unknowable — callers should then not choose resident mode.
+    means unknown — an accelerator that will not say how much memory it
+    has gets no guess, and callers then do not choose resident mode.
     ``RSDL_RESIDENT_BUDGET_GB`` overrides everything, as a total.
     """
     env = os.environ.get("RSDL_RESIDENT_BUDGET_GB")
     if env:
         return int(float(env) * 1e9), False
-    try:
-        dev = jax.local_devices()[0]
-        platform = dev.platform
-    except Exception:
+    stats = _local_memory_stats()
+    if stats is not None:
+        return int(budget_frac * stats[0]), True
+    if jax.local_devices()[0].platform != "cpu":
         return None, False
-    try:
-        # memory_stats can RAISE (not just return empty) on experimental
-        # PJRT plugins; the platform-specific fallbacks below must still
-        # fire in that case.
-        stats = dev.memory_stats() or {}
-        limit = int(stats.get("bytes_limit", 0))
-        if limit > 0:
-            return int(budget_frac * limit), True
-    except Exception:
-        pass
-    if platform == "tpu":
-        # Some TPU plugins (e.g. tunneled/experimental ones) expose no
-        # memory_stats. Refusing outright would silently bench the
-        # slower loader on exactly the hardware the resident mode
-        # targets; assume the v5e-class 16 GB HBM floor — then VERIFY it
-        # with a staged on-device allocation so a smaller-HBM part walks
-        # the budget down instead of OOMing mid-staging (ADVICE r3).
-        # RSDL_TPU_HBM_GB overrides and skips the probe. Mis-admission
-        # remains survivable (bench.py failover), but library callers
-        # get the probed figure.
-        env_hbm = os.environ.get("RSDL_TPU_HBM_GB")
-        if env_hbm:
-            return int(budget_frac * float(env_hbm) * 1e9), True
-        budget = int(budget_frac * 16e9)
-        for _ in range(3):
-            if _probe_device_alloc(dev, budget):
-                return budget, True
-            budget //= 2
-        return None, False
-    if platform != "cpu":
-        # A non-TPU accelerator that won't report its memory limit gets
-        # no guess: host RAM says nothing about device memory, and an
-        # over-admitted resident buffer OOMs the device mid-staging.
-        return None, False
-    try:
-        ram = os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
-        return int(budget_frac * ram), False
-    except (ValueError, OSError):
-        return None, False
+    ram = os.sysconf("SC_PHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+    return int(budget_frac * ram), False
 
 
 def fits_device(
@@ -298,26 +265,18 @@ def _fits_device_local(
     num_rows: Optional[int] = None,
 ) -> bool:
     # The mode's entire win is device memory being faster than host
-    # memory. On the CPU backend the "device" IS host RAM (and XLA-CPU
-    # gathers are slow), so auto mode measured ~3x SLOWER than the host
-    # map/reduce pipeline there (BENCHLOG 2026-07-30). Auto therefore
-    # requires a real accelerator; setting RSDL_RESIDENT_BUDGET_GB (or
-    # constructing DeviceResidentShufflingDataset directly) opts in
-    # anyway.
-    try:
-        platform = jax.local_devices()[0].platform
-    except Exception:
-        return False
+    # memory. On the CPU backend the "device" IS host RAM and XLA-CPU
+    # gathers are slow, so auto requires a real accelerator; setting
+    # RSDL_RESIDENT_BUDGET_GB (or constructing
+    # DeviceResidentShufflingDataset directly) opts in anyway.
+    platform = jax.local_devices()[0].platform
     if platform == "cpu" and not os.environ.get("RSDL_RESIDENT_BUDGET_GB"):
         return False
     budget, per_device = device_memory_budget(budget_frac)
     if budget is None:
         return False
     if num_rows is None:
-        try:
-            num_rows = dataset_num_rows(filenames)
-        except Exception:
-            return False
+        num_rows = dataset_num_rows(filenames)
     # Sharding only multiplies capacity when each device has its own
     # memory; virtual CPU devices share one host RAM.
     shards = (
@@ -766,26 +725,23 @@ class DeviceResidentShufflingDataset:
         # per epoch — so this trades transient memory for dispatch
         # latency and access locality.
         if self._materialize is None:
-            ncols = len(self._columns)
             data_shards = max(1, self.mesh.shape.get(self.batch_axis, 1))
-            per_device_copy = ncols * 4 * self._padded_rows // data_shards
-            limit = in_use = 0
-            try:
-                dstats = jax.local_devices()[0].memory_stats() or {}
-                limit = int(dstats.get("bytes_limit", 0))
-                in_use = int(dstats.get("bytes_in_use", 0))
-            except Exception:
-                pass
-            if limit > 0:
+            copy_bytes = packed_nbytes(
+                self._padded_rows, len(self._feature_columns)
+            )
+            stats = _local_memory_stats()
+            if stats is not None:
                 # Real accounting: bytes_in_use already includes the
                 # staged buffer AND whatever model/optimizer state the
                 # trainer holds, so the epoch copy is the only increment.
-                decision = in_use + per_device_copy <= 0.75 * limit
+                limit, in_use = stats
+                decision = in_use + copy_bytes // data_shards <= 0.75 * limit
             else:
                 budget, per_device = device_memory_budget(budget_frac=0.75)
                 shards = data_shards if per_device else 1
-                need = 2 * ncols * 4 * self._padded_rows / shards
-                decision = budget is not None and need <= budget
+                decision = (
+                    budget is not None and 2 * copy_bytes / shards <= budget
+                )
             if jax.process_count() > 1:
                 # Multi-controller: the two schedules issue DIFFERENT
                 # collectives, so every process must pick the same one.
@@ -1034,10 +990,9 @@ def make_fused_epoch(
     each epoch's permutation) living in device memory, the entire epoch —
     per-batch slice, bitcast unpack, and the training step — compiles to a
     single ``lax.scan``. One dispatch per epoch replaces one (or more)
-    host round-trips per batch, which on high-latency links (a tunneled
-    chip; any remote dispatch path) is the dominant delivery cost. No
-    host-side loader can do this; it is the device-resident analog of the
-    reference's tightest possible consumption loop.
+    host round-trips per batch. No host-side loader can do this; it is
+    the device-resident analog of the reference's tightest possible
+    consumption loop.
 
     ``step_body(state, features, label) -> (state, metrics)`` is the
     UNJITTED per-batch step (e.g. the body of
@@ -1099,7 +1054,8 @@ def make_fused_epoch(
             return jax.lax.scan(body, state, xs)
 
         fused = jax.jit(
-            run_epoch, donate_argnums=(0,) if donate_state else ()
+            traced_in_mesh(ds.mesh, run_epoch),
+            donate_argnums=(0,) if donate_state else (),
         )
         xs_cache = ds._fused_xs_cache
 
@@ -1143,7 +1099,10 @@ def make_fused_epoch(
 
         return jax.lax.scan(body, state, jnp.arange(full, dtype=jnp.int32))
 
-    fused = jax.jit(run_epoch, donate_argnums=(0,) if donate_state else ())
+    fused = jax.jit(
+        traced_in_mesh(ds.mesh, run_epoch),
+        donate_argnums=(0,) if donate_state else (),
+    )
 
     def run(state, epoch: int):
         ds._check_open()
@@ -1198,7 +1157,8 @@ def _run_gather_fused(ds, step_body, donate_state, state, epoch):
             )
 
         fn = jax.jit(
-            run_epoch, donate_argnums=(0,) if donate_state else ()
+            traced_in_mesh(ds.mesh, run_epoch),
+            donate_argnums=(0,) if donate_state else (),
         )
         ds._gather_cache[key] = (step_body, fn)
     state, losses = fn(state, ds._buf, ds._perm(epoch))

@@ -46,6 +46,7 @@ from . import transport
 # imported by merely importing the actor layer.
 from ray_shuffling_data_loader_tpu._lazy import lazy_module
 from ray_shuffling_data_loader_tpu.telemetry import _env
+from ray_shuffling_data_loader_tpu.utils.platform import spawn_environ
 
 faults = lazy_module("ray_shuffling_data_loader_tpu.runtime.faults")
 from .retry import call_policy, connect_policy
@@ -838,7 +839,10 @@ def spawn_actor(
         ),
         daemon=daemon,
     )
-    proc.start()
+    # Actors are host-side services; the process that spawns them owns
+    # the chip, so none may initialize a TPU backend.
+    with spawn_environ({"JAX_PLATFORMS": "cpu"}):
+        proc.start()
     # Readiness handshake with two escapes beyond the mp.Queue message:
     # (a) the registry file the child atomically writes just before its
     #     ready_q.put — observed once (2026-07-31): the child was up and

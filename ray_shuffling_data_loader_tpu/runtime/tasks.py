@@ -22,6 +22,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from ray_shuffling_data_loader_tpu import telemetry
 from ray_shuffling_data_loader_tpu._lazy import lazy_module
+from ray_shuffling_data_loader_tpu.utils.platform import spawn_environ
 
 # Fault-injection plane (ISSUE 14 gate-integrity): lazy proxy — the
 # plane's module body runs only when a worker actually starts, never
@@ -246,10 +247,9 @@ def _flush_telemetry_spools() -> None:
             pass
 
 
-def _worker_main(task_q, result_q, env: Dict[str, str]):
+def _worker_main(task_q, result_q):
     import pickle
 
-    os.environ.update(env)
     pid = os.getpid()
     # Unconditional: the role tag is process IDENTITY — the telemetry
     # spools (events/metrics source records) stamp it, not just
@@ -363,21 +363,12 @@ class WorkerPool:
         self._mp_ctx = ctx
         self._task_q = ctx.Queue()
         self._result_q = ctx.Queue()
-        env = dict(env or {})
-        # Workers are CPU-side shuffle executors; keep them off the TPU.
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        self._env = env
+        # Workers are CPU-side shuffle executors and the driver owns the
+        # chip: JAX_PLATFORMS=cpu, like the rest of ``env``, is in each
+        # worker's environment from its first instruction.
+        self._env = {**(env or {}), "JAX_PLATFORMS": "cpu"}
         self._procs_lock = threading.Lock()
-        self._procs = [
-            ctx.Process(
-                target=_worker_main,
-                args=(self._task_q, self._result_q, env),
-                daemon=True,
-            )
-            for _ in range(num_workers)
-        ]
-        for p in self._procs:
-            p.start()
+        self._procs = self._start_workers(num_workers)
         self._futures: Dict[int, TaskFuture] = {}
         self._futures_lock = threading.Lock()
         self._running_on: Dict[int, int] = {}  # task_id -> worker pid
@@ -405,6 +396,20 @@ class WorkerPool:
                 )
             except Exception:
                 pass
+
+    def _start_workers(self, n: int) -> list:
+        procs = [
+            self._mp_ctx.Process(
+                target=_worker_main,
+                args=(self._task_q, self._result_q),
+                daemon=True,
+            )
+            for _ in range(n)
+        ]
+        with spawn_environ(self._env):
+            for p in procs:
+                p.start()
+        return procs
 
     def _collect(self):
         while True:
@@ -481,16 +486,7 @@ class WorkerPool:
         single-host scale-up actuator). Returns the new pool size."""
         if self._closed or n <= 0:
             return self.num_workers
-        procs = [
-            self._mp_ctx.Process(
-                target=_worker_main,
-                args=(self._task_q, self._result_q, self._env),
-                daemon=True,
-            )
-            for _ in range(int(n))
-        ]
-        for p in procs:
-            p.start()
+        procs = self._start_workers(int(n))
         with self._procs_lock:
             self._procs.extend(procs)
             self.num_workers = self.width = len(self._procs)

@@ -1,10 +1,10 @@
-"""Shared utilities: platform pinning, wall-clock timing, path kinds."""
+"""Shared utilities: JAX process placement, wall-clock timing, path kinds."""
 
 import os
 
 from ray_shuffling_data_loader_tpu.utils.platform import (  # noqa: F401
-    force_platform_from_env,
-    pin_platform,
+    enable_compile_cache,
+    spawn_environ,
 )
 from ray_shuffling_data_loader_tpu.utils.timing import timer  # noqa: F401
 
@@ -183,11 +183,11 @@ __all__ = [
     "arrow_decode_threads",
     "decode_rowgroup_threads",
     "decode_use_threads",
-    "force_platform_from_env",
+    "enable_compile_cache",
     "is_remote_path",
     "parquet_filesystem",
-    "pin_platform",
     "shuffle_plan_label",
     "shuffle_plan_spec",
+    "spawn_environ",
     "timer",
 ]

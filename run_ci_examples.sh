@@ -18,7 +18,7 @@ python examples/train_dlrm_multirank.py --num-trainers 2 \
     --num-rows 50000 --num-files 4 --batch-size 5000 --epochs 2
 python -m ray_shuffling_data_loader_tpu.dataset
 python -m ray_shuffling_data_loader_tpu.torch_dataset
-python examples/train_dlrm_pod.py --simulate-pod 2 --platform cpu \
+python examples/train_dlrm_pod.py --simulate-pod 2 \
     --num-rows 30000 --num-files 8 --batch-size 3000 --epochs 1 \
     --rendezvous-dir "$(mktemp -d)"
 python __graft_entry__.py 8

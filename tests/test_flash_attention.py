@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jax_compat import needs_kernel_partitioning_apis
 
 from ray_shuffling_data_loader_tpu.ops import attention_reference
 from ray_shuffling_data_loader_tpu.ops.flash_attention import flash_attention
@@ -30,7 +29,6 @@ def _qkv(shape, seed=0, dtype=jnp.float32):
         ((2, 8, 1, 4), (128, 128)),  # seq smaller than the block
     ],
 )
-@needs_kernel_partitioning_apis
 def test_matches_dense_reference(causal, shape, blocks):
     q, k, v = _qkv(shape, seed=1)
     got = flash_attention(
@@ -49,7 +47,6 @@ def test_matches_dense_reference(causal, shape, blocks):
     )
 
 
-@needs_kernel_partitioning_apis
 def test_bfloat16(seed=3):
     q, k, v = _qkv((2, 32, 2, 8), seed=seed, dtype=jnp.bfloat16)
     got = flash_attention(
@@ -65,7 +62,6 @@ def test_bfloat16(seed=3):
     )
 
 
-@needs_kernel_partitioning_apis
 def test_gradients_exact():
     """The custom VJP is the dense reference's gradient — exact."""
     q, k, v = _qkv((1, 32, 2, 8), seed=4)
@@ -90,7 +86,6 @@ def test_gradients_exact():
         )
 
 
-@needs_kernel_partitioning_apis
 @pytest.mark.parametrize("causal", [False, True])
 def test_gradients_multi_chunk_ragged(causal):
     """Backward with several KV chunks and a ragged tail (T=300 over
@@ -117,11 +112,11 @@ def test_gradients_multi_chunk_ragged(causal):
         )
 
 
-@needs_kernel_partitioning_apis
 def test_gradients_sharded_mesh():
-    """Forward AND fused backward under a multi-device pjit: the
-    custom_partitioning wrappers split both pallas calls batch-wise on
-    the 8-device mesh; gradients match the dense reference."""
+    """Forward AND fused backward in a multi-device jit with the mesh in
+    context: ``shard_map`` splits both pallas calls batch-wise on the
+    8-device mesh (each device's kernel sees batch 1, not 8); gradients
+    match the dense reference."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()), ("data",))
@@ -137,7 +132,10 @@ def test_gradients_sharded_mesh():
             ** 2
         )
 
-    g_f = jax.jit(jax.grad(loss_flash, (0, 1, 2)))(qs, ks, vs)
+    with jax.set_mesh(mesh):
+        grad_fn = jax.jit(jax.grad(loss_flash, (0, 1, 2)))
+        assert "f32[1,64,2,8]" in str(jax.make_jaxpr(grad_fn)(qs, ks, vs))
+        g_f = grad_fn(qs, ks, vs)
     g_d = jax.grad(
         lambda q, k, v: jnp.sum(
             attention_reference(q, k, v, causal=True) ** 2
@@ -150,7 +148,6 @@ def test_gradients_sharded_mesh():
         )
 
 
-@needs_kernel_partitioning_apis
 def test_flash_backward_xla_escape_hatch(monkeypatch):
     """RSDL_FLASH_BWD=xla routes the VJP through the chunked-XLA
     backward; gradients stay exact."""

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jax_compat import needs_toplevel_shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_shuffling_data_loader_tpu.models import (
@@ -54,7 +53,6 @@ def test_forward_contract_and_causality():
     )
 
 
-@needs_toplevel_shard_map
 def test_sequence_parallel_matches_dense():
     """Same params under the dp x sp ring schedule and the dense lowering."""
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "sp"))

@@ -182,13 +182,15 @@ def run(queue_name, force_percol):
         rank=rank,
         feature_columns=["key", "embeddings_name0"],
         label_column="labels",
+        # A declared shape makes a column unpackable, which is what sends
+        # a batch down the per-column path; rows are compared flattened.
+        feature_shapes=[(1,), (1,)] if force_percol else None,
+        label_shape=(1,) if force_percol else None,
         num_reducers=2,
         seed=7,
         mesh=mesh,
         queue_name=queue_name,
     )
-    if force_percol:
-        ds._packed_ok = False
     ds.set_epoch(0)
     before = counter["n"]
     rows = []

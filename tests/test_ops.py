@@ -5,7 +5,6 @@ the custom VJP, and jit/vmap composition."""
 import numpy as np
 import pytest
 
-from jax_compat import needs_sharding_rule
 
 import jax
 import jax.numpy as jnp
@@ -41,7 +40,6 @@ def test_reference_matches_manual():
     )
 
 
-@needs_sharding_rule
 @pytest.mark.parametrize("b,block", [(8, 8), (10, 4), (3, 256)])
 def test_pallas_forward_parity(b, block):
     """Kernel (interpreted) == reference, including ragged tail tiles."""
@@ -55,7 +53,6 @@ def test_pallas_forward_parity(b, block):
     )
 
 
-@needs_sharding_rule
 def test_pallas_grad_matches_reference():
     x = _rand(6, 5, 8, seed=42)
 
@@ -77,7 +74,6 @@ def test_pallas_grad_matches_reference():
     )
 
 
-@needs_sharding_rule
 def test_pallas_under_jit():
     x = _rand(5, 6, 4, seed=7)
 

@@ -215,10 +215,19 @@ def test_close_invalidates_live_iterator(local_runtime, resident_files):
 def test_stats_accounting(local_runtime, resident_files):
     ds = _make(resident_files)
     # Features + label, 4 bytes per value, every real row staged once.
-    assert ds.stats.bytes_staged == packed_nbytes(NUM_ROWS, len(FEATURES))
+    assert ds.stats.bytes_staged == (len(FEATURES) + 1) * 4 * NUM_ROWS
     ds.set_epoch(0)
     n = sum(1 for _ in ds)
     assert ds.stats.batches_staged == n
+
+
+def test_packed_nbytes_counts_sublane_padding():
+    """Device residency is counted as the chip lays the buffer out: the
+    column count rounded up to 8 sublanes (20 columns hold 24 rows' worth),
+    so that what ``fits_device`` admits does not then exhaust HBM."""
+    assert packed_nbytes(1000, 19) == 24 * 4 * 1000  # 20 columns -> 24
+    assert packed_nbytes(1000, 7) == 8 * 4 * 1000  # exactly one tile
+    assert packed_nbytes(1000, 8) == 16 * 4 * 1000
 
 
 def test_range_decode(local_runtime, resident_files):

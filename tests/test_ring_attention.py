@@ -11,10 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jax_compat import (
-    needs_kernel_partitioning_apis,
-    needs_toplevel_shard_map,
-)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_shuffling_data_loader_tpu.ops import (
@@ -41,7 +37,6 @@ def _qkv(seed=0, dtype=jnp.float32):
     return mk(), mk(), mk()
 
 
-@needs_toplevel_shard_map
 @pytest.mark.parametrize("causal", [False, True])
 def test_matches_dense_reference(seq_mesh, causal):
     q, k, v = _qkv()
@@ -55,7 +50,6 @@ def test_matches_dense_reference(seq_mesh, causal):
     assert got.sharding.spec == (None, SEQ_AXIS, None, None)
 
 
-@needs_toplevel_shard_map
 def test_gradients_match_dense(seq_mesh):
     q, k, v = _qkv(seed=1)
     ring = make_ring_attention(seq_mesh, SEQ_AXIS, causal=True)
@@ -74,7 +68,6 @@ def test_gradients_match_dense(seq_mesh):
         )
 
 
-@needs_toplevel_shard_map
 def test_gradients_match_dense_noncausal(seq_mesh):
     """The custom ring VJP's non-causal branch (no mask recompute)."""
     q, k, v = _qkv(seed=7)
@@ -92,7 +85,6 @@ def test_gradients_match_dense_noncausal(seq_mesh):
         )
 
 
-@needs_kernel_partitioning_apis
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_flash_hops_match(seq_mesh, causal):
     """Ring with per-hop compute forced through the flash kernel
@@ -101,7 +93,7 @@ def test_ring_flash_hops_match(seq_mesh, causal):
     dense reference."""
     q, k, v = _qkv(seed=10)
     ring = make_ring_attention(
-        seq_mesh, SEQ_AXIS, causal=causal, use_flash=True
+        seq_mesh, SEQ_AXIS, causal=causal, use_flash=True, interpret=True
     )
     got = ring(q, k, v)
     want = attention_reference(q, k, v, causal=causal)
@@ -123,7 +115,6 @@ def test_ring_flash_hops_match(seq_mesh, causal):
         )
 
 
-@needs_kernel_partitioning_apis
 def test_ulysses_flash_local_matches(seq_mesh):
     """Ulysses with the local body forced through the flash kernel
     (interpret mode on CPU) — the TPU lowering's exactness, fwd + grad."""
@@ -134,7 +125,7 @@ def test_ulysses_flash_local_matches(seq_mesh):
         for _ in range(3)
     )
     fn = make_ulysses_attention(
-        seq_mesh, SEQ_AXIS, causal=True, use_flash=True
+        seq_mesh, SEQ_AXIS, causal=True, use_flash=True, interpret=True
     )
     got = fn(q, k, v)
     want = attention_reference(q, k, v, causal=True)
@@ -184,7 +175,6 @@ def test_blockwise_gradients_match_dense():
         )
 
 
-@needs_toplevel_shard_map
 def test_bfloat16_inputs(seq_mesh):
     q, k, v = _qkv(seed=2, dtype=jnp.bfloat16)
     ring = make_ring_attention(seq_mesh, SEQ_AXIS)
@@ -199,7 +189,6 @@ def test_bfloat16_inputs(seq_mesh):
     )
 
 
-@needs_toplevel_shard_map
 @pytest.mark.parametrize("causal", [False, True])
 def test_ulysses_matches_dense_reference(seq_mesh, causal):
     """The all-to-all strategy: exact for any mask (full T per device),
@@ -238,7 +227,6 @@ def test_blockwise_matches_dense(causal, kv_chunk):
     )
 
 
-@needs_toplevel_shard_map
 def test_ulysses_gradients_match_dense(seq_mesh):
     rng = np.random.default_rng(5)
     shape = (1, 32, 8, 4)
@@ -262,7 +250,6 @@ def test_ulysses_gradients_match_dense(seq_mesh):
         )
 
 
-@needs_toplevel_shard_map
 def test_respects_presharded_inputs(seq_mesh):
     """Feeding already-sequence-sharded arrays works and keeps shards."""
     q, k, v = _qkv(seed=3)

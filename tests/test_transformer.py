@@ -8,7 +8,6 @@ import numpy as np
 import optax
 import pytest
 
-from jax_compat import needs_toplevel_shard_map
 from jax.sharding import Mesh
 
 from ray_shuffling_data_loader_tpu.models import (
@@ -68,7 +67,6 @@ def test_sharded_train_step_loss_decreases():
     assert table.sharding.spec[0] == "model"
 
 
-@needs_toplevel_shard_map
 def test_ring_attention_encoder_matches_dense():
     """The same params run with dense vs ring attention must agree: the
     sequence-parallel path changes the schedule, not the math."""

@@ -1,15 +1,10 @@
 #!/usr/bin/env bash
-# Idle-host race hunt: widened-seed stress soaks + deep hypothesis runs,
-# yielding to any in-flight TPU capture between iterations (the capture
-# owns the core; see tpu_watch.sh). Usage: tools/run_soak.sh [iterations]
+# Idle-host race hunt: widened-seed stress soaks + deep hypothesis runs.
+# Usage: tools/run_soak.sh [iterations]
 set -u
 cd "$(dirname "$0")/.."
 ITER=${1:-5}
 for i in $(seq 1 "$ITER"); do
-  while [ -e tools/CAPTURE_IN_PROGRESS ]; do
-    echo "[soak] TPU capture in progress; standing by"
-    sleep 60
-  done
   echo "[soak] iteration $i/$ITER ($(date -u +%FT%TZ))"
   RSDL_STRESS_SEEDS=$((3 + i * 3)) python -m pytest tests/test_stress.py -q \
     2>&1 | tail -1

@@ -37,27 +37,6 @@ for T in 4 8 16; do
       echo "[sweep] $TAG already recorded; skipping"
       continue
     fi
-    # Yield the single core to any in-flight TPU capture: a concurrent
-    # sweep would distort the on-chip stall% artifact. (The reverse
-    # direction — a window opening mid-trial — is handled by the watch
-    # loop preempting the trial; see tpu_watch.sh.) A lock whose watcher
-    # PID is gone is stale (SIGKILL skips the EXIT trap) and is removed.
-    while [ -e tools/CAPTURE_IN_PROGRESS ]; do
-      wpid=$(cat tools/CAPTURE_IN_PROGRESS 2>/dev/null || echo "")
-      # Stale only if the watcher is gone AND no capture child survived
-      # it (a SIGKILLed watcher orphans its bench.py or TPU pytest
-      # stage, which keeps the core busy; clearing the lock then would
-      # defeat the exclusion).
-      if [ -n "$wpid" ] && ! kill -0 "$wpid" 2>/dev/null \
-          && ! pgrep -f "python bench.py" >/dev/null 2>&1 \
-          && ! pgrep -f "test_ops_tpu" >/dev/null 2>&1; then
-        echo "[sweep] stale capture lock (pid $wpid gone); clearing"
-        rm -f tools/CAPTURE_IN_PROGRESS
-        break
-      fi
-      echo "[sweep] TPU capture in progress; waiting ($(date -u +%FT%TZ))"
-      sleep 60
-    done
     echo "[sweep] trainers=$T reducers=$R ($(date -u +%FT%TZ))"
     python benchmarks/benchmark.py \
       --num-rows "$ROWS" --num-files "$FILES" \
