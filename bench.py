@@ -673,10 +673,10 @@ def run_bench(platform: str, num_chips: int):
 
     from ray_shuffling_data_loader_tpu.stats import TrialStatsCollector
 
-    # Both loaders report through the same collector vocabulary; the
-    # resident loader maps its stages onto it (map = epoch permutation,
-    # reduce = epoch materialization/gather, consume = batch delivery),
-    # with one map and one reduce per epoch.
+    # Only the map/reduce loader reports to the collector; the resident
+    # loader runs no stage tasks per epoch and takes no collector (its
+    # hand-over is the ``resident:handover`` span and the trace's
+    # programs), so its trial rows hold the trainer-side columns alone.
     collector = runtime.spawn_actor(
         TrialStatsCollector,
         NUM_EPOCHS,
@@ -705,7 +705,6 @@ def run_bench(platform: str, num_chips: int):
                 progress_cb=lambda: last_progress.__setitem__(
                     0, time.monotonic()
                 ),
-                stats_collector=collector,
             )
         return JaxShufflingDataset(
             filenames,
@@ -878,8 +877,9 @@ def run_bench(platform: str, num_chips: int):
     # wall-clock stage windows and mean task durations per epoch.
     phase = {}
     try:
-        # Resident runs report permutation/materialization through the
-        # same map/reduce event names, so this covers both loaders.
+        # The map/reduce loader's stage events; the resident loader runs
+        # no stage tasks per epoch (its hand-over is the
+        # ``resident:handover`` span and the trace's programs).
         epochs = collector.call("snapshot").epochs
         if epochs:
             phase = {
@@ -969,7 +969,13 @@ def run_bench(platform: str, num_chips: int):
         # map/reduce loader: time to the first delivered batch.
         "first_batch_s": round(stats.get("first_batch_s", 0.0), 2),
         "peak_hbm_gb": round(
-            stats.get("peak_device_bytes_in_use", 0) / 1e9, 3
+            max(
+                (
+                    (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                    for d in jax.local_devices()
+                ),
+                default=0,
+            ) / 1e9, 3
         ),
         "peak_shm_gb": round(sampler.peak_bytes / 1e9, 3),
         "peak_spill_gb": round(sampler.peak_spill_bytes / 1e9, 3),

@@ -19,16 +19,29 @@ result. ``--rehearse-on-cpu`` walks the same phases at a toy size with the
 kernels in the Pallas interpreter, to debug the script itself; every line
 it prints says so and it prints no result either.
 
+``--trace-out <file>`` runs the streaming epochs and the resident loader's
+per-batch epochs under a JAX profiler session with ``RSDL_TRACE`` on in
+every process, and writes ONE Chrome trace (open it at
+https://ui.perfetto.dev): the spans of the driver, the pool workers and
+the actors, and the device's programs and operations, all on the wall
+clock (``telemetry.trace_export(path, xplane=...)``). It then checks the
+order that file has to show: an epoch's first ``reduce`` ends before its
+first ``stage:h2d`` begins, and every batch's ``stage:transfer`` ends
+before the ``jit_step_fn`` that consumes it does.
+
 All ``jax`` imports sit inside ``main()``: ``runtime.init()`` spawns
 workers that re-import ``__main__``.
 """
 
 from __future__ import annotations
 
+import argparse
+import glob
 import json
 import os
 import shutil
 import sys
+import tempfile
 import time
 
 SEED = 0
@@ -42,11 +55,76 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 DATA_DIR = os.path.join(HERE, "smoke_data", "chip_smoke")
 
 
+def check_merged_trace(path: str, say) -> None:
+    """The order one clock has to show, read back from the merged file."""
+    with open(path) as f:
+        events = [e for e in json.load(f)["traceEvents"] if e["ph"] == "X"]
+
+    def named(name):
+        """The program's own spans of that name (the xplane's host plane
+        holds the live ones a second time, without their args)."""
+        return sorted(
+            (
+                e for e in events
+                if e["name"] == name and e.get("cat") != "xplane"
+            ),
+            key=lambda e: e["ts"],
+        )
+
+    def end(e):
+        return e["ts"] + e["dur"]
+
+    reduces, staged = named("reduce"), named("stage:h2d")
+    assert reduces and staged, (len(reduces), len(staged))
+    assert len({e["pid"] for e in reduces} - {os.getpid()}) >= 1, (
+        "no reduce span from a pool worker"
+    )
+    for epoch in sorted({e["args"]["epoch"] for e in staged}):
+        first_reduce = min(
+            end(e) for e in reduces if e["args"].get("epoch") == epoch
+        )
+        first_h2d = min(
+            e["ts"] for e in staged if e["args"]["epoch"] == epoch
+        )
+        assert first_reduce <= first_h2d, (epoch, first_reduce, first_h2d)
+    transfers = sorted(
+        named("stage:transfer"),
+        key=lambda e: (e["args"]["epoch"], e["args"]["batch"]),
+    )
+    assert len(transfers) == len(staged), (len(transfers), len(staged))
+    steps = [
+        e for e in events
+        if e.get("cat") == "xplane" and e["name"].startswith("jit_step_fn")
+    ]
+    steps.sort(key=lambda e: e["ts"])
+    say(
+        f"  merged trace {path}: {len(events)} spans from "
+        f"{len({e['pid'] for e in events})} processes and planes, "
+        f"{len(reduces)} reduce, {len(transfers)} stage:transfer, "
+        f"{len(steps)} jit_step_fn"
+    )
+    if not steps:
+        # The CPU rehearsal's xplane has no device plane.
+        say("  no device programs in the trace: step order not checked")
+        return
+    assert len(steps) >= len(transfers), (len(steps), len(transfers))
+    for transfer, step in zip(transfers, steps):
+        assert end(transfer) <= end(step), (transfer, step)
+    say(
+        "  one clock: every epoch's first reduce ended before its first "
+        "stage:h2d began, every stage:transfer before its step ended"
+    )
+
+
 def main(argv) -> int:
-    rehearse = argv == ["--rehearse-on-cpu"]
-    if argv and not rehearse:
-        print(f"usage: {sys.argv[0]} [--rehearse-on-cpu]", file=sys.stderr)
-        return 2
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--rehearse-on-cpu", action="store_true")
+    ap.add_argument(
+        "--trace-out", default=None,
+        help="write one merged Chrome trace of spans and device operations",
+    )
+    args = ap.parse_args(argv)
+    rehearse = args.rehearse_on_cpu
     tag = "[chip_smoke]"
     if rehearse:
         tag = "[chip_smoke CPU REHEARSAL - not a chip run]"
@@ -85,7 +163,7 @@ def main(argv) -> int:
     import numpy as np
     import optax
 
-    from ray_shuffling_data_loader_tpu import native, runtime
+    from ray_shuffling_data_loader_tpu import native, runtime, telemetry
     from ray_shuffling_data_loader_tpu.data_generation import (
         DATA_SPEC,
         KEY_COLUMN,
@@ -232,6 +310,29 @@ def main(argv) -> int:
     feature_columns = [*model_columns, KEY_COLUMN]
     mesh = make_mesh()  # every local device on the data axis
 
+    trace_dir = None
+    if args.trace_out:
+        # Before runtime.init(): the workers and actors inherit the switch
+        # and the spool directory through their environment.
+        trace_dir = tempfile.mkdtemp(prefix="chip-smoke-trace-")
+        telemetry.enable(os.path.join(trace_dir, "spool"))
+        telemetry.set_process_name("chip-smoke-driver")
+    in_session = False
+
+    def start_session():
+        nonlocal in_session
+        if trace_dir:
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
+            in_session = True
+
+    def stop_session():
+        nonlocal in_session
+        if in_session:
+            jax.profiler.stop_trace()
+            in_session = False
+
     runtime.init()
     ctx = runtime.get_context()
     try:
@@ -309,6 +410,8 @@ def main(argv) -> int:
                 f"loss {vals[0]:.4f} -> {vals[-1]:.4f}"
             )
 
+        start_session()
+
         # -- streaming ------------------------------------------------------
         @phase("streaming")
         def _streaming():
@@ -372,6 +475,7 @@ def main(argv) -> int:
                 check_epoch(keys, f"resident epoch {epoch}")
                 streams.append(np.concatenate(keys))
             check_losses("resident per batch", since)
+            stop_session()
 
             body = make_step_body(model, optimizer)
 
@@ -452,8 +556,21 @@ def main(argv) -> int:
         store = runtime.store_stats()
         assert store.num_objects == 0, f"store not empty: {store}"
     finally:
+        stop_session()
         runtime.shutdown()
         shutil.rmtree(DATA_DIR, ignore_errors=True)
+    if trace_dir:
+        xplane = sorted(
+            glob.glob(
+                os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+            )
+        )[-1]
+        os.makedirs(
+            os.path.dirname(os.path.abspath(args.trace_out)), exist_ok=True
+        )
+        telemetry.trace_export(args.trace_out, xplane=xplane)
+        check_merged_trace(args.trace_out, say)
+        shutil.rmtree(trace_dir, ignore_errors=True)
     leaked = [
         f
         for f in os.listdir(ctx.store.shm_dir)

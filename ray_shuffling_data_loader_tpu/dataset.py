@@ -25,7 +25,7 @@ import os
 import threading
 from typing import Iterator, List, Optional
 
-from ray_shuffling_data_loader_tpu import runtime
+from ray_shuffling_data_loader_tpu import runtime, telemetry
 from ray_shuffling_data_loader_tpu.batch_queue import (
     BatchQueue,
     DEFAULT_QUEUE_NAME,
@@ -320,7 +320,13 @@ class ShufflingDataset:
         is_done = False
         consumed_rows = 0  # audit: this rank's consumed-stream offset
         while not is_done:
-            pending = self._batch_queue.get_batch(self._rank, self._epoch)
+            # ``queue:get``: this trainer waiting on the queue actor — the
+            # shuffle's slack when short, the shuffle binding when long.
+            with telemetry.trace_span(
+                "queue:get", cat="queue", epoch=self._epoch, rank=self._rank
+            ) as span:
+                pending = self._batch_queue.get_batch(self._rank, self._epoch)
+                span.set(refs=len(pending))
             if pending and pending[-1] is None:
                 # Trailing producer-done sentinel; drain the rest first.
                 is_done = True

@@ -90,12 +90,12 @@ class JaxBatchSpec:
 
 
 class HostToDeviceStats:
-    """Staging instrumentation: bytes staged, wall time in ``device_put``
-    dispatch, consumer stall time (time the training loop waited on the
-    ring), and peak device-memory use while staging (the HBM-occupancy
-    analog of the reference's object-store sampling, ``stats.py:686-699``).
-    The reference measures the trainer-side analog as batch wait time
-    (``ray_torch_shuffle.py:201-230``)."""
+    """Staging instrumentation: bytes staged and consumer stall time (time
+    the training loop waited on the ring). The reference measures the
+    trainer-side analog as batch wait time
+    (``ray_torch_shuffle.py:201-230``). Where tracing was active
+    (``RSDL_TRACE`` or a profiler session), :meth:`as_dict` also carries
+    the per-layer counts folded from the process's spans."""
 
     def __init__(self):
         self.bytes_staged = 0
@@ -107,7 +107,6 @@ class HostToDeviceStats:
         self.bytes_staged_direct = 0
         self.batches_staged = 0
         self.batches_staged_direct = 0
-        self.put_dispatch_s = 0.0
         self.stall_s = 0.0
         self.stalls = 0
         # Decomposition of ``stall_s`` by what the stager was doing when
@@ -121,34 +120,138 @@ class HostToDeviceStats:
         self.stall_upstream_s = 0.0
         self.stall_staging_s = 0.0
         self.first_batch_s: Optional[float] = None
-        self.peak_device_bytes_in_use = 0
 
-    def sample_device_memory(self) -> None:
-        """Record current HBM occupancy, the largest over this process's
-        devices (accelerators report it via ``memory_stats``; the CPU
-        backend reports nothing)."""
-        for dev in jax.local_devices():
-            stats = dev.memory_stats()
-            if stats:
-                self.peak_device_bytes_in_use = max(
-                    self.peak_device_bytes_in_use,
-                    int(stats.get("bytes_in_use", 0)),
-                )
-
-    def as_dict(self) -> Dict[str, float]:
-        return {
+    def as_dict(self) -> Dict[str, Any]:
+        """The flat counts, plus ``"layers"`` (:func:`layer_counts` of the
+        process's span buffer, computed when asked for) where tracing
+        recorded something a layer counts; the key is absent otherwise."""
+        out: Dict[str, Any] = {
             "bytes_staged": self.bytes_staged,
             "bytes_staged_direct": self.bytes_staged_direct,
             "batches_staged": self.batches_staged,
             "batches_staged_direct": self.batches_staged_direct,
-            "put_dispatch_s": self.put_dispatch_s,
             "stall_s": self.stall_s,
             "stalls": self.stalls,
             "stall_upstream_s": self.stall_upstream_s,
             "stall_staging_s": self.stall_staging_s,
             "first_batch_s": self.first_batch_s or 0.0,
-            "peak_device_bytes_in_use": self.peak_device_bytes_in_use,
         }
+        layers = layer_counts(telemetry.local_spans())
+        if layers:
+            out["layers"] = layers
+        return out
+
+
+def layer_counts(spans) -> Dict[str, Dict[str, Any]]:
+    """What the per-layer metrics read of the layers beneath the loader,
+    folded from a span list and kept nowhere else; a layer is present
+    only if it recorded one of its spans. Everything else a span carries
+    (``put_ns``, ``error``, ``retry``, ``refs``) stays on the span, for
+    the merged trace.
+
+    * ``runtime.by_fn[<fn>]``: ``tasks``, ``wait_s`` (submit to the
+      worker's start), ``run_s`` of the ``pool:<fn>`` spans
+    * ``shuffle.epoch_s``: seconds of each ``shuffle:epoch``, in order
+    * ``delivery``: ``gets``, ``get_wait_s`` of the ``queue:get`` spans
+    * ``staging``: ``stager_s`` (``stage:epoch``, the stager thread's
+      life), ``ring_put_s`` (``stage:ring-put``, the loader's slack),
+      ``transfers`` and ``max_transfer_s`` of the ``stage:transfer`` spans
+    """
+    out: Dict[str, Dict[str, Any]] = {}
+    epochs: List[Tuple[float, float]] = []
+    for span in spans:
+        name = span["name"]
+        dur_s = span["dur"] / 1e6
+        if name.startswith("pool:"):
+            wait_s = min(dur_s, span["args"].get("wait_ns", 0) / 1e9)
+            fn = out.setdefault("runtime", {"by_fn": {}})["by_fn"].setdefault(
+                name[len("pool:"):], {"tasks": 0, "wait_s": 0.0, "run_s": 0.0}
+            )
+            fn["tasks"] += 1
+            fn["wait_s"] += wait_s
+            fn["run_s"] += dur_s - wait_s
+        elif name == "shuffle:epoch":
+            epochs.append((span["ts"], dur_s))
+        elif name == "queue:get":
+            delivery = out.setdefault(
+                "delivery", {"gets": 0, "get_wait_s": 0.0}
+            )
+            delivery["gets"] += 1
+            delivery["get_wait_s"] += dur_s
+        elif name in ("stage:epoch", "stage:ring-put", "stage:transfer"):
+            staging = out.setdefault(
+                "staging",
+                {"stager_s": 0.0, "ring_put_s": 0.0, "transfers": 0,
+                 "max_transfer_s": 0.0},
+            )
+            if name == "stage:epoch":
+                staging["stager_s"] += dur_s
+            elif name == "stage:ring-put":
+                staging["ring_put_s"] += dur_s
+            else:
+                staging["transfers"] += 1
+                staging["max_transfer_s"] = max(
+                    staging["max_transfer_s"], dur_s
+                )
+    if epochs:
+        out["shuffle"] = {"epoch_s": [dur_s for _, dur_s in sorted(epochs)]}
+    return out
+
+
+class _TransferWatcher:
+    """Times each batch from ``device_put``'s dispatch to its arrays ready
+    on the device (``stage:transfer``), on a thread of its own so that
+    neither the stager nor the consumer ever blocks for it. It exists only
+    while tracing is active: one per epoch's iteration, none otherwise."""
+
+    def __init__(self):
+        self._queue: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._thread = threading.Thread(
+            target=self._run, name="hbm-transfer-watch", daemon=True
+        )
+        self._thread.start()
+
+    def watch(self, epoch, batch, nbytes, put_wall, put, arrays) -> None:
+        """``put`` is what ``device_put`` returned, ``arrays`` the batch as
+        the consumer gets it (the jitted unpack's outputs)."""
+        self._queue.put((epoch, batch, nbytes, put_wall, put, arrays))
+
+    def close(self) -> None:
+        """No more batches: the thread ends once it has seen the ones it
+        was given ready. Nobody waits for it."""
+        self._queue.put(None)
+
+    def _run(self) -> None:
+        while True:
+            item = self._queue.get()
+            if item is None:
+                return
+            epoch, batch, nbytes, put_wall, put, arrays = item
+            del item
+            try:
+                jax.block_until_ready(put)
+                put_ready = time.time()
+                jax.block_until_ready(arrays)
+            except RuntimeError:
+                # The consumer donated or deleted the batch before it
+                # was seen ready: nothing to time.
+                continue
+            finally:
+                del put, arrays
+            telemetry.record_span(
+                "stage:transfer",
+                put_wall,
+                time.time() - put_wall,
+                cat="staging",
+                parent="stage:h2d",
+                epoch=epoch,
+                batch=batch,
+                bytes=nbytes,
+                # Dispatch -> the put's own buffer on the device: the
+                # transfer proper. The rest of the span is the unpack,
+                # which queues behind whatever the device is running.
+                put_ns=int(1e9 * (put_ready - put_wall)),
+            )
 
 
 class JaxShufflingDataset:
@@ -344,7 +447,7 @@ class JaxShufflingDataset:
             )
             unpack = self._get_unpack(names, dtypes[:-1], dtypes[-1])
             features, label_arr = unpack(packed_dev)
-        return features, label_arr, mat.nbytes
+        return features, label_arr, mat.nbytes, packed_dev
 
     # -- spec application ---------------------------------------------------
 
@@ -368,29 +471,49 @@ class JaxShufflingDataset:
         so one large put beats one per column. Heterogeneous
         shapes/dtypes are staged per column. A put or an unpack that the
         backend refuses raises: no path here degrades to another.
+
+        Returns ``((features, label), bytes put, clocks at the put's
+        dispatch, what the put returned)``; the last three are what the
+        transfer watcher times.
         """
         prof = _phases.stage_profiler("staging")
         # Device-direct fast path: the batch arrived as a packed block
         # already in staging layout — ship it without touching a byte on
         # the host.
-        if cb.packed is not None and self._direct_ok(cb):
-            t0 = time.perf_counter()
-            features, label_arr, nbytes = self._stage_direct(cb, prof)
-            dispatch_s = time.perf_counter() - t0
-            self.stats.put_dispatch_s += dispatch_s
+        direct = cb.packed is not None and self._direct_ok(cb)
+        if direct:
+            put_at = self._put_clocks()
+            features, label_arr, nbytes, put = self._stage_direct(cb, prof)
             self.stats.bytes_staged_direct += nbytes
-            self.stats.batches_staged += 1
             self.stats.batches_staged_direct += 1
-            if self._h2d_bytes is not None:
-                self._h2d_bytes.inc(nbytes)
-                self._h2d_batches.inc()
-                self._h2d_dispatch_s.observe(dispatch_s)
+        else:
+            features, label_arr, nbytes, put, put_at = self._stage_host(
+                cb, prof
+            )
+            self.stats.bytes_staged += nbytes
+        self.stats.batches_staged += 1
+        if self._h2d_bytes is not None:
+            self._h2d_bytes.inc(nbytes)
+            self._h2d_batches.inc()
+            self._h2d_dispatch_s.observe(time.perf_counter() - put_at[1])
+            if direct:
                 _metrics.safe_inc("h2d.direct_bytes", float(nbytes))
                 _metrics.safe_inc("h2d.direct_batches")
-            if self.stats.batches_staged % 8 == 0:
-                self.stats.sample_device_memory()
-            return features, label_arr
+        return (features, label_arr), nbytes, put_at, put
 
+    def _put_clocks(self) -> Optional[Tuple[float, float]]:
+        """``(wall, perf_counter)`` at a put's dispatch, read only where
+        something times the put: the metrics half's dispatch histogram (a
+        ``perf_counter`` delta) or the transfer watcher (a wall-clock
+        span)."""
+        if self._h2d_bytes is None and not telemetry.active():
+            return None
+        return time.time(), time.perf_counter()
+
+    def _stage_host(self, cb: ColumnBatch, prof):
+        """The host-copied staging of one columnar batch: narrow every
+        column to its device dtype, then one packed put or one per
+        column."""
         spec = self._spec
         host: Dict[str, np.ndarray] = {}
         packable = True
@@ -419,9 +542,9 @@ class JaxShufflingDataset:
             and self._rows_shardable(label.shape[0])
         )
 
-        t0 = time.perf_counter()
+        put_at = self._put_clocks()
         if packable:
-            features, label_arr, nbytes = self._stage_packed(
+            features, label_arr, nbytes, put = self._stage_packed(
                 host, label, prof
             )
         else:
@@ -436,17 +559,8 @@ class JaxShufflingDataset:
                     nbytes += arr.nbytes
                 label_arr = self._put(label, partial=partial)
                 nbytes += label.nbytes
-        dispatch_s = time.perf_counter() - t0
-        self.stats.put_dispatch_s += dispatch_s
-        self.stats.bytes_staged += nbytes
-        self.stats.batches_staged += 1
-        if self._h2d_bytes is not None:
-            self._h2d_bytes.inc(nbytes)
-            self._h2d_batches.inc()
-            self._h2d_dispatch_s.observe(dispatch_s)
-        if self.stats.batches_staged % 8 == 0:
-            self.stats.sample_device_memory()
-        return features, label_arr
+            put = (features, label_arr)
+        return features, label_arr, nbytes, put, put_at
 
     def _stage_packed(
         self, host: Dict[str, np.ndarray], label: np.ndarray, prof=None
@@ -485,7 +599,7 @@ class JaxShufflingDataset:
                 str(label.dtype),
             )
             features, label_arr = unpack(packed_dev)
-        return features, label_arr, packed.nbytes
+        return features, label_arr, packed.nbytes, packed_dev
 
     def _get_unpack(self, names, dtypes, label_dtype):
         """Jitted on-device unpack for the packed layout: row slices +
@@ -637,6 +751,9 @@ class JaxShufflingDataset:
         error: List[BaseException] = []
         epoch_start = time.perf_counter()
         epoch = self._ds._epoch  # pinned before iteration starts
+        # The epoch boundary is where the process's tracing flag follows
+        # a profiler session; every other site only reads it.
+        watcher = _TransferWatcher() if telemetry.refresh_active() else None
         if _metrics.enabled():
             # Resolve the stall counters up front so the stall-by-cause
             # series exists in every snapshot, zeros included — a run with
@@ -661,37 +778,56 @@ class JaxShufflingDataset:
         audit_on = _audit.enabled()
         staged_rows = 0
 
-        def stager():
+        def stage_all():
             nonlocal staged_rows
-            try:
-                for cb in self._ds:
-                    if cancel.is_set():
-                        # Early consumer exit (break mid-epoch): keep
-                        # draining the underlying dataset WITHOUT staging so
-                        # its task_done acks still flow and the epoch window
-                        # can advance; stage nothing more to HBM.
-                        continue
-                    if audit_on:
-                        _audit.record_staged(
-                            epoch, self._ds._rank, cb, staged_rows
-                        )
-                        staged_rows += cb.num_rows
-                    phase[0] = "staging"
-                    with telemetry.trace_span(
-                        "stage:h2d",
-                        cat="staging",
-                        epoch=epoch,
-                        batch=self.stats.batches_staged,
-                        rows=cb.num_rows,
-                    ):
-                        item = self._stage(cb)
+            for cb in self._ds:
+                if cancel.is_set():
+                    # Early consumer exit (break mid-epoch): keep
+                    # draining the underlying dataset WITHOUT staging so
+                    # its task_done acks still flow and the epoch window
+                    # can advance; stage nothing more to HBM.
+                    continue
+                if audit_on:
+                    _audit.record_staged(
+                        epoch, self._ds._rank, cb, staged_rows
+                    )
+                    staged_rows += cb.num_rows
+                phase[0] = "staging"
+                batch = self.stats.batches_staged
+                with telemetry.trace_span(
+                    "stage:h2d",
+                    cat="staging",
+                    epoch=epoch,
+                    batch=batch,
+                    rows=cb.num_rows,
+                ):
+                    item, nbytes, put_at, put = self._stage(cb)
+                if watcher is not None and put_at is not None:
+                    watcher.watch(epoch, batch, nbytes, put_at[0], put, item)
+                del put
+                # ``stage:ring-put``: the stager blocked on the full ring,
+                # i.e. ahead of the consumer — the loader's slack.
+                with telemetry.trace_span(
+                    "stage:ring-put", cat="staging", epoch=epoch, batch=batch
+                ):
                     while not cancel.is_set():
                         try:
                             ring.put(item, timeout=0.1)
                             break
                         except queue.Full:
                             continue
-                    phase[0] = "upstream"
+                phase[0] = "upstream"
+
+        def stager():
+            try:
+                # ``stage:epoch``: the life of this thread, the parent of
+                # everything it does (``queue:get``, ``stage:h2d``,
+                # ``stage:ring-put``).
+                with telemetry.trace_span(
+                    "stage:epoch", cat="staging", epoch=epoch,
+                    rank=self._ds._rank,
+                ):
+                    stage_all()
             except BaseException as exc:  # surfaced on the consumer side
                 error.append(exc)
             finally:
@@ -764,5 +900,7 @@ class JaxShufflingDataset:
                         break
                     time.sleep(0.01)
             thread.join()
+            if watcher is not None:
+                watcher.close()
             if error:
                 raise error[0]

@@ -142,6 +142,9 @@ def _interaction_pallas(
         out_specs=pl.BlockSpec((bt, p), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((padded, p), stacked.dtype),
         interpret=interpret,
+        # The kernel's own name in the trace, whatever jit calls the
+        # function that holds it.
+        name="dot_interaction_fwd",
     )(stacked, selectors)
     return out[:b]
 
@@ -215,6 +218,7 @@ def dot_interaction(
     """
     if use_pallas is None:
         use_pallas = auto_pallas()
-    if not use_pallas:
-        return dot_interaction_reference(stacked)
-    return _dot_interaction_pallas_vjp(stacked, block_batch, interpret)
+    with jax.named_scope("dot_interaction"):
+        if not use_pallas:
+            return dot_interaction_reference(stacked)
+        return _dot_interaction_pallas_vjp(stacked, block_batch, interpret)

@@ -108,15 +108,20 @@ def make_step_body(
     in one device program) compose from."""
 
     def step_fn(state: TrainState, features, labels):
+        # Named scopes land in every operation's ``op_name``, so a trace
+        # can be split into forward (``loss``), backward (the transposes
+        # of ``loss``) and ``optimizer`` whatever the compiler fuses.
         def loss_fn(params):
-            logits = model.apply(params, features)
-            return bce_loss(logits, labels)
+            with jax.named_scope("loss"):
+                logits = model.apply(params, features)
+                return bce_loss(logits, labels)
 
         loss, grads = jax.value_and_grad(loss_fn)(state.params)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             step=state.step + 1, params=params, opt_state=opt_state
         )

@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_shuffling_data_loader_tpu import runtime
+from ray_shuffling_data_loader_tpu import runtime, telemetry
 from ray_shuffling_data_loader_tpu.jax_dataset import HostToDeviceStats
 from ray_shuffling_data_loader_tpu.ops.placement import traced_in_mesh
 
@@ -336,7 +336,6 @@ class DeviceResidentShufflingDataset:
         num_rows: Optional[int] = None,
         progress_cb: Optional[Callable[[], None]] = None,
         materialize_epoch: Optional[bool] = None,
-        stats_collector=None,
     ):
         if jax.process_count() > 1 and num_trainers != 1:
             # Multi-controller SPMD: every process executes the SAME
@@ -382,13 +381,6 @@ class DeviceResidentShufflingDataset:
         # Called after every staged piece: lets a long staging pass feed
         # an external liveness watchdog (the bench arms one).
         self._progress_cb = progress_cb
-        # Optional TrialStatsCollector handle: the resident loader reports
-        # through the SAME event vocabulary as the map/reduce engine
-        # (map = epoch permutation draw, reduce = epoch
-        # materialization/gather stream, consume = per-batch delivery),
-        # so process_stats CSVs cover the flagship path too.
-        self._stats_collector = stats_collector
-        self._trial_t0 = time.perf_counter()
         self.stats = HostToDeviceStats()
         self._load(filenames, num_rows)
 
@@ -699,7 +691,6 @@ class DeviceResidentShufflingDataset:
         n = self.num_rows
         self.stats.batches_staged = 0
         self.stats.first_batch_s = time.perf_counter() - t0
-        self.stats.sample_device_memory()
 
         # Rank split: contiguous near-equal slices, arithmetically (the
         # same boundaries ``np.array_split`` would give over the row
@@ -710,11 +701,12 @@ class DeviceResidentShufflingDataset:
         self._rank_start = r * base + min(r, extra)
         self._rank_rows = base + (1 if r < extra else 0)
 
-        self._perm_fn = jax.jit(
-            lambda epoch: jax.random.permutation(
+        def epoch_permutation(epoch):
+            return jax.random.permutation(
                 jax.random.fold_in(jax.random.key(self.seed), epoch), n
             )
-        )
+
+        self._perm_fn = jax.jit(epoch_permutation)
         self._gather_cache: Dict[Tuple[str, int], object] = {}
 
         # Epoch materialization policy: ONE whole-epoch gather (then
@@ -868,17 +860,6 @@ class DeviceResidentShufflingDataset:
     def close(self) -> None:
         """Release the resident buffers (HBM) deterministically instead
         of waiting for GC — after this the dataset cannot iterate."""
-        sc = self._stats_collector
-        if sc is not None and not getattr(self, "_closed", False):
-            try:
-                sc.call_oneway(
-                    "report_staging", self.rank, self.stats.as_dict()
-                )
-                sc.call_oneway(
-                    "trial_done", time.perf_counter() - self._trial_t0
-                )
-            except Exception:
-                pass
         self._closed = True
         self._buf = None
         self._epoch_buf_cache.clear()
@@ -907,28 +888,9 @@ class DeviceResidentShufflingDataset:
         if self._epoch is None:
             raise RuntimeError("set_epoch must be called before iterating")
         epoch, skip = self._epoch, self._skip
-        sc = self._stats_collector
-        if sc is not None:
-            sc.call_oneway("epoch_start", epoch)
-            sc.call_oneway("map_start", epoch)
-        t_perm = time.perf_counter()
-        perm = self._perm(epoch)
-        if sc is not None:
-            # Block for an honest stage timing only when a collector is
-            # attached (measured runs); unmeasured runs stay fully async.
-            jax.block_until_ready(perm)
-            sc.call_oneway(
-                "map_done", epoch, time.perf_counter() - t_perm, 0.0
-            )
-            sc.call_oneway("reduce_start", epoch)
-        t_shuffle = time.perf_counter()
-        if self._materialize:
-            ebuf = self._epoch_buf(epoch)
-            if sc is not None:
-                jax.block_until_ready(ebuf)
-                sc.call_oneway(
-                    "reduce_done", epoch, time.perf_counter() - t_shuffle
-                )
+        # The epoch boundary is where the process's tracing flag follows
+        # a profiler session.
+        telemetry.refresh_active()
         b = self.batch_size
         full, rem = divmod(self._rank_rows, b)
         widths = [b] * full
@@ -945,36 +907,44 @@ class DeviceResidentShufflingDataset:
 
         pending = deque()
         start = self._rank_start + skip * b
-        for width in widths[skip:]:
+
+        def dispatch(index: int, width: int) -> None:
+            nonlocal start
             # Re-checked per batch: a close() between yields must fail
             # fast here, not crash inside jit on a None buffer (and, on
             # the materialized path, not keep serving from the local
             # ebuf reference after the docstring promised release).
             self._check_open()
-            if self._materialize:
-                item = self._slice_fn(width)(ebuf, np.int32(start))
-            else:
-                item = self._gather_fn(width)(self._buf, perm, np.int32(start))
+            with telemetry.trace_span(
+                "resident:dispatch", cat="resident", epoch=epoch, batch=index
+            ):
+                if self._materialize:
+                    item = self._slice_fn(width)(ebuf, np.int32(start))
+                else:
+                    item = self._gather_fn(width)(
+                        self._buf, perm, np.int32(start)
+                    )
             pending.append(item)
             start += width
             self.stats.batches_staged += 1
-            if sc is not None:
-                sc.call_oneway(
-                    "consume",
-                    self.rank,
-                    epoch,
-                    len(self._columns) * width * 4,
-                )
-            if self.stats.batches_staged % 32 == 0:
-                self.stats.sample_device_memory()
+
+        todo = list(enumerate(widths))[skip:]
+        # ``resident:handover``: what the host does between two epochs
+        # before the consumer has a batch to step on — the permutation's
+        # draw, ``permute_all`` and the first batch's dispatch. The device
+        # side of it is in the profiler's trace (``epoch_permutation``,
+        # ``permute_all``); nothing here waits for the device.
+        with telemetry.trace_span(
+            "resident:handover", cat="resident", epoch=epoch, rank=self.rank
+        ):
+            perm = self._perm(epoch)
+            ebuf = self._epoch_buf(epoch) if self._materialize else None
+            if todo:
+                dispatch(*todo[0])
+        for index, width in todo[1:]:
             while len(pending) > self._lookahead:
                 yield pending.popleft()
-        if sc is not None and not self._materialize:
-            # Per-batch gather mode: the "reduce" is the epoch's gather
-            # dispatch stream, complete once every batch is in flight.
-            sc.call_oneway(
-                "reduce_done", epoch, time.perf_counter() - t_shuffle
-            )
+            dispatch(index, width)
         while pending:
             yield pending.popleft()
 

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import multiprocessing as mp
 import os
+import sys
 import threading
 import time
 import traceback
@@ -204,6 +205,17 @@ def _outbound_ctx():
     return telemetry.outbound_context()
 
 
+_TRACE_MODULE = "ray_shuffling_data_loader_tpu.telemetry.trace"
+
+
+def _active_trace():
+    """``telemetry.trace`` while it records in this process, else None:
+    looked up in ``sys.modules`` (never imported ⇒ never active), then
+    one cached flag."""
+    trace = sys.modules.get(_TRACE_MODULE)
+    return trace if trace is not None and trace.active() else None
+
+
 def _flush_telemetry_spools() -> None:
     """The task-done spool barrier: trace, audit, metrics registry,
     plus (metrics-gated, lazily imported) the event log and straggler
@@ -374,6 +386,10 @@ class WorkerPool:
         self._running_on: Dict[int, int] = {}  # task_id -> worker pid
         self._task_names: Dict[int, str] = {}  # task_id -> fn name
         self._started: Dict[int, float] = {}  # task_id -> start monotonic
+        # While tracing is active: task_id -> [submit wall s, submitter's
+        # trace context and live span, start wall s], for the ``pool:<fn>``
+        # span the collector records when the task is done.
+        self._traced: Dict[int, list] = {}
         self._next_id = 0
         self._closed = False
         self._collector = threading.Thread(target=self._collect, daemon=True)
@@ -424,15 +440,49 @@ class WorkerPool:
                 with self._futures_lock:
                     self._running_on[task_id] = pid
                     self._started[task_id] = time.monotonic()
+                    traced = self._traced.get(task_id)
+                    if traced is not None:
+                        traced[2] = time.time()
                 continue
             _, task_id, result, error = item
             with self._futures_lock:
                 fut = self._futures.pop(task_id, None)
-                self._running_on.pop(task_id, None)
+                pid = self._running_on.pop(task_id, None)
                 self._started.pop(task_id, None)
-                self._task_names.pop(task_id, None)
+                name = self._task_names.pop(task_id, None)
+                traced = self._traced.pop(task_id, None)
+            if traced is not None:
+                self._record_pool_span(name, pid, traced, error)
             if fut is not None:
                 fut._fulfill(result, error)
+
+    @staticmethod
+    def _record_pool_span(name, pid, traced, error) -> None:
+        """The driver-side span of one task, submit to done, with the
+        time it waited for a worker (``wait_ns``: submit to the worker's
+        start message). Recorded before the future resolves, so whoever
+        awaited the task finds it in the buffer."""
+        trace = _active_trace()
+        if trace is None:
+            return
+        submit_s, args, start_s = traced
+        done_s = time.time()
+        if isinstance(error, dict):
+            args["error"] = error.get("type") or "TaskError"
+        elif error is not None:
+            args["error"] = "WorkerLost"
+        try:
+            trace.record_span(
+                f"pool:{name or 'task'}",
+                submit_s,
+                done_s - submit_s,
+                cat="runtime",
+                wait_ns=int(1e9 * max(0.0, (start_s or done_s) - submit_s)),
+                pid=pid,
+                **args,
+            )
+        except Exception:
+            pass  # telemetry never fails a task
 
     def _watch(self):
         # Fail in-flight tasks whose worker died (e.g. OOM-killed) so
@@ -471,13 +521,15 @@ class WorkerPool:
                     fut = self._futures.pop(tid, None)
                     self._running_on.pop(tid, None)
                     self._started.pop(tid, None)
-                    self._task_names.pop(tid, None)
+                    name = self._task_names.pop(tid, None)
+                    traced = self._traced.pop(tid, None)
                     if fut is not None:
-                        futs.append((fut, pid))
-            for fut, pid in futs:
-                fut._fulfill(
-                    None, f"worker process {pid} died while running this task"
-                )
+                        futs.append((fut, pid, name, traced))
+            for fut, pid, name, traced in futs:
+                error = f"worker process {pid} died while running this task"
+                if traced is not None:
+                    self._record_pool_span(name, pid, traced, error)
+                fut._fulfill(None, error)
 
     # -- elastic membership (ISSUE 10) ---------------------------------------
 
@@ -567,12 +619,17 @@ class WorkerPool:
         blob = pickle.dumps(
             (fn, args, kwargs, _outbound_ctx())
         )
+        trace = _active_trace()
         with self._futures_lock:
             task_id = self._next_id
             self._next_id += 1
             fut = TaskFuture(task_id)
             self._futures[task_id] = fut
             self._task_names[task_id] = getattr(fn, "__name__", "task")
+            if trace is not None:
+                self._traced[task_id] = [
+                    time.time(), trace.caused_context(), None
+                ]
         self._task_q.put((task_id, blob))
         return fut
 

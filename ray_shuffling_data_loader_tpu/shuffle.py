@@ -3096,6 +3096,10 @@ def shuffle_epoch(
 
     map_futs: List[TaskFuture] = []
     map_published: List[bool] = []
+    # ``shuffle:epoch`` runs from here, the first map's submission, to
+    # the last reducer's output handed to the queue (the deliver thread
+    # records it): what the host needs to make one epoch, hidden or not.
+    epoch_t0 = time.time() if telemetry.active() else None
     # Trace context for everything this epoch submits from THIS thread:
     # the task layer pickles the submitter's context next to each task, so
     # worker-side map spans inherit the epoch id (the deliver thread below
@@ -3349,7 +3353,10 @@ def shuffle_epoch(
                 )
                 backoff.backoff(str(exc))
                 _recover_lost_cache(exc.lost_object_id)
-                fut = _resubmit_map(i, publish=published)
+                # ``retry`` rides the context into the resubmitted
+                # task's ``pool:`` span: the runtime layer's retry count.
+                with telemetry.context(retry=attempt):
+                    fut = _resubmit_map(i, publish=published)
                 if published:
                     # Later epochs block on the NEW publishing attempt
                     # instead of degrading to per-epoch decode for the
@@ -3715,7 +3722,8 @@ def shuffle_epoch(
                                 # on identical doomed attempts.
                                 _recover_lost_cache(lost)
                             retried = True
-                            fut = _submit_reduce(r, refs_r)
+                            with telemetry.context(retry=attempt):
+                                fut = _submit_reduce(r, refs_r)
                     raise AssertionError("unreachable: retry budget mis-sized")
 
                 # Stream each reducer's output to its rank as soon as it
@@ -3879,6 +3887,14 @@ def shuffle_epoch(
                     if r + 1 == num_reducers or rank_of[r + 1] != rank:
                         batch_consumer.producer_done(rank, epoch)
                         done_ranks.add(rank)
+                if epoch_t0 is not None and not getattr(
+                    thread, "suspended", False
+                ):
+                    telemetry.record_span(
+                        "shuffle:epoch", epoch_t0, time.time() - epoch_t0,
+                        cat="shuffle", reducers=num_reducers,
+                        maps=len(filenames),
+                    )
                 if journal is not None and not getattr(
                     thread, "suspended", False
                 ):

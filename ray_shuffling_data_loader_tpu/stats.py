@@ -123,7 +123,6 @@ class StagingStats:
     rank: int
     bytes_staged: int = 0
     batches_staged: int = 0
-    put_dispatch_s: float = 0.0
     stall_s: float = 0.0
     stalls: int = 0
     # stall_s split by cause: upstream (no host batch — epoch window /
@@ -131,7 +130,6 @@ class StagingStats:
     stall_upstream_s: float = 0.0
     stall_staging_s: float = 0.0
     first_batch_s: float = 0.0
-    peak_device_bytes_in_use: int = 0
 
 
 @dataclass
@@ -291,14 +289,7 @@ class TrialStats:
         # TPU-native staging columns (no reference analog; the reference's
         # closest quantity is the example's trainer batch-wait time,
         # reference ``ray_torch_shuffle.py:201-230``).
-        put_dispatch_s = sum(s.put_dispatch_s for s in self.staging)
         out["total_bytes_staged"] = self.total_bytes_staged
-        out["put_dispatch_s"] = put_dispatch_s
-        out["h2d_gbps"] = (
-            self.total_bytes_staged / 1e9 / put_dispatch_s
-            if put_dispatch_s > 0
-            else 0.0
-        )
         out["total_stall_s"] = self.total_stall_s
         out["stall_pct"] = (
             100.0
@@ -306,9 +297,6 @@ class TrialStats:
             / (self.duration * max(1, len(self.staging)))
             if self.duration
             else 0.0
-        )
-        out["peak_hbm_bytes"] = max(
-            (s.peak_device_bytes_in_use for s in self.staging), default=0
         )
         # Audit columns (empty-string/zero when auditing was off so the
         # trial CSV schema is stable either way): epochs whose digest
@@ -434,15 +422,11 @@ class TrialStatsCollector:
                 rank=rank,
                 bytes_staged=int(staging.get("bytes_staged", 0)),
                 batches_staged=int(staging.get("batches_staged", 0)),
-                put_dispatch_s=float(staging.get("put_dispatch_s", 0.0)),
                 stall_s=float(staging.get("stall_s", 0.0)),
                 stalls=int(staging.get("stalls", 0)),
                 stall_upstream_s=float(staging.get("stall_upstream_s", 0.0)),
                 stall_staging_s=float(staging.get("stall_staging_s", 0.0)),
                 first_batch_s=float(staging.get("first_batch_s", 0.0)),
-                peak_device_bytes_in_use=int(
-                    staging.get("peak_device_bytes_in_use", 0)
-                ),
             )
         )
 

@@ -7,7 +7,9 @@ Two halves, both env-gated off by default (zero overhead when disabled):
   runtime's task and actor layers, and a Chrome-trace/Perfetto exporter
   (:func:`trace_export`). Enable with ``RSDL_TRACE=1`` (+
   ``RSDL_TRACE_DIR=<spool>`` for cross-process collection) or
-  :func:`enable` before ``runtime.init()``.
+  :func:`enable` before ``runtime.init()``; the trainer's process also
+  records while a JAX profiler session runs (:func:`active`), with its
+  live spans in the profiler's own trace.
 * :mod:`.metrics` — counters/gauges/histograms with cross-process
   sources, a sampled timeline, a JSON snapshot dump, a Prometheus
   text-format exporter (:func:`metrics.to_prometheus_text`), and a
@@ -65,6 +67,8 @@ _TRACE_NAMES = frozenset(
         "ENV_TRACE",
         "ENV_TRACE_DIR",
         "Span",
+        "active",
+        "clock_sync",
         "context",
         "current_context",
         "disable",
@@ -73,10 +77,12 @@ _TRACE_NAMES = frozenset(
         "enabled",
         "flush",
         "instant",
+        "local_spans",
         "name_thread_track",
         "outbound_context",
         "propagated_span",
         "record_span",
+        "refresh_active",
         "refresh_from_env",
         "reset_state",
         "safe_flush",

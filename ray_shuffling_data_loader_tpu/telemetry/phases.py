@@ -19,7 +19,8 @@ store-publish, ...) and feeds both telemetry halves:
   so ``tools/epoch_report.py`` / Perfetto show phase cost in context.
 
 Zero-overhead contract (same as trace/metrics/audit): when BOTH halves
-are off, :func:`stage_profiler` returns a shared no-op singleton — the
+are off (the trace half is ``trace.active()``: ``RSDL_TRACE`` or a JAX
+profiler session), :func:`stage_profiler` returns a shared no-op singleton — the
 per-stage cost is one cached-boolean check and the hot loops never
 allocate. Phases are only ever timed on the worker that runs them; no
 locks (a profiler instance is single-thread, like the task body).
@@ -121,7 +122,7 @@ _NULL = _NullProfiler()
 class _Phase:
     """One timed phase; records into the owning profiler on exit."""
 
-    __slots__ = ("_prof", "name", "nbytes", "_wall0", "_t0", "_prev")
+    __slots__ = ("_prof", "name", "nbytes", "_wall0", "_t0", "_prev", "_ann")
 
     def __init__(self, prof: "StageProfiler", name: str,
                  nbytes: Optional[int]):
@@ -141,12 +142,23 @@ class _Phase:
         # own ident: no two threads touch the same key, and the
         # profiler's cross-thread read takes a dict() copy
         _ACTIVE[ident] = (self._prof.stage, self.name, self._prof.args)
+        # Under a profiler session the phase is a host span of the
+        # xplane too, like trace_span's (the sub-span itself is recorded
+        # retroactively on exit, which the profiler cannot take).
+        annotation = _trace._annotation
+        if annotation is not None:
+            self._ann = annotation(f"{self._prof.stage}:{self.name}")
+            self._ann.__enter__()
+        else:
+            self._ann = None
         self._wall0 = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         ident = threading.get_ident()
         if self._prev is None:
             # rsdl-lint: disable=lock-discipline -- this thread's own
@@ -199,7 +211,7 @@ class StageProfiler:
                     _metrics.registry.counter(
                         "shuffle.phase_bytes", phase=name, stage=self.stage
                     ).inc(float(nbytes))
-            if _trace.enabled():
+            if _trace.active():
                 span_args = dict(self.args)
                 if nbytes:
                     span_args["nbytes"] = int(nbytes)
@@ -228,7 +240,7 @@ def stage_profiler(stage: str, **args):
     the sampling profiler is armed (``RSDL_PROFILE``), which needs the
     active-phase registry populated even with metrics and trace off —
     else the shared no-op (the disabled path allocates nothing)."""
-    if _metrics.enabled() or _trace.enabled() or profile_armed():
+    if _metrics.enabled() or _trace.active() or profile_armed():
         return StageProfiler(stage, **args)
     return _NULL
 

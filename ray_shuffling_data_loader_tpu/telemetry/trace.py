@@ -4,10 +4,16 @@ The tracing half of the telemetry subsystem (ISSUE 1; the metrics half is
 :mod:`.metrics`). Design constraints, in order:
 
 * **Zero overhead when disabled.** Tracing is off unless ``RSDL_TRACE`` is
-  truthy; every instrumentation site goes through :func:`trace_span` /
+  truthy or a JAX profiler session runs in this process (:func:`active`);
+  every instrumentation site goes through :func:`trace_span` /
   :func:`record_span`, which reduce to one cached boolean check and a
   shared no-op object when disabled. Nothing is allocated, no clock is
   read.
+* **One clock with the profiler.** Under a session every live span also
+  enters a ``jax.profiler.TraceAnnotation``, so it lands in the xplane's
+  host plane on the thread that ran it; :func:`clock_sync` marks one
+  instant on both clocks, and ``trace_export(xplane=...)`` shifts the
+  xplane's device and host lines onto the wall clock of the buffers.
 * **Per-process buffering, no collection daemon.** The pipeline spans four
   process kinds (driver, spawned task workers, actor processes, trainer
   ranks). Each process appends events to an in-memory buffer and drains it
@@ -27,6 +33,8 @@ The tracing half of the telemetry subsystem (ISSUE 1; the metrics half is
 
 Timestamps are wall-clock microseconds (``time.time()``), comparable
 across processes on one host; durations come from ``perf_counter`` deltas.
+:func:`local_spans` is what the loader folds into the per-layer counts of
+``HostToDeviceStats.as_dict()["layers"]``.
 """
 
 from __future__ import annotations
@@ -35,6 +43,7 @@ import atexit
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -58,6 +67,10 @@ _FLUSH_INTERVAL_S = 1.0
 
 _lock = threading.RLock()
 _enabled: Optional[bool] = None  # tri-state: None = not yet read from env
+# ``jax.profiler.TraceAnnotation`` while a profiler session was running at
+# the last :func:`refresh_active`, else None: the session half of
+# :func:`active`, and what live spans enter.
+_annotation = None
 _events: List[dict] = []
 _dropped = 0
 _last_flush = 0.0
@@ -66,7 +79,6 @@ _process_name: Optional[str] = None
 _process_meta_emitted = False
 _threads_named: set = set()
 _base_ctx: Dict[str, Any] = {}
-_tls = threading.local()  # span depth only (flush heuristic)
 # Context rides in a contextvar, NOT a thread-local: actor dispatches
 # interleave as asyncio tasks on one event-loop thread, and each task gets
 # its own copy of the contextvars Context — so a dispatch blocked for
@@ -78,6 +90,13 @@ _ctx_stack_var: "contextvars.ContextVar[Tuple[Dict[str, Any], ...]]" = (
     # not a Prometheus alias; never appears on a scrape
     contextvars.ContextVar("rsdl_trace_ctx", default=())
 )
+# The live spans, innermost last: behind ``parent``. A contextvar for the
+# same reason, and a span leaves it by identity, not by position, so an
+# exit out of order (a span handed to another task) takes only itself.
+_live_var: "contextvars.ContextVar[Tuple[Span, ...]]" = (
+    # rsdl-lint: disable=vocabulary-drift -- contextvar debug name
+    contextvars.ContextVar("rsdl_trace_live", default=())
+)
 
 
 def enabled() -> bool:
@@ -86,6 +105,58 @@ def enabled() -> bool:
     if _enabled is None:
         _enabled = _env.read_flag(ENV_TRACE)
     return _enabled
+
+
+def active() -> bool:
+    """Is any span recorded in this process: ``RSDL_TRACE``, or a JAX
+    profiler session seen at the last :func:`refresh_active`? What every
+    site reads; it never looks for a session itself (and never imports
+    ``jax``)."""
+    return _annotation is not None or enabled()
+
+
+def refresh_active() -> bool:
+    """Look for a JAX profiler session and set the process's flag: called
+    at the trainer's epoch boundaries and nowhere else, so the stager, the
+    shuffle driver and the pool's collector only ever read one cached
+    value. ``jax`` is looked up in ``sys.modules``, never imported. While a
+    session runs every call also marks the two clocks (:func:`clock_sync`),
+    so a session started between two epochs is tied at the next."""
+    global _annotation
+    profiler = sys.modules.get("jax.profiler")
+    annotation = getattr(profiler, "TraceAnnotation", None)
+    session = annotation is not None and bool(annotation.is_enabled())
+    _annotation = annotation if session else None
+    if session:
+        _register_atexit()
+        clock_sync()
+    return active()
+
+
+def clock_sync() -> None:
+    """One mark on both clocks: a ``clock.sync`` annotation in the
+    profiler's host plane (``start_ns`` on the session's clock) whose
+    ``wall_ns`` is ``time.time_ns()`` at its entry, and the same
+    ``wall_ns`` as an instant in this buffer. The difference of the two is
+    what ``trace_export(xplane=...)`` shifts the xplane by."""
+    annotation = _annotation
+    if annotation is None:
+        return
+    wall_ns = time.time_ns()
+    with annotation("clock.sync", wall_ns=wall_ns):
+        pass
+    _record(
+        {
+            "name": "clock.sync",
+            "cat": "clock",
+            "ph": "i",
+            "s": "p",
+            "ts": wall_ns / 1e3,
+            "pid": os.getpid(),
+            "tid": _tid(),
+            "args": {"wall_ns": wall_ns},
+        }
+    )
 
 
 def enable(spool_dir: Optional[str] = None) -> None:
@@ -113,8 +184,9 @@ def refresh_from_env() -> None:
     """Forget the cached enabled state and buffer limit; the next check
     re-reads the env (test harness hook — fixtures restore the env then
     call this)."""
-    global _enabled, _max_events_cached, _service_armed_cached
+    global _enabled, _max_events_cached, _service_armed_cached, _annotation
     _enabled = None
+    _annotation = None
     _max_events_cached = None
     _service_armed_cached = None
 
@@ -293,6 +365,24 @@ def _record(event: dict) -> None:
         _events.append(event)
 
 
+def caused_context() -> Dict[str, Any]:
+    """:func:`current_context` plus ``parent``, the name of the innermost
+    live span of this thread / asyncio task: what a span recorded (or a
+    task submitted) here and now carries about what caused it."""
+    merged = current_context()
+    live = _live_var.get()
+    if live:
+        merged["parent"] = live[-1].name
+    return merged
+
+
+def _annotation_args(args: Dict[str, Any]) -> Dict[str, Any]:
+    """The span's scalar args, as the profiler's annotation takes them."""
+    return {
+        k: v for k, v in args.items() if isinstance(v, (str, int, float))
+    }
+
+
 def record_span(
     name: str,
     start_s: float,
@@ -302,10 +392,14 @@ def record_span(
 ) -> None:
     """Record a span retroactively from a wall-clock start and duration —
     for sites that already measured the interval (e.g. the consumer-stall
-    accounting in ``jax_dataset``)."""
-    if not enabled():
+    accounting in ``jax_dataset``) and for spans that end on another
+    thread than they began (``pool:<fn>``, ``stage:transfer``). ``parent``
+    is the span that caused it: the caller's, else the live span of this
+    thread. Retroactive spans cannot enter the profiler's trace; they stay
+    in the buffer."""
+    if not active():
         return
-    merged = current_context()
+    merged = caused_context()
     merged.update(args)
     _record(
         {
@@ -323,7 +417,7 @@ def record_span(
 
 def instant(name: str, cat: str = "rsdl", **args: Any) -> None:
     """Record an instant marker (a vertical tick on the timeline)."""
-    if not enabled():
+    if not active():
         return
     merged = current_context()
     merged.update(args)
@@ -348,7 +442,7 @@ class Span:
     overlap without nesting (asyncio-interleaved actor dispatches), which
     the Chrome-trace viewers cannot render on a single track."""
 
-    __slots__ = ("name", "cat", "args", "_ts", "_t0", "_tid")
+    __slots__ = ("name", "cat", "args", "_ts", "_t0", "_tid", "_ann")
 
     def __init__(self, name: str, cat: str, args: Dict[str, Any],
                  tid: Optional[int] = None):
@@ -361,16 +455,27 @@ class Span:
         self.args.update(kv)
 
     def __enter__(self) -> "Span":
-        merged = current_context()
+        merged = caused_context()
         merged.update(self.args)
         self.args = merged
-        _tls.depth = getattr(_tls, "depth", 0) + 1
+        _live_var.set(_live_var.get() + (self,))
+        # Under a profiler session the span also lands in the xplane's
+        # host plane, on this thread, beside the device's operations.
+        annotation = _annotation
+        if annotation is not None:
+            self._ann = annotation(self.name, **_annotation_args(merged))
+            self._ann.__enter__()
+        else:
+            self._ann = None
         self._ts = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _live_var.set(tuple(s for s in _live_var.get() if s is not self))
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         _record(
@@ -385,7 +490,6 @@ class Span:
                 "args": self.args,
             }
         )
-        _tls.depth = max(0, getattr(_tls, "depth", 1) - 1)
         # Flush on ANY close (rate-limited inside _maybe_flush), not only
         # at depth 0: an async actor serving interleaved dispatches —
         # e.g. the batch queue under the PR-3 supervised consumer, which
@@ -421,7 +525,7 @@ def trace_span(name: str, cat: str = "rsdl", tid: Optional[int] = None,
     """Open a span covering the ``with`` block. When tracing is disabled
     this returns a shared no-op object — the disabled cost is one cached
     boolean check."""
-    if not enabled():
+    if not active():
         return _NULL
     _register_atexit()
     return Span(name, cat, args, tid=tid)
@@ -430,7 +534,7 @@ def trace_span(name: str, cat: str = "rsdl", tid: Optional[int] = None,
 def name_thread_track(tid: int, name: str) -> None:
     """Label a (possibly virtual) thread track in the exported trace.
     First call per tid wins; later automatic naming is skipped."""
-    if not enabled():
+    if not active():
         return
     with _lock:
         if tid in _threads_named:
@@ -456,7 +560,7 @@ def propagated_span(name: str, ctx: Optional[Dict[str, Any]],
     context is still re-entered when present (the metrics half ships
     one for epoch attribution — see :func:`outbound_context`); with
     nothing shipped this is a no-op."""
-    if not enabled():
+    if not active():
         if ctx:
             with context(**ctx):
                 yield
@@ -528,31 +632,135 @@ def _maybe_flush() -> None:
         flush()
 
 
-def trace_export(path: str) -> str:
+def _read_spool(path: str) -> List[dict]:
+    events: List[dict] = []
+    try:
+        with open(path) as f:
+            for line in f:
+                line = line.strip()
+                if not line:
+                    continue
+                try:
+                    events.append(json.loads(line))
+                except ValueError:
+                    continue  # torn concurrent append; skip
+    except OSError:
+        pass
+    return events
+
+
+# Synthetic process ids of the xplane's planes in a merged export: above
+# any pid the kernel hands out, so they cannot collide with a spool's.
+_XPLANE_PID0 = 1 << 30
+_XPLANE_DEVICE_LINES = ("XLA Modules", "XLA Ops")
+
+
+def _mark_offset_ns(planes) -> Optional[int]:
+    """``wall_ns - start_ns`` of the ``clock.sync`` marks: the largest,
+    since ``wall_ns`` is read just before a mark is entered and whatever
+    delays the entry (another thread holding the interpreter) only makes
+    the difference smaller."""
+    offsets = [
+        int(wall_ns) - int(ev.start_ns)
+        for _, lines in planes
+        for _, line_events in lines
+        for ev in line_events
+        if ev.name == "clock.sync"
+        for wall_ns in [dict(ev.stats).get("wall_ns")]
+        if wall_ns is not None
+    ]
+    return max(offsets, default=None)
+
+
+def _xplane_events(path: str, span_names: set) -> List[dict]:
+    """The xplane's device programs and operations and its host
+    annotations as Chrome-trace events on the wall clock: every event is
+    shifted by ``wall_ns - start_ns`` of the file's ``clock.sync`` marks
+    (:func:`clock_sync`). Of the host plane, the lines (threads) that
+    hold a span named in ``span_names`` or a mark are kept, whole: the
+    profiler's own runtime events on those threads come with them, the
+    backend's thread pools do not."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(path)
+    planes = [
+        (plane.name, [(line.name, list(line.events)) for line in plane.lines])
+        for plane in data.planes
+    ]
+    offset_ns = _mark_offset_ns(planes)
+    if offset_ns is None:
+        raise ValueError(
+            f"no clock.sync mark in {path}: refresh_active() saw no "
+            "profiler session while it was recorded"
+        )
+    events: List[dict] = []
+    for index, (plane_name, lines) in enumerate(planes):
+        device = plane_name.startswith("/device:")
+        if not device and not plane_name.startswith("/host:CPU"):
+            continue
+        pid = _XPLANE_PID0 + index
+        named = False
+        for tid, (line_name, line_events) in enumerate(lines):
+            if not line_events:
+                continue
+            if device and line_name not in _XPLANE_DEVICE_LINES:
+                continue
+            if not device and not any(
+                ev.name in span_names for ev in line_events
+            ):
+                continue
+            if not named:
+                named = True
+                events.append(
+                    {
+                        "name": "process_name", "ph": "M", "pid": pid,
+                        "tid": 0, "args": {"name": f"xplane {plane_name}"},
+                    }
+                )
+            events.append(
+                {
+                    "name": "thread_name", "ph": "M", "pid": pid,
+                    "tid": tid, "args": {"name": line_name},
+                }
+            )
+            for ev in line_events:
+                events.append(
+                    {
+                        # An operation's name is its whole HLO
+                        # instruction; keep what stands before " = ".
+                        "name": ev.name.split(" = ")[0].lstrip("%")[:120],
+                        "cat": "xplane",
+                        "ph": "X",
+                        "ts": (int(ev.start_ns) + offset_ns) / 1e3,
+                        "dur": int(ev.duration_ns) / 1e3,
+                        "pid": pid,
+                        "tid": tid,
+                        "args": {},
+                    }
+                )
+    return events
+
+
+def trace_export(path: str, xplane: Optional[str] = None) -> str:
     """Merge this process's buffer and every spool file into ONE Chrome
     trace JSON at ``path`` (open with chrome://tracing or
-    https://ui.perfetto.dev). Returns ``path``."""
+    https://ui.perfetto.dev). With ``xplane`` (an ``.xplane.pb`` the JAX
+    profiler wrote while :func:`refresh_active` saw its session), the
+    device's ``XLA Modules`` / ``XLA Ops`` lines and the host plane's
+    annotations go into the same file, shifted onto the wall clock by the
+    ``clock.sync`` mark. Returns ``path``."""
     flush()
     events: List[dict] = []
     directory = spool_dir()
     if directory and os.path.isdir(directory):
         for fname in sorted(os.listdir(directory)):
-            if not (fname.startswith("trace-") and fname.endswith(".jsonl")):
-                continue
-            try:
-                with open(os.path.join(directory, fname)) as f:
-                    for line in f:
-                        line = line.strip()
-                        if not line:
-                            continue
-                        try:
-                            events.append(json.loads(line))
-                        except ValueError:
-                            continue  # torn concurrent append; skip
-            except OSError:
-                continue
+            if fname.startswith("trace-") and fname.endswith(".jsonl"):
+                events.extend(_read_spool(os.path.join(directory, fname)))
     with _lock:
         events.extend(_events)  # no-spool mode: the local buffer
+    if xplane:
+        span_names = {e["name"] for e in events if e.get("ph") == "X"}
+        events.extend(_xplane_events(xplane, span_names | {"clock.sync"}))
     # Metadata first, then chronological — what the viewers expect.
     events.sort(key=lambda e: (e.get("ph") != "M", e.get("ts", 0)))
     payload = {"traceEvents": events, "displayTimeUnit": "ms"}
@@ -561,3 +769,19 @@ def trace_export(path: str) -> str:
         json.dump(payload, f)
     os.replace(tmp, path)
     return path
+
+
+def local_spans() -> List[dict]:
+    """Every complete span this process recorded: its buffer, and what it
+    already drained to its own spool file (the ``RSDL_TRACE_DIR`` route)."""
+    events: List[dict] = []
+    directory = spool_dir()
+    if directory:
+        events.extend(
+            _read_spool(
+                os.path.join(directory, f"trace-{os.getpid()}.jsonl")
+            )
+        )
+    with _lock:
+        events.extend(_events)
+    return [e for e in events if e.get("ph") == "X"]

@@ -113,11 +113,8 @@ def test_trial_row_matches_reference_columns():
         ]
     tpu_native_columns = [
         "total_bytes_staged",
-        "put_dispatch_s",
-        "h2d_gbps",
         "total_stall_s",
         "stall_pct",
-        "peak_hbm_bytes",
     ]
     c = TrialStatsCollector(
         num_epochs=1,
@@ -139,9 +136,7 @@ def test_trial_row_matches_reference_columns():
         0,
         {
             "bytes_staged": 4_000_000_000,
-            "put_dispatch_s": 2.0,
             "stall_s": 0.25,
-            "peak_device_bytes_in_use": 7,
         },
     )
     c.trial_done(10.0)
@@ -152,9 +147,8 @@ def test_trial_row_matches_reference_columns():
     assert not missing, f"trial row missing columns: {missing}"
     assert row["num_row_groups_per_file"] == 2
     assert row["max_concurrent_epochs"] == 2
-    assert row["h2d_gbps"] == pytest.approx(2.0)  # 4 GB / 2 s
+    assert row["total_bytes_staged"] == 4_000_000_000
     assert row["stall_pct"] == pytest.approx(2.5)  # 0.25 s of 10 s
-    assert row["peak_hbm_bytes"] == 7
 
 
 def test_get_stats_times_out_before_done():
@@ -202,69 +196,6 @@ def test_shuffle_reports_to_collector_actor(local_runtime, stats_dataset):
         assert e.duration > 0
         assert all(c.nbytes > 0 for c in e.consume_records)
     collector.terminate()
-
-
-class _SyncHandle:
-    """In-process stand-in for a spawned collector actor handle."""
-
-    def __init__(self, obj):
-        self.obj = obj
-
-    def call_oneway(self, name, *args):
-        getattr(self.obj, name)(*args)
-
-    def call(self, name, *args):
-        return getattr(self.obj, name)(*args)
-
-
-def test_resident_loader_reports_trial_row(local_runtime, stats_dataset):
-    """The flagship resident loader reports through the collector's
-    map/reduce/consume vocabulary, so its trial row carries the full
-    reference column set (VERDICT r2 weak item 3)."""
-    import numpy as np
-
-    from ray_shuffling_data_loader_tpu.resident import (
-        DeviceResidentShufflingDataset,
-    )
-
-    num_epochs = 2
-    c = TrialStatsCollector(
-        num_epochs=num_epochs,
-        num_maps_per_epoch=1,
-        num_reduces_per_epoch=1,
-        num_rows=1200,
-        batch_size=200,
-        num_trainers=1,
-    )
-    ds = DeviceResidentShufflingDataset(
-        list(stats_dataset),
-        num_epochs=num_epochs,
-        batch_size=200,  # divisible by the 8-device mesh
-        feature_columns=["key", "embeddings_name0"],
-        label_column="labels",
-        seed=3,
-        stats_collector=_SyncHandle(c),
-    )
-    for epoch in range(num_epochs):
-        ds.set_epoch(epoch)
-        keys = np.concatenate(
-            [np.asarray(f["key"]) for f, _ in ds]
-        )
-        assert np.array_equal(np.sort(keys), np.arange(1200))
-    ds.close()
-    stats = asyncio.run(c.get_stats(timeout=5))
-    row = stats.row()
-    # The same columns the map/reduce trial row carries (asserted in
-    # test_trial_row_matches_reference_columns) are populated here.
-    assert row["num_epochs"] == num_epochs
-    assert row["duration"] > 0
-    assert row["avg_map_stage_duration"] >= 0
-    assert row["avg_reduce_stage_duration"] > 0
-    assert row["avg_consume_stage_duration"] >= 0
-    assert row["total_bytes_staged"] > 0
-    assert len(stats.epochs) == num_epochs
-    # 6 batches per epoch -> 6 consume records per epoch.
-    assert all(len(e.consume_records) == 6 for e in stats.epochs)
 
 
 def test_process_stats_writes_csvs(tmp_path):

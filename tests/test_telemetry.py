@@ -126,7 +126,11 @@ def test_span_nesting_context_and_schema(telemetry_on, tmp_path):
     (retro,) = _spans(events, "retro")
     # Context stack merges into span args; inner sees both frames.
     assert outer["args"]["trial"] == 1 and "epoch" not in outer["args"]
-    assert inner["args"] == {"trial": 1, "epoch": 2, "extra": "x"}
+    # ... and names the live span that caused it.
+    assert inner["args"] == {
+        "trial": 1, "epoch": 2, "extra": "x", "parent": "outer",
+    }
+    assert "parent" not in outer["args"]
     # Nesting: inner lies within outer on the same thread track.
     assert inner["tid"] == outer["tid"]
     assert inner["ts"] >= outer["ts"]
