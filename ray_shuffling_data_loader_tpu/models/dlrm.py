@@ -7,7 +7,13 @@ actual workload its loader feeds is a DLRM-like tabular embedding model —
 (``data_generation.py:56-77``). This module implements that model properly,
 TPU-first:
 
-* per-column embedding tables, looked up with ``take`` (gather);
+* per-column ``[vocab, embed_dim]`` float32 embedding tables, read by
+  :func:`~..ops.embedding.embedding_lookup`: a row gather (scatter-add of
+  gradient rows on the way back) through a view of the table whose rows
+  fill the chip's 128 lanes when ``embed_dim`` is narrower and divides
+  128 (four rows of the shipped ``embed_dim`` 32 side by side), plain
+  ``jnp.take`` otherwise. The width chooses; the parameter's shape, the
+  values read and the rows updated are the same either way;
 * dot-interaction of embedding vectors (batched matmul → MXU) as in the
   DLRM architecture, upper-triangle extracted with a static mask;
 * top MLP in **bfloat16 compute / float32 params** so the matmuls hit the
@@ -53,6 +59,11 @@ class TabularDLRM(nn.Module):
     def __call__(self, features: Dict[str, jax.Array]) -> jax.Array:
         """features: column name -> int32 [batch] index array. Returns
         float32 [batch] logits."""
+        from ray_shuffling_data_loader_tpu.ops import (
+            dot_interaction,
+            embedding_lookup,
+        )
+
         embeds: List[jax.Array] = []
         for col in sorted(self.vocab_sizes):
             table = self.param(
@@ -61,21 +72,16 @@ class TabularDLRM(nn.Module):
                 (self.vocab_sizes[col], self.embed_dim),
                 jnp.float32,
             )
-            # Hashing trick: fold ids into the table (a no-op when ids are
-            # in range). Without it, a capped vocab (``vocab_cap`` in
-            # tests/smoke runs) feeds out-of-range ids to ``jnp.take``,
-            # whose default OOB mode FILLS WITH NaN — poisoning the loss.
-            idx = features[col].reshape(-1) % self.vocab_sizes[col]
             embeds.append(
-                jnp.take(table, idx, axis=0).astype(self.compute_dtype)
+                embedding_lookup(table, features[col]).astype(
+                    self.compute_dtype
+                )
             )
 
         # [batch, num_cols, dim]
         stacked = jnp.stack(embeds, axis=1)
         # Dot interaction (batched Gram on the MXU + upper-triangle
         # compaction), fused in VMEM by the Pallas kernel on TPU.
-        from ray_shuffling_data_loader_tpu.ops import dot_interaction
-
         inter_flat = dot_interaction(
             stacked,
             use_pallas=self.use_pallas_interaction,

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 import numpy as np
 
+from ray_shuffling_data_loader_tpu.ops.embedding import embedding_lookup
 from ray_shuffling_data_loader_tpu.ops.flash_attention import (
     flash_attention,
 )
@@ -101,10 +102,7 @@ class TabTransformer(nn.Module):
                 (self.vocab_sizes[col], self.embed_dim),
                 jnp.float32,
             )
-            # Same hashing trick as the DLRM: capped vocabs must not feed
-            # out-of-range ids to the gather (OOB fills with NaN).
-            idx = features[col].reshape(-1) % self.vocab_sizes[col]
-            tokens.append(jnp.take(table, idx, axis=0))
+            tokens.append(embedding_lookup(table, features[col]))
         x = jnp.stack(tokens, axis=1)  # [batch, n_cols, dim]
         col_embed = self.param(
             "col_embed",

@@ -5,6 +5,11 @@ from ray_shuffling_data_loader_tpu.ops.interaction import (  # noqa: F401
     dot_interaction_reference,
     num_pairs,
 )
+from ray_shuffling_data_loader_tpu.ops.embedding import (  # noqa: F401
+    embedding_lookup,
+    lookup_pack,
+    packed_tables,
+)
 from ray_shuffling_data_loader_tpu.ops.flash_attention import (  # noqa: F401
     flash_attention,
 )
@@ -20,6 +25,9 @@ __all__ = [
     "dot_interaction",
     "dot_interaction_reference",
     "num_pairs",
+    "embedding_lookup",
+    "lookup_pack",
+    "packed_tables",
     "attention_reference",
     "blockwise_attention",
     "flash_attention",

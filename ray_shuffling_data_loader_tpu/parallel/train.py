@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_shuffling_data_loader_tpu.ops.embedding import packed_tables
 from ray_shuffling_data_loader_tpu.ops.placement import traced_in_mesh
 from ray_shuffling_data_loader_tpu.parallel.mesh import (
     DATA_AXIS,
@@ -36,6 +37,7 @@ from ray_shuffling_data_loader_tpu.parallel.mesh import (
     param_shardings,
     replicated,
 )
+from ray_shuffling_data_loader_tpu.telemetry.trace import trace_span
 
 
 class TrainState(NamedTuple):
@@ -142,19 +144,28 @@ def make_train_step(
     Batch arrives sharded along ``data`` (as produced by
     ``JaxShufflingDataset``); XLA derives the gradient all-reduce.
     """
-    batch_in = batch_sharding(mesh, 1)
-    step_fn = traced_in_mesh(mesh, make_step_body(model, optimizer))
+    # How the step's embedding tables will be read is fixed by the shapes
+    # and the mesh when it is traced: a count on the span, not a rate.
+    built = {}
+    tables = getattr(model, "vocab_sizes", None)
+    if tables:
+        built["packed_tables"], built["pack"] = packed_tables(
+            tables, model.embed_dim, mesh
+        )
+    with trace_span("step:build", **built):
+        batch_in = batch_sharding(mesh, 1)
+        step_fn = traced_in_mesh(mesh, make_step_body(model, optimizer))
 
-    return jax.jit(
-        step_fn,
-        in_shardings=(
-            state_shardings,
-            None,  # features dict: let jax use committed input shardings
-            batch_in,
-        ),
-        out_shardings=(state_shardings, None),
-        donate_argnums=(0,) if donate_state else (),
-    )
+        return jax.jit(
+            step_fn,
+            in_shardings=(
+                state_shardings,
+                None,  # features dict: let jax use committed input shardings
+                batch_in,
+            ),
+            out_shardings=(state_shardings, None),
+            donate_argnums=(0,) if donate_state else (),
+        )
 
 
 def _tree_dot(a, b) -> jax.Array:

@@ -17,16 +17,20 @@ from ray_shuffling_data_loader_tpu.parallel import (
     MODEL_AXIS,
     adasum_reduce,
     batch_sharding,
+    bce_loss,
     init_state,
     make_mesh,
     make_psum_train_step,
     make_train_step,
+    param_shardings,
     param_spec,
 )
 
 
-def small_model():
-    return dlrm_for_data_spec(embed_dim=8, top_mlp=(32, 16), vocab_cap=1000)
+def small_model(embed_dim=8):
+    return dlrm_for_data_spec(
+        embed_dim=embed_dim, top_mlp=(32, 16), vocab_cap=1000
+    )
 
 
 def test_forward_shapes():
@@ -55,9 +59,10 @@ def test_mesh_validation():
         make_mesh(model_parallelism=3)
 
 
-def test_sharded_init_and_step():
+@pytest.mark.parametrize("embed_dim", [8, 32])
+def test_sharded_init_and_step(embed_dim):
     mesh = make_mesh(model_parallelism=2)
-    model = small_model()
+    model = small_model(embed_dim)
     feats_host = example_features(model, 16)
     opt = optax.adam(1e-3)
     state, shardings = init_state(
@@ -113,11 +118,12 @@ def test_pallas_interaction_partitions_on_mesh():
     )
 
 
-def test_psum_step_matches_pjit_step():
+@pytest.mark.parametrize("embed_dim", [8, 32])
+def test_psum_step_matches_pjit_step(embed_dim):
     """Explicit shard_map+psum DP and sharding-driven pjit DP must compute
     the same update."""
     mesh = make_mesh(model_parallelism=1)
-    model = small_model()
+    model = small_model(embed_dim)
     feats_host = example_features(model, 16)
     opt = optax.sgd(0.1)
     state_a, shardings = init_state(model, opt, mesh, feats_host)
@@ -140,6 +146,74 @@ def test_psum_step_matches_pjit_step():
     # bf16 compute + different reduction order (global mean vs per-shard
     # mean-then-pmean) allow small drift.
     np.testing.assert_allclose(np.asarray(la), np.asarray(lb), rtol=2e-2, atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "vocab, viewed",
+    [
+        (1024, True),  # 512 rows a device: whole 4-row lines of the view
+        (1004, False),  # 502 rows a device: the plain path for this table
+    ],
+)
+def test_split_table_reads_and_adds_as_on_one_device(vocab, viewed):
+    """A vocabulary split over the ``model`` axis: the loss and every
+    gradient leaf are the single-device values, whether the split table
+    keeps the lane-filled view or not, and the partitioner is not made
+    to gather the table onto every device."""
+    from ray_shuffling_data_loader_tpu.ops import lookup_pack
+    from ray_shuffling_data_loader_tpu.ops.placement import traced_in_mesh
+
+    mesh = make_mesh(model_parallelism=2)
+    assert (lookup_pack(vocab, 32, 2) == 4) == viewed
+    model = TabularDLRM(
+        vocab_sizes={"split": vocab, "small": 7}, embed_dim=32,
+        top_mlp=(32, 16),
+        # float32 throughout, so that the two runs differ by the order
+        # of float32 additions only and the comparison can be tight.
+        compute_dtype=jnp.float32,
+    )
+    feats_host = example_features(model, 64, seed=3)
+    labels_host = jnp.linspace(0, 1, 64, dtype=jnp.float32)
+    params = model.init(jax.random.key(1), feats_host)
+
+    def loss_and_grads(params, feats, labels):
+        return jax.value_and_grad(
+            lambda p: bce_loss(model.apply(p, feats), labels)
+        )(params)
+
+    want_loss, want = jax.jit(loss_and_grads)(params, feats_host, labels_host)
+
+    shardings = param_shardings(params, mesh, vocab_shard_threshold=512)
+    assert shardings["params"]["embed_split"].spec == (MODEL_AXIS, None)
+    assert shardings["params"]["embed_small"].spec == ()
+    bsh = batch_sharding(mesh, 1)
+    on_mesh = jax.jit(
+        traced_in_mesh(mesh, loss_and_grads),
+        in_shardings=(shardings, None, bsh),
+        out_shardings=(None, shardings),
+    )
+    args = (
+        jax.device_put(params, shardings),
+        {k: jax.device_put(v, bsh) for k, v in feats_host.items()},
+        jax.device_put(labels_host, bsh),
+    )
+    got_loss, got = on_mesh(*args)
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
+    assert got["params"]["embed_split"].sharding.spec == (MODEL_AXIS, None)
+    jax.tree.map(
+        lambda g, w: np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w),
+            rtol=1e-4, atol=1e-5 * float(jnp.abs(w).max()),
+        ),
+        got, want,
+    )
+    # Each device keeps its half of the table: no collective moves a
+    # whole table (or its view) between them.
+    hlo = on_mesh.lower(*args).compile().as_text()
+    whole = (f"f32[{vocab},32]", f"f32[{vocab // 4},128]")
+    for line in hlo.splitlines():
+        if " all-gather(" in line or " all-to-all(" in line:
+            assert not any(shape in line for shape in whole), line
 
 
 def test_psum_bf16_gradient_reduce_tracks_f32():

@@ -517,10 +517,43 @@ def test_the_step_names_its_parts_in_the_hlo():
     for piece in hlo.split('op_name="')[1:]:
         op_names.add(piece.split('"', 1)[0])
     for scope in ("/jvp(loss)/", "transpose(jvp(loss))", "/optimizer/",
-                  "/dot_interaction/", "dot_interaction_fwd"):
+                  "/dot_interaction/", "dot_interaction_fwd", "/embedding/"):
         assert any(scope in n for n in op_names), scope
+    # The lookup's gather forward, its scatter-add backward.
+    assert any("jvp(loss)" in n and "/embedding/" in n for n in op_names)
+    assert any(
+        "transpose(jvp(loss))" in n and "/embedding/" in n for n in op_names
+    )
     # The optimizer's update is no part of the loss.
     assert not any("loss" in n and "/optimizer/" in n for n in op_names)
+
+
+@pytest.mark.parametrize(
+    "embed_dim, packed_tables, pack", [(32, 19, 4), (128, 0, 1)]
+)
+def test_step_build_says_how_the_tables_are_read(
+    monkeypatch, embed_dim, packed_tables, pack
+):
+    """``make_train_step`` leaves on ``step:build`` how many of the model's
+    tables the step it traced reads through the lane-filled view: a count
+    fixed by the shapes, the shipped model's 19 at ``embed_dim`` 32, none
+    at 128."""
+    import optax
+
+    from ray_shuffling_data_loader_tpu.models import dlrm_for_data_spec
+    from ray_shuffling_data_loader_tpu.parallel import (
+        make_mesh,
+        make_train_step,
+    )
+
+    monkeypatch.setenv("RSDL_TRACE", "1")
+    trace.refresh_from_env()
+    mesh = make_mesh(devices=jax.devices()[:1])
+    model = dlrm_for_data_spec(embed_dim=embed_dim)
+    make_train_step(model, optax.adam(1e-3), mesh, None)
+    (span,) = [s for s in trace.local_spans() if s["name"] == "step:build"]
+    assert span["args"]["packed_tables"] == packed_tables
+    assert span["args"]["pack"] == pack
 
 
 # -- (f) the pool's spans ------------------------------------------------------------
