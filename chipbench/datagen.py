@@ -6,6 +6,11 @@ a later change to the program's generator cannot change the yardstick. The
 schema comes from the configuration's file (``data_spec``), the values from
 ``--seed``: the same seed gives the same files, byte for byte.
 
+A schema entry is ``[low, high, dtype]``, one number a row as upstream has
+it, or ``[low, high, dtype, width]``: ``width`` numbers a row, written as one
+Parquet column of ``fixed_size_list<dtype>[width]`` and read back as
+``[rows, width]``. A sample's shape is data, like its ranges.
+
 The files are the benchmark's inputs and its ground truth: ``read_truth``
 reads them back, so that what the loaders delivered is compared with what
 was put on disk, by key.
@@ -22,7 +27,7 @@ KEY_COLUMN = "key"
 
 
 def _np_dtype(name: str):
-    return {"int64": np.int64, "float64": np.float64}[name]
+    return {"int64": np.int64, "int32": np.int32, "float64": np.float64}[name]
 
 
 def generate_row_group(
@@ -45,17 +50,29 @@ def generate_row_group(
             dtype=np.int64,
         )
     }
-    for col, (low, high, dtype) in data_spec.items():
+    for col, (low, high, dtype, *width) in data_spec.items():
         dtype = _np_dtype(dtype)
+        # Three elements: the upstream draw, call for call.
+        shape = (num_rows_in_group, int(width[0])) if width else num_rows_in_group
         if np.issubdtype(dtype, np.integer):
-            buffer[col] = rng.integers(
-                low, high, num_rows_in_group, dtype=dtype
-            )
+            buffer[col] = rng.integers(low, high, shape, dtype=dtype)
         else:
             buffer[col] = (high - low) * rng.random(
-                num_rows_in_group, dtype=np.float64
+                shape, dtype=np.float64
             ) + low
     return buffer
+
+
+def _arrow_column(values: np.ndarray):
+    """A ``[rows]`` array as a plain column, a ``[rows, width]`` array as a
+    column of fixed-size lists."""
+    import pyarrow as pa
+
+    if values.ndim == 1:
+        return pa.array(values)
+    return pa.FixedSizeListArray.from_arrays(
+        pa.array(values.reshape(-1)), values.shape[1]
+    )
 
 
 def write_file(
@@ -86,7 +103,7 @@ def write_file(
         )
     table = pa.table(
         {
-            name: pa.array(np.concatenate([g[name] for g in groups]))
+            name: _arrow_column(np.concatenate([g[name] for g in groups]))
             for name in groups[0]
         }
     )
@@ -127,20 +144,35 @@ def generate(
     return list(names), int(sum(sizes))
 
 
+def narrowed(col: np.ndarray) -> np.ndarray:
+    """A column in the 32-bit type the device gets (int64 -> int32, float64
+    -> float32: the configuration's values all fit)."""
+    narrow = np.int32 if np.issubdtype(col.dtype, np.integer) else np.float32
+    return col.astype(narrow)
+
+
+def _numpy_column(column) -> np.ndarray:
+    """One file's column: ``[rows]``, or ``[rows, width]`` of a column of
+    fixed-size lists."""
+    import pyarrow as pa
+
+    if pa.types.is_fixed_size_list(column.type):
+        flat = column.combine_chunks().flatten().to_numpy(zero_copy_only=False)
+        return flat.reshape(-1, column.type.list_size)
+    return column.to_numpy(zero_copy_only=False)
+
+
 def read_truth(filenames: Sequence[str]) -> Dict[str, np.ndarray]:
     """Every column of the data set as it lies on disk, in key order and
-    narrowed to the 32-bit types the device gets (int64 -> int32, float64
-    -> float32: the configuration's values all fit)."""
+    narrowed to the 32-bit types the device gets."""
     import pyarrow.parquet as pq
 
     parts = [pq.read_table(f) for f in filenames]
     out = {}
     for name in parts[0].column_names:
-        col = np.concatenate(
-            [p.column(name).to_numpy(zero_copy_only=False) for p in parts]
+        out[name] = narrowed(
+            np.concatenate([_numpy_column(p.column(name)) for p in parts])
         )
-        narrow = np.int32 if np.issubdtype(col.dtype, np.integer) else np.float32
-        out[name] = col.astype(narrow)
     keys = out[KEY_COLUMN]
     if not np.array_equal(keys, np.arange(len(keys), dtype=np.int32)):
         raise AssertionError("the files' keys are not 0..n-1 in order")

@@ -1,25 +1,30 @@
 """One run of one cell: set-up, the window, the comparison, the metrics.
 
-Everything that belongs to one configuration, one traffic mix or one
-per-layer metric is a file of its own, found by the name ``BENCHMARK.json``
-gives it: ``configs/<name>.json`` (by the entry's ``file``),
-``traffic/<traffic>.json``, ``layer_metrics/<metric>.py``. This module is the
-one general consumer loop that reads them:
+Everything that belongs to one configuration, one traffic mix, one
+per-layer metric or one model family is a file of its own, found by name:
+``configs/<name>.json`` (by the entry's ``file`` in ``BENCHMARK.json``),
+``traffic/<traffic>.json``, ``layer_metrics/<metric>.py``, and
+``families/<family>/`` by the ``family`` the configuration's file names.
+This module is the one general consumer loop that reads them:
 
-    runtime.init -> data files from --seed -> init_state / make_train_step
-    -> the loader the configuration names -> warm-up on epoch 0 (the steps
-    the reference follows) -> WINDOW: set_epoch, for batch in loader: step,
-    at most ``steps_in_flight`` steps running ahead, epochs back to back
-    -> close, read the memory peak, free the state -> the comparison.
+    runtime.init -> data files from --seed -> the family's state and
+    compiled step -> the loader the configuration names -> warm-up on epoch
+    0 (the steps the reference follows) -> WINDOW: set_epoch, for batch in
+    loader: step, ``steps_in_flight`` steps dispatched ahead of the one
+    waited for (seconds of device work, so that the chip stays fed while the
+    host stands still), epochs back to back -> when the time is up send
+    nothing more, wait for all that was sent, read the clock -> the memory
+    peak, free the state -> the comparison.
 
-From the program it takes the system under test (runtime, loaders,
-``init_state``, ``make_train_step``, the model) and nothing of the
-yardstick. All ``jax`` imports sit inside functions: the runtime's workers
-re-import ``__main__``.
+From the program it takes the system under test (runtime, loaders, mesh;
+through the family's ``program.py`` the model, its state and its step) and
+nothing of the yardstick. All ``jax`` imports sit inside functions: the
+runtime's workers re-import ``__main__``.
 """
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
@@ -28,13 +33,14 @@ import statistics
 import sys
 import tempfile
 import time
+import types
 from typing import Callable, Dict, List, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
 
-# -- data files: cell, configuration, traffic mix, metric readers ----------
+# -- data files: cell, configuration, traffic mix, metric readers, family ------
 
 
 def load_benchmark(root: str = ROOT) -> dict:
@@ -78,24 +84,52 @@ def load_reader(name: str, root: str = ROOT, paths0: str = "chipbench"):
     return module.read
 
 
+FAMILY_MODULES = ("counts", "reference", "program")
+
+
+def load_family(cfg: dict, root: str = ROOT, paths0: str = "chipbench"):
+    """The family the configuration names: ``families/<family>/`` as a
+    package of its own, with its ``counts``, ``reference`` and ``program``.
+    A configuration that names none, or one that is not there, is an error,
+    not a default."""
+    base = os.path.join(root, paths0, "families")
+    found = sorted(
+        d for d in (os.listdir(base) if os.path.isdir(base) else [])
+        if os.path.isfile(os.path.join(base, d, "__init__.py"))
+    )
+    name = cfg.get("family")
+    if name not in found:
+        raise KeyError(
+            f"configuration {cfg.get('name')!r} names the family {name!r}; "
+            f"families found under {base}: {found}"
+        )
+    path = os.path.join(base, name)
+    package = "chipbench_family_" + name.replace(".", "_").replace("-", "_")
+    loaded = sys.modules.get(package)
+    if loaded is None or list(loaded.__path__) != [path]:
+        # Another root's family of the same name gives way, with its modules.
+        for key in [k for k in sys.modules if k.startswith(package + ".")]:
+            del sys.modules[key]
+        spec = importlib.util.spec_from_file_location(
+            package, os.path.join(path, "__init__.py"),
+            submodule_search_locations=[path],
+        )
+        loaded = importlib.util.module_from_spec(spec)
+        sys.modules[package] = loaded
+        spec.loader.exec_module(loaded)
+    return types.SimpleNamespace(
+        name=name,
+        **{m: importlib.import_module(f"{package}.{m}") for m in FAMILY_MODULES},
+    )
+
+
 # -- the program's side -------------------------------------------------------
 
 
-def _program_params(weights: dict, like, cfg: dict):
-    """The benchmark's flat weights in the program's (flax) tree."""
+def _like(tree, like):
+    """``tree``, once it has the shapes and types of the program's own."""
     import jax
 
-    from chipbench import work
-
-    inner = {
-        f"embed_{c}": weights[f"embed_{c}"] for c in work.model_columns(cfg)
-    }
-    for i in range(len(work.mlp_shapes(cfg))):
-        inner[f"Dense_{i}"] = {
-            "kernel": weights[f"dense_{i}.w"],
-            "bias": weights[f"dense_{i}.b"],
-        }
-    tree = {"params": inner}
     want = jax.tree.map(lambda x: (x.shape, x.dtype), like)
     got = jax.tree.map(lambda x: (x.shape, x.dtype), tree)
     if want != got:
@@ -106,21 +140,10 @@ def _program_params(weights: dict, like, cfg: dict):
     return tree
 
 
-def _flat_leaves(tree, cfg: dict) -> dict:
-    """The program's tree back under the reference's leaf names."""
-    from chipbench import work
-
-    inner = tree["params"]
-    out = {f"embed_{c}": inner[f"embed_{c}"] for c in work.model_columns(cfg)}
-    for i in range(len(work.mlp_shapes(cfg))):
-        out[f"dense_{i}.w"] = inner[f"Dense_{i}"]["kernel"]
-        out[f"dense_{i}.b"] = inner[f"Dense_{i}"]["bias"]
-    return out
-
-
-def _make_loader(cfg, filenames, mesh, feature_columns, loader_seed, epochs):
-    """The loader the configuration names, with the time its constructor
-    took: the resident loader stages the whole data set there."""
+def _make_loader(
+    cfg, filenames, mesh, feature_columns, label_column, loader_seed, epochs
+):
+    """The loader the configuration names, asked for the family's columns."""
     kind = cfg["loader"]
     if kind == "stream":
         from ray_shuffling_data_loader_tpu.jax_dataset import JaxShufflingDataset
@@ -132,7 +155,7 @@ def _make_loader(cfg, filenames, mesh, feature_columns, loader_seed, epochs):
             batch_size=int(cfg["batch_size"]),
             rank=0,
             feature_columns=feature_columns,
-            label_column=cfg["label_column"],
+            label_column=label_column,
             num_reducers=int(cfg["num_reducers"]),
             max_concurrent_epochs=int(cfg["max_concurrent_epochs"]),
             seed=loader_seed,
@@ -148,7 +171,7 @@ def _make_loader(cfg, filenames, mesh, feature_columns, loader_seed, epochs):
             num_epochs=epochs,
             batch_size=int(cfg["batch_size"]),
             feature_columns=feature_columns,
-            label_column=cfg["label_column"],
+            label_column=label_column,
             seed=loader_seed,
             mesh=mesh,
             num_rows=int(cfg["num_rows"]),
@@ -190,75 +213,49 @@ def host_facts() -> dict:
 
 
 class Program:
-    """The program's compiled train step with its state, started from the
-    benchmark's weights, and the readings the comparison takes from its
-    first steps: each step's loss, the norm and the sketch of every leaf of
-    the first gradient as the optimizer got it (Adam's first moment after
-    one step is (1 - b1) g), and the norm of every leaf's change after the steps
-    followed, read before a later step consumes the donated state."""
+    """The program's compiled train step with its state, as the family's
+    ``program.Side`` built them, started from the benchmark's weights, and
+    the readings the comparison takes from its first steps: each step's
+    loss, the norm and the sketch of every leaf of the first gradient as the
+    optimizer got it (Adam's first moment after one step is (1 - b1) g), and
+    the norm of every leaf's change after the steps followed, read before a
+    later step consumes the donated state."""
 
-    def __init__(self, cfg, mesh, seed, rehearse=False, tamper_step=None):
+    def __init__(self, cfg, family, mesh, seed, rehearse=False, tamper_step=None):
         import jax
-        import jax.numpy as jnp
-        import optax
 
-        from ray_shuffling_data_loader_tpu.models import dlrm_for_data_spec
-        from ray_shuffling_data_loader_tpu.parallel import (
-            init_state,
-            make_train_step,
-        )
         from ray_shuffling_data_loader_tpu.parallel.mesh import replicated
 
-        from chipbench import reference, work
+        from chipbench import check
 
-        self.cfg = cfg
-        self.model_cols = work.model_columns(cfg)
-        vocab_cap = int(cfg.get("vocab_cap", 0))
-        model = dlrm_for_data_spec(
-            embed_dim=int(cfg["model"]["embed_dim"]),
-            top_mlp=tuple(cfg["model"]["top_mlp"]),
-            vocab_cap=vocab_cap or None,
-            use_pallas_interaction=True,
-            interpret_interaction=rehearse,
-        )
-        opt = cfg["optimizer"]
-        optimizer = optax.adam(
-            float(opt["learning_rate"]), b1=float(opt["b1"]),
-            b2=float(opt["b2"]), eps=float(opt["eps"]),
-        )
-        batch = int(cfg["batch_size"])
-        example = {c: jnp.zeros((batch,), jnp.int32) for c in self.model_cols}
-        state, shardings = init_state(
-            model, optimizer, mesh, example,
-            rng=jax.random.key(seed & 0x7FFFFFFF),
-        )
+        self.side = side = family.program.Side(cfg, mesh, seed, rehearse)
         # Both sides start from the benchmark's weights, not the program's.
-        self._weights = lambda: reference.init_params(
-            cfg, seed, vocab_cap, sharding=replicated(mesh)
+        self._weights = lambda: family.reference.init_params(
+            cfg, seed, sharding=replicated(mesh)
         )
-        self.state = state._replace(
-            params=_program_params(self._weights(), state.params, cfg)
+        self.state = side.state._replace(
+            params=_like(side.tree(self._weights()), side.state.params)
         )
-        step = make_train_step(model, optimizer, mesh, shardings)
-        self._step = tamper_step(step) if tamper_step else step
-        b1 = float(opt["b1"])
+        self._step = tamper_step(side.step) if tamper_step else side.step
+        # The side keeps the columns and the tree's names; the state is
+        # this object's alone, since every step donates it.
+        side.state = side.step = None
+        b1 = float(cfg["optimizer"]["b1"])
+
         def first_gradient(mu):
             g = {k: v / (1.0 - b1) for k, v in mu.items()}
-            norms = {k: jnp.sqrt(jnp.sum(jnp.square(v))) for k, v in g.items()}
-            return norms, reference.sketches(g)
+            return check.norms(g), check.sketches(g)
 
         self._first_gradient = jax.jit(first_gradient)
         self._change_norms = jax.jit(
-            lambda p, p0: {
-                k: jnp.sqrt(jnp.sum(jnp.square(p[k] - p0[k]))) for k in p
-            }
+            lambda p, p0: check.norms({k: p[k] - p0[k] for k in p})
         )
         self.readings = {"loss": []}
 
     def step_on(self, features, label):
         """One train step on one batch; returns the loss, on the device."""
         self.state, metrics = self._step(
-            self.state, {c: features[c] for c in self.model_cols}, label
+            self.state, *self.side.inputs(features, label)
         )
         return metrics["loss"]
 
@@ -267,13 +264,13 @@ class Program:
         if "grad_norm" not in self.readings:
             self.readings["grad_norm"], self.readings["grad_sketch"] = (
                 self._first_gradient(
-                    _flat_leaves(self.state.opt_state[0].mu, self.cfg)
+                    self.side.flat(self.state.opt_state[0].mu)
                 )
             )
 
     def record_change(self) -> None:
         self.readings["change_norm"] = self._change_norms(
-            _flat_leaves(self.state.params, self.cfg), self._weights()
+            self.side.flat(self.state.params), self._weights()
         )
 
     def fetch_readings(self) -> dict:
@@ -321,6 +318,7 @@ def run_cell(
     if rehearse:
         cfg = {**cfg, **cfg["rehearsal"]}
     chips = int(cell["chips"])
+    family = load_family(cfg, root, bench["paths"][0])
 
     import jax
     import numpy as np
@@ -328,7 +326,7 @@ def run_cell(
     from ray_shuffling_data_loader_tpu import runtime
     from ray_shuffling_data_loader_tpu.parallel import make_mesh
 
-    from chipbench import check, datagen, reference, trace_reduce, work
+    from chipbench import check, datagen, trace_reduce, work
 
     devices = list(devices if devices is not None else jax.devices()[:chips])
     if len(devices) != chips:
@@ -336,11 +334,7 @@ def run_cell(
     mesh = make_mesh(devices=devices)
     batch = int(cfg["batch_size"])
     num_rows = int(cfg["num_rows"])
-    vocab_cap = int(cfg.get("vocab_cap", 0))
     key_col = datagen.KEY_COLUMN
-    label_col = cfg["label_column"]
-    model_cols = work.model_columns(cfg)
-    feature_cols = [*model_cols, key_col]
     in_flight = int(traffic["steps_in_flight"])
     warm_steps = int(traffic["warmup_steps"])
     stride = int(traffic["sample_stride"])
@@ -368,18 +362,28 @@ def run_cell(
             f"data: {num_rows} rows in {len(filenames)} files, "
             f"{disk_bytes / 1e9:.2f} GB on disk, {time.perf_counter() - t0:.1f} s"
         )
-        program = Program(cfg, mesh, seed, rehearse, tamper.get("step"))
+        program = Program(cfg, family, mesh, seed, rehearse, tamper.get("step"))
         say(
-            f"model: {work.num_parameters(cfg, vocab_cap) / 1e6:.1f} M "
-            f"parameters, batch {batch}, mesh {dict(mesh.shape)}"
+            f"model: family {family.name}, "
+            f"{family.counts.num_parameters(cfg) / 1e6:.1f} M parameters, "
+            f"batch {batch}, mesh {dict(mesh.shape)}"
         )
+        # The columns the family asks the loader for, and the key beside them.
+        label_col = program.side.label_column
+        feature_cols = [*program.side.feature_columns, key_col]
+
+        def delivered(features, label) -> dict:
+            """A delivered batch as one ``{column: array}``."""
+            return {**features, label_col: label} if label_col else dict(features)
 
         # -- the loader, and the first batch ----------------------------------
-        # More epochs than the fastest plausible window can use.
+        # More epochs than the fastest plausible window can use; a window
+        # that does use them all (a toy rehearsal's) closes there.
         epochs_given = int(traffic["epochs_given"])
         t_loader = time.perf_counter()
         ds = _make_loader(
-            cfg, filenames, mesh, feature_cols, seed & 0x7FFFFFFF, epochs_given
+            cfg, filenames, mesh, feature_cols, label_col,
+            seed & 0x7FFFFFFF, epochs_given,
         )
 
         losses: List = []  # device scalars, fetched after the window
@@ -402,7 +406,7 @@ def run_cell(
                 jax.block_until_ready((features, label))
                 first_batch_s = time.perf_counter() - t_loader
             features, label, loss = consume(features, label)
-            warm_batches.append({**features, label_col: label})
+            warm_batches.append(delivered(features, label))
             program.record(loss)
         program.record_change()
         # Every program of the window is compiled by now; let the rest of
@@ -435,7 +439,7 @@ def run_cell(
         epoch = 1
         done = False
         t_iter = t_open
-        while not done:
+        while not done and epoch < epochs_given:
             ds.set_epoch(epoch)
             it = iter(ds)
             keys: List = []
@@ -458,7 +462,7 @@ def run_cell(
                 losses.append(loss)
                 keys.append(features[key_col])
                 if (attempted - 1) % stride == sample_at:
-                    samples.append({**features, label_col: label})
+                    samples.append(delivered(features, label))
                 now = time.perf_counter()
                 iter_s.append(now - t_iter)
                 t_iter = now
@@ -516,18 +520,22 @@ def run_cell(
         )
         bad_losses = sum(not np.isfinite(x) for x in loss_values)
         numbers["losses_not_finite"] = bad_losses
+        # The reference follows the files' rows of the keys delivered.
         ref_batches = []
         for b in warm_host:
             keys = np.clip(b[key_col].astype(np.int64), 0, num_rows - 1)
             ref_batches.append(
-                ({c: truth[c][keys] for c in model_cols}, truth[label_col][keys])
+                family.reference.batch_of(
+                    cfg, {c: col[keys] for c, col in truth.items()}
+                )
             )
         del truth
-        ref = reference.Reference(cfg).follow(
-            lambda: reference.init_params(cfg, seed, vocab_cap), ref_batches
+        ref = family.reference.Reference(cfg).follow(
+            lambda: family.reference.init_params(cfg, seed), ref_batches
         )
         training = check.training_numbers(prog, ref)
-        say(f"loss_gap {training.pop('loss_gap')!r}, not compared")
+        for name in check.PRINTED:
+            say(f"{name} {training.pop(name)!r}, not compared")
         numbers.update(training)
         limits = {
             **{k: 0 for k in (
@@ -545,6 +553,7 @@ def run_cell(
         kind = devices[0].device_kind
         ctxm = {
             "cfg": cfg,
+            "family": family,
             "cell": cell,
             "traffic": traffic,
             "chips": chips,

@@ -3,7 +3,8 @@ unpacked arrays ready on the device, the one wait of a batch that a clock
 around ``next()`` cannot see (``layers.staging``). A batch's arrays are
 ready only once the device reaches its unpack, which queues behind the steps
 already enqueued: the floor of this reading is the step time x the steps in
-flight (two run-ahead steps of 193 ms read 388 ms), not the link's time. It
+flight (two run-ahead steps of 193 ms read 388 ms; the mix's twelve, since PR
+27, twelve of them), not the link's time. It
 is a stall detector: seconds in a run that stalls inside a transfer."""
 
 

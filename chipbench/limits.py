@@ -5,13 +5,14 @@
 For each seed, at the configuration's own size: the program's first three
 steps (its compiled step, started from the benchmark's weights, on three
 batches of rows that all differ, straight from the generator) against the
-plain reference: the lower readings. For the first ``--controls`` seeds also
-the control (the reference in float8, put in the program's place) and the
-fault "half of the batch left out, the mean taken over the rest" (planted
-in the reference put in the program's place): the upper readings. "A step
-that returns its state unchanged" reads 1 on ``change_norm_gap`` by
-construction and needs no run. Not part of a benchmark run; the numbers go
-into ``PERF.md`` and the configuration's ``limits``.
+family's plain reference: the lower readings. For the first ``--controls``
+seeds also the control (the reference in the family's next lower precision,
+put in the program's place) and the fault "half of the batch left out, the
+mean taken over the rest" (planted in the reference put in the program's
+place): the upper readings. "A step that returns its state unchanged" reads
+1 on ``change_norm_gap`` by construction and needs no run. Not part of a
+benchmark run; the numbers go into ``PERF.md`` and the configuration's
+``limits``.
 """
 
 from __future__ import annotations
@@ -29,24 +30,21 @@ if ROOT not in sys.path:
 
 def generator_batches(cfg: dict, seed: int, steps: int):
     """``steps`` batches of rows that all differ, straight from the
-    generator: ``[(features, labels)]`` as numpy arrays."""
-    import numpy as np
-
-    from chipbench import datagen, work
+    generator, as the files would hold them: ``[{column: numpy}]``."""
+    from chipbench import datagen
 
     batch = int(cfg["batch_size"])
     raw = datagen.generate_row_group(cfg["data_spec"], 0, 0, steps * batch, seed)
-    cols = work.model_columns(cfg)
     return [
-        (
-            {c: raw[c][i * batch : (i + 1) * batch].astype(np.int32) for c in cols},
-            raw[cfg["label_column"]][i * batch : (i + 1) * batch].astype(np.float32),
-        )
+        {
+            c: datagen.narrowed(col[i * batch : (i + 1) * batch])
+            for c, col in raw.items()
+        }
         for i in range(steps)
     ]
 
 
-def program_readings(cfg, mesh, seed, batches, rehearse=False):
+def program_readings(cfg, family, mesh, seed, batches, rehearse=False):
     """The program's compiled step driven over ``batches`` from the
     benchmark's weights; the readings the comparison takes."""
     import jax
@@ -56,11 +54,14 @@ def program_readings(cfg, mesh, seed, batches, rehearse=False):
     from chipbench import harness
 
     bsh = batch_sharding(mesh, 1)
-    program = harness.Program(cfg, mesh, seed, rehearse)
-    for feats, labels in batches:
+    program = harness.Program(cfg, family, mesh, seed, rehearse)
+    side = program.side
+    for rows in batches:
         loss = program.step_on(
-            {c: jax.device_put(v, bsh) for c, v in feats.items()},
-            jax.device_put(labels, bsh),
+            {c: jax.device_put(rows[c], bsh) for c in side.feature_columns},
+            jax.device_put(rows[side.label_column], bsh)
+            if side.label_column
+            else None,
         )
         program.record(loss)
     program.record_change()
@@ -84,7 +85,7 @@ def main(argv) -> int:
     from ray_shuffling_data_loader_tpu.parallel import make_mesh
     from ray_shuffling_data_loader_tpu.utils import enable_compile_cache
 
-    from chipbench import check, harness, reference
+    from chipbench import check, harness
 
     enable_compile_cache()
     wanted = "cpu" if args.rehearse_on_cpu else "tpu"
@@ -97,22 +98,28 @@ def main(argv) -> int:
         cfg = json.load(f)
     if args.rehearse_on_cpu:
         cfg = {**cfg, **cfg["rehearsal"]}
+    family = harness.load_family(cfg, paths0=bench["paths"][0])
+    reference = family.reference
     mesh = make_mesh(devices=jax.devices()[:1])
     batch = int(cfg["batch_size"])
-    vocab_cap = int(cfg.get("vocab_cap", 0))
     ref_plain = reference.Reference(cfg)
-    ref_fp8 = reference.Reference(cfg, quant="fp8")
+    ref_control = reference.Reference(cfg, quant=reference.CONTROL)
     rows = []
     for n, seed in enumerate(int(s) for s in args.seeds.split(",")):
         batches = generator_batches(cfg, seed, args.steps)
-        prog = program_readings(cfg, mesh, seed, batches, args.rehearse_on_cpu)
-        make = lambda: reference.init_params(cfg, seed, vocab_cap)  # noqa: E731
-        ref = ref_plain.follow(make, batches)
+        prog = program_readings(
+            cfg, family, mesh, seed, batches, args.rehearse_on_cpu
+        )
+        make = lambda: reference.init_params(cfg, seed)  # noqa: E731
+        ref_batches = [reference.batch_of(cfg, b) for b in batches]
+        ref = ref_plain.follow(make, ref_batches)
         sides = {"program": prog}
         if n < args.controls:
-            sides["control_fp8"] = ref_fp8.follow(make, batches)
+            sides["control_" + reference.CONTROL] = ref_control.follow(
+                make, ref_batches
+            )
             sides["fault_half_batch"] = ref_plain.follow(
-                make, batches, rows_used=batch // 2
+                make, ref_batches, rows_used=batch // 2
             )
         row = {"seed": seed}
         for side, readings in sides.items():

@@ -25,6 +25,10 @@ def config(name):
         return json.load(f)
 
 
+def family_of(cfg):
+    return harness.load_family(cfg)
+
+
 # -- the arithmetic, against hand counts ----------------------------------------
 
 
@@ -37,10 +41,14 @@ def config(name):
 )
 def test_work_from_the_config_file_alone(name, flops, params, state):
     cfg = config(name)
-    assert work.flops_per_row(cfg) == flops
-    assert work.num_parameters(cfg) == params
-    assert work.state_bytes(cfg) == state
-    assert sum(work.vocab_sizes(cfg).values()) == 2_912_607
+    counts = family_of(cfg).counts
+    assert counts.flops_per_row(cfg) == flops
+    assert counts.num_parameters(cfg) == params
+    assert counts.state_bytes(cfg) == state
+    assert sum(counts.vocab_sizes(cfg).values()) == 2_912_607
+    # The rehearsal's cap on the tables is the configuration's, not a flag.
+    toy = {**cfg, **cfg["rehearsal"]}
+    assert max(counts.vocab_sizes(toy).values()) == 5000
 
 
 def test_packed_and_permute_bytes():
@@ -53,7 +61,7 @@ def test_packed_and_permute_bytes():
 
 def test_interaction_work():
     cfg = config("dlrm-mlperf-stream")
-    w = work.interaction_fwd_work(cfg, 250_000)
+    w = family_of(cfg).counts.interaction_fwd_work(cfg, 250_000)
     assert w["flops"] == 250_000 * 171 * 128 * 2
     assert w["bytes"] == 250_000 * (19 * 128 * 2 + 171 * 2)
 
@@ -80,6 +88,7 @@ def test_benchmark_json_names_files_that_exist():
     for c in BENCH["configs"]:
         cfg = config(c["name"])
         assert cfg["name"] == c["name"]
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
         for key in c["reduced"]:
             assert key in cfg and key in cfg["published"], key
     cells = {w["name"] for w in BENCH["workloads"]}
@@ -99,7 +108,7 @@ def test_a_new_config_mix_cell_and_metric_are_files_and_entries_only(tmp_path):
     root = str(tmp_path)
     shutil.copytree(os.path.join(ROOT, "chipbench"), os.path.join(root, "chipbench"))
     before = {
-        p: open(os.path.join(dp, p), "rb").read()
+        os.path.join(dp, p): open(os.path.join(dp, p), "rb").read()
         for dp, _, fs in os.walk(os.path.join(root, "chipbench"))
         for p in fs
     }
@@ -129,20 +138,16 @@ def test_a_new_config_mix_cell_and_metric_are_files_and_entries_only(tmp_path):
     # A reader that finds nothing returns nothing, never 0.
     assert harness.load_reader("new.rows", root)({"rows": 0}) is None
     for p, data in before.items():
-        found = [
-            os.path.join(dp, p)
-            for dp, _, fs in os.walk(os.path.join(root, "chipbench"))
-            if p in fs
-        ]
-        assert open(found[0], "rb").read() == data, p
+        assert open(p, "rb").read() == data, p
 
 
 @pytest.mark.parametrize(
     "name", [m["name"] for m in BENCH["per_layer"]]
 )
 def test_a_reader_with_nothing_to_read_returns_nothing(name):
+    cfg = config("dlrm-shipped-resident")
     ctx = {
-        "cfg": config("dlrm-shipped-resident"), "cell": {}, "traffic": {},
+        "cfg": cfg, "family": family_of(cfg), "cell": {}, "traffic": {},
         "chips": 1, "device_kind": "cpu", "peaks": None, "window_s": 0.0,
         "rows": 0, "iter_s": [], "wait_s": 0.0, "first_batch_s": None,
         "loader_stats": {}, "trace": None,
@@ -183,7 +188,8 @@ def test_reduce_trace_window_busy_and_readers():
     assert tr["window_s"] == pytest.approx((350 - 90) / 1e9)
     assert tr["busy_s"] == pytest.approx(125 / 1e9)
     assert [n for n, _ in tr["breakdown"]["device_ops"]][0] == "fusion.1"
-    ctx = {"trace": tr, "cfg": config("dlrm-shipped-resident"), "chips": 1,
+    cfg = config("dlrm-shipped-resident")
+    ctx = {"trace": tr, "cfg": cfg, "family": family_of(cfg), "chips": 1,
            "peaks": work.peaks_for("TPU v5 lite")}
     assert harness.load_reader("device.idle_pct")(ctx) == pytest.approx(
         100 * (1 - 125 / 260)
@@ -193,7 +199,10 @@ def test_reduce_trace_window_busy_and_readers():
     assert harness.load_reader("resident.permute_roofline")(ctx) == pytest.approx(
         100 * least / 40e-9
     )
-    assert harness.load_reader("interaction.fwd_roofline")(ctx) > 0
+    least = family_of(cfg).counts.interaction_fwd_work(cfg, 250_000)["bytes"] / 819e9
+    assert harness.load_reader("interaction.fwd_roofline")(ctx) == pytest.approx(
+        100 * least / 25e-9
+    )
     assert trace_reduce.short_name(planes[dev]["XLA Ops"][1][0]) == "jvp.1[tpu_custom_call]"
 
 
@@ -214,6 +223,7 @@ def test_training_numbers_by_the_worst_leaf():
     prog = json.loads(json.dumps(ref))
     prog["loss"][1] = 0.69 * 1.01
     prog["grad_norm"]["c"] = 0.1  # tiny leaf: measured against the median
+    prog["grad_norm"]["a"] = 1.05  # gaps 0.05, 0, 0.1: the median leaf's 0.05
     prog["change_norm"]["c"] = 0.0  # its gradient is nought: left out
     prog["change_norm"]["a"] = 0.0  # has not moved: reads a/median = 0.5
     prog["grad_sketch"]["a"] = [0.6, 0.5]  # differs by 0.3 of a norm of 1
@@ -222,6 +232,8 @@ def test_training_numbers_by_the_worst_leaf():
     assert n["grad_diff"] == pytest.approx(0.1)  # the median leaf of 0.3, 0.1, 0
     assert n["loss_gap"] == pytest.approx(0.01)
     assert n["grad_norm_gap"] == pytest.approx(0.1 - 1e-6)
+    assert n["grad_norm_mid_gap"] == pytest.approx(0.05)
+    assert set(check.PRINTED) == {"loss_gap", "grad_norm_gap"}
     assert n["change_norm_gap"] == pytest.approx(0.1 / 0.2)
     same = check.training_numbers(ref, ref)
     assert set(same.values()) == {0.0}
@@ -273,7 +285,7 @@ def toy():
 
     cfg = config("dlrm-shipped-resident")
     cfg = {**cfg, **cfg["rehearsal"]}
-    return cfg, make_mesh(devices=jax.devices()[:1])
+    return cfg, family_of(cfg), make_mesh(devices=jax.devices()[:1])
 
 
 def test_reference_agrees_with_the_program_and_the_control_does_not(toy):
@@ -281,21 +293,27 @@ def test_reference_agrees_with_the_program_and_the_control_does_not(toy):
     interpreter) stays inside the configuration's limits against the
     float32 reference; the reference in float8 put in its place, and the
     reference fed half of each batch, do not."""
-    from chipbench import reference
-
-    cfg, mesh = toy
+    cfg, family, mesh = toy
+    reference = family.reference
+    assert reference.CONTROL == "fp8"
     seed = 12
-    batches = limits.generator_batches(cfg, seed, 3)
-    make = lambda: reference.init_params(cfg, seed, int(cfg["vocab_cap"]))  # noqa: E731
+    rows = limits.generator_batches(cfg, seed, 3)
+    batches = [reference.batch_of(cfg, r) for r in rows]
+    make = lambda: reference.init_params(cfg, seed)  # noqa: E731
     ref = reference.Reference(cfg).follow(make, batches)
     def judged(side):
         numbers = check.training_numbers(side, ref)
-        numbers.pop("loss_gap")  # printed, not compared
+        for name in check.PRINTED:  # printed, not compared
+            numbers.pop(name)
         return check.judge(numbers, cfg["limits"])
 
-    ok, compared = judged(limits.program_readings(cfg, mesh, seed, batches, rehearse=True))
+    ok, compared = judged(
+        limits.program_readings(cfg, family, mesh, seed, rows, rehearse=True)
+    )
     assert ok, compared
-    ok, compared = judged(reference.Reference(cfg, quant="fp8").follow(make, batches))
+    ok, compared = judged(
+        reference.Reference(cfg, quant=reference.CONTROL).follow(make, batches)
+    )
     assert not ok and not compared["grad_diff"]["ok"], compared
     ok, compared = judged(
         reference.Reference(cfg).follow(make, batches, rows_used=2048)
@@ -308,26 +326,24 @@ def test_a_sketch_estimates_the_norm_of_a_difference():
     column is off by the same amount, which a plain fold would add up."""
     import jax.numpy as jnp
 
-    from chipbench import reference
-
     rng = np.random.default_rng(0)
     a = rng.normal(size=(3000, 128)).astype(np.float32)
     b = a + 0.01 * rng.normal(size=(1, 128)).astype(np.float32)
-    gap = np.asarray(reference.sketch(jnp.asarray(a))) - np.asarray(
-        reference.sketch(jnp.asarray(b))
+    gap = np.asarray(check.sketch(jnp.asarray(a))) - np.asarray(
+        check.sketch(jnp.asarray(b))
     )
     assert np.linalg.norm(gap) == pytest.approx(np.linalg.norm(a - b), rel=0.15)
-    assert np.asarray(reference.sketch(jnp.ones((5,)))).shape == (256,)
+    assert np.asarray(check.sketch(jnp.ones((5,)))).shape == (256,)
 
 
 def test_weights_come_from_the_seed(toy):
-    from chipbench import reference
-
-    cfg, _ = toy
-    a = reference.init_params(cfg, 2**31 + 5, 5000)
-    b = reference.init_params(cfg, 2**31 + 5, 5000)
-    c = reference.init_params(cfg, 5, 5000)
-    assert len(a) == 19 + 2 * len(work.mlp_shapes(cfg))
+    cfg, family, _ = toy
+    reference = family.reference
+    a = reference.init_params(cfg, 2**31 + 5)
+    b = reference.init_params(cfg, 2**31 + 5)
+    c = reference.init_params(cfg, 5)
+    assert len(a) == 19 + 2 * len(family.counts.mlp_shapes(cfg))
+    assert a["embed_embeddings_name16"].shape == (5000, 32)
     assert all(np.array_equal(a[k], b[k]) for k in a)
     assert not np.array_equal(a["dense_0.w"], c["dense_0.w"])
 
