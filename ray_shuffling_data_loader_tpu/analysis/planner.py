@@ -139,6 +139,8 @@ def footer_stats(
             itemsize = dt.itemsize
             if narrow_to_32 and itemsize == 8:
                 itemsize = 4
+            for w in _shuffle._row_shape_of(fld):
+                itemsize *= w
             width += itemsize
         if width:
             bytes_per_row = float(width)

@@ -41,6 +41,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ray_shuffling_data_loader_tpu import telemetry
 from ray_shuffling_data_loader_tpu.dataset import ShufflingDataset
 from ray_shuffling_data_loader_tpu.runtime import ColumnBatch
+from ray_shuffling_data_loader_tpu.runtime.store import (
+    packed_slots,
+    packed_widths,
+)
 from ray_shuffling_data_loader_tpu.telemetry import audit as _audit
 from ray_shuffling_data_loader_tpu.telemetry import metrics as _metrics
 from ray_shuffling_data_loader_tpu.telemetry import phases as _phases
@@ -63,7 +67,8 @@ class JaxBatchSpec:
     ``_normalize_torch_data_spec``, reference ``torch_dataset.py:144-201``)."""
 
     feature_columns: List[str]
-    label_column: str
+    # None: a batch of features only (a sequence model's tokens).
+    label_column: Optional[str]
     feature_types: Optional[List[Any]] = None
     feature_shapes: Optional[List[Optional[Tuple[int, ...]]]] = None
     label_type: Any = None
@@ -156,6 +161,10 @@ def layer_counts(spans) -> Dict[str, Dict[str, Any]]:
     * ``staging``: ``stager_s`` (``stage:epoch``, the stager thread's
       life), ``ring_put_s`` (``stage:ring-put``, the loader's slack),
       ``transfers`` and ``max_transfer_s`` of the ``stage:transfer`` spans
+    * ``train step[<name>]``: of the spans of the category ``train`` (the
+      counters a model's step hands over, under the names and with the
+      numbers the model chose: ``parallel/train.py``), ``spans`` and
+      ``sum``, every number they carry added up; a reader divides
     """
     out: Dict[str, Dict[str, Any]] = {}
     epochs: List[Tuple[float, float]] = []
@@ -178,6 +187,14 @@ def layer_counts(spans) -> Dict[str, Dict[str, Any]]:
             )
             delivery["gets"] += 1
             delivery["get_wait_s"] += dur_s
+        elif span.get("cat") == "train":
+            counter = out.setdefault("train step", {}).setdefault(
+                name, {"spans": 0, "sum": {}}
+            )
+            counter["spans"] += 1
+            for key, value in span["args"].items():
+                if isinstance(value, (int, float)):
+                    counter["sum"][key] = counter["sum"].get(key, 0) + value
         elif name in ("stage:epoch", "stage:ring-put", "stage:transfer"):
             staging = out.setdefault(
                 "staging",
@@ -259,7 +276,10 @@ class JaxShufflingDataset:
 
     Iterating yields ``(features, label)`` where ``features`` is a dict
     mapping feature column name to a global ``jax.Array`` sharded along
-    ``batch_axis``, and ``label`` likewise.
+    ``batch_axis``, and ``label`` likewise (``None`` where
+    ``label_column`` is None: a batch of features only). A column of one
+    number a row arrives as ``[batch]``; a ``fixed_size_list`` column of
+    ``width`` numbers a row (a token sequence) as ``[batch, width]``.
 
     Args mirror :class:`~.dataset.ShufflingDataset` (reference
     ``dataset.py:37-48``) plus the batch spec and device placement:
@@ -285,7 +305,7 @@ class JaxShufflingDataset:
         batch_size: int,
         rank: int,
         feature_columns: List[str],
-        label_column: str,
+        label_column: Optional[str] = None,
         feature_types: Optional[List[Any]] = None,
         feature_shapes: Optional[List[Any]] = None,
         label_type: Any = None,
@@ -314,10 +334,10 @@ class JaxShufflingDataset:
             mesh = Mesh(np.array(jax.local_devices()), (batch_axis,))
         self.mesh = mesh
         self.batch_axis = batch_axis
-        # Device-direct delivery (ROADMAP 3): when every spec column is a
-        # flat 4-byte tensor and the batch divides this process's slice
-        # of the data axis, ask the shuffle to emit reducer output
-        # already in the [n_cols, batch] staging layout — the stager then
+        # Device-direct delivery (ROADMAP 3): when every spec column holds
+        # 4-byte numbers as the files have them and the batch divides
+        # this process's slice of the data axis, ask the shuffle to emit
+        # reducer output already in the [n_slots, batch] staging layout — the stager then
         # ``device_put``s straight off the store's mmapped segments,
         # killing the host-side rebatch+pack amplification. The layout
         # request must exist BEFORE the underlying dataset construction:
@@ -364,11 +384,13 @@ class JaxShufflingDataset:
 
     def _device_layout_request(self, batch_size: int) -> Optional[Dict]:
         """The staging layout to ask the shuffle for, or None when this
-        spec cannot take it: any explicit non-4-byte dtype, any feature
-        shape (packed rows are flat), or a batch that does not divide
-        this process's slice of the data axis (full batches must shard).
-        Columns are ordered features-then-label — the exact row order of
-        the packed block and of the on-device unpack."""
+        spec cannot take it: any explicit non-4-byte dtype, any explicit
+        feature shape (a reshape on the host; a column's own width, as
+        the files have it, is the reducer's to pack), or a batch that
+        does not divide this process's slice of the data axis (full
+        batches must shard). Columns are ordered features-then-label —
+        the exact slot order of the packed block and of the on-device
+        unpack."""
         from ray_shuffling_data_loader_tpu.shuffle import (
             device_direct_enabled,
         )
@@ -385,31 +407,46 @@ class JaxShufflingDataset:
                 return None
         if batch_size % self._local_batch_shards() != 0:
             return None
-        return {
-            "batch": int(batch_size),
-            "columns": [*spec.feature_columns, spec.label_column],
-        }
+        return {"batch": int(batch_size), "columns": self._spec_columns()}
+
+    def _spec_columns(self) -> List[str]:
+        """The spec's columns in delivery order: features, then the label
+        where there is one."""
+        spec = self._spec
+        label = [] if spec.label_column is None else [spec.label_column]
+        return [*spec.feature_columns, *label]
+
+    def _spec_types(self) -> List[Any]:
+        spec = self._spec
+        label = [] if spec.label_column is None else [spec.label_type]
+        return [*spec.feature_types, *label]
 
     def _direct_ok(self, cb: ColumnBatch) -> bool:
         """Can this packed batch ship without any host conversion? The
         layout's PREFIX columns and their ACTUAL dtypes (stamped by the
         reducer; the reducer appends any extra dataset columns after the
         requested prefix) must match what the spec would have produced
-        host-side — cached per distinct layout signature."""
+        host-side — cached per distinct layout signature. A wide column's
+        slab holds whole rows, so it cannot be cut along the batch axis
+        where it lies: with more than one local shard such a batch takes
+        the host path."""
         lay = cb.layout or {}
-        sig = (tuple(lay.get("columns", ())), tuple(lay.get("dtypes", ())))
+        sig = (
+            tuple(lay.get("columns", ())),
+            tuple(lay.get("dtypes", ())),
+            tuple(lay.get("widths") or ()),
+        )
         ok = self._direct_sig_cache.get(sig)
         if ok is None:
-            spec = self._spec
-            want = [*spec.feature_columns, spec.label_column]
+            want = self._spec_columns()
             n = len(want)
             names = list(sig[0])
             dtypes = [np.dtype(d) for d in sig[1]]
             ok = names[:n] == want and len(dtypes) == len(names)
+            if ok and any(w != 1 for w in sig[2][:n]):
+                ok = self._local_batch_shards() == 1
             if ok:
-                for dt, want_t in zip(
-                    dtypes[:n], (*spec.feature_types, spec.label_type)
-                ):
+                for dt, want_t in zip(dtypes[:n], self._spec_types()):
                     target = np.dtype(
                         want_t if want_t is not None
                         else _default_device_dtype(dt)
@@ -422,7 +459,7 @@ class JaxShufflingDataset:
 
     def _stage_direct(self, cb: ColumnBatch, prof):
         """Zero-host-copy staging: one async ``device_put`` of the
-        batch's contiguous ``[n_spec_cols, batch]`` int32 prefix block
+        batch's contiguous ``[n_spec_slots, batch]`` int32 prefix block
         straight off the store's mmapped segment (the reducer packed the
         requested columns first; extra dataset columns sit after the
         prefix and never ship), then the existing jitted on-device
@@ -430,8 +467,9 @@ class JaxShufflingDataset:
         pages directly — no rebatch, no host pack, no intermediate
         buffer."""
         lay = cb.layout
-        n = len(self._spec.feature_columns) + 1
-        mat = cb.packed[:n]  # contiguous prefix view
+        n = len(self._spec_columns())
+        widths = tuple(packed_widths(lay)[:n])
+        mat = cb.packed[: sum(widths)]  # contiguous prefix view
         sharding = NamedSharding(self.mesh, P(None, self.batch_axis))
         with prof.phase("device_put", nbytes=mat.nbytes):
             if jax.process_count() > 1:
@@ -441,11 +479,14 @@ class JaxShufflingDataset:
             else:
                 packed_dev = jax.device_put(mat, sharding)
         with prof.phase("sync"):
-            names = tuple(lay["columns"][: n - 1])
+            nf = len(self._spec.feature_columns)
             dtypes = tuple(
                 str(np.dtype(d)) for d in lay["dtypes"][:n]
             )
-            unpack = self._get_unpack(names, dtypes[:-1], dtypes[-1])
+            unpack = self._get_unpack(
+                tuple(lay["columns"][:nf]), dtypes[:nf],
+                dtypes[nf] if n > nf else None, widths,
+            )
             features, label_arr = unpack(packed_dev)
         return features, label_arr, mat.nbytes, packed_dev
 
@@ -527,19 +568,21 @@ class JaxShufflingDataset:
                 packable = (
                     packable and arr.ndim == 1 and arr.dtype.itemsize == 4
                 )
-            label = self._device_view(
-                cb[spec.label_column], spec.label_type, spec.label_shape
+            label = (
+                None if spec.label_column is None
+                else self._device_view(
+                    cb[spec.label_column], spec.label_type, spec.label_shape
+                )
             )
-            ph.add_bytes(sum(a.nbytes for a in host.values()) + label.nbytes)
+            every = [*host.values(), *([] if label is None else [label])]
+            ph.add_bytes(sum(a.nbytes for a in every))
         packable = (
             packable
-            and label.ndim == 1
-            and label.dtype.itemsize == 4
-            and len({a.shape[0] for a in host.values()} | {label.shape[0]})
-            == 1
+            and (label is None or (label.ndim == 1 and label.dtype.itemsize == 4))
+            and len({a.shape[0] for a in every}) == 1
             # A ragged final partial can't take the row-sharded packed
             # layout; the per-column path replicates it (see _put).
-            and self._rows_shardable(label.shape[0])
+            and self._rows_shardable(every[0].shape[0])
         )
 
         put_at = self._put_clocks()
@@ -557,17 +600,20 @@ class JaxShufflingDataset:
                 for col, arr in host.items():
                     features[col] = self._put(arr, partial=partial)
                     nbytes += arr.nbytes
-                label_arr = self._put(label, partial=partial)
-                nbytes += label.nbytes
+                label_arr = None
+                if label is not None:
+                    label_arr = self._put(label, partial=partial)
+                    nbytes += label.nbytes
             put = (features, label_arr)
         return features, label_arr, nbytes, put, put_at
 
     def _stage_packed(
-        self, host: Dict[str, np.ndarray], label: np.ndarray, prof=None
+        self, host: Dict[str, np.ndarray], label: Optional[np.ndarray],
+        prof=None,
     ):
         """One transfer for the whole batch: bit-pack all 4-byte columns
         as int32 rows of a ``[n_cols+1, batch]`` buffer (float rows are
-        bitcast back on device).
+        bitcast back on device; no last row where there is no label).
 
         Multi-controller pods pack their LOCAL shard and assemble the
         global buffer with one ``make_array_from_process_local_data``
@@ -577,12 +623,13 @@ class JaxShufflingDataset:
         if prof is None:
             prof = _phases.stage_profiler("staging")
         names = tuple(host)
-        batch = label.shape[0]
+        rows = [host[name] for name in names]
+        if label is not None:
+            rows.append(label)
         with prof.phase("pack") as ph:
-            packed = np.empty((len(names) + 1, batch), np.int32)
-            for i, name in enumerate(names):
-                packed[i] = host[name].view(np.int32)
-            packed[-1] = label.view(np.int32)
+            packed = np.empty((len(rows), rows[0].shape[0]), np.int32)
+            for i, row in enumerate(rows):
+                packed[i] = row.view(np.int32)
             ph.add_bytes(packed.nbytes)
         sharding = NamedSharding(self.mesh, P(None, self.batch_axis))
         with prof.phase("device_put", nbytes=packed.nbytes):
@@ -596,15 +643,18 @@ class JaxShufflingDataset:
             unpack = self._get_unpack(
                 names,
                 tuple(str(host[n].dtype) for n in names),
-                str(label.dtype),
+                None if label is None else str(label.dtype),
             )
             features, label_arr = unpack(packed_dev)
         return features, label_arr, packed.nbytes, packed_dev
 
-    def _get_unpack(self, names, dtypes, label_dtype):
+    def _get_unpack(self, names, dtypes, label_dtype, widths=None):
         """Jitted on-device unpack for the packed layout: row slices +
         bitcasts, executed as ONE device computation (a single dispatch
-        round-trip, vs one per column).
+        round-trip, vs one per column). ``label_dtype`` None: no label
+        row, the label comes back None. ``widths`` (None: one slot a
+        column) names the slots each column takes, the label's last; a
+        wide column's slots read back as ``[batch, width]``.
 
         The computation is device-local by construction — each device
         already holds its batch shard of every packed row, so unpacking
@@ -612,47 +662,60 @@ class JaxShufflingDataset:
         expressed through ``shard_map`` with pinned specs, which
         GUARANTEES no collective can be inserted: ranks may dispatch it
         at independent staging rates without cross-host rendezvous."""
-        key = (names, dtypes, label_dtype)
+        # One slot a column is one program, however it was said: the
+        # direct and the host-packed path of a scalar stream share it (a
+        # second would compile the first time a batch straddles reducers).
+        widths = tuple(widths or ())
+        if all(w == 1 for w in widths):
+            widths = None
+        key = (names, dtypes, label_dtype, widths)
         fn = self._unpack_cache.get(key)
         if fn is None:
-            row_sharding = NamedSharding(self.mesh, P(self.batch_axis))
+            columns = len(names) + (label_dtype is not None)
+            widths = widths or (1,) * columns
+            slots = packed_slots({"columns": range(columns), "widths": widths})
+
+            def row_of(packed, i, dt):
+                at, w = slots[i]
+                row = (
+                    packed[at] if w == 1
+                    else packed[at : at + w].reshape(packed.shape[1], w)
+                )
+                if dt != "int32":
+                    row = jax.lax.bitcast_convert_type(row, jnp.dtype(dt))
+                return row
 
             def unpack(packed):
-                feats = {}
-                for i, (name, dt) in enumerate(zip(names, dtypes)):
-                    row = packed[i]
-                    if dt != "int32":
-                        row = jax.lax.bitcast_convert_type(
-                            row, jnp.dtype(dt)
-                        )
-                    feats[name] = row
-                lab = packed[-1]
-                if label_dtype != "int32":
-                    lab = jax.lax.bitcast_convert_type(
-                        lab, jnp.dtype(label_dtype)
-                    )
-                return feats, lab
+                feats = {
+                    name: row_of(packed, i, dt)
+                    for i, (name, dt) in enumerate(zip(names, dtypes))
+                }
+                if label_dtype is None:
+                    return feats, None
+                return feats, row_of(packed, len(names), label_dtype)
 
+            def rows(i):
+                return P(self.batch_axis, *([None] * (widths[i] != 1)))
+
+            feat_specs = {name: rows(i) for i, name in enumerate(names)}
+            label_spec = None if label_dtype is None else rows(len(names))
             if jax.process_count() > 1:
-                row_spec = P(self.batch_axis)
                 fn = jax.jit(
                     jax.shard_map(
                         unpack,
                         mesh=self.mesh,
                         in_specs=(P(None, self.batch_axis),),
-                        out_specs=(
-                            {name: row_spec for name in names},
-                            row_spec,
-                        ),
+                        out_specs=(feat_specs, label_spec),
                         check_vma=False,
                     )
                 )
             else:
+                placed = lambda spec: NamedSharding(self.mesh, spec)  # noqa: E731
                 fn = jax.jit(
                     unpack,
                     out_shardings=(
-                        {name: row_sharding for name in names},
-                        row_sharding,
+                        {n: placed(s) for n, s in feat_specs.items()},
+                        None if label_spec is None else placed(label_spec),
                     ),
                 )
             self._unpack_cache[key] = fn

@@ -12,6 +12,10 @@ normalizer).
 
 Scope: attention over ``[batch, seq, heads, head_dim]`` (split over batch
 and heads on multi-device meshes with ``shard_map``, see :mod:`.placement`).
+Grouped-query attention: ``k`` and ``v`` may carry fewer heads than ``q``
+(``heads % kv_heads == 0``); a query head reads its group's key/value
+blocks through the block index map, so the repeat never exists in HBM,
+and the dK/dV kernel sums a group's query heads in its VMEM accumulator.
 It composes with the sequence-parallel schedules (the Ulysses local body
 and each ring hop are exactly this computation) but is wired as the
 standalone ``flash_attention`` op — same auto-policy as the DLRM
@@ -149,6 +153,28 @@ def _flash_kernel(
         l_ref[0] = l_scr[:, :1]
 
 
+def _to_bh(x, t_pad):
+    """``[b, t, heads, d] -> [b * heads, t_pad, d]``."""
+    b, t, heads, d = x.shape
+    x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * heads, t, d)
+    if t_pad != t:
+        x = jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
+    return x
+
+
+def _kv_head_map(h: int, hk: int):
+    """Row of the ``[b * hk, t, d]`` key/value arrays that row ``bh`` of
+    the ``[b * h, t, d]`` queries reads: its group's head."""
+    if h % hk:
+        raise ValueError(
+            f"{h} query heads are not a multiple of {hk} key/value heads"
+        )
+    group = h // hk
+    if group == 1:
+        return lambda bh: bh
+    return lambda bh: (bh // h) * hk + (bh % h) // group
+
+
 def _flash_forward(
     q: jax.Array,
     k: jax.Array,
@@ -166,21 +192,16 @@ def _flash_forward(
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
+    kv_of = _kv_head_map(h, k.shape[2])
     scale = 1.0 / math.sqrt(d)
     bq = min(block_q, t)
     bk = min(block_k, t)
     tq_pad = -(-t // bq) * bq
     tk_pad = -(-t // bk) * bk
 
-    def to_bh(x, t_pad):
-        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
-        if t_pad != t:
-            x = jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
-        return x
-
-    qb = to_bh(q, tq_pad)
-    kb = to_bh(k, tk_pad)
-    vb = to_bh(v, tk_pad)
+    qb = _to_bh(q, tq_pad)
+    kb = _to_bh(k, tk_pad)
+    vb = _to_bh(v, tk_pad)
 
     kernel = functools.partial(
         _flash_kernel,
@@ -195,8 +216,8 @@ def _flash_forward(
         grid=(b * h, tq_pad // bq, tk_pad // bk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda bh, i, j: (kv_of(bh), j, 0)),
+            pl.BlockSpec((1, bk, d), lambda bh, i, j: (kv_of(bh), j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
@@ -217,6 +238,9 @@ def _flash_forward(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # The kernel's own name in the trace, whatever jit calls the
+        # function that holds it.
+        name="flash_attention_fwd",
     )(qb, kb, vb)
     out = out[:, :t].reshape(b, h, t, d)
     out = jnp.transpose(out, (0, 2, 1, 3))
@@ -271,9 +295,11 @@ def _flash_bwd_dkv_kernel(
     block_q: int,
     block_k: int,
     seq_len: int,
+    q_blocks: int,
 ):
-    """dK/dV: grid (batch·head, kv-block, q-block) with q innermost; the
-    dk/dv accumulators live in VMEM and are revisited across q blocks.
+    """dK/dV: grid (batch·kv-head, kv-block, group·q-block) with the
+    group's query heads and their q blocks innermost; the dk/dv
+    accumulators live in VMEM and are revisited across all of them.
 
         p  = softmax block recomputed from (m, l)
         dv += pᵀ @ dO
@@ -283,10 +309,10 @@ def _flash_bwd_dkv_kernel(
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    inner = pl.program_id(2)
+    qi = inner % q_blocks
 
-    @pl.when(qi == 0)
+    @pl.when(inner == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
@@ -326,7 +352,7 @@ def _flash_bwd_dkv_kernel(
     else:
         _update()
 
-    @pl.when(qi == nq - 1)
+    @pl.when(inner == pl.num_programs(2) - 1)
     def _fin():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -405,17 +431,15 @@ def _flash_backward_pallas(
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
+    hk = k.shape[2]
+    group = h // hk
+    kv_of = _kv_head_map(h, hk)
     scale = 1.0 / math.sqrt(d)
     bq = min(block_q, t)
     bk = min(block_k, t)
     tq_pad = -(-t // bq) * bq
     tk_pad = -(-t // bk) * bk
-
-    def to_bh(x, t_pad):
-        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
-        if t_pad != t:
-            x = jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
-        return x
+    nq = tq_pad // bq
 
     def rows_bh(x, t_pad, fill=0.0):  # [b, h, t] -> [bh, t_pad, 1]
         x = x.reshape(b * h, t, 1)
@@ -425,12 +449,12 @@ def _flash_backward_pallas(
             )
         return x
 
-    qb = to_bh(q, tq_pad)
-    kb = to_bh(k, tk_pad)
-    vb = to_bh(v, tk_pad)
+    qb = _to_bh(q, tq_pad)
+    kb = _to_bh(k, tk_pad)
+    vb = _to_bh(v, tk_pad)
     # Native dtype: the kernels cast each dO block to f32 on load, so a
     # host-side f32 copy would only double dO's HBM traffic.
-    dob = to_bh(ct, tq_pad)
+    dob = _to_bh(ct, tq_pad)
     # Padded q rows carry m = -inf so the kernels' live-row guard
     # (m > NEG_INF/2) zeroes them directly, rather than relying on the
     # zero-padded q/dO rows keeping exp(0)/1e-30 products finite*0.
@@ -443,9 +467,17 @@ def _flash_backward_pallas(
     )
     db = rows_bh(big_d, tq_pad)
 
-    q_spec = pl.BlockSpec((1, bq, d), lambda bh, j, i: (bh, i, 0))
-    kv_spec = pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0))
-    row_spec = pl.BlockSpec((1, bq, 1), lambda bh, j, i: (bh, i, 0))
+    def q_of(bkv, inner):
+        """The query row of this kv head's group that ``inner`` is at."""
+        return (bkv // hk) * h + (bkv % hk) * group + inner // nq
+
+    q_spec = pl.BlockSpec(
+        (1, bq, d), lambda bkv, j, i: (q_of(bkv, i), i % nq, 0)
+    )
+    kv_spec = pl.BlockSpec((1, bk, d), lambda bkv, j, i: (bkv, j, 0))
+    row_spec = pl.BlockSpec(
+        (1, bq, 1), lambda bkv, j, i: (q_of(bkv, i), i % nq, 0)
+    )
     dkv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel,
@@ -454,17 +486,15 @@ def _flash_backward_pallas(
             block_q=bq,
             block_k=bk,
             seq_len=t,
+            q_blocks=nq,
         ),
-        grid=(b * h, tk_pad // bk, tq_pad // bq),
+        grid=(b * hk, tk_pad // bk, group * nq),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
                   row_spec],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, j, i: (bh, j, 0)),
-        ],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk_pad, d), v.dtype),
+            jax.ShapeDtypeStruct((b * hk, tk_pad, d), k.dtype),
+            jax.ShapeDtypeStruct((b * hk, tk_pad, d), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
@@ -474,11 +504,12 @@ def _flash_backward_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qb, kb, vb, dob, mb, lb, db)
     dkb, dvb = dkv
 
     q_spec2 = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
-    kv_spec2 = pl.BlockSpec((1, bk, d), lambda bh, i, j: (bh, j, 0))
+    kv_spec2 = pl.BlockSpec((1, bk, d), lambda bh, i, j: (kv_of(bh), j, 0))
     row_spec2 = pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0))
     dqb = pl.pallas_call(
         functools.partial(
@@ -499,13 +530,14 @@ def _flash_backward_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qb, kb, vb, dob, mb, lb, db)
 
-    def from_bh(x, t_real):
-        x = x[:, :t_real].reshape(b, h, t_real, d)
+    def from_bh(x):
+        x = x[:, :t].reshape(b, -1, t, d)
         return jnp.transpose(x, (0, 2, 1, 3))
 
-    return from_bh(dqb, t), from_bh(dkb, t), from_bh(dvb, t)
+    return from_bh(dqb), from_bh(dkb), from_bh(dvb)
 
 
 # The kernels split over batch and heads. Sequence and head_dim stay whole
@@ -566,15 +598,31 @@ def _bwd(causal, block_q, block_k, interpret, res, ct):
     # the chunked-XLA exact backward (shared with blockwise_attention)
     # as an escape hatch.
     if os.environ.get("RSDL_FLASH_BWD", "pallas").lower() == "xla":
-        return _chunked_attention_bwd(
-            q, k, v, out, ct, causal, max(block_k, 128)
+        group = q.shape[2] // k.shape[2]
+        dq, dk, dv = _chunked_attention_bwd(
+            q, _repeat_kv(k, group), _repeat_kv(v, group), out, ct, causal,
+            max(block_k, 128),
         )
+        return dq, _sum_groups(dk, group), _sum_groups(dv, group)
     return _sharded_flash_bwd(causal, block_q, block_k, interpret)(
         q, k, v, out, m, l, ct
     )
 
 
 _flash_vjp.defvjp(_fwd, _bwd)
+
+
+def _repeat_kv(x: jax.Array, group: int) -> jax.Array:
+    """Key/value heads repeated to the query heads (the XLA paths only:
+    the kernels read a group's head in place)."""
+    return x if group == 1 else jnp.repeat(x, group, axis=2)
+
+
+def _sum_groups(dx: jax.Array, group: int) -> jax.Array:
+    if group == 1:
+        return dx
+    b, t, h, d = dx.shape
+    return dx.reshape(b, t, h // group, group, d).sum(axis=3)
 
 
 def flash_attention(
@@ -587,7 +635,10 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """Fused attention over ``[batch, seq, heads, head_dim]``.
+    """Fused attention over ``q [batch, seq, heads, head_dim]`` and ``k``,
+    ``v`` ``[batch, seq, kv_heads, head_dim]``; ``kv_heads`` divides
+    ``heads`` (grouped-query attention: query head ``i`` reads key/value
+    head ``i // (heads // kv_heads)``).
 
     ``use_pallas=None`` auto-selects the kernel on any TPU backend (split
     batch/head-wise over the context mesh — same policy as
@@ -600,5 +651,8 @@ def flash_attention(
     if use_pallas is None:
         use_pallas = auto_pallas()
     if not use_pallas:
-        return attention_reference(q, k, v, causal=causal)
+        group = q.shape[2] // k.shape[2]
+        return attention_reference(
+            q, _repeat_kv(k, group), _repeat_kv(v, group), causal=causal
+        )
     return _flash_vjp(q, k, v, causal, block_q, block_k, interpret)

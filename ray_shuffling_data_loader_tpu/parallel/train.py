@@ -14,14 +14,17 @@ over NCCL with fp16 compression and Adasum (``ray_torch_shuffle.py:183-193``)
   — the literal NCCL-allreduce analog, kept for parity and for readers
   mapping from the Horovod example.
 
-Loss: binary cross-entropy on the synthetic float label
-(``DATA_SPEC['labels']`` is uniform [0,1); BCE against a soft target is
-well-defined and keeps the workload honest).
+Loss: the model's own where it brings one (:func:`model_loss`: a sequence
+model's next-token cross-entropy over a batch that has no label); else
+binary cross-entropy on the synthetic float label (``DATA_SPEC['labels']``
+is uniform [0,1); BCE against a soft target is well-defined and keeps the
+workload honest).
 """
 
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -37,7 +40,11 @@ from ray_shuffling_data_loader_tpu.parallel.mesh import (
     param_shardings,
     replicated,
 )
-from ray_shuffling_data_loader_tpu.telemetry.trace import trace_span
+from ray_shuffling_data_loader_tpu.telemetry.trace import (
+    active as tracing_active,
+    defer_span,
+    trace_span,
+)
 
 
 class TrainState(NamedTuple):
@@ -98,27 +105,52 @@ def init_state(
     return state, shardings
 
 
+def model_loss(model) -> Tuple[Callable, int]:
+    """``(loss_fn, batch_inputs)``: the loss a step differentiates and how
+    many inputs a batch is.
+
+    A model that brings its own (``model.loss_fn(params, *batch) -> loss``
+    or ``(loss, counters)``, with ``model.batch_inputs`` inputs a batch: a
+    sequence model's one, the features) keeps it. A model that brings none
+    scores labelled rows: ``(features, labels)`` under
+    :func:`bce_loss`, the step this module always made."""
+    own = getattr(model, "loss_fn", None)
+    if own is not None:
+        return own, int(model.batch_inputs)
+
+    def loss_fn(params, features, labels):
+        logits = model.apply(params, features)
+        return bce_loss(logits, labels)
+
+    return loss_fn, 2
+
+
 def make_step_body(
     model, optimizer: optax.GradientTransformation
 ) -> Callable:
     """The UNJITTED per-batch train step:
-    ``(state, features, labels) -> (state, {"loss"})``.
+    ``(state, *batch) -> (state, {"loss", *counters})``; ``batch`` is
+    ``(features, labels)`` unless the model brings a loss of its own
+    (:func:`model_loss`).
 
     The building block both :func:`make_train_step` (jitted with
     shardings) and the resident loader's epoch fusion
     (:func:`~.resident.make_fused_epoch` scans it across a whole epoch
     in one device program) compose from."""
+    batch_loss, _ = model_loss(model)
 
-    def step_fn(state: TrainState, features, labels):
+    def step_fn(state: TrainState, *batch):
         # Named scopes land in every operation's ``op_name``, so a trace
         # can be split into forward (``loss``), backward (the transposes
         # of ``loss``) and ``optimizer`` whatever the compiler fuses.
         def loss_fn(params):
             with jax.named_scope("loss"):
-                logits = model.apply(params, features)
-                return bce_loss(logits, labels)
+                out = batch_loss(params, *batch)
+            return out if isinstance(out, tuple) else (out, {})
 
-        loss, grads = jax.value_and_grad(loss_fn)(state.params)
+        (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state.params
+        )
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(
                 grads, state.opt_state, state.params
@@ -127,7 +159,7 @@ def make_step_body(
         new_state = TrainState(
             step=state.step + 1, params=params, opt_state=opt_state
         )
-        return new_state, {"loss": loss}
+        return new_state, {"loss": loss, **counters}
 
     return step_fn
 
@@ -142,30 +174,58 @@ def make_train_step(
     """Sharding-annotated jitted train step (idiomatic pjit path).
 
     Batch arrives sharded along ``data`` (as produced by
-    ``JaxShufflingDataset``); XLA derives the gradient all-reduce.
+    ``JaxShufflingDataset``); XLA derives the gradient all-reduce. The
+    step takes ``(state, features, labels)``, or ``(state, features)``
+    from a model that brings its own loss (:func:`model_loss`). Counters
+    such a model's step returns beside the loss (``model.step_counters``)
+    stay on the device; while tracing is active each step's are handed to
+    the span buffer, which fetches them when it is read.
     """
     # How the step's embedding tables will be read is fixed by the shapes
     # and the mesh when it is traced: a count on the span, not a rate.
-    built = {}
+    built = dict(getattr(model, "build_facts", None) or {})
     tables = getattr(model, "vocab_sizes", None)
     if tables:
         built["packed_tables"], built["pack"] = packed_tables(
             tables, model.embed_dim, mesh
         )
+    _, batch_inputs = model_loss(model)
     with trace_span("step:build", **built):
-        batch_in = batch_sharding(mesh, 1)
         step_fn = traced_in_mesh(mesh, make_step_body(model, optimizer))
-
-        return jax.jit(
+        batch_in = (
+            None,  # features dict: let jax use committed input shardings
+            batch_sharding(mesh, 1),
+        )[:batch_inputs]
+        step = jax.jit(
             step_fn,
-            in_shardings=(
-                state_shardings,
-                None,  # features dict: let jax use committed input shardings
-                batch_in,
-            ),
+            in_shardings=(state_shardings, *batch_in),
             out_shardings=(state_shardings, None),
             donate_argnums=(0,) if donate_state else (),
         )
+    counters = getattr(model, "step_counters", None)
+    return _with_counter_spans(step, counters) if counters else step
+
+
+def _with_counter_spans(step: Callable, counters: Dict[str, Tuple]) -> Callable:
+    """``step`` whose counters (``{span name: (metrics keys, fold)}``) are
+    recorded as spans of the category ``train`` while tracing is active,
+    each carrying ``fold(*values)``: the values stay on the device until
+    the span buffer is read (``telemetry.defer_span``), so no step ever
+    waits for one."""
+
+    @functools.wraps(step)
+    def counted(state, *batch):
+        state, metrics = step(state, *batch)
+        if tracing_active():
+            now = time.time()
+            for name, (keys, fold) in counters.items():
+                defer_span(
+                    name, now, [metrics[k] for k in keys], fold, cat="train"
+                )
+        return state, metrics
+
+    counted.lower = step.lower
+    return counted
 
 
 def _tree_dot(a, b) -> jax.Array:

@@ -157,6 +157,32 @@ def _file_metadata(filenames: Sequence[str]):
     return out
 
 
+def _refuse_wide_columns(filenames: Sequence[str], columns: Sequence[str]) -> None:
+    """The resident buffer is ``[columns, rows]``: one number a column a
+    row. A ``fixed_size_list`` column (a token sequence) has no place in
+    it; say so before anything is decoded."""
+    import pyarrow as pa
+
+    from ray_shuffling_data_loader_tpu.shuffle import _open_parquet_file
+
+    if not filenames:
+        return
+    schema = _open_parquet_file(filenames[0])[0].schema_arrow
+    wide = [
+        f"{name}: {schema.field(name).type}"
+        for name in columns
+        if name in schema.names
+        and not pa.types.is_primitive(schema.field(name).type)
+    ]
+    if wide:
+        raise ValueError(
+            "DeviceResidentShufflingDataset holds one number a column a "
+            f"row; these columns hold more: {wide}. Stream them with "
+            "JaxShufflingDataset, which delivers a fixed_size_list column "
+            "as [batch, width]."
+        )
+
+
 def packed_nbytes(num_rows: int, num_feature_columns: int) -> int:
     """Device residency of the packed ``[features + label, rows]`` int32
     buffer. The TPU lays its last two dimensions out in (8, 128) tiles,
@@ -382,6 +408,7 @@ class DeviceResidentShufflingDataset:
         # an external liveness watchdog (the bench arms one).
         self._progress_cb = progress_cb
         self.stats = HostToDeviceStats()
+        _refuse_wide_columns(filenames, self._columns)
         self._load(filenames, num_rows)
 
     # -- one-time staging ---------------------------------------------------

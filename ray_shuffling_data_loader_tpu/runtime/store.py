@@ -443,12 +443,17 @@ class ColumnBatch(Mapping[str, np.ndarray]):
 # ---------------------------------------------------------------------------
 # A reducer that knows the trainer's staging layout emits its batch-
 # aligned rows as ONE column named PACKED_COLUMN of shape
-# ``[n_batches, n_cols, batch]`` int32: batch ``b`` is the contiguous
-# ``[n_cols, batch]`` block the JAX stager ships to the device with a
+# ``[n_batches, n_slots, batch]`` int32: batch ``b`` is the contiguous
+# ``[n_slots, batch]`` block the JAX stager ships to the device with a
 # single ``device_put`` straight off the mmapped segment (float columns
 # ride as int32 bit patterns and are bitcast back on device — the same
-# wire trick the legacy host-side pack used). The ``layout`` descriptor
-# in the segment meta names the logical columns, their true dtypes, and
+# wire trick the legacy host-side pack used). A column of one number a
+# row takes one slot; a column of ``width`` numbers a row (a
+# ``fixed_size_list``) takes ``width`` slots, and its ``width * batch``
+# contiguous words hold the batch's rows one after the other, so that
+# they read back as ``[batch, width]``. The ``layout`` descriptor
+# in the segment meta names the logical columns, their true dtypes,
+# their ``widths`` (left out where every column takes one slot) and
 # the batch size, so every consumer — local mmap, legacy pickle fetch,
 # or the striped zero-copy TCP plane — can reconstruct zero-copy logical
 # column views without a repack.
@@ -472,32 +477,68 @@ def device_batch_rows(cb: "ColumnBatch") -> int:
     return int(mat.shape[0]) * int(mat.shape[2])
 
 
+def packed_widths(layout: dict) -> List[int]:
+    """Slots each column of a packed layout takes: 1, or a wide
+    column's width."""
+    return [int(w) for w in layout.get("widths") or [1] * len(layout["columns"])]
+
+
+def packed_slots(layout: dict) -> List[Tuple[int, int]]:
+    """``(first slot, width)`` of each column of a packed layout."""
+    out, at = [], 0
+    for w in packed_widths(layout):
+        out.append((at, w))
+        at += w
+    return out
+
+
+def packed_column_view(block: np.ndarray, at: int, width: int, dtype):
+    """One column of one batch's ``[n_slots, batch]`` block as a
+    zero-copy view in its true dtype: ``[batch]``, or ``[batch, width]``
+    of a wide column."""
+    if width == 1:
+        return block[at].view(dtype)
+    return block[at : at + width].reshape(block.shape[1], width).view(dtype)
+
+
 def iter_packed_batches(cb: "ColumnBatch") -> Iterator["ColumnBatch"]:
     """Split a packed device-batch segment into per-batch views.
 
     Each yielded batch is an ordinary :class:`ColumnBatch` whose logical
-    columns are ZERO-COPY views into the segment (row ``i`` of the block,
-    bit-viewed back to its true dtype), with ``.packed`` set to the
-    contiguous ``[n_cols, batch]`` int32 block for direct staging."""
+    columns are ZERO-COPY views into the segment (the column's slots of
+    the block, bit-viewed back to its true dtype), with ``.packed`` set
+    to the contiguous ``[n_slots, batch]`` int32 block for direct
+    staging."""
     lay = cb.layout or {}
     mat = cb[PACKED_COLUMN]
     names = lay["columns"]
     dtypes = [np.dtype(d) for d in lay["dtypes"]]
+    slots = packed_slots(lay)
     for b in range(mat.shape[0]):
         block = mat[b]
         cols = {
-            name: block[i].view(dt)
-            for i, (name, dt) in enumerate(zip(names, dtypes))
+            name: packed_column_view(block, at, w, dt)
+            for name, dt, (at, w) in zip(names, dtypes, slots)
         }
         yield ColumnBatch(
             cols, _keepalive=cb._keepalive, layout=lay, packed=block
         )
 
 
+def packed_logical_column(mat: np.ndarray, at: int, width: int, dtype):
+    """One column's logical values over a whole packed body ``[m,
+    n_slots, B]``: ``[m * B]`` or ``[m * B, width]`` (one contiguous
+    copy of just that column)."""
+    if width == 1:
+        return mat[:, at, :].reshape(-1).view(dtype)
+    # Each batch's slab is its rows one after the other.
+    return mat[:, at : at + width, :].reshape(-1, width).view(dtype)
+
+
 class _LazyLogicalColumns(Mapping[str, np.ndarray]):
     """Logical column access over a whole packed segment without
-    materializing every column: column ``name`` is the flattened
-    ``mat[:, i, :]`` plane (one contiguous copy of just that column,
+    materializing every column: column ``name`` is the flattened plane
+    of its slots (one contiguous copy of just that column,
     built on first access). Audit digests read only the key column, so
     this keeps the audit path O(key bytes), not O(segment bytes)."""
 
@@ -506,6 +547,7 @@ class _LazyLogicalColumns(Mapping[str, np.ndarray]):
         lay = cb.layout or {}
         self._names = list(lay["columns"])
         self._dtypes = [np.dtype(d) for d in lay["dtypes"]]
+        self._slots = packed_slots(lay)
         self._cache: Dict[str, np.ndarray] = {}
 
     def __getitem__(self, name: str) -> np.ndarray:
@@ -515,8 +557,9 @@ class _LazyLogicalColumns(Mapping[str, np.ndarray]):
                 i = self._names.index(name)
             except ValueError:
                 raise KeyError(name) from None
-            plane = self._mat[:, i, :]  # (n_batches, B), rows contiguous
-            out = plane.reshape(-1).view(self._dtypes[i])
+            out = packed_logical_column(
+                self._mat, *self._slots[i], self._dtypes[i]
+            )
             self._cache[name] = out
         return out
 

@@ -45,6 +45,7 @@ import numpy as np
 from ray_shuffling_data_loader_tpu import runtime, telemetry
 from ray_shuffling_data_loader_tpu._lazy import lazy_module
 from ray_shuffling_data_loader_tpu.runtime import ColumnBatch, ObjectRef
+from ray_shuffling_data_loader_tpu.runtime import store as _store
 from ray_shuffling_data_loader_tpu.runtime.retry import stage_policy
 from ray_shuffling_data_loader_tpu.runtime.tasks import (
     TaskError,
@@ -371,20 +372,58 @@ def file_row_group_sizes(filename: str) -> List[int]:
 def _np_dtype_of(field) -> Optional[np.dtype]:
     """The numpy dtype an Arrow schema field decodes to, or None when it
     has no fixed-width numeric equivalent (the parallel assembly path
-    then declines to preallocate and falls back to single-shot)."""
+    then declines to preallocate and falls back to single-shot). A
+    ``fixed_size_list`` of a numeric type decodes to its element type,
+    ``list_size`` numbers a row."""
+    import pyarrow as pa
+
+    typ = field.type
+    if pa.types.is_fixed_size_list(typ):
+        typ = typ.value_type
     try:
-        dt = np.dtype(field.type.to_pandas_dtype())
+        dt = np.dtype(typ.to_pandas_dtype())
     except (TypeError, NotImplementedError):
         return None
     return dt if dt.kind in "fiub" else None
 
 
+def _row_shape_of(field) -> Tuple[int, ...]:
+    """``(width,)`` of a ``fixed_size_list`` field, ``()`` of any other."""
+    import pyarrow as pa
+
+    if pa.types.is_fixed_size_list(field.type):
+        return (int(field.type.list_size),)
+    return ()
+
+
+def _column_to_numpy(col) -> np.ndarray:
+    """One Arrow column as a contiguous array: ``[rows]``, or ``[rows,
+    width]`` of a ``fixed_size_list`` column of a numeric type (a sample
+    of more than one number a row: a token sequence). Every stage below
+    moves a column by whole rows of ``arr[i]``, whatever their width."""
+    import pyarrow as pa
+
+    typ = col.type
+    if pa.types.is_fixed_size_list(typ):
+        if col.null_count:
+            raise ValueError(
+                f"a fixed_size_list column with null rows cannot be "
+                f"decoded to [rows, {typ.list_size}]"
+            )
+        chunks = col.chunks if isinstance(col, pa.ChunkedArray) else [col]
+        flat = [
+            c.flatten().to_numpy(zero_copy_only=False) for c in chunks
+        ] or [np.empty(0, typ.value_type.to_pandas_dtype())]
+        arr = flat[0] if len(flat) == 1 else np.concatenate(flat)
+        return np.ascontiguousarray(arr).reshape(-1, typ.list_size)
+    return np.ascontiguousarray(col.to_numpy(zero_copy_only=False))
+
+
 def _table_to_columns(table) -> Dict[str, np.ndarray]:
-    cols = {}
-    for name, col in zip(table.column_names, table.columns):
-        arr = col.to_numpy(zero_copy_only=False)
-        cols[name] = np.ascontiguousarray(arr)
-    return cols
+    return {
+        name: _column_to_numpy(col)
+        for name, col in zip(table.column_names, table.columns)
+    }
 
 
 def _note_pruned(schema, group_rows, sel_rows, proj, labels=None) -> None:
@@ -405,6 +444,8 @@ def _note_pruned(schema, group_rows, sel_rows, proj, labels=None) -> None:
             field = schema.field(i)
             dt = _np_dtype_of(field)
             width = dt.itemsize if dt is not None else 8
+            for w in _row_shape_of(field):
+                width *= w
             if proj is not None and field.name not in proj:
                 pruned_col_bytes += width
             else:
@@ -632,8 +673,12 @@ def read_parquet_columns(
                 names = proj if proj is not None else list(schema.names)
                 cols = {}
                 for name in names:
-                    dt = _np_dtype_of(schema.field(name))
-                    cols[name] = np.empty(0, dt if dt is not None else np.int64)
+                    field = schema.field(name)
+                    dt = _np_dtype_of(field)
+                    cols[name] = np.empty(
+                        (0, *_row_shape_of(field)),
+                        dt if dt is not None else np.int64,
+                    )
         ph.add_bytes(sum(v.nbytes for v in cols.values()))
     return ColumnBatch(cols)
 
@@ -1526,19 +1571,19 @@ class _PackedOutput:
     ships), any remaining dataset columns after — so the delivered
     stream keeps the same column set as the legacy path: boundary
     remainders concat cleanly with legacy segments in the consumer's
-    carry buffer, and audit digests can fold any key column."""
+    carry buffer, and audit digests can fold any key column. A column of
+    ``width`` numbers a row takes ``width`` slots of each batch's block
+    (``runtime/store.py``: the packed layout)."""
 
     def __init__(self, store, layout: dict, start: int, total: int,
-                 names: List[str], col_dtypes: Dict[str, "np.dtype"]):
-        from ray_shuffling_data_loader_tpu.runtime.store import (
-            DEVICE_BATCH_KIND,
-            PACKED_COLUMN,
-        )
-
+                 names: List[str], col_dtypes: Dict[str, "np.dtype"],
+                 col_widths: Optional[Dict[str, int]] = None):
         self.B = B = int(layout["batch"])
         self.names = names = list(names)
         self.dtypes = [np.dtype(col_dtypes[n]) for n in names]
+        self.widths = [int((col_widths or {}).get(n, 1)) for n in names]
         self.ncols = len(names)
+        self.nslots = sum(self.widths)
         self.total = int(total)
         self.h = h = min(total, (-int(start)) % B)
         self.m = m = (total - h) // B
@@ -1553,21 +1598,26 @@ class _PackedOutput:
             self.head = self._remainder(h)
             if m:
                 descriptor = {
-                    "kind": DEVICE_BATCH_KIND,
+                    "kind": _store.DEVICE_BATCH_KIND,
                     "batch": B,
                     "columns": names,
                     "dtypes": [d.str for d in self.dtypes],
                 }
+                if self.nslots != self.ncols:
+                    # Only a stream with a wide column carries the key:
+                    # a scalar stream's descriptor is what it was.
+                    descriptor["widths"] = self.widths
+                self.slots = _store.packed_slots(descriptor)
                 self.body = store.create_columns(
                     {
-                        PACKED_COLUMN: (
-                            (m, self.ncols, B), np.dtype(np.int32)
+                        _store.PACKED_COLUMN: (
+                            (m, self.nslots, B), np.dtype(np.int32)
                         )
                     },
                     layout=descriptor,
                 )
                 self._pendings.append(self.body)
-                self.mat = self.body.columns[PACKED_COLUMN]
+                self.mat = self.body.columns[_store.PACKED_COLUMN]
             else:  # pragma: no cover - engagement requires m >= 1
                 self.body = None
                 self.mat = None
@@ -1576,27 +1626,33 @@ class _PackedOutput:
             self.abort()
             raise
 
+    def _row_shape(self, i: int) -> Tuple[int, ...]:
+        return () if self.widths[i] == 1 else (self.widths[i],)
+
     def _remainder(self, rows: int):
         if rows <= 0:
             return None
         p = self._store.create_columns(
-            {n: ((rows,), d) for n, d in zip(self.names, self.dtypes)}
+            {
+                n: ((rows, *self._row_shape(i)), d)
+                for i, (n, d) in enumerate(zip(self.names, self.dtypes))
+            }
         )
         self._pendings.append(p)
         return p
 
     def chunks(self):
         """``(lo, hi, {name: writable view})`` destinations in output-row
-        order. Body views are rows of the packed block bit-viewed back to
-        the column dtype — a take/gather into them lands bytes already in
-        staging layout."""
+        order. Body views are a column's slots of the packed block
+        bit-viewed back to the column dtype — a take/gather into them
+        lands bytes already in staging layout."""
         if self.head is not None:
             yield 0, self.h, self.head.columns
         for b in range(self.m):
             lo = self.h + b * self.B
             views = {
-                n: self.mat[b, i].view(dt)
-                for i, (n, dt) in enumerate(zip(self.names, self.dtypes))
+                n: _store.packed_column_view(self.mat[b], at, w, dt)
+                for n, dt, (at, w) in zip(self.names, self.dtypes, self.slots)
             }
             yield lo, lo + self.B, views
         if self.tail is not None:
@@ -1631,18 +1687,32 @@ class _PackedOutput:
             if mask.any():
                 sel = None if mask.all() else mask
                 rel = (dest if sel is None else dest[sel]) - body_lo
-                # Flat packed position of logical row r for column i:
-                # (r // B) * (n_cols * B) + i * B + (r % B); the constant
-                # i*B term rides as a base-offset view so ONE position
+                # Flat packed position of logical row r for a one-slot
+                # column at slot s:
+                # (r // B) * (n_slots * B) + s * B + (r % B); the constant
+                # s*B term rides as a base-offset view so ONE position
                 # array serves every column through the same threaded
                 # scatter kernel.
-                pos = (rel // B) * (self.ncols * B) + rel % B
+                pos = (rel // B) * (self.nslots * B) + rel % B
                 flat = self.mat.reshape(-1)
-                for i, n in enumerate(self.names):
+                for n, (at, w) in zip(self.names, self.slots):
                     src = _sub(n, sel)
                     if src.dtype != np.int32:
                         src = src.view(np.int32)
-                    native.scatter(src, pos, flat[i * B:])
+                    if w == 1:
+                        native.scatter(src, pos, flat[at * B:])
+                        continue
+                    # A wide column's rows lie whole inside their batch's
+                    # slab: one scatter a batch this window reaches.
+                    of_batch = rel // B
+                    for b in np.unique(of_batch):
+                        here = of_batch == b
+                        native.scatter(
+                            src[here], rel[here] % B,
+                            _store.packed_column_view(
+                                self.mat[b], at, w, np.int32
+                            ),
+                        )
         if self.tail is not None:
             mask = dest >= body_hi
             if mask.any():
@@ -1661,11 +1731,13 @@ class _PackedOutput:
         if self.head is not None:
             pieces.append(self.head.columns[name])
         if self.m:
-            pieces.append(self.mat[:, i, :].reshape(-1).view(dt))
+            pieces.append(
+                _store.packed_logical_column(self.mat, *self.slots[i], dt)
+            )
         if self.tail is not None:
             pieces.append(self.tail.columns[name])
         if not pieces:
-            return np.empty(0, dt)
+            return np.empty((0, *self._row_shape(i)), dt)
         return pieces[0] if len(pieces) == 1 else np.concatenate(pieces)
 
     def record_audit(self, epoch: int, reduce_index: int) -> None:
@@ -1689,8 +1761,9 @@ class _PackedOutput:
 
 def _packed_output(store, pack, total: int, template) -> Optional[_PackedOutput]:
     """A :class:`_PackedOutput` when device-direct packing can engage for
-    this reducer — the task got a layout, every reducer column is a flat
-    4-byte column with the requested columns present, and the interval
+    this reducer — the task got a layout, every reducer column holds
+    4-byte numbers, one a row or a fixed ``width`` a row, with the
+    requested columns present, and the interval
     holds at least one whole aligned batch — else None (the legacy
     columnar segment is emitted; refs are self-describing, so consumers
     handle a mixed stream)."""
@@ -1715,15 +1788,22 @@ def _packed_output(store, pack, total: int, template) -> Optional[_PackedOutput]
     # legacy path exactly.
     names = req + [n for n in all_names if n not in req]
     col_dtypes: Dict[str, np.dtype] = {}
+    col_widths: Dict[str, int] = {}
     for n in names:
         v = template[n]
-        if v.dtype.itemsize != 4 or v.shape[1:] != ():
+        # One slot means one number a row: a list of one stays columnar.
+        if v.dtype.itemsize != 4 or v.ndim > 2 or (
+            v.ndim == 2 and v.shape[1] < 2
+        ):
             return None
         col_dtypes[n] = v.dtype
+        col_widths[n] = int(v.shape[1]) if v.ndim == 2 else 1
     h = min(total, (-int(start)) % B)
     if (total - h) // B < 1:
         return None
-    return _PackedOutput(store, layout, start, total, names, col_dtypes)
+    return _PackedOutput(
+        store, layout, start, total, names, col_dtypes, col_widths
+    )
 
 
 def shuffle_gather_reduce(
@@ -2669,10 +2749,12 @@ def _dataset_stats_task(
         for col in batch.schema:
             if wanted is not None and col.name not in wanted:
                 continue
-            dt = np.dtype(col.type.to_pandas_dtype())
+            dt = _np_dtype_of(col) or np.dtype(col.type.to_pandas_dtype())
             if narrow_to_32:
                 dt = narrowed_dtype(dt)
-            per_row += float(dt.itemsize)
+            per_row += float(
+                dt.itemsize * int(np.prod(_row_shape_of(col), dtype=np.int64))
+            )
         break  # one bounded sample batch: fixed-width schema
     if per_row == 0.0:
         raise OSError(f"empty sample from {filenames[0]}")

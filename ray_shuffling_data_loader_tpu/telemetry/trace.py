@@ -230,6 +230,7 @@ def reset_state() -> None:
     global _dropped, _process_meta_emitted
     with _lock:
         _events.clear()
+        _deferred.clear()
         _threads_named.clear()
         _dropped = 0
         _process_meta_emitted = False
@@ -413,6 +414,37 @@ def record_span(
             "args": merged,
         }
     )
+
+
+# Spans whose arguments are still on a device: ``(name, start_s, cat,
+# values, fold)``, resolved when the buffer is read.
+_deferred: List[Tuple[str, float, str, Any, Any]] = []
+
+
+def defer_span(name: str, start_s: float, values, fold, cat: str = "rsdl") -> None:
+    """Record a zero-length span whose arguments ``fold(*numpy values)``
+    come from ``values``, arrays that may still be on a device (the
+    counters a compiled step returned): nothing waits for them here. They
+    are fetched, and the span recorded, when the buffer is next read
+    (:func:`local_spans`, and so :func:`trace_export`), never by a
+    periodic flush. ``numpy.asarray`` does the fetching: this module never
+    imports ``jax``."""
+    if not active():
+        return
+    with _lock:
+        if len(_deferred) + len(_events) < _max_events():
+            _deferred.append((name, start_s, cat, list(values), fold))
+
+
+def _resolve_deferred() -> None:
+    import numpy as np
+
+    with _lock:
+        pending, _deferred[:] = list(_deferred), []
+    for name, start_s, cat, values, fold in pending:
+        record_span(
+            name, start_s, 0.0, cat=cat, **fold(*map(np.asarray, values))
+        )
 
 
 def instant(name: str, cat: str = "rsdl", **args: Any) -> None:
@@ -749,6 +781,7 @@ def trace_export(path: str, xplane: Optional[str] = None) -> str:
     device's ``XLA Modules`` / ``XLA Ops`` lines and the host plane's
     annotations go into the same file, shifted onto the wall clock by the
     ``clock.sync`` mark. Returns ``path``."""
+    _resolve_deferred()
     flush()
     events: List[dict] = []
     directory = spool_dir()
@@ -774,6 +807,7 @@ def trace_export(path: str, xplane: Optional[str] = None) -> str:
 def local_spans() -> List[dict]:
     """Every complete span this process recorded: its buffer, and what it
     already drained to its own spool file (the ``RSDL_TRACE_DIR`` route)."""
+    _resolve_deferred()
     events: List[dict] = []
     directory = spool_dir()
     if directory:

@@ -14,6 +14,7 @@ XLA_FLAGS="--xla_force_host_platform_device_count=2" \
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python examples/train_long_context.py --dp 2 --sp 4 --steps 8 \
     --seq-len 256
+python examples/train_lfm2_moe.py
 python examples/train_dlrm_multirank.py --num-trainers 2 \
     --num-rows 50000 --num-files 4 --batch-size 5000 --epochs 2
 python -m ray_shuffling_data_loader_tpu.dataset
