@@ -206,8 +206,8 @@ class DenseFFN(nn.Module):
 
 
 class ExpertFFN(nn.Module):
-    """Returns ``(y, load)``: ``load [experts_held]`` counts the tokens
-    routed to each expert held here."""
+    """Returns ``(y, counts)``: ``load [experts_held]``, ``dropped`` and
+    ``fallback`` of :func:`~..ops.moe.experts_ffn`."""
 
     cfg: Lfm2MoeConfig
     dtype: Any
@@ -238,19 +238,20 @@ class ExpertFFN(nn.Module):
                 cfg.norm_topk_prob, cfg.routed_scaling_factor,
             )
         with jax.named_scope("experts"):
-            y, load, dropped = moe.experts_ffn(
+            y, load, dropped, fallback = moe.experts_ffn(
                 tokens, experts, weights, w1, w3, w2, cfg.first_expert,
-                tile=self.row_tile, use_pallas=self.use_pallas,
-                interpret=self.interpret,
+                cfg.num_experts, tile=self.row_tile,
+                use_pallas=self.use_pallas, interpret=self.interpret,
             )
-        return y.reshape(x.shape), {"load": load, "dropped": dropped}
+        counts = {"load": load, "dropped": dropped, "fallback": fallback}
+        return y.reshape(x.shape), counts
 
 
 class Layer(nn.Module):
     """One published layer: its operator and its FFN, each behind an RMS
     norm and added to the stream. Returns ``(x, counts)``: an expert
-    layer's ``{"load", "dropped"}`` (:func:`~..ops.moe.experts_ffn`), a
-    dense layer's ``{}``."""
+    layer's ``{"load", "dropped", "fallback"}``
+    (:func:`~..ops.moe.experts_ffn`), a dense layer's ``{}``."""
 
     cfg: Lfm2MoeConfig
     kind: str
@@ -285,17 +286,20 @@ class Layer(nn.Module):
         return x + y, counts
 
 
-def moe_load_counts(load, dropped) -> dict:
+def moe_load_counts(load, dropped, fallback) -> dict:
     """What the ``moe:load`` counter of one step carries, from the step's
     ``[expert layers, experts_held]`` token counts and its ``[expert
-    layers]`` counts of assignments left out of the buffer: the fullest
-    expert, the mean, the assignments dropped (the layer is built to drop
-    none; this is the count that says so) and the expert layers."""
+    layers]`` counts of assignments left out of the buffer and of layers
+    that ran in the worst-case buffer: the fullest expert, the mean, the
+    assignments dropped (the layer is built to drop none; this is the
+    count that says so), the expert layers, and those of them whose load
+    outgrew the bounded buffer."""
     return {
         "max": int(load.max()),
         "mean": float(load.mean()),
         "dropped": int(dropped.sum()),
         "layers": int(load.shape[0]),
+        "fallback": int(fallback.sum()),
     }
 
 
@@ -303,8 +307,9 @@ class Lfm2MoeLM(nn.Module):
     """``__call__({"tokens": [batch, seq] int32}) -> (loss, counters)``:
     the mean next-token cross-entropy over the vocabulary rows held, and
     ``{"moe_load": [expert layers, experts_held], "moe_dropped": [expert
-    layers]}``: the tokens routed to each held expert and the assignments
-    left out. ``logits=True`` returns the logits instead (float32
+    layers], "moe_fallback": [expert layers]}``: the tokens routed to each
+    held expert, the assignments left out, and 1 where the layer ran in the
+    worst-case buffer. ``logits=True`` returns the logits instead (float32
     ``[batch, seq, vocab]``: a test's size only).
 
     ``use_pallas`` / ``interpret`` go to the attention and expert kernels
@@ -325,7 +330,9 @@ class Lfm2MoeLM(nn.Module):
     # of their values)}``.
     batch_inputs = 1
     step_counters = {
-        "moe:load": (("moe_load", "moe_dropped"), moe_load_counts)
+        "moe:load": (
+            ("moe_load", "moe_dropped", "moe_fallback"), moe_load_counts
+        )
     }
 
     @property
@@ -370,12 +377,11 @@ class Lfm2MoeLM(nn.Module):
             if of_layer:
                 counts.append(of_layer)
         x = RMSNorm(cfg.norm_eps, dt, name="final_norm")(x)
-        held = (0, cfg.experts_held)
+        none = {"load": (0, cfg.experts_held), "dropped": (0,), "fallback": (0,)}
         counters = {
-            "moe_load": jnp.stack([c["load"] for c in counts])
-            if counts else jnp.zeros(held, jnp.int32),
-            "moe_dropped": jnp.stack([c["dropped"] for c in counts])
-            if counts else jnp.zeros(held[:1], jnp.int32),
+            f"moe_{name}": jnp.stack([c[name] for c in counts])
+            if counts else jnp.zeros(shape, jnp.int32)
+            for name, shape in none.items()
         }
         with jax.named_scope("head"):
             head = head.astype(dt)

@@ -13,9 +13,18 @@ experts are laid out expert by expert in one buffer whose groups are
 padded to whole row tiles (:func:`plan_dispatch`), so that every tile
 belongs to one expert and a grouped matrix product is a tiled matmul that
 picks its weight block per tile (:func:`grouped_matmul`: a Pallas kernel
-on TPU, ``jax.lax.ragged_dot`` elsewhere). The buffer has the static size
-of the worst case (every assignment held here); tiles past the last used
-one are skipped, not computed.
+on TPU, ``jax.lax.ragged_dot`` elsewhere); tiles past the last used one
+are skipped, not computed.
+
+The plan is laid out for the worst case (every assignment held here), but
+the buffer the rows move through is sized for the load a chip's share can
+expect: ``EXPECTED_LOAD_FACTOR`` times the even share of the assignments
+(:func:`bounded_rows`). The groups start at row 0, so the plan's first
+``bounded_rows`` entries are the whole plan whenever the step's load fits;
+where it does not, the same body runs in the worst-case buffer behind one
+``lax.cond`` (:func:`experts_ffn` counts that as ``fallback``). A chip
+that holds half the routed experts or more has no smaller buffer to take,
+and no ``cond``.
 
 Moving rows in and out of the buffer is a gather in both directions
 (``dispatch`` / ``combine`` carry custom VJPs): a buffer row holds at most
@@ -38,6 +47,13 @@ from ray_shuffling_data_loader_tpu.ops.placement import auto_pallas
 # block are compute-bound on a v5e (512 FLOPs a weight byte against a
 # ridge of 240).
 ROW_TILE = 512
+
+# The bounded buffer holds this many times the even share of the
+# assignments. A held share's total is binomial around the even share
+# (16,384 +- 120 of 131,072 at 8 experts of 64), and a router balanced by
+# anything stays far under twice it; one that collapses onto the held
+# experts takes the worst-case buffer and loses nothing but time.
+EXPECTED_LOAD_FACTOR = 2
 
 
 # -- routing ------------------------------------------------------------------
@@ -112,6 +128,19 @@ def buffer_rows(assignments: int, experts_held: int, tile: int) -> int:
     """Static size of the dispatch buffer: every assignment held here, each
     group padded by less than a tile and holding at least one."""
     return (-(-assignments // tile) + experts_held) * tile
+
+
+def bounded_rows(
+    assignments: int, experts_held: int, experts_routed: int, tile: int
+) -> int:
+    """Static size of the buffer for ``EXPECTED_LOAD_FACTOR`` times the
+    even share of ``assignments`` (``experts_held`` of ``experts_routed``),
+    and never more than the worst case's."""
+    even = -(-assignments * experts_held // experts_routed)
+    return min(
+        buffer_rows(EXPECTED_LOAD_FACTOR * even, experts_held, tile),
+        buffer_rows(assignments, experts_held, tile),
+    )
 
 
 def plan_dispatch(
@@ -446,6 +475,88 @@ def grouped_matmul(
 # -- the layer ------------------------------------------------------------------
 
 
+# Jitted (``rows``, ``tile``, ``use_pallas``, ``interpret`` static) so that a
+# model's expert layers, whose shapes are the same, are traced and lowered
+# once, not once a layer: a body holds three to nine kernels, and there are
+# two bodies a pass behind the ``cond``.
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _held_experts(rows, tile, use_pallas, interpret,
+                  x, weights, w1, w3, w2, plan):
+    """Dispatch, the three grouped products and the combine through a
+    buffer of the plan's first ``rows`` rows: the whole plan where
+    ``plan.tiles_used * tile <= rows``. An assignment's ``position`` past
+    ``rows`` stays out of range of the smaller buffer."""
+    plan = plan._replace(
+        source=plan.source[:rows],
+        row_weight=plan.row_weight[:rows],
+        tile_expert=plan.tile_expert[: rows // tile],
+    )
+    kernel = dict(tile=tile, use_pallas=use_pallas, interpret=interpret)
+    xs = dispatch(x, plan.source, plan.position)
+    h = jax.nn.silu(
+        grouped_matmul(xs, w1, plan, **kernel).astype(jnp.float32)
+    ) * grouped_matmul(xs, w3, plan, **kernel).astype(jnp.float32)
+    out = grouped_matmul(h.astype(x.dtype), w2, plan, **kernel)
+    return combine(out, weights, plan.position, plan.source, plan.row_weight)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _held_experts_vjp(rows, tile, use_pallas, interpret,
+                      ct, x, weights, w1, w3, w2, plan):
+    """The cotangents of ``(x, weights, w1, w3, w2)`` through
+    :func:`_held_experts` at ``rows``, the body computed again from its
+    inputs."""
+    _, vjp = jax.vjp(
+        lambda *diff: _held_experts(rows, tile, use_pallas, interpret, *diff, plan),
+        x, weights, w1, w3, w2,
+    )
+    return vjp(ct)
+
+
+def _one_of(body, rows, tile, use_pallas, interpret, fits, *operands):
+    """``body`` at ``rows`` where the step's load ``fits``, at the rows of
+    the whole plan (the last operand) where it does not: one branch runs."""
+    worst = operands[-1].source.shape[0]
+    return jax.lax.cond(
+        fits,
+        functools.partial(body, rows, tile, use_pallas, interpret),
+        functools.partial(body, worst, tile, use_pallas, interpret),
+        *operands,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _bounded_or_worst(rows, tile, use_pallas, interpret,
+                      fits, x, weights, w1, w3, w2, plan):
+    """:func:`_held_experts` in a buffer of ``rows`` rows where the step's
+    load ``fits``, in the plan's whole buffer where it does not.
+    Differentiated as a whole, from its inputs alone, the backward pass one
+    ``cond`` of the two bodies' own VJPs: ``cond``'s own rule would join
+    the branches' residuals, and the branch taken would write zeros of the
+    worst case's size for the other's. The layer is recomputed in the
+    backward pass anyway (``nn.remat``), so this costs no extra pass."""
+    return _one_of(
+        _held_experts, rows, tile, use_pallas, interpret,
+        fits, x, weights, w1, w3, w2, plan,
+    )
+
+
+def _bounded_or_worst_fwd(rows, tile, use_pallas, interpret, fits, *inputs):
+    out = _bounded_or_worst(rows, tile, use_pallas, interpret, fits, *inputs)
+    return out, (fits, inputs)
+
+
+def _bounded_or_worst_bwd(rows, tile, use_pallas, interpret, res, ct):
+    fits, inputs = res
+    grads = _one_of(
+        _held_experts_vjp, rows, tile, use_pallas, interpret, fits, ct, *inputs
+    )
+    return (None, *grads, None)
+
+
+_bounded_or_worst.defvjp(_bounded_or_worst_fwd, _bounded_or_worst_bwd)
+
+
 def experts_ffn(
     x: jax.Array,
     experts: jax.Array,
@@ -454,6 +565,7 @@ def experts_ffn(
     w3: jax.Array,
     w2: jax.Array,
     first_expert: int,
+    experts_routed: int,
     *,
     tile: int = ROW_TILE,
     use_pallas: Optional[bool] = None,
@@ -464,17 +576,24 @@ def experts_ffn(
     x_t) * W3_e x_t)``.
 
     ``x`` ``[tokens, hidden]``; ``experts`` / ``weights`` ``[tokens,
-    top_k]`` from :func:`route`; ``w1``, ``w3`` ``[experts_held, hidden,
-    width]``, ``w2`` ``[experts_held, width, hidden]``. Returns ``(y,
-    load, dropped)``: ``load [experts_held]`` counts the tokens routed to
-    each held expert, ``dropped []`` those of them the buffer left out
-    (:class:`DispatchPlan`: none)."""
-    plan = plan_dispatch(experts, weights, first_expert, w1.shape[0], tile)
-    kernel = dict(tile=tile, use_pallas=use_pallas, interpret=interpret)
-    xs = dispatch(x, plan.source, plan.position)
-    h = jax.nn.silu(
-        grouped_matmul(xs, w1, plan, **kernel).astype(jnp.float32)
-    ) * grouped_matmul(xs, w3, plan, **kernel).astype(jnp.float32)
-    out = grouped_matmul(h.astype(x.dtype), w2, plan, **kernel)
-    y = combine(out, weights, plan.position, plan.source, plan.row_weight)
-    return y, plan.load, plan.dropped
+    top_k]`` from :func:`route` over ``experts_routed`` experts; ``w1``,
+    ``w3`` ``[experts_held, hidden, width]``, ``w2`` ``[experts_held,
+    width, hidden]``. Returns ``(y, load, dropped, fallback)``: ``load
+    [experts_held]`` counts the tokens routed to each held expert,
+    ``dropped []`` those of them the buffer left out (:class:`DispatchPlan`:
+    none), ``fallback []`` is 1 where the load outgrew the bounded buffer
+    (:func:`bounded_rows`) and the layer ran in the worst-case one, else
+    0."""
+    held = w1.shape[0]
+    plan = plan_dispatch(experts, weights, first_expert, held, tile)
+    rows = bounded_rows(experts.size, held, experts_routed, tile)
+    static = (rows, tile, use_pallas, interpret)
+    inputs = (x, weights, w1, w3, w2, plan)
+    if rows == plan.source.shape[0]:
+        y = _held_experts(*static, *inputs)
+        fallback = jnp.zeros((), jnp.int32)
+    else:
+        fits = plan.tiles_used[0] * tile <= rows
+        y = _bounded_or_worst(*static, fits, *inputs)
+        fallback = 1 - fits.astype(jnp.int32)
+    return y, plan.load, plan.dropped, fallback

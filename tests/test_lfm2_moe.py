@@ -157,14 +157,16 @@ def test_the_expert_shares_add_up_to_the_uncut_layer(family):
             of_reference += ref.experts_ffn(
                 cfg, share, "l2.", x, same, first=first, held=held
             )
-            y, load, dropped = moe.experts_ffn(
+            y, load, dropped, fallback = moe.experts_ffn(
                 tokens, experts, weights, share["l2.moe.w1"],
-                share["l2.moe.w3"], share["l2.moe.w2"], first, tile=8,
-                use_pallas=True, interpret=True,
+                share["l2.moe.w3"], share["l2.moe.w2"], first, routed,
+                tile=8, use_pallas=True, interpret=True,
             )
             of_program += y.reshape(x.shape)
             loads.append(np.asarray(load))
-            assert int(dropped) == 0
+            # A quarter of the experts under a random router: every share
+            # fits the buffer for twice the even load.
+            assert int(dropped) == 0 and int(fallback) == 0
     assert float(jnp.abs(whole).max()) > 0.1
     assert np.allclose(of_reference, whole, atol=1e-5)
     assert np.allclose(of_program, whole, atol=1e-5)
@@ -197,39 +199,235 @@ def test_a_sliced_vocabulary_gives_the_matching_columns_of_the_whole(family):
 # -- the expert layer -----------------------------------------------------------
 
 
+# The expert layer at toy sizes: 40 tokens x 4 choices = 160 assignments over
+# 16 experts, 4 of them held, in tiles of 8 rows. The even share is 40
+# assignments, so the bounded buffer has (80 / 8 + 4) tiles = 112 rows and the
+# worst-case one (160 / 8 + 4) tiles = 192.
+TOKENS, TOP_K, HIDDEN, WIDTH, HELD, ROUTED, TILE = 40, 4, 16, 24, 4, 16, 8
+ASSIGNMENTS = TOKENS * TOP_K
+WORST_ROWS = moe.buffer_rows(ASSIGNMENTS, HELD, TILE)
+BOUNDED_ROWS = moe.bounded_rows(ASSIGNMENTS, HELD, ROUTED, TILE)
+
+# Tokens that choose each held expert (token t chooses e where t < loads[e];
+# the rest of its four choices go to experts held elsewhere) -> the tiles the
+# plan uses (an empty group keeps one) and whether the layer falls back.
+LOADS = {
+    "fits": ((0, 10, 10, 10), 7, 0),
+    "ends on the bounded buffer's last row": ((0, 40, 32, 25), 14, 0),
+    "one tile over": ((0, 40, 32, 33), 15, 1),
+    "three of four choices held": ((0, 40, 40, 40), 16, 1),
+    "the worst case": ((40, 40, 40, 40), 20, 1),
+}
+REMAT = dict(policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+
+
+def _expert_inputs():
+    keys = jax.random.split(jax.random.key(5), 5)
+    x = jax.random.normal(keys[0], (TOKENS, HIDDEN))
+    w1 = jax.random.normal(keys[1], (HELD, HIDDEN, WIDTH)) / 4
+    w3 = jax.random.normal(keys[2], (HELD, HIDDEN, WIDTH)) / 4
+    w2 = jax.random.normal(keys[3], (HELD, WIDTH, HIDDEN)) / 3
+    weights = jax.random.uniform(keys[4], (TOKENS, TOP_K)) + 0.1
+    return x, weights, w1, w3, w2
+
+
+def _choices(loads):
+    """``[TOKENS, TOP_K]`` expert ids, a token's all different."""
+    rows = []
+    for t in range(TOKENS):
+        held = [e for e in range(HELD) if t < loads[e]]
+        rows.append(held + list(range(9, 9 + TOP_K - len(held))))
+    return jnp.asarray(rows, jnp.int32)
+
+
+def _kernel_options(kernel):
+    return dict(use_pallas=kernel == "pallas", interpret=kernel == "pallas")
+
+
+def _weighed(y):
+    """A scalar whose gradient differs at every element of ``y``."""
+    return jnp.sum(y * jnp.cos(jnp.arange(y.size, dtype=y.dtype).reshape(y.shape)))
+
+
+def _layer(experts, routed, kw):
+    """``experts_ffn`` as the model's layer runs it, a function of what is
+    differentiated: ``-> (scalar, (y, load, dropped, fallback))``."""
+
+    def layer(x, weights, w1, w3, w2):
+        y, *counts = moe.experts_ffn(
+            x, experts, weights, w1, w3, w2, 0, routed, tile=TILE, **kw
+        )
+        return _weighed(y), (y, *counts)
+
+    return layer
+
+
+def _worst_case_only(experts, kw):
+    """The parent's layer: the same body in the whole plan's buffer."""
+
+    def layer(x, weights, w1, w3, w2):
+        plan = moe.plan_dispatch(experts, weights, 0, HELD, TILE)
+        y = moe._held_experts(
+            WORST_ROWS, TILE, kw["use_pallas"], kw["interpret"],
+            x, weights, w1, w3, w2, plan,
+        )
+        return _weighed(y), (y, plan.load, plan.dropped)
+
+    return layer
+
+
+def _through_remat(layer):
+    """Value and gradients with the layer recomputed in the backward pass
+    under the model's policy (``nn.remat`` is ``jax.checkpoint`` lifted)."""
+    return jax.jit(jax.value_and_grad(
+        jax.checkpoint(layer, **REMAT), argnums=(0, 1, 2, 3, 4), has_aux=True
+    ))
+
+
+@pytest.mark.parametrize("load", sorted(LOADS))
 @pytest.mark.parametrize("kernel", ["ragged_dot", "pallas"])
-def test_routing_drops_no_token_under_a_skewed_router(kernel):
-    """Every token chooses the same four experts, three of them held: the
-    buffer is its worst case but for one expert's share, and every
-    assignment is computed."""
-    t, h, w, held = 40, 16, 8, 4
-    keys = jax.random.split(jax.random.key(5), 4)
-    x = jax.random.normal(keys[0], (t, h))
-    w1 = jax.random.normal(keys[1], (held, h, w)) / 4
-    w3 = jax.random.normal(keys[2], (held, h, w)) / 4
-    w2 = jax.random.normal(keys[3], (held, w, h)) / 3
-    experts = jnp.tile(jnp.array([[1, 2, 3, 9]], jnp.int32), (t, 1))
-    weights = jnp.full((t, 4), 0.25)
-    kw = dict(use_pallas=kernel == "pallas", interpret=kernel == "pallas")
+def test_routing_drops_no_token_under_a_skewed_router(kernel, load):
+    """Whatever share of the assignments the held experts draw (under the
+    bounded buffer, on its last row, a tile over it, three choices of four,
+    all of them), every assignment is computed, and the result and every
+    gradient are those of the worst-case buffer alone, bit for bit."""
+    loads, tiles, falls_back = LOADS[load]
+    assert (WORST_ROWS, BOUNDED_ROWS) == (192, 112)
+    inputs = x, weights, w1, w3, w2 = _expert_inputs()
+    experts = _choices(loads)
+    kw = _kernel_options(kernel)
     with jax.default_matmul_precision("highest"):
-        y, load, dropped = moe.experts_ffn(
-            x, experts, weights, w1, w3, w2, 0, tile=8, **kw
-        )
+        (_, (y, load_, dropped, fallback)), grads = _through_remat(
+            _layer(experts, ROUTED, kw)
+        )(*inputs)
+        (_, (y_worst, load_worst, _)), grads_worst = _through_remat(
+            _worst_case_only(experts, kw)
+        )(*inputs)
         want = sum(
-            0.25 * ((jax.nn.silu(x @ w1[e]) * (x @ w3[e])) @ w2[e]) for e in (1, 2, 3)
+            ((experts == e) * weights).sum(-1, keepdims=True)
+            * ((jax.nn.silu(x @ w1[e]) * (x @ w3[e])) @ w2[e])
+            for e in range(HELD)
         )
-    assert load.tolist() == [0, t, t, t] and int(dropped) == 0
+    assert load_.tolist() == list(loads) == load_worst.tolist()
+    assert int(dropped) == 0 and int(fallback) == falls_back
     assert np.allclose(y, want, atol=1e-5)
-    plan = moe.plan_dispatch(experts, weights, 0, held, 8)
+    assert np.array_equal(y, y_worst)
+    for name, got, ref in zip(("x", "weights", "w1", "w3", "w2"), grads, grads_worst):
+        assert np.array_equal(got, ref), name
+        assert float(jnp.abs(got).max()) > 0, name
+    plan = moe.plan_dispatch(experts, weights, 0, HELD, TILE)
+    assert int(plan.tiles_used[0]) == tiles
+    assert (tiles * TILE <= BOUNDED_ROWS) == (not falls_back)
     # The count is read off the plan: a buffer row lost is an assignment
     # dropped.
-    lost = plan._replace(source=plan.source.at[plan.position[0, 0]].set(t))
-    assert int(jnp.sum(lost.load) - jnp.sum(lost.source < t)) == 1
-    assert int(plan.tiles_used[0]) == 1 + 3 * (t // 8)
-    # Every assignment held here has a buffer row of its own.
-    rows = np.asarray(plan.position)[:, :3].reshape(-1)
-    assert len(set(rows.tolist())) == 3 * t and rows.max() < plan.source.shape[0]
-    assert (np.asarray(plan.position)[:, 3] == plan.source.shape[0]).all()
+    lost = plan._replace(source=plan.source.at[plan.position[0, 1]].set(TOKENS))
+    assert int(jnp.sum(lost.load) - jnp.sum(lost.source < TOKENS)) == 1
+    # Every assignment held here has a buffer row of its own, under the
+    # tiles used; every other lies past the worst-case buffer, so past the
+    # bounded one too.
+    held = np.asarray(experts) < HELD
+    rows = np.asarray(plan.position)[held]
+    assert len(set(rows.tolist())) == sum(loads) and rows.max() < tiles * TILE
+    assert (np.asarray(plan.position)[~held] == WORST_ROWS).all()
+
+
+def _subjaxprs(value):
+    from jax.extend import core as jcore
+
+    if isinstance(value, jcore.ClosedJaxpr):
+        yield value.jaxpr
+    elif isinstance(value, jcore.Jaxpr):
+        yield value
+    elif isinstance(value, (tuple, list)):
+        for v in value:
+            yield from _subjaxprs(v)
+
+
+def _equations(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of what it calls, kernels' bodies
+    left out, with the ``cond`` branches it lies in (``(eqn, index)``)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name == "cond":
+            for i, branch in enumerate(eqn.params["branches"]):
+                yield from _equations(branch.jaxpr, inside + ((eqn, i),))
+            continue
+        for value in eqn.params.values():
+            for sub in _subjaxprs(value):
+                yield from _equations(sub, inside)
+
+
+def _wide(eqn, rows):
+    """The equation's float outputs of two or more axes with ``rows`` or
+    more along one: rows x hidden or rows x width."""
+    return [
+        v.aval.shape for v in eqn.outvars
+        if hasattr(v.aval, "shape") and len(v.aval.shape) >= 2
+        and jnp.issubdtype(v.aval.dtype, jnp.floating)
+        and max(v.aval.shape) >= rows
+    ]
+
+
+@pytest.mark.parametrize("kernel", ["ragged_dot", "pallas"])
+def test_the_bounded_branch_moves_no_array_of_the_worst_case_s_rows(kernel):
+    """The point of the bounded buffer, guarded where no chip is: in the
+    branch taken when the load fits, forward and backward, nothing wide has
+    the worst case's rows; the only wide arrays of an assignment a row are
+    gathers by ``position`` (the combine, and the dispatch's backward)."""
+    experts = _choices(LOADS["fits"][0])
+    layer = _layer(experts, ROUTED, _kernel_options(kernel))
+    grad = jax.grad(
+        jax.checkpoint(lambda *a: layer(*a)[0], **REMAT), argnums=(0, 1, 2, 3, 4)
+    )
+    equations = list(_equations(jax.make_jaxpr(grad)(*_expert_inputs()).jaxpr))
+    conds = {id(e): e for e, _ in equations if e.primitive.name == "cond"}
+    # One in the forward pass, one in the backward pass (which recomputes
+    # the forward inside its own branch).
+    assert len(conds) >= 2
+    seen = {(id(e), i): 0 for e in conds.values() for i in (0, 1)}
+    for eqn, inside in equations:
+        if not inside:
+            # Around the branches: integers of the plan only.
+            assert not _wide(eqn, ASSIGNMENTS), eqn
+            continue
+        (cond, branch), = inside
+        seen[id(cond), branch] += len(_wide(eqn, WORST_ROWS))
+        if branch == 1:
+            assert not _wide(eqn, WORST_ROWS), eqn
+            for shape in _wide(eqn, ASSIGNMENTS):
+                # ``jnp.take`` is a jitted ``_take`` around its gather.
+                assert "gather" == eqn.primitive.name or (
+                    eqn.params.get("name") == "_take"
+                ), eqn
+                assert shape == (ASSIGNMENTS, HIDDEN)
+    # The other branch of each is the worst case's body, and is wide.
+    assert all(seen[id(e), 0] >= 5 for e in conds.values())
+
+
+@pytest.mark.parametrize("routed", [HELD, 2 * HELD])
+def test_a_chip_that_holds_half_the_experts_or_more_runs_the_parent_s_layer(routed):
+    """No smaller buffer to take, so no ``cond`` is built: the traced layer
+    is the worst-case body, and ``fallback`` a constant 0."""
+    assert moe.bounded_rows(ASSIGNMENTS, HELD, routed, TILE) == WORST_ROWS
+    experts = _choices(LOADS["the worst case"][0])
+    inputs = _expert_inputs()
+    kw = _kernel_options("ragged_dot")
+    layer, worst = _layer(experts, routed, kw), _worst_case_only(experts, kw)
+    names = {
+        e.primitive.name
+        for e, _ in _equations(jax.make_jaxpr(layer)(*inputs).jaxpr)
+    }
+    assert "cond" not in names and "custom_vjp_call" in names
+    _, (y, _, dropped, fallback) = jax.jit(layer)(*inputs)
+    assert np.array_equal(y, jax.jit(worst)(*inputs)[1][0])
+    assert int(fallback) == 0 and int(dropped) == 0
+    with_cond = _layer(experts, 4 * HELD, kw)
+    assert "cond" in {
+        e.primitive.name
+        for e, _ in _equations(jax.make_jaxpr(with_cond)(*inputs).jaxpr)
+    }
 
 
 def test_the_router_chooses_by_the_bias_and_weighs_without_it():
@@ -343,7 +541,13 @@ def test_the_dlrm_step_is_the_program_it_was():
     assert str(new) == str(old)
 
 
-def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(monkeypatch):
+@pytest.mark.parametrize("router", ["even", "collapsed"])
+def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
+    monkeypatch, router
+):
+    """``collapsed``: a selection bias sends every token to the four experts
+    held, so every expert layer outgrows the bounded buffer and falls back,
+    and still drops nothing."""
     from ray_shuffling_data_loader_tpu import telemetry
     from ray_shuffling_data_loader_tpu.jax_dataset import layer_counts
     from ray_shuffling_data_loader_tpu.parallel import init_state, make_train_step
@@ -363,6 +567,13 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(mon
     trace.reset_state()
     try:
         state, shardings = init_state(model, optimizer, mesh, batch)
+        if router == "collapsed":
+            # (A buffer of its own a layer: the step donates its state.)
+            state = state._replace(params=jax.tree_util.tree_map_with_path(
+                lambda path, leaf: jnp.where(jnp.arange(16) < 4, 10.0, 0.0)
+                if path[-1].key == "expert_bias" else leaf,
+                state.params,
+            ))
         step = make_train_step(model, optimizer, mesh, shardings)
         losses = []
         for _ in range(3):
@@ -370,6 +581,7 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(mon
             losses.append(float(metrics["loss"]))
         assert metrics["moe_load"].shape == (4, 4)
         assert metrics["moe_dropped"].tolist() == [0, 0, 0, 0]
+        assert metrics["moe_fallback"].tolist() == [router == "collapsed"] * 4
         assert losses[2] < losses[0]
         spans = telemetry.local_spans()
     finally:
@@ -381,13 +593,21 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(mon
     assert build[-1]["args"]["experts_held"] == 4 and build[-1]["args"]["layers"] == 5
     loads = [s["args"] for s in spans if s["name"] == "moe:load"]
     assert len(loads) == 3 and all(a["dropped"] == 0 for a in loads)
-    # 4 sequences x 64 tokens x 4 choices, a quarter of the experts held.
-    assert loads[0]["mean"] == pytest.approx(4 * 64 * 4 / 16, rel=0.2)
+    # 4 sequences x 64 tokens x 4 choices, a quarter of the experts held:
+    # each draws a sixteenth of the assignments, or every token.
+    if router == "even":
+        assert loads[0]["mean"] == pytest.approx(4 * 64 * 4 / 16, rel=0.2)
+    else:
+        assert loads[0]["mean"] == loads[0]["max"] == 4 * 64
+    assert all(a["fallback"] == (4 if router == "collapsed" else 0) for a in loads)
     # The loader folds a step's counters by category, whatever their name.
     assert all(s["cat"] == "train" for s in spans if s["name"] == "moe:load")
     folded = layer_counts(spans)["train step"]["moe:load"]
     assert folded["spans"] == 3 and folded["sum"]["dropped"] == 0
     assert folded["sum"]["layers"] == 12
+    # Of the expert layers' executions, the share that missed the bounded
+    # buffer: fallback / layers.
+    assert folded["sum"]["fallback"] == (12 if router == "collapsed" else 0)
     assert folded["sum"]["max"] >= folded["sum"]["mean"] > 0
     assert folded["sum"]["mean"] == pytest.approx(sum(a["mean"] for a in loads))
 
