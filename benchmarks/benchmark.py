@@ -17,8 +17,8 @@ Run:
 
 Scope: this harness measures the HOST shuffle engine (map/reduce +
 actor consumers). The device-resident loader bypasses that engine
-entirely; its end-to-end measurement lives in the repo-root ``bench.py``
-(which auto-selects between the loaders) and ``BENCHLOG.md``.
+entirely; its end-to-end measurement is the benchmark cell
+``resident-train`` (``chipbench/run.py``, ``PERF.md``).
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@
 set -euo pipefail
 cd "$(dirname "$0")"
 
-TARGETS=(ray_shuffling_data_loader_tpu tests benchmarks examples bench.py __graft_entry__.py)
+TARGETS=(ray_shuffling_data_loader_tpu tests benchmarks examples __graft_entry__.py)
 
 if ! command -v ruff >/dev/null 2>&1; then
     echo "ruff not installed; running syntax check only" >&2

@@ -81,7 +81,7 @@ def harvest(
     out: List[Tuple[str, str, str, int]] = []
     for src in project.sources.values():
         top = src.path.split("/", 1)[0]
-        if top not in (PACKAGE, "tools") and src.path != "bench.py":
+        if top not in (PACKAGE, "tools"):
             continue
         tree = src.tree
         if tree is None:

@@ -11,8 +11,8 @@ Scope semantics:
 
 * ``public`` — a deploy-time tuning surface an operator may set;
   documented in TUNING.md, covered by compatibility expectations.
-* ``internal`` — bench/test/harness plumbing (``RSDL_BENCH_*``,
-  ``RSDL_T_*``, ...): may appear in docs but carries no compatibility
+* ``internal`` — test/harness plumbing (``RSDL_T_*``, ``RSDL_MP_*``,
+  ...): may appear in docs but carries no compatibility
   promise and no documentation requirement.
 
 ``prefix=True`` declares a family: any name starting with ``name``
@@ -133,14 +133,10 @@ KNOBS: Tuple[Knob, ...] = (
          "cross-process trace spool dir"),
     Knob("RSDL_TRACE_BUFFER", "int", "200000", "public",
          "per-process span buffer bound"),
-    Knob("RSDL_TRACE_OUT", "path", "unset", "public",
-         "default --trace-out for bench.py"),
     Knob("RSDL_METRICS", "flag", "off", "public",
          "master metrics gate (events/stragglers/capacity ride it)"),
     Knob("RSDL_METRICS_DIR", "path", "$RSDL_RUNTIME_DIR/metrics", "public",
          "metrics spool override"),
-    Knob("RSDL_METRICS_OUT", "path", "unset", "public",
-         "default --metrics-out for bench.py"),
     Knob("RSDL_AUDIT", "flag", "off", "public",
          "exactly-once digest layer gate"),
     Knob("RSDL_AUDIT_DIR", "path", "unset", "public",
@@ -230,8 +226,7 @@ KNOBS: Tuple[Knob, ...] = (
          "avoids phase-locking with 1 s periodic work"),
     Knob("RSDL_PROFILE_DIR", "path", "<runtime_dir>/profiles", "public",
          "profile spool override (per-process profile-*.json "
-         "aggregates; was the jax.profiler wrap knob, now "
-         "RSDL_BENCH_XPROF_DIR)"),
+         "aggregates)"),
     Knob("RSDL_PROFILE_TOP_N", "int", "20", "public",
          "default row count for /profile and rsdl_prof top tables"),
     # -- spool-federation plane (ISSUE 19) ----------------------------------
@@ -250,20 +245,13 @@ KNOBS: Tuple[Knob, ...] = (
          "seeds per stress-soak scenario"),
     Knob("RSDL_DRYRUN_MP", "enum", "on", "internal",
          "dryrun_multichip 2-process leg toggle"),
-    # -- internal families (bench / harness plumbing) -----------------------
-    Knob("RSDL_BENCH_", "prefix", "-", "internal",
-         "bench.py workload/capture knobs (documented rows in TUNING.md "
-         "carry no compatibility promise)", prefix=True),
-    Knob("RSDL_SWEEP_", "prefix", "-", "internal",
-         "trainer-sweep workload shape (read by tools/*.sh)", prefix=True),
+    # -- internal families (harness plumbing) -------------------------------
     Knob("RSDL_T_", "prefix", "-", "internal",
          "2-process pod test harness plumbing", prefix=True),
     Knob("RSDL_MP_", "prefix", "-", "internal",
          "dryrun_multichip 2-process leg plumbing", prefix=True),
     Knob("RSDL_TEST_", "prefix", "-", "internal",
          "TPU-gated test harness plumbing (repo/tmp paths)", prefix=True),
-    Knob("RSDL_PROBE", "str", "-", "internal",
-         "bench backend-probe stdout marker (not an env read)"),
     Knob("RSDL_CI_TIER", "enum", "all", "internal",
          "run_ci_tests.sh tier selection (shell-read)"),
 )

@@ -27,6 +27,7 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import json
+import logging
 import multiprocessing as mp
 import os
 import secrets
@@ -51,6 +52,8 @@ from ray_shuffling_data_loader_tpu.utils.platform import spawn_environ
 faults = lazy_module("ray_shuffling_data_loader_tpu.runtime.faults")
 from .retry import call_policy, connect_policy
 from .transport import Address
+
+logger = logging.getLogger(__name__)
 
 
 # The caller's trace context to ship with a request frame, or None when
@@ -222,10 +225,14 @@ class _ActorHost:
                 )
             await asyncio.sleep(0 if spins < 16 else 0.001)
             spins += 1
-        loop = asyncio.get_running_loop()
-        await loop.run_in_executor(
-            None, transport.sendmsg_all, sock, frames
-        )
+
+        def _send():
+            # asyncio hands out a TransportSocket, which has no send
+            # methods: dup() is a real socket on the same connection.
+            with sock.dup() as raw:
+                transport.sendmsg_all(raw, frames)
+
+        await asyncio.get_running_loop().run_in_executor(None, _send)
 
     async def _handle_client(self, reader, writer):
         try:
@@ -315,6 +322,12 @@ class _ActorHost:
                         # bytes by a blocked reader. Tear the connection
                         # down so the client fails into its
                         # ActorDiedError ladder instead of hanging.
+                        # The client sees only "connection closed": the
+                        # cause is logged here or it is lost.
+                        logger.exception(
+                            "out-of-band reply to %s failed; closing the "
+                            "connection", method,
+                        )
                         try:
                             writer.close()
                         except Exception:

@@ -52,8 +52,8 @@ Surfacing: structured ``scale.*`` / ``evict.*`` events on ``/events``,
 ``headroom_low`` / ``drain_stuck`` default SLO rules key on
 ``elastic.shm_headroom_frac`` / ``elastic.drain_age_seconds``), the
 ``cluster`` membership section on ``/status``, and
-``scale_events`` / ``evicted_gb`` / ``drains`` embedded by
-``bench.py`` into its result JSON next to ``telemetry_final``.
+``scale_events`` / ``evicted_gb`` / ``drains`` from :func:`summary`
+(tests read it; nothing in the program does).
 """
 
 from __future__ import annotations
@@ -139,7 +139,7 @@ class ElasticController:
         # operator, not the policy.
         self._added_agents: List[Tuple[str, Any]] = []  # (host_id, handle)
         self._drain_started: Dict[Tuple, float] = {}  # address -> mono ts
-        # Lifetime totals (bench embeds these next to telemetry_final).
+        # Lifetime totals (summary()).
         self.scale_events = 0
         self.evicted_bytes = 0
         self.drains = 0
@@ -680,7 +680,7 @@ class ElasticController:
         then drop spill segments older than the drop-age rung
         (``force_drop`` ignores the age — the operator's/test's
         explicit last rung). Returns the pass's stats (also accumulated
-        for bench)."""
+        into the lifetime totals)."""
         now = time.time() if now is None else float(now)
         stats = {
             "demoted": 0, "demoted_bytes": 0,
@@ -902,7 +902,7 @@ def stop() -> None:
 
 
 def summary() -> Dict[str, Any]:
-    """Lifetime totals for bench embedding (empty when no controller
-    ever ran in this process)."""
+    """Lifetime totals (empty when no controller ever ran in this
+    process)."""
     ctl = _controller
     return ctl.summary() if ctl is not None else {}

@@ -73,8 +73,9 @@ class StageFailedError(TaskError):
     (``RSDL_STAGE_MAX_ATTEMPTS``, default 3) — the structured terminal
     error a poison task produces instead of retrying forever across
     hosts. Subclasses :class:`TaskError` so pre-existing ``except
-    TaskError`` callers (and tests) keep working; ``bench.py``'s error
-    JSON picks up the stage/epoch fields."""
+    TaskError`` callers (and tests) keep working; the stage, epoch and
+    attempts fields are for the caller (tests read them, nothing in the
+    program does)."""
 
     def __init__(self, stage: str, epoch: int, attempts: int, message: str):
         super().__init__(message, error_type="StageFailedError")
@@ -480,11 +481,9 @@ def _decode_rowgroups_parallel(
     Why columns and not row-group ranges: a range split must assemble
     each column contiguously across workers, and that copy is GIL-held
     and bandwidth-bound, serializing behind the decode; finer per-group
-    reads that interleave copy with decode pay ~4 ms of scanner setup
-    PER read_row_groups call. Both shapes measured ~0.9-1.2x at 2
-    threads on the r11 host. Column striping needs ONE read per worker
-    and no cross-worker assembly at all — 1.6x measured (BENCHLOG
-    r11). Row groups remain the plan's SELECTION axis (the selective
+    reads that interleave copy with decode pay a scanner setup PER
+    read_row_groups call. Column striping needs ONE read per worker
+    and no cross-worker assembly at all. Row groups remain the plan's SELECTION axis (the selective
     schedule prunes them); columns are its parallel axis. Returns None
     for single-column files (nothing to stripe; the caller falls back
     to the bit-identical single-shot read)."""
@@ -820,8 +819,8 @@ def _file_assignment(
 
 def plan_is_prunable(plan: Optional[Tuple[str, int]] = None) -> bool:
     """Can the plan family ever skip a row group for a reducer?
-    Rowwise cannot (every group holds rows for every reducer whp —
-    BENCHLOG r11); block plans can by construction. The
+    Rowwise cannot (every group holds rows for every reducer whp);
+    block plans can by construction. The
     ``RSDL_SELECTIVE_READS=auto`` gate keys on this. ``plan``: the
     resolved spec (None = parse this process's env — driver/tool
     callers only, same rule as :func:`_file_assignment`)."""
@@ -1179,10 +1178,11 @@ def selective_reads_decision(
     ``auto`` (ISSUE 12) engages only when the plan family is prunable
     (:func:`plan_is_prunable` — block plans): under a rowwise plan
     every reducer's selection covers every row group, so selective
-    would silently re-read+decode each file ~R times (BENCHLOG r11
-    measured 282 vs ~70 groups); ``auto`` declines to the materialized
-    path instead and says why — the reason string lands in the decode
-    summary ``bench.py`` embeds. ``on`` is the operator forcing it
+    would silently re-read+decode each file ~R times; ``auto`` declines
+    to the materialized path instead and says why — the reason string
+    goes into the plan compiler's ``selective`` term
+    (``analysis/planner.py``) and nowhere else. ``on`` is the operator
+    forcing it
     regardless (the amplification is their call); anything else is
     off.
 
@@ -1323,10 +1323,7 @@ def selective_file_selection(
     covers exactly the rows the materialized map would partition to
     this reducer; under a block plan the selections are additionally
     DISJOINT across reducers by construction — each group decodes
-    exactly once per epoch instead of ~R times. Shared by
-    :func:`shuffle_selective_reduce` and ``tools/shuffle_profile.py``'s
-    per-plan decode sweep (one command reproduces the amplification
-    numbers)."""
+    exactly once per epoch instead of ~R times."""
     sizes = np.asarray(file_row_group_sizes(filename), dtype=np.int64)
     n = int(sizes.sum())
     assignment = _file_assignment(
@@ -1376,7 +1373,7 @@ def shuffle_selective_reduce(
     this reducer drew NONE of its rows. Under the rowwise plan that
     almost never happens — every group holds rows for every reducer, so
     selections degrade to whole-file decode and the epoch re-reads each
-    file ~R times (the measured BENCHLOG r11 limit). Under a BLOCK plan
+    file ~R times. Under a BLOCK plan
     (``RSDL_SHUFFLE_PLAN=block[:G]``) whole row groups belong to one
     reducer, selections are disjoint by construction, and each group
     decodes exactly once per epoch — ``decode_rows_pruned`` engages for
@@ -2603,9 +2600,8 @@ class _DecodeCache:
                 pass
 
 
-# Once-per-process microprobe results (VERDICT r3 item 4: auto policies
-# were fitted from 1-vCPU measurements; a runtime measurement beats a
-# baked constant on any host shape).
+# Once-per-process microprobe results (a runtime measurement adapts to
+# the host's shape where a baked constant cannot).
 _PROBE_CACHE: Dict[str, object] = {}
 _PROBE_LOCK = threading.Lock()
 
@@ -2622,8 +2618,8 @@ def _probed_host_costs() -> Dict[str, float]:
       (a random-permutation row gather via the same threaded
       :func:`native.take` the schedule executes, numpy fallback
       included) at a cache-resident and a DRAM-resident buffer size.
-      Gather bandwidth is strongly size-dependent (5x on the round-3
-      host) because a small cache gathers out of L2/L3; the policy
+      Gather bandwidth is strongly size-dependent because a small
+      cache gathers out of L2/L3; the policy
       interpolates by the dataset's actual cached size.
     * ``copy`` — the materialized path's hot op: a sequential pass
       through the SAME threaded kernel (``take`` with sorted indices),
@@ -2771,9 +2767,8 @@ def _est_decoded_bytes(
     """Estimated decoded-columns footprint of the dataset: measured
     bytes/row (decode microprobe on the first file — the schema is
     uniform across a dataset) x total rows from Parquet footers, plus
-    15% planning headroom. Falls back to the round-3 fitted on-disk
-    expansion factors (BENCHLOG 2026-07-30: snappy DATA_SPEC decodes to
-    ~0.95x disk; 1.3x un-narrowed / 0.7x narrowed with headroom) if the
+    15% planning headroom. Falls back to fitted on-disk expansion
+    factors (1.3x un-narrowed / 0.7x narrowed, headroom included) if the
     footer sweep fails where plain getsize would work. Raises OSError
     (callers treat that as "unknown: decline")."""
     if not filenames:
@@ -2792,8 +2787,8 @@ def _est_decoded_bytes(
         ).result()
         est = per_row * total_rows * 1.15
     except Exception:
-        # Any probe/footer failure falls back to the round-3 fitted
-        # on-disk expansion factors; only getsize itself failing raises
+        # Any probe/footer failure falls back to the fitted on-disk
+        # expansion factors; only getsize itself failing raises
         # OSError (the pre-probe "unknown: decline" contract).
         factor = 0.7 if narrow_to_32 else 1.3
         est = sum(os.path.getsize(f) for f in filenames) * factor
@@ -2812,9 +2807,9 @@ def _decode_cache_auto(
     the (estimated) decoded size fits comfortably inside the store's
     capacity budget alongside ~2 epochs of in-flight shuffle state.
 
-    Sizing comes from :func:`_est_decoded_bytes` (measured expansion —
-    BENCHLOG 2026-07-30); a wrong guess only shifts segments into the
-    spill tier rather than breaking anything. When the budget is unknowable (``capacity_bytes`` None —
+    Sizing comes from :func:`_est_decoded_bytes`; a wrong guess only
+    shifts segments into the spill tier rather than breaking anything.
+    When the budget is unknowable (``capacity_bytes`` None —
     budgeting disabled, statvfs failure, or spill dir on the same
     tmpfs), there IS no spill tier to absorb a wrong guess, so auto
     stays off."""
@@ -2840,17 +2835,15 @@ def _index_schedule_allowed(
     weighs its read amplification: every gather reads ~the ENTIRE cached
     dataset (a 1/R row subset still touches every cache line), so one
     epoch's gathers read ``R x cache_bytes`` where the materialized path
-    reads ~3x cache_bytes total. Measured at 25 GB / R=8 / 1 vCPU the
-    index schedule LOSES 1.7x pipelined, while at <=5 GB isolated stages
-    it wins 1.9x (BENCHLOG 2026-07-30) — so auto engages only when the
-    per-epoch read traffic is modest relative to the host's parallelism
-    (threaded gathers amortize it on real many-core TPU hosts), and only
-    single-host (cross-host the reads would ride DCN).
+    reads ~3x cache_bytes total. Which side wins therefore depends on
+    size and host (neither has a chip reading) — so auto engages only
+    when the per-epoch read traffic is modest relative to the host's
+    parallelism (threaded gathers amortize it on many-core hosts), and
+    only single-host (cross-host the reads would ride DCN).
     ``RSDL_INDEX_SHUFFLE=on|off`` overrides.
 
-    The auto gate is a measured time model (VERDICT r3: the old
-    ``16 GB x cpu_count`` budget was fitted on a 1-vCPU host and said
-    nothing about WHY; a runtime measurement adapts to any host shape).
+    The auto gate is a measured time model (a runtime measurement
+    adapts to any host shape; a fitted budget says nothing about WHY).
     Per-epoch cost of each schedule, from what the code actually does:
 
     * index:  ``min(8, R) x cache / gather_bw`` — R reducer gathers;
@@ -2859,10 +2852,10 @@ def _index_schedule_allowed(
       ``min(8 x cache/R, cache)`` and the total caps at ``8 x cache``.
     * materialized: ``3 x cache / copy_bw`` of sequential traffic (map
       partition gather over sorted runs + reduce concat-permute + cache
-      read — BENCHLOG 2026-07-30) **plus** ``F x R`` store round-trips
+      read) **plus** ``F x R`` store round-trips
       for its partition-object matrix, which is what the index schedule
-      structurally eliminates and why it wins outright on small
-      datasets (r3 measured 1.9x at <=5 GB) despite slower gathers.
+      structurally eliminates and why it can win on small datasets
+      despite slower gathers.
 
     Engage iff the modeled index epoch is no slower. All three costs
     come from :func:`_probed_host_costs` on THIS host.
@@ -3920,7 +3913,7 @@ def shuffle_epoch(
                     _status_epoch(epoch, delivered_inc=1, job=jid)
                     if jid is not None:
                         # Per-job delivered-volume rate: the fairness
-                        # signal the service bench/SLOs key on. Bytes,
+                        # signal the service SLOs key on. Bytes,
                         # not rows — a whole-segment reducer output
                         # carries no row window, and opening it just to
                         # count would cost a read on the hot path.
@@ -4028,8 +4021,8 @@ def shuffle_epoch(
 def device_direct_enabled() -> bool:
     """The ONE parser of the ``RSDL_DEVICE_DIRECT`` kill switch (default
     ``auto`` = honor consumer layout requests). Shared by the shuffle
-    gate, the stager's request builder, and bench reporting so the
-    disable spellings can never drift apart."""
+    gate and the stager's request builder so the disable spellings
+    can never drift apart."""
     return os.environ.get(
         "RSDL_DEVICE_DIRECT", "auto"
     ).strip().lower() not in ("off", "0", "false")
@@ -4115,7 +4108,7 @@ def shuffle(
     steady-state schedule (see :func:`shuffle_epoch`) when policy allows.
 
     ``schedule_log``: optional list; each epoch appends
-    ``(epoch, "index" | "mapreduce")`` — observability for tests/bench.
+    ``(epoch, "index" | "mapreduce")`` — observability for tests.
 
     ``device_layout``: device-direct delivery (ROADMAP 3, see
     :func:`shuffle_epoch`) — ``{"batch": B, "columns": [...]}`` from a

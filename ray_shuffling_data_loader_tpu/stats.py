@@ -10,10 +10,6 @@ TPU-first differences:
 * Store utilization comes from this runtime's session-scoped shared-memory
   store (:func:`~.runtime.store_stats`) instead of a raw gRPC probe into the
   raylet (reference ``stats.py:653-683``).
-* The collector additionally understands trainer-side HBM staging stats
-  (bytes staged, ``device_put`` dispatch time, stall time) reported by
-  :class:`~.jax_dataset.JaxShufflingDataset` — the north-star metrics
-  (BASELINE.md: stall% and host→HBM bandwidth) are first-class columns.
 * Timings use ``timeit.default_timer`` wall-clock deltas reported by the
   tasks themselves, exactly like the reference (``shuffle.py:149-167``).
 """
@@ -117,22 +113,6 @@ class StoreSample:
 
 
 @dataclass
-class StagingStats:
-    """Trainer-side HBM staging report (from ``HostToDeviceStats.as_dict``)."""
-
-    rank: int
-    bytes_staged: int = 0
-    batches_staged: int = 0
-    stall_s: float = 0.0
-    stalls: int = 0
-    # stall_s split by cause: upstream (no host batch — epoch window /
-    # shuffle) vs staging (H2D pipeline behind). See HostToDeviceStats.
-    stall_upstream_s: float = 0.0
-    stall_staging_s: float = 0.0
-    first_batch_s: float = 0.0
-
-
-@dataclass
 class TrialStats:
     """Whole-trial stats (reference ``stats.py:55-64``)."""
 
@@ -155,7 +135,6 @@ class TrialStats:
     store_samples: Deque[StoreSample] = field(
         default_factory=lambda: deque(maxlen=_metrics.MAX_TIMELINE_SAMPLES)
     )
-    staging: List[StagingStats] = field(default_factory=list)
     # Live-metrics snapshots ({"ts", "values"}) forwarded by the store
     # sampler when the telemetry metrics half is on — the same series
     # telemetry.metrics.dump_json() writes, so CSV stats and live metrics
@@ -208,18 +187,10 @@ class TrialStats:
             default=0,
         )
 
-    @property
-    def total_stall_s(self) -> float:
-        return sum(s.stall_s for s in self.staging)
-
-    @property
-    def total_bytes_staged(self) -> int:
-        return sum(s.bytes_staged for s in self.staging)
-
     def row(self) -> Dict[str, float]:
         """The trial-CSV row: the reference's exact fieldname set
-        (reference ``stats.py:335-381``) followed by the TPU-native
-        staging/stall columns (north-star metrics, BASELINE.md)."""
+        (reference ``stats.py:335-381``) followed by the spill-tier and
+        audit columns."""
         out = {
             "num_files": self.num_files,
             "num_row_groups_per_file": self.num_row_groups_per_file,
@@ -286,18 +257,6 @@ class TrialStats:
             ],
         )
 
-        # TPU-native staging columns (no reference analog; the reference's
-        # closest quantity is the example's trainer batch-wait time,
-        # reference ``ray_torch_shuffle.py:201-230``).
-        out["total_bytes_staged"] = self.total_bytes_staged
-        out["total_stall_s"] = self.total_stall_s
-        out["stall_pct"] = (
-            100.0
-            * self.total_stall_s
-            / (self.duration * max(1, len(self.staging)))
-            if self.duration
-            else 0.0
-        )
         # Audit columns (empty-string/zero when auditing was off so the
         # trial CSV schema is stable either way): epochs whose digest
         # reconciliation passed, and the ones that failed, by id.
@@ -414,22 +373,6 @@ class TrialStatsCollector:
             )
         )
 
-    # -- trainer-side hooks --------------------------------------------------
-
-    def report_staging(self, rank: int, staging: Dict[str, float]) -> None:
-        self.stats.staging.append(
-            StagingStats(
-                rank=rank,
-                bytes_staged=int(staging.get("bytes_staged", 0)),
-                batches_staged=int(staging.get("batches_staged", 0)),
-                stall_s=float(staging.get("stall_s", 0.0)),
-                stalls=int(staging.get("stalls", 0)),
-                stall_upstream_s=float(staging.get("stall_upstream_s", 0.0)),
-                stall_staging_s=float(staging.get("stall_staging_s", 0.0)),
-                first_batch_s=float(staging.get("first_batch_s", 0.0)),
-            )
-        )
-
     def audit_epoch(self, epoch: int, verdict: Dict[str, Any]) -> None:
         """One epoch's audit verdict (fire-and-forget from the shuffle
         driver's reconciler) — joins the trial CSV via the audit_*
@@ -476,9 +419,9 @@ class TrialStatsCollector:
         return True
 
     def snapshot(self) -> TrialStats:
-        """Current stats without awaiting completion — for callers (like
-        the repo bench) that drive consumption themselves and never send
-        ``consume`` records, which ``get_stats`` would wait for."""
+        """Current stats without awaiting completion — for callers that
+        drive consumption themselves and never send ``consume`` records,
+        which ``get_stats`` would wait for."""
         self.stats.epochs = [self._epochs[e] for e in sorted(self._epochs)]
         return self.stats
 
@@ -522,12 +465,6 @@ class ObjectStoreStatsCollector:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.samples: List[StoreSample] = []
-
-    def set_collector(self, collector) -> None:
-        """Re-point the sampler at a different collector actor (e.g. the
-        bench failover respawns its stats collector mid-run). Benign
-        race with the sampler thread: the handle is re-read each period."""
-        self._collector = collector
 
     def _sample_metrics(self, sample: StoreSample) -> None:
         reg = _metrics.registry

@@ -32,8 +32,8 @@ and aggregates them with per-kind merge semantics, and
 + stats artifacts into per-epoch critical-path reports.
 
 See docs/observability.md for the span/metric vocabulary and how to open
-a trace in Perfetto. ``bench.py --trace-out=trace.json`` emits both
-artifacts for a benchmark run.
+a trace in Perfetto. ``chipbench/run.py --trace 1`` traces a benchmark
+run's whole window (program spans and the device's, on one clock).
 """
 
 from ray_shuffling_data_loader_tpu.telemetry import metrics  # noqa: F401

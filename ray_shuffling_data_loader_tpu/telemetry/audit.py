@@ -944,8 +944,9 @@ def verdicts() -> List[dict]:
 
 
 def summary(reconcile_if_needed: bool = True) -> dict:
-    """One embeddable dict: overall ok + the per-epoch verdicts. Used by
-    ``bench.py --audit`` (success and watchdog/error-JSON paths)."""
+    """One embeddable dict: overall ok + the per-epoch verdicts — the
+    shape ``tools/audit_report.py --audit-json`` reads. Nothing in the
+    program calls it."""
     out = verdicts()
     if not out and reconcile_if_needed:
         try:

@@ -313,8 +313,8 @@ def labeled_sum(
     this helper lives beside the key format so callers never re-parse
     it). ``by_label`` maps the ``{...}`` suffix to its value. The ONE
     definition for label-aware counter totals (ISSUE 12 put
-    ``{schedule, plan}`` labels on the decode counters; ``bench.py``'s
-    decode summary and the tests both fold through here)."""
+    ``{schedule, plan}`` labels on the decode counters; the tests fold
+    through here, nothing in the program does)."""
     total, by_label = 0.0, {}
     prefix = name + "{"
     for key, value in flat.items():
@@ -413,9 +413,9 @@ def aggregate(
 ) -> Dict[str, float]:
     """The cluster-aggregated flat snapshot: every process's spooled
     registry plus the local live one, merged with correct per-kind
-    semantics. This is what ``bench.py`` embeds as ``telemetry_final``
-    and what the ``/metrics`` endpoint serves — a pure file read, no
-    RPCs, safe on error paths."""
+    semantics. This is what the ``/metrics`` endpoint serves and what
+    the run ledger and the ``/critical`` analyzer read — a pure file
+    read, no RPCs, safe on error paths."""
     return flatten(
         aggregate_typed(
             max_age_s=max_age_s,

@@ -1,8 +1,7 @@
 """Per-op phase profiler for the shuffle hot path.
 
-The r5 VERDICT's open question ("Next round" #3) was *where* a
-7.7 s-average reduce task spends its time — stage-level stats
-(``TrialStatsCollector``) see only whole-task durations. This module
+Stage-level stats (``TrialStatsCollector``) see only whole-task
+durations, not *where* a reduce task spends its time. This module
 times the named phases INSIDE a stage task (decode, narrow,
 partition-scatter, window-fetch, concat-take gather, permute,
 store-publish, ...) and feeds both telemetry halves:
@@ -11,9 +10,8 @@ store-publish, ...) and feeds both telemetry halves:
   ``shuffle.phase_seconds{phase=P,stage=S}`` plus a byte counter
   ``shuffle.phase_bytes{phase=P,stage=S}`` when the caller reports the
   bytes a phase moved. Worker-side observations ride the existing
-  task-done spool (:mod:`.export`), so ``/metrics``,
-  ``bench.py``'s ``telemetry_final``, and ``tools/shuffle_profile.py``
-  all see the cluster-wide per-phase cost without new plumbing.
+  task-done spool (:mod:`.export`), so ``/metrics`` sees the
+  cluster-wide per-phase cost without new plumbing.
 * **trace** — a retroactive sub-span per phase
   (``map:decode``, ``reduce:gather``, ...) on the worker's timeline,
   so ``tools/epoch_report.py`` / Perfetto show phase cost in context.

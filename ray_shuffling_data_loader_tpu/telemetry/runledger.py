@@ -6,11 +6,10 @@ the runtime directory and die with the session (``runtime.shutdown``
 removes the tree). The question they cannot answer is the one asked a
 week later: *did last night's run regress against Tuesday's?* This
 module is the cross-run memory — at the end of every ``shuffle()``
-run (done, failed, **or** suspended) and every ``bench.py`` trial, one
-self-contained JSON record is appended to a flock-guarded,
-fsync'd NDJSON file:
+run (done, failed, **or** suspended), one self-contained JSON record
+is appended to a flock-guarded, fsync'd NDJSON file:
 
-* **identity** — run id, kind (shuffle/bench), host/pid, the service
+* **identity** — run id, kind, host/pid, the service
   tenant (job id + name) when the service plane stamped one;
 * **configuration** — the resolved shuffle-plan family and a snapshot
   of every ``RSDL_*`` knob set in the environment (driven off the

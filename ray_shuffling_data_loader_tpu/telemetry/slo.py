@@ -258,8 +258,8 @@ _rules_cache: Optional[List[Dict[str, Any]]] = None
 # at state["job"]).
 _states: Dict[str, Dict[str, Any]] = {}
 # Lifetime fire counts per instance key — kept apart from _states so a
-# departed tenant's counts survive its instance cleanup (bench and the
-# run ledger read these at run end, after jobs have ended).
+# departed tenant's counts survive its instance cleanup (the run
+# ledger reads these at run end, after jobs have ended).
 _fired_totals: Dict[str, int] = {}
 _history: List[Dict[str, Any]] = []
 
@@ -817,8 +817,7 @@ def alerts_body() -> Dict[str, Any]:
 def fired_counts() -> Dict[str, int]:
     """``{rule or rule|job: times fired}`` over this engine's lifetime
     (kept apart from instance state, so a departed tenant's counts
-    survive its cleanup) — what ``bench.py`` embeds in
-    ``telemetry_final`` and the run ledger records."""
+    survive its cleanup) — what the run ledger records."""
     with _lock:
         return {key: int(n) for key, n in _fired_totals.items() if n}
 

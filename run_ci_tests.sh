@@ -262,16 +262,6 @@ if [ "$tier" != "slow" ]; then
     echo "run_ledger --regress did not name the regressed frame" >&2
     exit 1
   fi
-  # TCP-plane lane (ISSUE 5/6): the two-process loopback "two-host"
-  # bench at a small shape — a worker host joins over real TCP (own shm
-  # dir), the windowed-fetch microbench runs all framings (legacy
-  # pickle, RSDL_TCP_ZEROCOPY vectored, and RSDL_TCP_STREAMS=2 striped),
-  # and the end-to-end two-host shuffle — striping on cluster-wide —
-  # must reconcile exactly-once over the wire (the bench exits non-zero
-  # on any error OR an audit mismatch, so the exit code IS the gate).
-  RSDL_BENCH_TCP_WINDOWS=12 RSDL_BENCH_TCP_WINDOW_MB=1 \
-    RSDL_BENCH_TCP_SHUFFLE_GB=0.02 RSDL_BENCH_TCP_STREAMS=2 \
-    python bench.py --plane tcp > /dev/null
 fi
 if [ "$tier" != "fast" ]; then
   python -m pytest tests/ -m slow -v --durations=10 || rc=$?

@@ -31,3 +31,14 @@ def local_runtime():
     ctx = runtime.init(num_workers=2)
     yield ctx
     runtime.shutdown()
+
+
+@pytest.fixture
+def index_schedule_pinned(monkeypatch):
+    """For tests of what the index schedule DELIVERS. Whether it may run
+    is otherwise ``shuffle._index_schedule_allowed``'s reading of a
+    stopwatch taken once a process (``_probed_host_costs``): a starved
+    xdist worker times the 2 MB gather 4-25 x slow and the policy then
+    says no for every test of that process. The cache must still be hot
+    (``_DecodeCache.hot_refs``), so a cold epoch stays ``mapreduce``."""
+    monkeypatch.setenv("RSDL_INDEX_SHUFFLE", "on")

@@ -331,7 +331,9 @@ def test_fixed_seed_delivered_digests_reproducible(
     assert [s for _, s in other] != [s for _, s in first]
 
 
-def test_index_schedule_audited(audit_runtime, audit_dataset):
+def test_index_schedule_audited(
+    audit_runtime, audit_dataset, index_schedule_pinned
+):
     """The steady-state index schedule (plan + sparse gather from the
     decode cache) carries the same digest equality as the materialized
     path — the audit covers both schedules."""

@@ -603,6 +603,22 @@ def _run_locality_cluster(tmp_path, tag: str, extra_env: dict) -> int:
     raise AssertionError(f"no CROSS_BYTES in head output:\n{out}")
 
 
+def _cross_host_bytes(tmp_path, tag: str, extra_env: dict) -> int:
+    """Bytes the store servers moved between the two hosts in one run. A
+    measurement of 0 means the run degenerated — the worker host was
+    evicted under CPU saturation and everything ran locally — which says
+    nothing about the path under test: retry a couple of times before
+    declaring the environment unusable."""
+    for attempt in range(3):
+        cross = _run_locality_cluster(tmp_path, f"{tag}{attempt}", extra_env)
+        if cross > 0:
+            return cross
+    pytest.skip(
+        f"cluster degenerated to a single host in every {tag!r} run "
+        "(CPU-saturated environment); the comparison needs two live hosts"
+    )
+
+
 @slow
 def test_locality_scheduling_cuts_cross_host_bytes(tmp_path):
     """Two-host cluster, skewed input ownership: locality-aware reduce
@@ -611,30 +627,32 @@ def test_locality_scheduling_cuts_cross_host_bytes(tmp_path):
     # With two healthy hosts and skewed ownership, EVERY healthy run moves
     # bytes across hosts: round-robin reduce placement obviously, and the
     # locality run too (file 1 maps on the worker, so even all-reduces-on-
-    # head still pulls that partition across). A measurement of 0 means
-    # the run degenerated — the worker host was evicted under CPU
-    # saturation and everything ran locally — which invalidates the
-    # comparison rather than informing it. Retry a couple of times before
-    # declaring the environment unusable.
-    def _measure(tag: str, extra_env: dict) -> int:
-        for attempt in range(3):
-            cross = _run_locality_cluster(
-                tmp_path, f"{tag}{attempt}", extra_env
-            )
-            if cross > 0:
-                return cross
-        pytest.skip(
-            f"cluster degenerated to a single host in every {tag!r} run "
-            "(CPU-saturated environment); locality comparison needs two "
-            "live hosts"
-        )
-
-    rr = _measure("rr", {"RSDL_DISABLE_LOCALITY": "1"})
-    loc = _measure("loc", {})
+    # head still pulls that partition across); _cross_host_bytes retries
+    # a run that moved none.
+    rr = _cross_host_bytes(tmp_path, "rr", {"RSDL_DISABLE_LOCALITY": "1"})
+    loc = _cross_host_bytes(tmp_path, "loc", {})
     assert loc < rr * 0.7, (
         f"locality={loc} bytes vs round-robin={rr} bytes — "
         "expected a >=30% cross-host reduction"
     )
+
+
+@slow
+def test_two_host_shuffle_over_striped_zerocopy(tmp_path):
+    """The exactly-once two-host shuffle with every cross-host read on
+    the vectored reply path (``RSDL_TCP_ZEROCOPY``) and striped over two
+    streams (``RSDL_TCP_STREAMS``), cluster-wide: each "host" has its
+    own shm dir, so the bytes ride the store servers' TCP replies."""
+    cross = _cross_host_bytes(
+        tmp_path,
+        "zc",
+        {
+            "RSDL_DISABLE_LOCALITY": "1",
+            "RSDL_TCP_ZEROCOPY": "1",
+            "RSDL_TCP_STREAMS": "2",
+        },
+    )
+    assert cross > 0
 
 
 @slow

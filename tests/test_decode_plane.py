@@ -583,7 +583,8 @@ def test_block_selective_prunes_in_process(
 
 
 def test_shared_cache_hit_across_runs(
-    local_runtime, rg_dataset, monkeypatch, shared_cache_clean
+    local_runtime, rg_dataset, monkeypatch, shared_cache_clean,
+    index_schedule_pinned,
 ):
     """Two consecutive shuffle() calls with the shared tier armed: the
     second starts cache-hot (epoch 0 goes straight to the index

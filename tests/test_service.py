@@ -546,7 +546,7 @@ def test_chaos_reducer_crash_isolated(svc_env, svc_files):
 # ---------------------------------------------------------------------------
 
 
-def test_cross_job_cache_hot(svc_env, svc_files):
+def test_cross_job_cache_hot(svc_env, svc_files, index_schedule_pinned):
     """Job 2 over the same files rides job 1's decoded segments from
     its FIRST epoch (index schedule at epoch 0 — the Parquet decode is
     skipped entirely), while claims fence the segments and release at

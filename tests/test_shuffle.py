@@ -246,7 +246,9 @@ def test_dataset_with_decode_cache_exactly_once(local_runtime, small_dataset):
             assert keys != first_epoch_order
 
 
-def test_index_schedule_stream_identical(local_runtime, small_dataset):
+def test_index_schedule_stream_identical(
+    local_runtime, small_dataset, index_schedule_pinned
+):
     """Steady-state index schedule (plan + sparse gather from the decode
     cache) must deliver a bit-identical stream to the materialized
     map/reduce path — same rows, same order, per (epoch, rank)."""
@@ -277,7 +279,9 @@ def test_index_schedule_stream_identical(local_runtime, small_dataset):
     assert dict(fast.done) == dict(slow.done)
 
 
-def test_index_schedule_resume_matches(local_runtime, small_dataset):
+def test_index_schedule_resume_matches(
+    local_runtime, small_dataset, index_schedule_pinned
+):
     """Checkpoint resume determinism across schedules: an epoch that ran
     via the index schedule originally must reproduce the exact stream when
     re-run cold (materialized) after a resume."""

@@ -4,10 +4,11 @@
 Renders one human-readable per-epoch table from the artifacts a run
 leaves behind (any subset works; more inputs = more columns):
 
-* ``--bench bench.json`` — the bench's one-line JSON result; its
-  embedded ``"audit"`` summary (``bench.py --audit``) is the primary
-  verdict source, and headline fields (GB/s, stall%, backend) become the
-  report header.
+* ``--bench bench.json`` — the shape the retired ``bench.py --audit``
+  printed: one JSON object whose ``"audit"`` section (an
+  ``audit.summary()``) is the primary verdict source and whose headline
+  fields (GB/s, stall%, backend) become the report header. Nothing in
+  the repo writes it any more.
 * ``--metrics run.metrics.json`` — ``telemetry.metrics.dump_json``
   artifact; the ``audit.*`` gauges/counters in its final snapshot are
   the fallback verdict source, and totals are cross-checked.
@@ -24,8 +25,7 @@ audit key / unshared spool — zero coverage must not read as a pass).
 
 Example::
 
-    python bench.py --audit --trace-out=/tmp/run.json > /tmp/bench.json
-    python tools/audit_report.py --bench /tmp/bench.json \
+    python tools/audit_report.py --audit-json /tmp/audit.json \
         --metrics /tmp/run.json.metrics.json
 """
 
@@ -231,7 +231,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter,
     )
-    parser.add_argument("--bench", help="bench result JSON (bench.py stdout)")
+    parser.add_argument(
+        "--bench", help="result JSON with an \"audit\" section"
+    )
     parser.add_argument(
         "--metrics", help="metrics timeline/snapshot JSON (dump_json)"
     )

@@ -3,8 +3,8 @@
 
 Answers the operator question the raw artifacts only imply: **which
 stage was the bottleneck this epoch?** Ingests the merged Chrome-trace
-JSON (``telemetry.trace_export`` / ``bench.py --trace-out``) and
-optionally the ``stats.process_stats`` CSVs and the bench result JSON,
+JSON (``telemetry.trace_export``) and optionally the
+``stats.process_stats`` CSVs and a result JSON (see ``--bench``),
 then computes per epoch:
 
 * the wall-clock **busy time per pipeline stage** — ``map``, ``reduce``,
@@ -51,9 +51,12 @@ The interval-union / critical-path math itself is shared with the live
 ``/critical`` analyzer (``telemetry/critical.py``): the online verdict
 and this report agree by construction.
 
-With ``--baseline BENCH_rXX.json`` (either a raw ``bench.py`` JSON line
-or the round-capture wrapper with a ``"parsed"`` field) the current
-run's headline numbers (``--bench``, same shapes) gate a regression
+``--bench`` and ``--baseline`` read the shape the retired ``bench.py``
+printed: one JSON object with ``value`` (throughput) and ``stall_pct``,
+raw or inside a wrapper's ``"parsed"`` field. Nothing in the repo writes
+it any more (``tests/fixtures/epoch_report/`` holds examples). With
+``--baseline`` the current run's headline numbers (``--bench``) gate a
+regression
 check: exit **1** when throughput drops more than ``--threshold-pct``
 (default 10) or stall% rises more than ``--stall-threshold-pts``
 (default 10) — so a CI lane can fail on a real slowdown. Exit 2 on
@@ -66,10 +69,8 @@ recorded nothing" must not gate green.
 
 Pure stdlib, no server. Example::
 
-    python bench.py --trace-out=/tmp/run.json > /tmp/bench.json
     python tools/epoch_report.py --trace /tmp/run.json \
-        --epoch-csv epoch_stats.csv --bench /tmp/bench.json \
-        --baseline BENCH_r05.json --events /tmp/spool/events \
+        --epoch-csv epoch_stats.csv --events /tmp/spool/events \
         --task-records /tmp/spool/metrics/tasks
 """
 
@@ -200,8 +201,8 @@ def _load_ndjson(
 
 def _bench_fields(obj: Optional[dict]) -> Dict[str, Any]:
     """Headline fields from a bench result JSON — accepts both the raw
-    one-line shape and the round-capture wrapper (``{"parsed": {...}}``,
-    the BENCH_rXX.json format)."""
+    one-line shape and the round-capture wrapper
+    (``{"parsed": {...}}``)."""
     if not obj:
         return {}
     if isinstance(obj.get("parsed"), dict):
@@ -694,11 +695,11 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument("--epoch-csv", help="stats.py epoch_stats.csv")
     parser.add_argument("--trial-csv", help="stats.py trial_stats.csv")
     parser.add_argument(
-        "--bench", help="current run's bench result JSON (bench.py stdout)"
+        "--bench", help="current run's result JSON (value, stall_pct)"
     )
     parser.add_argument(
         "--baseline",
-        help="baseline bench JSON (raw line or BENCH_rXX.json wrapper) "
+        help="baseline result JSON (raw, or a wrapper with \"parsed\") "
         "to gate regressions against",
     )
     parser.add_argument(

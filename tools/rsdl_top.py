@@ -16,7 +16,7 @@ ANSI clear + redraw, so it works over any ssh session.
 
 Usage::
 
-    RSDL_METRICS=1 RSDL_OBS_PORT=9100 python bench.py ... &
+    RSDL_METRICS=1 RSDL_OBS_PORT=9100 python <your program> &
     python tools/rsdl_top.py                    # live, 2 s refresh
     python tools/rsdl_top.py --once             # one frame (CI smoke)
     python tools/rsdl_top.py --once --json      # machine-readable frame
