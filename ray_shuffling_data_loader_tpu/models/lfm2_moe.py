@@ -20,12 +20,16 @@ configuration):
   here; next-token cross-entropy, the mean over positions.
 
 Parameters are float32, compute is bfloat16 (``compute_dtype``). Every layer
-is recomputed in the backward pass (``nn.remat``) but for its matrix products:
-what stays from the forward pass is a layer's input and the outputs of its
-plain matmuls (the projections of the operator and of the dense FFN); the
-elementwise work, the attention kernel and the whole expert layer (routing,
-dispatch, grouped products, combine) run again. The output head's logits are
-recomputed one sequence at a time.
+is recomputed in the backward pass (``nn.remat``) but for what costs most to
+make again: what stays from the forward pass is a layer's input, the outputs
+of its plain matmuls (the projections of the operator and of the dense FFN)
+and what the attention kernel's backward reads of its forward (``KEPT``: the
+output and the softmax row statistics, ``2 * (heads * head_dim + 4 * heads)``
+bytes a token in bfloat16: 142.6 MB an attention layer at 4 x 8,192 tokens
+and 32 heads of 64), so that the kernel's forward runs once a step. The
+elementwise work (norms, rotary positions, gates) and the whole expert layer
+(routing, dispatch, grouped products, combine) run again. The output head's
+logits are recomputed one sequence at a time.
 
 The model brings its own loss (``loss_fn``) and its step's counters, which
 ``parallel/train.py`` picks up: a batch is ``{"tokens": [batch, seq]}`` and
@@ -43,7 +47,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_shuffling_data_loader_tpu.ops import moe
-from ray_shuffling_data_loader_tpu.ops.flash_attention import flash_attention
+from ray_shuffling_data_loader_tpu.ops.flash_attention import (
+    ATTENTION_OUT,
+    ATTENTION_STATS,
+    flash_attention,
+)
 from ray_shuffling_data_loader_tpu.ops.short_conv import causal_depthwise_conv1d
 
 
@@ -303,6 +311,14 @@ def moe_load_counts(load, dropped, fallback) -> dict:
     }
 
 
+# What a recomputed layer keeps of its forward pass: the outputs of its plain
+# matmuls, and the residuals the attention kernel names for its backward.
+KEPT = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(ATTENTION_OUT, ATTENTION_STATS),
+)
+
+
 class Lfm2MoeLM(nn.Module):
     """``__call__({"tokens": [batch, seq] int32}) -> (loss, counters)``:
     the mean next-token cross-entropy over the vocabulary rows held, and
@@ -314,7 +330,9 @@ class Lfm2MoeLM(nn.Module):
 
     ``use_pallas`` / ``interpret`` go to the attention and expert kernels
     (None: the kernels on a TPU backend). Every layer is recomputed in the
-    backward pass but for its plain matmuls' outputs, which are kept."""
+    backward pass but for ``KEPT``: its plain matmuls' outputs and the
+    attention kernel's output and row statistics (142.6 MB an attention
+    layer at 4 x 8,192 tokens), so that kernel's forward is not."""
 
     cfg: Lfm2MoeConfig
     compute_dtype: Any = jnp.bfloat16
@@ -342,6 +360,10 @@ class Lfm2MoeLM(nn.Module):
             "model": "lfm2_moe",
             "experts_held": self.cfg.experts_held,
             "layers": self.cfg.num_hidden_layers,
+            # The layers whose attention residuals ``KEPT`` holds on to.
+            "attention_kept": sum(
+                kind == "full_attention" for _, kind, _ in self.cfg.layers()
+            ),
         }
 
     def loss_fn(self, params, features):
@@ -363,10 +385,7 @@ class Lfm2MoeLM(nn.Module):
         )
         with jax.named_scope("embed"):
             x = jnp.take(embed, tokens, axis=0).astype(dt)
-        layer_cls = nn.remat(
-            Layer,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        )
+        layer_cls = nn.remat(Layer, policy=KEPT)
         counts = []
         for index, kind, dense in cfg.layers():
             x, of_layer = layer_cls(

@@ -42,6 +42,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 from ray_shuffling_data_loader_tpu.ops.placement import (
@@ -540,6 +541,16 @@ def _flash_backward_pallas(
     return from_bh(dqb), from_bh(dkb), from_bh(dvb)
 
 
+# What the fused backward reads of the forward kernel, under
+# ``jax.ad_checkpoint.checkpoint_name``: the output ``[b, t, h, d]`` and the
+# softmax row statistics ``m``, ``l`` ``[b, h, t]``. A caller that recomputes
+# its layers (``jax.checkpoint`` / ``nn.remat``) lists both in its policy
+# (``save_only_these_names``) to keep them, so that the backward pass does not
+# run the forward kernel again; under any other policy, or none, the names
+# are identities.
+ATTENTION_OUT = "flash_attention_out"
+ATTENTION_STATS = "flash_attention_stats"
+
 # The kernels split over batch and heads. Sequence and head_dim stay whole
 # on every device (each tile reads full K/V rows) — sequence sharding is
 # the ring/Ulysses schedules' job, not this op's.
@@ -587,7 +598,11 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
         causal, block_q, block_k, interpret, return_stats=True
     )(q, k, v)
     # ``out`` joins the residuals (the backward needs D = rowsum(ct*out))
-    # along with the softmax statistics the fused backward consumes.
+    # along with the softmax statistics the fused backward consumes. All
+    # three are named in the form ``_bwd`` reads, for a caller's policy.
+    out = checkpoint_name(out, ATTENTION_OUT)
+    m = checkpoint_name(m, ATTENTION_STATS)
+    l = checkpoint_name(l, ATTENTION_STATS)
     return out, (q, k, v, out, m, l)
 
 

@@ -2,6 +2,9 @@
 (the compiled-on-TPU check lives in ``tests/test_ops_tpu.py``'s pattern;
 CI has no TPU)."""
 
+import collections
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,7 +12,17 @@ import pytest
 
 
 from ray_shuffling_data_loader_tpu.ops import attention_reference
-from ray_shuffling_data_loader_tpu.ops.flash_attention import flash_attention
+from ray_shuffling_data_loader_tpu.ops.flash_attention import (
+    ATTENTION_OUT,
+    ATTENTION_STATS,
+    flash_attention,
+)
+
+
+# (``ops.flash_attention`` is the function; this is its module.)
+flash_module = importlib.import_module(
+    "ray_shuffling_data_loader_tpu.ops.flash_attention"
+)
 
 
 def _qkv(shape, seed=0, dtype=jnp.float32):
@@ -146,6 +159,91 @@ def test_gradients_sharded_mesh():
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gd), rtol=1e-4, atol=1e-4
         )
+
+
+# -- the residuals' names ---------------------------------------------------------
+
+
+def _kernel_loss(q, k, v):
+    out = flash_attention(
+        q, k, v, causal=True, use_pallas=True,
+        block_q=16, block_k=16, interpret=True,
+    )
+    return jnp.sum(out.astype(jnp.float32) ** 2)
+
+
+def _kernels(jaxpr):
+    """How often each Pallas kernel is called in ``jaxpr`` and what it
+    calls, by the kernel's name."""
+    found = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] += 1
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _kernels(sub)
+    return found
+
+
+def test_the_residuals_names_change_nothing_for_a_bare_caller(monkeypatch):
+    """Outside a policy that lists them the names are identities: value and
+    gradients are, bit for bit, those of a forward rule that names nothing
+    (the parent's)."""
+    q, k, v = _qkv((2, 48, 4, 8), seed=11, dtype=jnp.bfloat16)
+    k, v = k[:, :, :2], v[:, :, :2]
+    grad = jax.value_and_grad(_kernel_loss, (0, 1, 2))
+    named = grad(q, k, v)
+
+    def names():
+        fwd = jax.make_jaxpr(flash_module._fwd, static_argnums=(3, 4, 5, 6))
+        return [
+            e.params["name"] for e in fwd(q, k, v, True, 16, 16, True).eqns
+            if e.primitive.name == "name"
+        ]
+
+    assert names() == [ATTENTION_OUT, ATTENTION_STATS, ATTENTION_STATS]
+    monkeypatch.setattr(flash_module, "checkpoint_name", lambda x, name: x)
+    jax.clear_caches()
+    assert names() == []
+    bare = grad(q, k, v)
+    for got, want in zip(jax.tree.leaves(named), jax.tree.leaves(bare)):
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+
+
+@pytest.mark.parametrize(
+    "policy,forwards",
+    [
+        # ``models/lm.py`` / ``models/transformer.py``: a plain checkpoint, or
+        # a policy that lists neither name, recomputes the kernel as before.
+        ("none", 2),
+        ("dots", 2),
+        # A caller that lists the names keeps what the backward reads.
+        ("names", 1),
+    ],
+)
+def test_only_a_policy_that_lists_the_names_keeps_the_forward_kernel_s_outputs(
+    policy, forwards
+):
+    policies = jax.checkpoint_policies
+    kw = {
+        "none": {},
+        "dots": {"policy": policies.dots_with_no_batch_dims_saveable},
+        "names": {
+            "policy": policies.save_only_these_names(ATTENTION_OUT, ATTENTION_STATS)
+        },
+    }[policy]
+    q, k, v = _qkv((1, 32, 2, 8), seed=12)
+    grad = jax.grad(jax.checkpoint(_kernel_loss, **kw), (0, 1, 2))
+    assert _kernels(jax.make_jaxpr(grad)(q, k, v).jaxpr) == {
+        "flash_attention_fwd": forwards,
+        "flash_attention_bwd_dkv": 1,
+        "flash_attention_bwd_dq": 1,
+    }
+    for got, want in zip(grad(q, k, v), jax.grad(_kernel_loss, (0, 1, 2))(q, k, v)):
+        assert np.array_equal(got, want)
 
 
 def test_flash_backward_xla_escape_hatch(monkeypatch):

@@ -3,6 +3,8 @@ attention and short convolution against the benchmark's plain float32
 reference and against dense formulas. CPU only, toy sizes, the kernels in
 the Pallas interpreter."""
 
+import collections
+import contextlib
 import json
 import os
 import sys
@@ -18,6 +20,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from chipbench import check, harness, limits  # noqa: E402
+from ray_shuffling_data_loader_tpu.models import lfm2_moe  # noqa: E402
 from ray_shuffling_data_loader_tpu.models.lfm2_moe import (  # noqa: E402
     Lfm2MoeConfig,
     Lfm2MoeLM,
@@ -541,6 +544,23 @@ def test_the_dlrm_step_is_the_program_it_was():
     assert str(new) == str(old)
 
 
+@contextlib.contextmanager
+def _tracing(monkeypatch):
+    """``RSDL_TRACE`` on and the span buffer empty inside; off and empty
+    again after."""
+    from ray_shuffling_data_loader_tpu.telemetry import trace
+
+    monkeypatch.setenv("RSDL_TRACE", "1")
+    trace.refresh_from_env()
+    trace.reset_state()
+    try:
+        yield
+    finally:
+        monkeypatch.delenv("RSDL_TRACE")
+        trace.refresh_from_env()
+        trace.reset_state()
+
+
 @pytest.mark.parametrize("router", ["even", "collapsed"])
 def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
     monkeypatch, router
@@ -551,7 +571,6 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
     from ray_shuffling_data_loader_tpu import telemetry
     from ray_shuffling_data_loader_tpu.jax_dataset import layer_counts
     from ray_shuffling_data_loader_tpu.parallel import init_state, make_train_step
-    from ray_shuffling_data_loader_tpu.telemetry import trace
 
     cfg = toy_config()
     family = harness.load_family(cfg)
@@ -562,10 +581,7 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
     mesh = make_mesh(devices=jax.devices()[:1])
     batch = {"tokens": jax.random.randint(jax.random.key(0), (4, 64), 0, 256)}
     optimizer = optax.adam(1e-3)
-    monkeypatch.setenv("RSDL_TRACE", "1")
-    trace.refresh_from_env()
-    trace.reset_state()
-    try:
+    with _tracing(monkeypatch):
         state, shardings = init_state(model, optimizer, mesh, batch)
         if router == "collapsed":
             # (A buffer of its own a layer: the step donates its state.)
@@ -584,13 +600,11 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
         assert metrics["moe_fallback"].tolist() == [router == "collapsed"] * 4
         assert losses[2] < losses[0]
         spans = telemetry.local_spans()
-    finally:
-        monkeypatch.delenv("RSDL_TRACE")
-        trace.refresh_from_env()
-        trace.reset_state()
     build = [s for s in spans if s["name"] == "step:build"]
     assert build and build[-1]["args"]["model"] == "lfm2_moe"
     assert build[-1]["args"]["experts_held"] == 4 and build[-1]["args"]["layers"] == 5
+    # The cut's one attention layer keeps its kernel's residuals.
+    assert build[-1]["args"]["attention_kept"] == 1
     loads = [s["args"] for s in spans if s["name"] == "moe:load"]
     assert len(loads) == 3 and all(a["dropped"] == 0 for a in loads)
     # 4 sequences x 64 tokens x 4 choices, a quarter of the experts held:
@@ -610,6 +624,97 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
     assert folded["sum"]["fallback"] == (12 if router == "collapsed" else 0)
     assert folded["sum"]["max"] >= folded["sum"]["mean"] > 0
     assert folded["sum"]["mean"] == pytest.approx(sum(a["mean"] for a in loads))
+
+
+# -- what a recomputed layer keeps ------------------------------------------------
+
+
+def _kernel_model():
+    """The cut at its rehearsal sizes as the benchmark builds it (bfloat16
+    compute, every kernel in the interpreter) and a batch of tokens."""
+    cfg = toy_config()
+    family = harness.load_family(cfg)
+    kernels = cfg["kernels"]
+    model = Lfm2MoeLM(
+        Lfm2MoeConfig.from_dict(family.program.model_config(cfg)),
+        use_pallas=True, interpret=True,
+        block_q=kernels["attention_block_q"], block_k=kernels["attention_block_k"],
+        row_tile=kernels["expert_row_tile"],
+    )
+    batch = {"tokens": jax.random.randint(jax.random.key(1), (4, 64), 0, 256)}
+    params = model.init(jax.random.key(2), batch)
+    return model, params, batch
+
+
+def _kernel_calls(model, params, batch):
+    """How often the gradient of the model's loss calls each Pallas kernel."""
+    grad = jax.grad(lambda p: model.apply(p, batch)[0])
+    return collections.Counter(
+        eqn.params["name"]
+        for eqn, _ in _equations(jax.make_jaxpr(grad)(params).jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    )
+
+
+def test_the_attention_kernel_s_forward_runs_once_a_step():
+    """The layer is recomputed in the backward pass; the kernel's output and
+    row statistics are kept, so its forward is dead code there. The expert
+    layer is recomputed as it was."""
+    calls = _kernel_calls(*_kernel_model())
+    attention = {n: c for n, c in calls.items() if n.startswith("flash_attention")}
+    assert attention == {
+        "flash_attention_fwd": 1,
+        "flash_attention_bwd_dkv": 1,
+        "flash_attention_bwd_dq": 1,
+    }
+    # Four expert layers, forward and recomputed, three products each, in
+    # each of the two buffers' branches.
+    assert calls["moe_experts_fwd"] == 4 * 2 * 3 * 2
+
+
+def test_what_is_kept_changes_no_number(monkeypatch):
+    """Loss and every gradient leaf under ``KEPT`` are, bit for bit, those
+    under the matmuls' policy alone (the parent's: two forwards)."""
+    model, params, batch = _kernel_model()
+
+    def loss_and_gradient():
+        # (Jitted anew each time: the policy is read when the model is traced.)
+        return jax.jit(jax.value_and_grad(lambda p: model.apply(p, batch)[0]))(params)
+
+    kept = loss_and_gradient()
+    calls = _kernel_calls(model, params, batch)
+    monkeypatch.setattr(lfm2_moe, "KEPT", REMAT["policy"])
+    jax.clear_caches()
+    assert _kernel_calls(model, params, batch) == {**calls, "flash_attention_fwd": 2}
+    parent = loss_and_gradient()
+    leaves = jax.tree.leaves(kept)
+    assert len(leaves) == 1 + len(jax.tree.leaves(params))
+    for got, want in zip(leaves, jax.tree.leaves(parent)):
+        assert np.isfinite(got).all() and np.array_equal(got, want)
+
+
+@pytest.mark.parametrize(
+    "cut,kept", [("the whole cut", 1), ("attention + experts", 1), ("conv + experts", 0)]
+)
+def test_step_build_counts_the_attention_layers_whose_residuals_are_kept(
+    monkeypatch, cut, kept
+):
+    """A fact of the traced step, recorded when it is built (nothing is
+    compiled here): 0 for a cut without attention."""
+    from ray_shuffling_data_loader_tpu import telemetry
+    from ray_shuffling_data_loader_tpu.parallel import make_train_step
+
+    first, count = CUTS[cut]
+    cfg = toy_config(first_layer=first, num_hidden_layers=count)
+    model = Lfm2MoeLM(
+        Lfm2MoeConfig.from_dict(harness.load_family(cfg).program.model_config(cfg))
+    )
+    with _tracing(monkeypatch):
+        mesh = make_mesh(devices=jax.devices()[:1])
+        make_train_step(model, optax.adam(1e-3), mesh, None)
+        spans = telemetry.local_spans()
+    (build,) = [s["args"] for s in spans if s["name"] == "step:build"]
+    assert build["attention_kept"] == kept and build["layers"] == count
 
 
 def test_the_family_s_tree_carries_every_leaf_there_and_back(family):
