@@ -1,0 +1,16 @@
+"""The family ``laguna``: full and sliding-window causal attention mixed
+(their head counts and rotary positions differ by kind of layer) and a
+routed mixture of experts with a shared expert beside it, over a stream of
+token sequences, as one chip of an expert-parallel deployment holds them:
+some of each layer's routed experts, a slice of the vocabulary, the layers
+of one pipeline stage.
+
+``counts``     parameters, resident state, FLOPs a sequence and the kernels'
+               operations and bytes, from the configuration's sizes alone;
+``reference``  the plain float32 reference of this chip's share, its float8
+               control, the weights from ``--seed``, and how the files' rows
+               become its batch;
+``program``    the one place that imports the program's model.
+
+The first two import nothing of the program.
+"""

@@ -1,0 +1,377 @@
+"""What the sparse sequence models share (``lfm2_moe.py``, ``laguna.py``):
+the parts of a pre-norm residual layer, the language model around a stack
+of recomputed layers, and its loss.
+
+* :class:`RMSNorm`; :class:`Rope` and :func:`rotary` (the half-split
+  convention, plain or YaRN frequencies, on all or on the first dimensions
+  of a head);
+* :class:`Attention`: grouped-query causal attention through
+  ``ops/flash_attention.py``, full or over a sliding window;
+* :class:`DenseFFN` (the gated three-matrix form) and :class:`ExpertFFN`
+  (the experts ONE chip holds of a routed layer, ``ops/moe.py``);
+* :class:`SequenceLM`: embedding, the layers (each recomputed in the
+  backward pass but for what its model's policy keeps), final norm, the
+  head over the vocabulary rows held, :func:`next_token_loss`, and the
+  step's counters of the expert layers' load.
+
+Parameters are float32, compute is ``dtype`` (bfloat16 on the chip).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ray_shuffling_data_loader_tpu.ops import moe
+from ray_shuffling_data_loader_tpu.ops.flash_attention import flash_attention
+
+
+def fan_in(shape, fan_in_axis=-2):
+    """Normal initializer of deviation ``1 / sqrt(fan_in)``."""
+    return nn.initializers.normal(stddev=1.0 / math.sqrt(shape[fan_in_axis]))
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        return (x32 * inv * scale).astype(self.dtype)
+
+
+# -- rotary positions --------------------------------------------------------------
+
+
+def yarn_bounds(
+    dim: int, theta: float, original_length: int, beta_fast: float,
+    beta_slow: float,
+) -> Tuple[int, int]:
+    """``(low, high)``: the pair indices between which YaRN's ramp runs.
+    ``c(r) = dim ln(original_length / (2 pi r)) / (2 ln theta)`` is the
+    index of the pair that turns ``r`` times over the original length;
+    pairs under ``low`` (more than ``beta_fast`` turns) keep their
+    frequency, pairs over ``high`` (fewer than ``beta_slow``) are
+    interpolated."""
+
+    def pair(turns: float) -> float:
+        return dim * math.log(original_length / (2 * math.pi * turns)) / (
+            2 * math.log(theta)
+        )
+
+    return (
+        max(math.floor(pair(beta_fast)), 0),
+        min(math.ceil(pair(beta_slow)), dim - 1),
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """Rotary positions of one kind of layer. The first ``dim`` dimensions
+    of each head turn (the rest pass through), pair ``i`` at ``theta **
+    (-2 i / dim)`` a position; with ``factor`` > 1 (YaRN) the slow pairs
+    are interpolated by it over a linear ramp (:func:`yarn_bounds`), and
+    cos and sin are multiplied by ``attention_factor``."""
+
+    dim: int
+    theta: float
+    factor: float = 1.0
+    original_length: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self) -> jax.Array:
+        """``[dim / 2]`` float32: the angle each pair turns a position."""
+        freq = 1.0 / (
+            self.theta ** (jnp.arange(0, self.dim, 2, dtype=jnp.float32) / self.dim)
+        )
+        if self.factor == 1.0:
+            return freq
+        low, high = yarn_bounds(
+            self.dim, self.theta, self.original_length, self.beta_fast,
+            self.beta_slow,
+        )
+        ramp = jnp.clip(
+            (jnp.arange(self.dim // 2, dtype=jnp.float32) - low)
+            / max(high - low, 1e-3),
+            0.0, 1.0,
+        )
+        return ramp * freq / self.factor + (1.0 - ramp) * freq
+
+
+def rotary(x: jax.Array, rope: Rope) -> jax.Array:
+    """Rotary positions on ``[batch, seq, heads, head_dim]`` (the
+    half-split convention: of the ``rope.dim`` dimensions that turn,
+    dimension ``i`` turns with ``i + rope.dim / 2``), in float32."""
+    seq, dim = x.shape[1], rope.dim
+    freq = rope.inv_freq()
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    if rope.attention_factor != 1.0:
+        cos, sin = cos * rope.attention_factor, sin * rope.attention_factor
+    turned, passed = x.astype(jnp.float32), []
+    if dim < x.shape[-1]:
+        turned, passed = turned[..., :dim], [turned[..., dim:]]
+    x1, x2 = jnp.split(turned, 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, *passed], axis=-1
+    ).astype(x.dtype)
+
+
+# -- the operators and the FFNs ----------------------------------------------------
+
+
+class Attention(nn.Module):
+    """Grouped-query causal attention: ``heads`` query heads over
+    ``kv_heads`` key/value heads of ``head_dim``, rotary positions on q and
+    k (after an RMS norm on each head's q and k where ``qk_norm_eps`` is
+    given), every key up to the query's own or the last ``window`` of
+    them, an output projection. ``scope_name`` names its operations in a
+    trace."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    rope: Rope
+    dtype: Any
+    use_pallas: Optional[bool]
+    interpret: bool
+    block_q: int
+    block_k: int
+    qk_norm_eps: Optional[float] = None
+    window: Optional[int] = None
+    scope_name: str = "attention"
+
+    @nn.compact
+    def __call__(self, x):
+        h, d = x.shape[-1], self.head_dim
+        nq, nkv = self.heads, self.kv_heads
+        wq = self.param("q_proj", fan_in((h, nq * d)), (h, nq * d))
+        wk = self.param("k_proj", fan_in((h, nkv * d)), (h, nkv * d))
+        wv = self.param("v_proj", fan_in((h, nkv * d)), (h, nkv * d))
+        wo = self.param("out_proj", fan_in((nq * d, h)), (nq * d, h))
+        b, t, _ = x.shape
+        with jax.named_scope(self.scope_name):
+            q = jnp.dot(x, wq.astype(self.dtype)).reshape(b, t, nq, d)
+            k = jnp.dot(x, wk.astype(self.dtype)).reshape(b, t, nkv, d)
+            v = jnp.dot(x, wv.astype(self.dtype)).reshape(b, t, nkv, d)
+            if self.qk_norm_eps is not None:
+                q = RMSNorm(self.qk_norm_eps, self.dtype, name="q_norm")(q)
+                k = RMSNorm(self.qk_norm_eps, self.dtype, name="k_norm")(k)
+            q = rotary(q, self.rope)
+            k = rotary(k, self.rope)
+            out = flash_attention(
+                q, k, v, causal=True, use_pallas=self.use_pallas,
+                interpret=self.interpret,
+                block_q=self.block_q, block_k=self.block_k,
+                window=self.window,
+            )
+            return jnp.dot(out.reshape(b, t, nq * d), wo.astype(self.dtype))
+
+
+class DenseFFN(nn.Module):
+    """``W2 (silu(W1 x) * W3 x)`` at ``width``."""
+
+    width: int
+    dtype: Any
+    scope_name: str = "dense_ffn"
+
+    @nn.compact
+    def __call__(self, x):
+        h, width = x.shape[-1], self.width
+        w1 = self.param("w1", fan_in((h, width)), (h, width))
+        w3 = self.param("w3", fan_in((h, width)), (h, width))
+        w2 = self.param("w2", fan_in((width, h)), (width, h))
+        with jax.named_scope(self.scope_name):
+            up = jax.nn.silu(jnp.dot(x, w1.astype(self.dtype))) * jnp.dot(
+                x, w3.astype(self.dtype)
+            )
+            return jnp.dot(up, w2.astype(self.dtype))
+
+
+@dataclasses.dataclass(frozen=True)
+class Experts:
+    """One chip's share of a routed layer: ``held`` experts of ``width``
+    from ``first`` on, of the ``routed`` that the router scores, ``top_k``
+    a token; a ``selection_bias`` enters the choice only; the chosen
+    scores are divided by their sum where ``norm_topk`` and multiplied by
+    ``scaling`` (``ops/moe.py`` :func:`~..ops.moe.route`)."""
+
+    width: int
+    routed: int
+    held: int
+    first: int
+    top_k: int
+    selection_bias: bool
+    norm_topk: bool = True
+    scaling: float = 1.0
+
+
+class ExpertFFN(nn.Module):
+    """Returns ``(y, counts)``: ``load [held]``, ``dropped`` and
+    ``fallback`` of :func:`~..ops.moe.experts_ffn`."""
+
+    experts: Experts
+    dtype: Any
+    use_pallas: Optional[bool]
+    interpret: bool
+    row_tile: int
+
+    @nn.compact
+    def __call__(self, x):
+        spec = self.experts
+        h, width, held = x.shape[-1], spec.width, spec.held
+        gate = self.param("gate", fan_in((h, spec.routed)), (h, spec.routed))
+        bias = (
+            self.param(
+                "expert_bias", nn.initializers.normal(stddev=0.01),
+                (spec.routed,),
+            )
+            if spec.selection_bias
+            else None
+        )
+        w1 = self.param("w1", fan_in((held, h, width)), (held, h, width))
+        w3 = self.param("w3", fan_in((held, h, width)), (held, h, width))
+        w2 = self.param("w2", fan_in((held, width, h)), (held, width, h))
+        tokens = x.reshape(-1, h)
+        with jax.named_scope("router"):
+            experts, weights = moe.route(
+                tokens, gate, bias, spec.top_k, spec.norm_topk, spec.scaling,
+            )
+        with jax.named_scope("experts"):
+            y, load, dropped, fallback = moe.experts_ffn(
+                tokens, experts, weights, w1, w3, w2, spec.first,
+                spec.routed, tile=self.row_tile,
+                use_pallas=self.use_pallas, interpret=self.interpret,
+            )
+        counts = {"load": load, "dropped": dropped, "fallback": fallback}
+        return y.reshape(x.shape), counts
+
+
+def moe_load_counts(load, dropped, fallback) -> dict:
+    """What the ``moe:load`` counter of one step carries, from the step's
+    ``[expert layers, experts_held]`` token counts and its ``[expert
+    layers]`` counts of assignments left out of the buffer and of layers
+    that ran in the worst-case buffer: the fullest expert, the mean, the
+    assignments dropped (the layer is built to drop none; this is the
+    count that says so), the expert layers, and those of them whose load
+    outgrew the bounded buffer."""
+    return {
+        "max": int(load.max()),
+        "mean": float(load.mean()),
+        "dropped": int(dropped.sum()),
+        "layers": int(load.shape[0]),
+        "fallback": int(fallback.sum()),
+    }
+
+
+# -- the language model around the layers --------------------------------------------
+
+
+class SequenceLM(nn.Module):
+    """``__call__({"tokens": [batch, seq] int32}) -> (loss, counters)``:
+    the mean next-token cross-entropy over the vocabulary rows held, and
+    ``{"moe_load": [expert layers, experts_held], "moe_dropped": [expert
+    layers], "moe_fallback": [expert layers]}``: the tokens routed to each
+    held expert, the assignments left out, and 1 where the layer ran in the
+    worst-case buffer. ``logits=True`` returns the logits instead (float32
+    ``[batch, seq, vocab]``: a test's size only).
+
+    A model gives ``cfg`` (``vocab_size``, ``hidden_size``, ``norm_eps``,
+    ``experts_held``, ``layers()``: what each layer kept is, its published
+    index first), :meth:`recomputed_layer` and ``build_facts``.
+    ``use_pallas`` / ``interpret`` go to the attention and expert kernels
+    (None: the kernels on a TPU backend)."""
+
+    cfg: Any
+    compute_dtype: Any = jnp.bfloat16
+    use_pallas: Optional[bool] = None
+    interpret: bool = False
+    block_q: int = 512
+    block_k: int = 512
+    row_tile: int = moe.ROW_TILE
+
+    # How ``parallel/train.py`` drives a model that brings its own loss:
+    # one step input (the features, no labels), and the step's counters
+    # beside the loss: ``{span name: (metrics keys, what the span carries
+    # of their values)}``.
+    batch_inputs = 1
+    step_counters = {
+        "moe:load": (
+            ("moe_load", "moe_dropped", "moe_fallback"), moe_load_counts
+        )
+    }
+
+    def recomputed_layer(self, *of_layer) -> nn.Module:
+        """The layer that one entry of ``cfg.layers()`` describes, under
+        ``nn.remat`` with the model's policy of what is kept. It maps ``x``
+        to ``(x, counts)``: an expert layer's ``{"load", "dropped",
+        "fallback"}``, a dense layer's ``{}``."""
+        raise NotImplementedError
+
+    def loss_fn(self, params, features):
+        """``(loss, counters)`` of one batch of features."""
+        return self.apply(params, features)
+
+    @nn.compact
+    def __call__(self, features, logits: bool = False):
+        cfg = self.cfg
+        tokens = features["tokens"]
+        dt = self.compute_dtype
+        embed = self.param(
+            "embed", fan_in((cfg.vocab_size, cfg.hidden_size), -1),
+            (cfg.vocab_size, cfg.hidden_size),
+        )
+        head = self.param(
+            "head", fan_in((cfg.hidden_size, cfg.vocab_size)),
+            (cfg.hidden_size, cfg.vocab_size),
+        )
+        with jax.named_scope("embed"):
+            x = jnp.take(embed, tokens, axis=0).astype(dt)
+        counts = []
+        for of_layer in cfg.layers():
+            x, layer_counts = self.recomputed_layer(*of_layer)(x)
+            if layer_counts:
+                counts.append(layer_counts)
+        x = RMSNorm(cfg.norm_eps, dt, name="final_norm")(x)
+        none = {"load": (0, cfg.experts_held), "dropped": (0,), "fallback": (0,)}
+        counters = {
+            f"moe_{name}": jnp.stack([c[name] for c in counts])
+            if counts else jnp.zeros(shape, jnp.int32)
+            for name, shape in none.items()
+        }
+        with jax.named_scope("head"):
+            head = head.astype(dt)
+            if logits:
+                return jnp.dot(x, head, preferred_element_type=jnp.float32)
+            return next_token_loss(x, head, tokens), counters
+
+
+def next_token_loss(x: jax.Array, head: jax.Array, tokens: jax.Array):
+    """Mean cross-entropy of position ``t``'s logits against token ``t +
+    1``, over every position but each sequence's last. One sequence's
+    logits at a time, recomputed in the backward pass: ``[seq, vocab]``
+    float32 is all that ever exists of them."""
+    seq = tokens.shape[1]
+    targets = jnp.roll(tokens, -1, axis=1)
+    counted = (jnp.arange(seq) < seq - 1).astype(jnp.float32)
+
+    @jax.checkpoint
+    def of_sequence(args):
+        x_row, target_row = args
+        logits = jnp.dot(x_row, head, preferred_element_type=jnp.float32)
+        picked = jnp.take_along_axis(logits, target_row[:, None], axis=-1)[:, 0]
+        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * counted)
+
+    total = jnp.sum(jax.lax.map(of_sequence, (x, targets)))
+    return total / (tokens.shape[0] * (seq - 1))

@@ -33,24 +33,33 @@ logits are recomputed one sequence at a time.
 
 The model brings its own loss (``loss_fn``) and its step's counters, which
 ``parallel/train.py`` picks up: a batch is ``{"tokens": [batch, seq]}`` and
-has no label.
+has no label. The layer's parts and the language model around the layers
+are ``models/blocks.py``'s, shared with ``models/laguna.py``; the short
+convolution, the layer pattern and what is kept are this model's.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_shuffling_data_loader_tpu.ops import moe
+from ray_shuffling_data_loader_tpu.models.blocks import (
+    Attention,
+    DenseFFN,
+    ExpertFFN,
+    Experts,
+    RMSNorm,
+    Rope,
+    SequenceLM,
+    fan_in,
+)
 from ray_shuffling_data_loader_tpu.ops.flash_attention import (
     ATTENTION_OUT,
     ATTENTION_STATS,
-    flash_attention,
 )
 from ray_shuffling_data_loader_tpu.ops.short_conv import causal_depthwise_conv1d
 
@@ -101,44 +110,20 @@ class Lfm2MoeConfig:
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
 
+    @property
+    def experts(self) -> Experts:
+        return Experts(
+            self.moe_intermediate_size, self.num_experts, self.experts_held,
+            self.first_expert, self.num_experts_per_tok, self.use_expert_bias,
+            self.norm_topk_prob, self.routed_scaling_factor,
+        )
+
     def layers(self) -> Sequence[Tuple[int, str, bool]]:
         """``(published index, op kind, dense FFN?)`` of each layer kept."""
         kept = range(self.first_layer, self.first_layer + self.num_hidden_layers)
         return [
             (i, self.layer_types[i], i < self.num_dense_layers) for i in kept
         ]
-
-
-def _fan_in(shape, fan_in_axis=-2):
-    """Normal initializer of deviation ``1 / sqrt(fan_in)``."""
-    return nn.initializers.normal(stddev=1.0 / math.sqrt(shape[fan_in_axis]))
-
-
-class RMSNorm(nn.Module):
-    eps: float
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        x32 = x.astype(jnp.float32)
-        inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
-        return (x32 * inv * scale).astype(self.dtype)
-
-
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary positions on ``[batch, seq, heads, head_dim]`` (the
-    half-split convention: dimension ``i`` turns with ``i + head_dim/2``),
-    in float32."""
-    seq, dim = x.shape[1], x.shape[-1]
-    freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * freq[None, :]
-    cos = jnp.cos(angle)[None, :, None, :]
-    sin = jnp.sin(angle)[None, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
 
 
 class ShortConv(nn.Module):
@@ -148,111 +133,18 @@ class ShortConv(nn.Module):
     @nn.compact
     def __call__(self, x):
         h = self.cfg.hidden_size
-        w_in = self.param("in_proj", _fan_in((h, 3 * h)), (h, 3 * h))
+        w_in = self.param("in_proj", fan_in((h, 3 * h)), (h, 3 * h))
         taps = self.param(
-            "conv", _fan_in((h, self.cfg.conv_L_cache), -1),
+            "conv", fan_in((h, self.cfg.conv_L_cache), -1),
             (h, self.cfg.conv_L_cache),
         )
-        w_out = self.param("out_proj", _fan_in((h, h)), (h, h))
+        w_out = self.param("out_proj", fan_in((h, h)), (h, h))
         with jax.named_scope("short_conv"):
             gate_b, gate_c, u = jnp.split(
                 jnp.dot(x, w_in.astype(self.dtype)), 3, axis=-1
             )
             y = gate_c * causal_depthwise_conv1d(gate_b * u, taps)
             return jnp.dot(y, w_out.astype(self.dtype))
-
-
-class Attention(nn.Module):
-    cfg: Lfm2MoeConfig
-    dtype: Any
-    use_pallas: Optional[bool]
-    interpret: bool
-    block_q: int
-    block_k: int
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        h, d = cfg.hidden_size, cfg.head_dim
-        nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
-        wq = self.param("q_proj", _fan_in((h, nq * d)), (h, nq * d))
-        wk = self.param("k_proj", _fan_in((h, nkv * d)), (h, nkv * d))
-        wv = self.param("v_proj", _fan_in((h, nkv * d)), (h, nkv * d))
-        wo = self.param("out_proj", _fan_in((nq * d, h)), (nq * d, h))
-        b, t, _ = x.shape
-        with jax.named_scope("attention"):
-            q = jnp.dot(x, wq.astype(self.dtype)).reshape(b, t, nq, d)
-            k = jnp.dot(x, wk.astype(self.dtype)).reshape(b, t, nkv, d)
-            v = jnp.dot(x, wv.astype(self.dtype)).reshape(b, t, nkv, d)
-            q = RMSNorm(cfg.norm_eps, self.dtype, name="q_norm")(q)
-            k = RMSNorm(cfg.norm_eps, self.dtype, name="k_norm")(k)
-            q = rotary(q, cfg.rope_theta)
-            k = rotary(k, cfg.rope_theta)
-            out = flash_attention(
-                q, k, v, causal=True, use_pallas=self.use_pallas,
-                interpret=self.interpret,
-                block_q=self.block_q, block_k=self.block_k,
-            )
-            return jnp.dot(out.reshape(b, t, nq * d), wo.astype(self.dtype))
-
-
-class DenseFFN(nn.Module):
-    cfg: Lfm2MoeConfig
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, x):
-        h, width = self.cfg.hidden_size, self.cfg.intermediate_size
-        w1 = self.param("w1", _fan_in((h, width)), (h, width))
-        w3 = self.param("w3", _fan_in((h, width)), (h, width))
-        w2 = self.param("w2", _fan_in((width, h)), (width, h))
-        with jax.named_scope("dense_ffn"):
-            up = jax.nn.silu(jnp.dot(x, w1.astype(self.dtype))) * jnp.dot(
-                x, w3.astype(self.dtype)
-            )
-            return jnp.dot(up, w2.astype(self.dtype))
-
-
-class ExpertFFN(nn.Module):
-    """Returns ``(y, counts)``: ``load [experts_held]``, ``dropped`` and
-    ``fallback`` of :func:`~..ops.moe.experts_ffn`."""
-
-    cfg: Lfm2MoeConfig
-    dtype: Any
-    use_pallas: Optional[bool]
-    interpret: bool
-    row_tile: int
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        h, width, held = cfg.hidden_size, cfg.moe_intermediate_size, cfg.experts_held
-        gate = self.param("gate", _fan_in((h, cfg.num_experts)), (h, cfg.num_experts))
-        bias = (
-            self.param(
-                "expert_bias", nn.initializers.normal(stddev=0.01),
-                (cfg.num_experts,),
-            )
-            if cfg.use_expert_bias
-            else None
-        )
-        w1 = self.param("w1", _fan_in((held, h, width)), (held, h, width))
-        w3 = self.param("w3", _fan_in((held, h, width)), (held, h, width))
-        w2 = self.param("w2", _fan_in((held, width, h)), (held, width, h))
-        tokens = x.reshape(-1, h)
-        with jax.named_scope("router"):
-            experts, weights = moe.route(
-                tokens, gate, bias, cfg.num_experts_per_tok,
-                cfg.norm_topk_prob, cfg.routed_scaling_factor,
-            )
-        with jax.named_scope("experts"):
-            y, load, dropped, fallback = moe.experts_ffn(
-                tokens, experts, weights, w1, w3, w2, cfg.first_expert,
-                cfg.num_experts, tile=self.row_tile,
-                use_pallas=self.use_pallas, interpret=self.interpret,
-            )
-        counts = {"load": load, "dropped": dropped, "fallback": fallback}
-        return y.reshape(x.shape), counts
 
 
 class Layer(nn.Module):
@@ -279,36 +171,22 @@ class Layer(nn.Module):
             x = x + ShortConv(cfg, self.dtype, name="conv")(normed)
         elif self.kind == "full_attention":
             x = x + Attention(
-                cfg, self.dtype, self.use_pallas, self.interpret,
-                self.block_q, self.block_k, name="self_attn",
+                cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim,
+                Rope(cfg.head_dim, cfg.rope_theta), self.dtype,
+                self.use_pallas, self.interpret, self.block_q, self.block_k,
+                qk_norm_eps=cfg.norm_eps, name="self_attn",
             )(normed)
         else:
             raise ValueError(f"unknown layer type {self.kind!r}")
         normed = RMSNorm(cfg.norm_eps, self.dtype, name="ffn_norm")(x)
         if self.dense:
-            return x + DenseFFN(cfg, self.dtype, name="feed_forward")(normed), {}
+            ffn = DenseFFN(cfg.intermediate_size, self.dtype, name="feed_forward")
+            return x + ffn(normed), {}
         y, counts = ExpertFFN(
-            cfg, self.dtype, self.use_pallas, self.interpret, self.row_tile,
-            name="feed_forward",
+            cfg.experts, self.dtype, self.use_pallas, self.interpret,
+            self.row_tile, name="feed_forward",
         )(normed)
         return x + y, counts
-
-
-def moe_load_counts(load, dropped, fallback) -> dict:
-    """What the ``moe:load`` counter of one step carries, from the step's
-    ``[expert layers, experts_held]`` token counts and its ``[expert
-    layers]`` counts of assignments left out of the buffer and of layers
-    that ran in the worst-case buffer: the fullest expert, the mean, the
-    assignments dropped (the layer is built to drop none; this is the
-    count that says so), the expert layers, and those of them whose load
-    outgrew the bounded buffer."""
-    return {
-        "max": int(load.max()),
-        "mean": float(load.mean()),
-        "dropped": int(dropped.sum()),
-        "layers": int(load.shape[0]),
-        "fallback": int(fallback.sum()),
-    }
 
 
 # What a recomputed layer keeps of its forward pass: the outputs of its plain
@@ -319,39 +197,14 @@ KEPT = jax.checkpoint_policies.save_from_both_policies(
 )
 
 
-class Lfm2MoeLM(nn.Module):
-    """``__call__({"tokens": [batch, seq] int32}) -> (loss, counters)``:
-    the mean next-token cross-entropy over the vocabulary rows held, and
-    ``{"moe_load": [expert layers, experts_held], "moe_dropped": [expert
-    layers], "moe_fallback": [expert layers]}``: the tokens routed to each
-    held expert, the assignments left out, and 1 where the layer ran in the
-    worst-case buffer. ``logits=True`` returns the logits instead (float32
-    ``[batch, seq, vocab]``: a test's size only).
-
-    ``use_pallas`` / ``interpret`` go to the attention and expert kernels
-    (None: the kernels on a TPU backend). Every layer is recomputed in the
-    backward pass but for ``KEPT``: its plain matmuls' outputs and the
-    attention kernel's output and row statistics (142.6 MB an attention
-    layer at 4 x 8,192 tokens), so that kernel's forward is not."""
+class Lfm2MoeLM(SequenceLM):
+    """The LFM2-MoE of one chip's share (:class:`~.blocks.SequenceLM`).
+    Every layer is recomputed in the backward pass but for ``KEPT``: its
+    plain matmuls' outputs and the attention kernel's output and row
+    statistics (142.6 MB an attention layer at 4 x 8,192 tokens), so that
+    kernel's forward is not."""
 
     cfg: Lfm2MoeConfig
-    compute_dtype: Any = jnp.bfloat16
-    use_pallas: Optional[bool] = None
-    interpret: bool = False
-    block_q: int = 512
-    block_k: int = 512
-    row_tile: int = moe.ROW_TILE
-
-    # How ``parallel/train.py`` drives a model that brings its own loss:
-    # one step input (the features, no labels), and the step's counters
-    # beside the loss: ``{span name: (metrics keys, what the span carries
-    # of their values)}``.
-    batch_inputs = 1
-    step_counters = {
-        "moe:load": (
-            ("moe_load", "moe_dropped", "moe_fallback"), moe_load_counts
-        )
-    }
 
     @property
     def build_facts(self) -> dict:
@@ -366,64 +219,9 @@ class Lfm2MoeLM(nn.Module):
             ),
         }
 
-    def loss_fn(self, params, features):
-        """``(loss, counters)`` of one batch of features."""
-        return self.apply(params, features)
-
-    @nn.compact
-    def __call__(self, features, logits: bool = False):
-        cfg = self.cfg
-        tokens = features["tokens"]
-        dt = self.compute_dtype
-        embed = self.param(
-            "embed", _fan_in((cfg.vocab_size, cfg.hidden_size), -1),
-            (cfg.vocab_size, cfg.hidden_size),
+    def recomputed_layer(self, index, kind, dense) -> nn.Module:
+        return nn.remat(Layer, policy=KEPT)(
+            self.cfg, kind, dense, self.compute_dtype, self.use_pallas,
+            self.interpret, self.block_q, self.block_k, self.row_tile,
+            name=f"layer_{index}",
         )
-        head = self.param(
-            "head", _fan_in((cfg.hidden_size, cfg.vocab_size)),
-            (cfg.hidden_size, cfg.vocab_size),
-        )
-        with jax.named_scope("embed"):
-            x = jnp.take(embed, tokens, axis=0).astype(dt)
-        layer_cls = nn.remat(Layer, policy=KEPT)
-        counts = []
-        for index, kind, dense in cfg.layers():
-            x, of_layer = layer_cls(
-                cfg, kind, dense, dt, self.use_pallas, self.interpret,
-                self.block_q, self.block_k, self.row_tile,
-                name=f"layer_{index}",
-            )(x)
-            if of_layer:
-                counts.append(of_layer)
-        x = RMSNorm(cfg.norm_eps, dt, name="final_norm")(x)
-        none = {"load": (0, cfg.experts_held), "dropped": (0,), "fallback": (0,)}
-        counters = {
-            f"moe_{name}": jnp.stack([c[name] for c in counts])
-            if counts else jnp.zeros(shape, jnp.int32)
-            for name, shape in none.items()
-        }
-        with jax.named_scope("head"):
-            head = head.astype(dt)
-            if logits:
-                return jnp.dot(x, head, preferred_element_type=jnp.float32)
-            return next_token_loss(x, head, tokens), counters
-
-
-def next_token_loss(x: jax.Array, head: jax.Array, tokens: jax.Array):
-    """Mean cross-entropy of position ``t``'s logits against token ``t +
-    1``, over every position but each sequence's last. One sequence's
-    logits at a time, recomputed in the backward pass: ``[seq, vocab]``
-    float32 is all that ever exists of them."""
-    seq = tokens.shape[1]
-    targets = jnp.roll(tokens, -1, axis=1)
-    counted = (jnp.arange(seq) < seq - 1).astype(jnp.float32)
-
-    @jax.checkpoint
-    def of_sequence(args):
-        x_row, target_row = args
-        logits = jnp.dot(x_row, head, preferred_element_type=jnp.float32)
-        picked = jnp.take_along_axis(logits, target_row[:, None], axis=-1)[:, 0]
-        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * counted)
-
-    total = jnp.sum(jax.lax.map(of_sequence, (x, targets)))
-    return total / (tokens.shape[0] * (seq - 1))

@@ -23,6 +23,16 @@ interaction kernel (``ops/interaction.py``): Pallas on TPU backends, the
 XLA reference on backends Mosaic cannot target, interpret mode by
 explicit argument in CPU tests.
 
+Sliding window: with ``window`` a causal query at position ``i`` sees the
+keys ``i - window < j <= i``. The three kernels then run on a grid of the
+band alone: a query block's inner steps are the key blocks that intersect
+its band (:func:`_key_blocks`; a key block's, for dK/dV, the query blocks
+that intersect its own, :func:`_query_blocks`), the blocks outside are
+neither fetched nor computed, and the mask is applied only in the blocks
+that the band's two edges cut. Those Pallas calls are named
+``flash_attention_window_*``. Without a window nothing here differs from
+the plain kernels.
+
 Differentiability: the kernel carries an exact, memory-safe custom VJP.
 The forward emits its softmax row statistics (m, l) as outputs; the
 backward is two fused Pallas kernels — dK/dV (q innermost, VMEM
@@ -42,6 +52,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 
@@ -56,6 +67,51 @@ from ray_shuffling_data_loader_tpu.ops.ring_attention import (
     _chunked_attention_bwd,
     attention_reference,
 )
+
+
+def _key_blocks(qi, block_q, block_k, window, k_blocks, xp=jnp):
+    """``(first, last)`` key block that the band of query block ``qi``
+    touches: the keys ``q - window < k <= q`` of its queries."""
+    first = xp.maximum(qi * block_q - (window - 1), 0) // block_k
+    last = xp.minimum(((qi + 1) * block_q - 1) // block_k, k_blocks - 1)
+    return first, last
+
+
+def _query_blocks(ki, block_q, block_k, window, q_blocks, xp=jnp):
+    """``(first, last)`` query block whose band touches key block ``ki``:
+    the queries ``k <= q < k + window`` of its keys."""
+    first = (ki * block_k) // block_q
+    last = xp.minimum(
+        ((ki + 1) * block_k + window - 2) // block_q, q_blocks - 1
+    )
+    return first, last
+
+
+def _band_steps(blocks_of, blocks, *sizes) -> int:
+    """Inner grid steps of a windowed kernel: the most blocks that
+    ``blocks_of`` gives any of the ``blocks`` outer ones."""
+    first, last = blocks_of(np.arange(blocks), *sizes, xp=np)
+    return int((last - first + 1).max())
+
+
+def _on_band(run, qi, ki, block_q, block_k, window, update):
+    """``update(masked)`` where ``run``: unmasked in a block that lies whole
+    inside the band, masked in one that the diagonal or the window's far
+    edge cuts (a padded key lies past every real query, so the diagonal's
+    mask covers it)."""
+    from jax.experimental import pallas as pl
+
+    inside = (ki * block_k + block_k - 1 <= qi * block_q) & (
+        (qi + 1) * block_q - 1 - ki * block_k < window
+    )
+    pl.when(run & inside)(functools.partial(update, False))
+    pl.when(run & jnp.logical_not(inside))(functools.partial(update, True))
+
+
+def _kernel_name(window, which: str) -> str:
+    """The Pallas call's name in a trace: the windowed kernels are a
+    population of their own."""
+    return "flash_attention_" + ("" if window is None else "window_") + which
 
 
 def _flash_kernel(
@@ -74,6 +130,8 @@ def _flash_kernel(
     block_q: int,
     block_k: int,
     seq_len: int,
+    window: Optional[int] = None,
+    k_blocks: int = 0,
 ):
     """One (batch·head, q-block, kv-block) grid cell.
 
@@ -81,21 +139,28 @@ def _flash_kernel(
     revisited across it, carrying (running max, normalizer, accumulator)
     in VMEM scratch. The softmax statistics (row max ``m`` and
     normalizer ``l``) are emitted as outputs: the backward kernels and
-    the ring schedule's stats merge consume them.
+    the ring schedule's stats merge consume them. With ``window`` the
+    innermost axis steps through the query block's band of ``k_blocks``
+    key blocks, from its first.
     """
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    if window is None:
+        ki = step
+    else:
+        first, last = _key_blocks(qi, block_q, block_k, window, k_blocks)
+        ki = first + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr[...], NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr[...])
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
-    def _update():
+    def _update(masked=True):
         q = q_ref[0]  # [bq, d]
         k = k_ref[0]  # [bk, d]
         v = v_ref[0]
@@ -112,13 +177,15 @@ def _flash_kernel(
             jnp.int32, (block_q, block_k), 1
         )
         needs_mask = causal or seq_len % block_k != 0
-        if needs_mask:
+        if needs_mask and masked:
             valid = k_pos < seq_len  # pad keys past the real sequence
             if causal:
                 q_pos = qi * block_q + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 0
                 )
                 valid = valid & (q_pos >= k_pos)
+                if window is not None:
+                    valid = valid & (q_pos - k_pos < window)
             s = jnp.where(valid, s, NEG_INF)
         m_prev = m_scr[:, :1]  # [bq, 1] (lanes replicated)
         l_prev = l_scr[:, :1]
@@ -137,7 +204,10 @@ def _flash_kernel(
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if causal:
+    if window is not None:
+        # The band's own steps; a query block near the start has fewer.
+        _on_band(ki <= last, qi, ki, block_q, block_k, window, _update)
+    elif causal:
         # Skip fully-masked (strictly upper-right) blocks: the first
         # valid kv block for q-block qi always exists at ki == 0, so the
         # ki == 0 initialization above is never the skipped cell.
@@ -145,13 +215,33 @@ def _flash_kernel(
     else:
         _update()
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _fin():
         o_ref[0] = (
             acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
         ).astype(o_ref.dtype)
         m_ref[0] = m_scr[:, :1]
         l_ref[0] = l_scr[:, :1]
+
+
+def _key_steps(nq, nk, bq, bk, window):
+    """The inner grid axis of the forward and dQ kernels: ``(steps,
+    key_block(i, j), kernel keywords)``. Every key block without a window;
+    with one, query block ``i``'s band, and past its last block that block
+    again, so that nothing is fetched for a step that does not run."""
+    if window is None:
+        return nk, (lambda i, j: j), {}
+    band = (bq, bk, window, nk)
+
+    def key_block(i, j):
+        first, last = _key_blocks(i, *band)
+        return jnp.minimum(first + j, last)
+
+    return (
+        _band_steps(_key_blocks, nq, *band),
+        key_block,
+        {"window": window, "k_blocks": nk},
+    )
 
 
 def _to_bh(x, t_pad):
@@ -185,10 +275,12 @@ def _flash_forward(
     block_k: int,
     interpret: bool,
     return_stats: bool = False,
+    window: Optional[int] = None,
 ):
     """Fused forward. With ``return_stats`` also returns the softmax row
     statistics ``(m, l)`` as float32 ``[b, h, t]`` — residuals for the
-    fused backward and merge inputs for the ring schedule."""
+    fused backward and merge inputs for the ring schedule. With ``window``
+    (causal only) the grid's inner axis is the band's key blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -204,6 +296,8 @@ def _flash_forward(
     kb = _to_bh(k, tk_pad)
     vb = _to_bh(v, tk_pad)
 
+    nk = tk_pad // bk
+    steps, key_block, of_band = _key_steps(tq_pad // bq, nk, bq, bk, window)
     kernel = functools.partial(
         _flash_kernel,
         scale=scale,
@@ -211,14 +305,19 @@ def _flash_forward(
         block_q=bq,
         block_k=bk,
         seq_len=t,
+        **of_band,
     )
     out, m, l = pl.pallas_call(
         kernel,
-        grid=(b * h, tq_pad // bq, tk_pad // bk),
+        grid=(b * h, tq_pad // bq, steps),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (kv_of(bh), j, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, i, j: (kv_of(bh), j, 0)),
+            pl.BlockSpec(
+                (1, bk, d), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
+            ),
+            pl.BlockSpec(
+                (1, bk, d), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
+            ),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
@@ -241,7 +340,7 @@ def _flash_forward(
         interpret=interpret,
         # The kernel's own name in the trace, whatever jit calls the
         # function that holds it.
-        name="flash_attention_fwd",
+        name=_kernel_name(window, "fwd"),
     )(qb, kb, vb)
     out = out[:, :t].reshape(b, h, t, d)
     out = jnp.transpose(out, (0, 2, 1, 3))
@@ -250,7 +349,8 @@ def _flash_forward(
     return out, m[:, :t, 0].reshape(b, h, t), l[:, :t, 0].reshape(b, h, t)
 
 
-def _bwd_probs(q, k, m, l, ki, scale, causal, block_q, block_k, seq_len, qi):
+def _bwd_probs(q, k, m, l, ki, scale, causal, block_q, block_k, seq_len, qi,
+               window=None, masked=True):
     """Shared backward-kernel algebra: recompute the normalized
     probability block from the saved statistics."""
     s = (
@@ -265,13 +365,15 @@ def _bwd_probs(q, k, m, l, ki, scale, causal, block_q, block_k, seq_len, qi):
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (q.shape[0], k.shape[0]), 1
     )
-    if causal or seq_len % block_k != 0:
+    if (causal or seq_len % block_k != 0) and masked:
         valid = k_pos < seq_len
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (q.shape[0], k.shape[0]), 0
             )
             valid = valid & (q_pos >= k_pos)
+            if window is not None:
+                valid = valid & (q_pos - k_pos < window)
         s = jnp.where(valid, s, NEG_INF)
     p = jnp.exp(s - m) / jnp.maximum(l, 1e-30)
     # Fully-masked rows kept m at NEG_INF and must contribute nothing.
@@ -297,10 +399,14 @@ def _flash_bwd_dkv_kernel(
     block_k: int,
     seq_len: int,
     q_blocks: int,
+    window: Optional[int] = None,
+    band_steps: int = 0,
 ):
     """dK/dV: grid (batch·kv-head, kv-block, group·q-block) with the
     group's query heads and their q blocks innermost; the dk/dv
-    accumulators live in VMEM and are revisited across all of them.
+    accumulators live in VMEM and are revisited across all of them. With
+    ``window`` a query head's inner steps are the ``band_steps`` query
+    blocks from the first whose band touches this key block.
 
         p  = softmax block recomputed from (m, l)
         dv += pᵀ @ dO
@@ -311,21 +417,25 @@ def _flash_bwd_dkv_kernel(
 
     ki = pl.program_id(1)
     inner = pl.program_id(2)
-    qi = inner % q_blocks
+    if window is None:
+        qi = inner % q_blocks
+    else:
+        first, last = _query_blocks(ki, block_q, block_k, window, q_blocks)
+        qi = first + inner % band_steps
 
     @pl.when(inner == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
 
-    def _update():
+    def _update(masked=True):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0].astype(jnp.float32)
         p = _bwd_probs(
             q, k, m_ref[0], l_ref[0], ki, scale, causal, block_q,
-            block_k, seq_len, qi,
+            block_k, seq_len, qi, window, masked,
         )
         dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
             p,
@@ -347,7 +457,9 @@ def _flash_bwd_dkv_kernel(
             preferred_element_type=jnp.float32,
         ) * scale
 
-    if causal:
+    if window is not None:
+        _on_band(qi <= last, qi, ki, block_q, block_k, window, _update)
+    elif causal:
         # q blocks strictly above the diagonal see only masked scores.
         pl.when((qi + 1) * block_q > ki * block_k)(_update)
     else:
@@ -375,27 +487,35 @@ def _flash_bwd_dq_kernel(
     block_q: int,
     block_k: int,
     seq_len: int,
+    window: Optional[int] = None,
+    k_blocks: int = 0,
 ):
     """dQ: grid (batch·head, q-block, kv-block) with kv innermost;
-    ``dq += ds @ k · scale`` accumulates in VMEM across kv blocks."""
+    ``dq += ds @ k · scale`` accumulates in VMEM across kv blocks (with
+    ``window``: across the band's, as in the forward)."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    if window is None:
+        ki = step
+    else:
+        first, last = _key_blocks(qi, block_q, block_k, window, k_blocks)
+        ki = first + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
 
-    def _update():
+    def _update(masked=True):
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0].astype(jnp.float32)
         p = _bwd_probs(
             q, k, m_ref[0], l_ref[0], ki, scale, causal, block_q,
-            block_k, seq_len, qi,
+            block_k, seq_len, qi, window, masked,
         )
         dp = jax.lax.dot_general(
             do,
@@ -410,18 +530,20 @@ def _flash_bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         ) * scale
 
-    if causal:
+    if window is not None:
+        _on_band(ki <= last, qi, ki, block_q, block_k, window, _update)
+    elif causal:
         pl.when((qi + 1) * block_q > ki * block_k)(_update)
     else:
         _update()
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == steps - 1)
     def _fin():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _flash_backward_pallas(
-    q, k, v, out, m, l, ct, causal, block_q, block_k, interpret
+    q, k, v, out, m, l, ct, causal, block_q, block_k, interpret, window=None
 ):
     """Fused flash backward: two Pallas kernels (dK/dV with q innermost,
     dQ with kv innermost) consuming the forward's saved statistics — no
@@ -468,16 +590,33 @@ def _flash_backward_pallas(
     )
     db = rows_bh(big_d, tq_pad)
 
+    # dK/dV's inner axis: a query head's steps, every query block or (with
+    # a window) those whose band touches the key block.
+    if window is None:
+        q_steps, dkv_band = nq, {}
+
+        def q_block(j, i):
+            return i % nq
+
+    else:
+        band = (bq, bk, window, nq)
+        q_steps = _band_steps(_query_blocks, tk_pad // bk, *band)
+        dkv_band = {"window": window, "band_steps": q_steps}
+
+        def q_block(j, i):
+            first, last = _query_blocks(j, *band)
+            return jnp.minimum(first + i % q_steps, last)
+
     def q_of(bkv, inner):
         """The query row of this kv head's group that ``inner`` is at."""
-        return (bkv // hk) * h + (bkv % hk) * group + inner // nq
+        return (bkv // hk) * h + (bkv % hk) * group + inner // q_steps
 
     q_spec = pl.BlockSpec(
-        (1, bq, d), lambda bkv, j, i: (q_of(bkv, i), i % nq, 0)
+        (1, bq, d), lambda bkv, j, i: (q_of(bkv, i), q_block(j, i), 0)
     )
     kv_spec = pl.BlockSpec((1, bk, d), lambda bkv, j, i: (bkv, j, 0))
     row_spec = pl.BlockSpec(
-        (1, bq, 1), lambda bkv, j, i: (q_of(bkv, i), i % nq, 0)
+        (1, bq, 1), lambda bkv, j, i: (q_of(bkv, i), q_block(j, i), 0)
     )
     dkv = pl.pallas_call(
         functools.partial(
@@ -488,8 +627,9 @@ def _flash_backward_pallas(
             block_k=bk,
             seq_len=t,
             q_blocks=nq,
+            **dkv_band,
         ),
-        grid=(b * hk, tk_pad // bk, group * nq),
+        grid=(b * hk, tk_pad // bk, group * q_steps),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
                   row_spec],
         out_specs=[kv_spec, kv_spec],
@@ -505,12 +645,15 @@ def _flash_backward_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_attention_bwd_dkv",
+        name=_kernel_name(window, "bwd_dkv"),
     )(qb, kb, vb, dob, mb, lb, db)
     dkb, dvb = dkv
 
+    k_steps, key_block, dq_band = _key_steps(nq, tk_pad // bk, bq, bk, window)
     q_spec2 = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
-    kv_spec2 = pl.BlockSpec((1, bk, d), lambda bh, i, j: (kv_of(bh), j, 0))
+    kv_spec2 = pl.BlockSpec(
+        (1, bk, d), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
+    )
     row_spec2 = pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0))
     dqb = pl.pallas_call(
         functools.partial(
@@ -520,8 +663,9 @@ def _flash_backward_pallas(
             block_q=bq,
             block_k=bk,
             seq_len=t,
+            **dq_band,
         ),
-        grid=(b * h, tq_pad // bq, tk_pad // bk),
+        grid=(b * h, tq_pad // bq, k_steps),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2,
                   row_spec2, row_spec2],
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
@@ -531,7 +675,7 @@ def _flash_backward_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_attention_bwd_dq",
+        name=_kernel_name(window, "bwd_dq"),
     )(qb, kb, vb, dob, mb, lb, db)
 
     def from_bh(x):
@@ -558,13 +702,14 @@ _QKV_DIMS = (DATA_AXIS, None, MODEL_AXIS, None)  # [b, t, h, d]
 _STAT_DIMS = (DATA_AXIS, MODEL_AXIS, None)  # [b, h, t]
 
 
-def _sharded_flash(causal, block_q, block_k, interpret, return_stats=False):
+def _sharded_flash(causal, block_q, block_k, interpret, window,
+                   return_stats=False):
     """The forward kernel, split over the context mesh (:mod:`.placement`)."""
 
     def run(q, k, v):
         return _flash_forward(
             q, k, v, causal, block_q, block_k, interpret,
-            return_stats=return_stats,
+            return_stats=return_stats, window=window,
         )
 
     out_dims = [_QKV_DIMS]
@@ -573,12 +718,13 @@ def _sharded_flash(causal, block_q, block_k, interpret, return_stats=False):
     return over_mesh(run, in_dims=[_QKV_DIMS] * 3, out_dims=out_dims)
 
 
-def _sharded_flash_bwd(causal, block_q, block_k, interpret):
+def _sharded_flash_bwd(causal, block_q, block_k, interpret, window):
     """The fused backward under the same batch/head split."""
 
     def run(q, k, v, out, m, l, ct):
         return _flash_backward_pallas(
-            q, k, v, out, m, l, ct, causal, block_q, block_k, interpret
+            q, k, v, out, m, l, ct, causal, block_q, block_k, interpret,
+            window,
         )
 
     return over_mesh(
@@ -588,14 +734,14 @@ def _sharded_flash_bwd(causal, block_q, block_k, interpret):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_vjp(q, k, v, causal, block_q, block_k, interpret):
-    return _sharded_flash(causal, block_q, block_k, interpret)(q, k, v)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_vjp(q, k, v, causal, block_q, block_k, interpret, window):
+    return _sharded_flash(causal, block_q, block_k, interpret, window)(q, k, v)
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret):
+def _fwd(q, k, v, causal, block_q, block_k, interpret, window=None):
     out, m, l = _sharded_flash(
-        causal, block_q, block_k, interpret, return_stats=True
+        causal, block_q, block_k, interpret, window, return_stats=True
     )(q, k, v)
     # ``out`` joins the residuals (the backward needs D = rowsum(ct*out))
     # along with the softmax statistics the fused backward consumes. All
@@ -606,20 +752,24 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
     return out, (q, k, v, out, m, l)
 
 
-def _bwd(causal, block_q, block_k, interpret, res, ct):
+def _bwd(causal, block_q, block_k, interpret, window, res, ct):
     q, k, v, out, m, l = res
     # Fused Pallas backward by default (consumes the forward's saved
     # statistics — no stats-recompute pass); RSDL_FLASH_BWD=xla selects
     # the chunked-XLA exact backward (shared with blockwise_attention)
     # as an escape hatch.
     if os.environ.get("RSDL_FLASH_BWD", "pallas").lower() == "xla":
+        if window is not None:
+            raise ValueError(
+                "RSDL_FLASH_BWD=xla: the chunked backward knows no window"
+            )
         group = q.shape[2] // k.shape[2]
         dq, dk, dv = _chunked_attention_bwd(
             q, _repeat_kv(k, group), _repeat_kv(v, group), out, ct, causal,
             max(block_k, 128),
         )
         return dq, _sum_groups(dk, group), _sum_groups(dv, group)
-    return _sharded_flash_bwd(causal, block_q, block_k, interpret)(
+    return _sharded_flash_bwd(causal, block_q, block_k, interpret, window)(
         q, k, v, out, m, l, ct
     )
 
@@ -649,11 +799,18 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused attention over ``q [batch, seq, heads, head_dim]`` and ``k``,
     ``v`` ``[batch, seq, kv_heads, head_dim]``; ``kv_heads`` divides
     ``heads`` (grouped-query attention: query head ``i`` reads key/value
     head ``i // (heads // kv_heads)``).
+
+    ``window`` (with ``causal``): position ``i`` sees the ``window`` keys
+    ``i - window < j <= i``, itself among them, and the kernels visit the
+    blocks of that band only. Any positive width: one that is no multiple
+    of the blocks is masked where it ends, one of the sequence's length or
+    more is plain causal attention and runs as it.
 
     ``use_pallas=None`` auto-selects the kernel on any TPU backend (split
     batch/head-wise over the context mesh — same policy as
@@ -663,11 +820,20 @@ def flash_attention(
     only tests on the CPU set it, and it is never derived from the
     backend.
     """
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"a window is a causal query's last window >= 1 keys, "
+                f"not causal={causal}, window={window}"
+            )
+        if window >= q.shape[1]:
+            window = None
     if use_pallas is None:
         use_pallas = auto_pallas()
     if not use_pallas:
         group = q.shape[2] // k.shape[2]
         return attention_reference(
-            q, _repeat_kv(k, group), _repeat_kv(v, group), causal=causal
+            q, _repeat_kv(k, group), _repeat_kv(v, group), causal=causal,
+            window=window,
         )
-    return _flash_vjp(q, k, v, causal, block_q, block_k, interpret)
+    return _flash_vjp(q, k, v, causal, block_q, block_k, interpret, window)

@@ -51,10 +51,13 @@ NEG_INF = -1e30  # finite "minus infinity": avoids NaN from (-inf) - (-inf)
 
 
 def attention_reference(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Dense softmax attention, [batch, seq, heads, head_dim] — the
-    single-device reference the ring construction must match."""
+    single-device reference the ring construction must match. ``window``
+    (with ``causal``): each query's last ``window`` keys, its own among
+    them."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum(
         "bqhd,bkhd->bhqk",
@@ -64,6 +67,8 @@ def attention_reference(
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        if window is not None:
+            mask &= jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :] < window
         s = jnp.where(mask[None, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", w, v.astype(jnp.float32))
