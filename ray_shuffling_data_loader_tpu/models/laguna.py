@@ -54,6 +54,7 @@ from ray_shuffling_data_loader_tpu.ops.flash_attention import (
     ATTENTION_OUT,
     ATTENTION_STATS,
 )
+from ray_shuffling_data_loader_tpu.ops.moe import ROUTING
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 
@@ -207,10 +208,14 @@ class Layer(nn.Module):
 # What a recomputed layer keeps of its forward pass beside its input: the
 # residuals that the attention kernels name for their backward (the output
 # and the softmax row statistics), so that neither kernel's forward runs
-# twice a step. Every matmul runs again: at 691 M parameters the state takes
-# 8.3 GB of the chip and a sequence's kept dots would not fit beside it.
+# twice a step, and an expert layer's routing bookkeeping (``ops/moe.py``
+# ``ROUTING``: the experts chosen and the dispatch plan built from them,
+# integers but for a row's weight: 1,083,784 B a layer at 8,192 tokens, 8
+# choices and 32 experts held), so that ``top_k``, the plan's sort and its
+# scatters run once. Every matmul runs again: at 691 M parameters the state
+# takes 8.3 GB of the chip and a sequence's kept dots would not fit beside it.
 KEPT = jax.checkpoint_policies.save_only_these_names(
-    ATTENTION_OUT, ATTENTION_STATS
+    ATTENTION_OUT, ATTENTION_STATS, ROUTING
 )
 
 
@@ -230,8 +235,10 @@ class LagunaLM(SequenceLM):
             "window": cfg.sliding_window,
             "heads_full": cfg.heads_of(FULL),
             "heads_window": cfg.heads_of(SLIDING),
-            # The layers whose attention residuals ``KEPT`` holds on to.
+            # The layers whose attention residuals ``KEPT`` holds on to,
+            # and the expert layers whose routing and plan it does.
             "attention_kept": cfg.num_hidden_layers,
+            "routing_kept": sum(not dense for *_, dense in cfg.layers()),
         }
 
     def recomputed_layer(self, index, kind, heads, dense) -> nn.Module:

@@ -26,10 +26,14 @@ of its plain matmuls (the projections of the operator and of the dense FFN)
 and what the attention kernel's backward reads of its forward (``KEPT``: the
 output and the softmax row statistics, ``2 * (heads * head_dim + 4 * heads)``
 bytes a token in bfloat16: 142.6 MB an attention layer at 4 x 8,192 tokens
-and 32 heads of 64), so that the kernel's forward runs once a step. The
-elementwise work (norms, rotary positions, gates) and the whole expert layer
-(routing, dispatch, grouped products, combine) run again. The output head's
-logits are recomputed one sequence at a time.
+and 32 heads of 64), so that the kernel's forward runs once a step, and an
+expert layer's routing bookkeeping (``ops/moe.py`` ``ROUTING``: the experts
+chosen and the dispatch plan, 2,131,048 B a layer at 4 x 8,192 tokens, 4
+choices and 8 experts held), so that ``top_k``, the plan's sort and its
+scatters run once a step. The elementwise work (norms, rotary positions,
+gates) and the rest of the expert layer (the router's scores, dispatch,
+grouped products, combine) run again. The output head's logits are
+recomputed one sequence at a time.
 
 The model brings its own loss (``loss_fn``) and its step's counters, which
 ``parallel/train.py`` picks up: a batch is ``{"tokens": [batch, seq]}`` and
@@ -61,6 +65,7 @@ from ray_shuffling_data_loader_tpu.ops.flash_attention import (
     ATTENTION_OUT,
     ATTENTION_STATS,
 )
+from ray_shuffling_data_loader_tpu.ops.moe import ROUTING
 from ray_shuffling_data_loader_tpu.ops.short_conv import causal_depthwise_conv1d
 
 
@@ -190,33 +195,41 @@ class Layer(nn.Module):
 
 
 # What a recomputed layer keeps of its forward pass: the outputs of its plain
-# matmuls, and the residuals the attention kernel names for its backward.
+# matmuls, the residuals the attention kernel names for its backward (142.6
+# MB an attention layer), and an expert layer's routing bookkeeping
+# (``ops/moe.py`` ``ROUTING``: the experts chosen and the dispatch plan,
+# integers but for a row's weight, 2.1 MB a layer), so that ``top_k``, the
+# plan's sort and its scatters run once a step.
 KEPT = jax.checkpoint_policies.save_from_both_policies(
     jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    jax.checkpoint_policies.save_only_these_names(ATTENTION_OUT, ATTENTION_STATS),
+    jax.checkpoint_policies.save_only_these_names(
+        ATTENTION_OUT, ATTENTION_STATS, ROUTING
+    ),
 )
 
 
 class Lfm2MoeLM(SequenceLM):
     """The LFM2-MoE of one chip's share (:class:`~.blocks.SequenceLM`).
     Every layer is recomputed in the backward pass but for ``KEPT``: its
-    plain matmuls' outputs and the attention kernel's output and row
-    statistics (142.6 MB an attention layer at 4 x 8,192 tokens), so that
-    kernel's forward is not."""
+    plain matmuls' outputs, the attention kernel's output and row
+    statistics (142.6 MB an attention layer at 4 x 8,192 tokens) and an
+    expert layer's routing and dispatch plan (2.1 MB), so that neither that
+    kernel's forward nor the plan's sort and scatters are."""
 
     cfg: Lfm2MoeConfig
 
     @property
     def build_facts(self) -> dict:
         """What ``step:build`` says of the step this model makes."""
+        layers = self.cfg.layers()
         return {
             "model": "lfm2_moe",
             "experts_held": self.cfg.experts_held,
             "layers": self.cfg.num_hidden_layers,
-            # The layers whose attention residuals ``KEPT`` holds on to.
-            "attention_kept": sum(
-                kind == "full_attention" for _, kind, _ in self.cfg.layers()
-            ),
+            # The layers whose attention residuals ``KEPT`` holds on to,
+            # and the expert layers whose routing and plan it does.
+            "attention_kept": sum(kind == "full_attention" for _, kind, _ in layers),
+            "routing_kept": sum(not dense for *_, dense in layers),
         }
 
     def recomputed_layer(self, index, kind, dense) -> nn.Module:

@@ -5,6 +5,7 @@ against their formulas, and that LFM2's traced step is the parent's. CPU
 only, toy sizes, the kernels in the Pallas interpreter."""
 
 import collections
+import contextlib
 import hashlib
 import json
 import math
@@ -23,13 +24,15 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from chipbench import check, harness, limits  # noqa: E402
-from ray_shuffling_data_loader_tpu.models import blocks  # noqa: E402
+from ray_shuffling_data_loader_tpu.models import blocks, laguna  # noqa: E402
 from ray_shuffling_data_loader_tpu.models.laguna import (  # noqa: E402
     LagunaConfig,
     LagunaLM,
 )
 from ray_shuffling_data_loader_tpu.ops import moe  # noqa: E402
 from ray_shuffling_data_loader_tpu.ops.flash_attention import (  # noqa: E402
+    ATTENTION_OUT,
+    ATTENTION_STATS,
     flash_attention,
 )
 from ray_shuffling_data_loader_tpu.parallel import (  # noqa: E402
@@ -203,10 +206,9 @@ def test_without_a_window_lfm2_s_attention_call_is_the_parent_s(parent_traces):
     assert "flash_attention_window" not in str(traced)
 
 
-def test_lfm2_s_traced_step_is_the_parent_s(parent_traces):
-    """The whole train step of LFM2 at its rehearsal sizes, every kernel
-    in it: moving its layer's parts to ``models/blocks.py`` and giving the
-    attention kernels a window changed no equation of it."""
+def _lfm2_step_traced():
+    """The whole train step of LFM2 at its rehearsal sizes, every kernel in
+    it, as a jaxpr."""
     from ray_shuffling_data_loader_tpu.models.lfm2_moe import (
         Lfm2MoeConfig,
         Lfm2MoeLM,
@@ -228,8 +230,29 @@ def test_lfm2_s_traced_step_is_the_parent_s(parent_traces):
     state = jax.eval_shape(
         lambda p: TrainState(jnp.zeros((), jnp.int32), p, optimizer.init(p)), params
     )
-    traced = jax.make_jaxpr(make_step_body(model, optimizer))(state, batch)
-    assert _traced(traced) == parent_traces["step"]
+    return jax.make_jaxpr(make_step_body(model, optimizer))(state, batch)
+
+
+def test_lfm2_s_traced_step_is_the_parent_s(parent_traces, monkeypatch):
+    """Moving LFM2's layer parts to ``models/blocks.py`` and giving the
+    attention kernels a window changed no equation of its step (ISSUE 32).
+    ISSUE 33 names the routing and the plan in ``ops/moe.py``: the step
+    differs from the one pinned at ``01a534d`` by those ``name`` equations
+    (nine an expert layer) and by nothing else. So the step is pinned
+    again as it stands (``lfm2_traced_routing_named.json``), and with
+    ``checkpoint_name`` patched to the identity in ``ops/moe.py`` it still
+    is, equation for equation, the step of ``01a534d``."""
+    with open(
+        os.path.join(ROOT, "tests", "fixtures", "lfm2_traced_routing_named.json")
+    ) as f:
+        pinned = json.load(f)
+    assert pinned["jax"] == parent_traces["jax"]
+    traced = _lfm2_step_traced()
+    assert str(traced).count(f"name[name={moe.ROUTING}]") == pinned["names"] == 9 * 4
+    assert _traced(traced) == pinned["step"]
+    monkeypatch.setattr(moe, "checkpoint_name", lambda value, name: value)
+    jax.clear_caches()
+    assert _traced(_lfm2_step_traced()) == parent_traces["step"]
 
 
 # -- (d) the rotary tables ---------------------------------------------------------------
@@ -475,6 +498,23 @@ def _kernel_model(**over):
     return model, batch
 
 
+@contextlib.contextmanager
+def _tracing(monkeypatch):
+    """``RSDL_TRACE`` on and the span buffer empty inside; off and empty
+    again after."""
+    from ray_shuffling_data_loader_tpu.telemetry import trace
+
+    monkeypatch.setenv("RSDL_TRACE", "1")
+    trace.refresh_from_env()
+    trace.reset_state()
+    try:
+        yield
+    finally:
+        monkeypatch.delenv("RSDL_TRACE")
+        trace.refresh_from_env()
+        trace.reset_state()
+
+
 def test_each_attention_kernel_s_forward_runs_once_a_step():
     """Three sliding layers and two full ones, each recomputed in the
     backward pass with its kernel's output and row statistics kept."""
@@ -500,29 +540,22 @@ def test_each_attention_kernel_s_forward_runs_once_a_step():
 def test_the_step_says_what_it_was_built_for_and_names_its_scopes(monkeypatch):
     from ray_shuffling_data_loader_tpu import telemetry
     from ray_shuffling_data_loader_tpu.parallel import init_state, make_train_step
-    from ray_shuffling_data_loader_tpu.telemetry import trace
 
     model, batch = _kernel_model()
     model = model.clone(use_pallas=False, interpret=False)
     mesh = make_mesh(devices=jax.devices()[:1])
     optimizer = optax.adam(1e-5)
-    monkeypatch.setenv("RSDL_TRACE", "1")
-    trace.refresh_from_env()
-    trace.reset_state()
-    try:
+    with _tracing(monkeypatch):
         state, shardings = init_state(model, optimizer, mesh, batch)
         step = make_train_step(model, optimizer, mesh, shardings)
         lowered = step.lower(state, batch).as_text(debug_info=True)
         state, metrics = step(state, batch)
         spans = telemetry.local_spans()
-    finally:
-        monkeypatch.delenv("RSDL_TRACE")
-        trace.refresh_from_env()
-        trace.reset_state()
     (build,) = [s["args"] for s in spans if s["name"] == "step:build"]
     assert build == {
         "model": "laguna", "experts_held": 4, "layers": 5, "window": 16,
         "heads_full": 6, "heads_window": 8, "attention_kept": 5,
+        "routing_kept": 4,
     }
     (load,) = [s["args"] for s in spans if s["name"] == "moe:load"]
     assert set(load) == {"max", "mean", "dropped", "layers", "fallback"}
@@ -533,6 +566,79 @@ def test_the_step_says_what_it_was_built_for_and_names_its_scopes(monkeypatch):
         "dense_ffn", "head",
     ):
         assert re.search(rf'loss[^"]*/{scope}/', lowered), scope
+
+
+# ``KEPT`` as it stood before ISSUE 33: the attention kernels' residuals
+# alone, the routing and the plan built again.
+KEPT_BEFORE_ROUTING = jax.checkpoint_policies.save_only_these_names(
+    ATTENTION_OUT, ATTENTION_STATS
+)
+
+
+@pytest.mark.parametrize("router", ["even", "collapsed"])
+def test_keeping_the_routing_and_the_plan_changes_no_number(monkeypatch, router):
+    """Loss, counters and every gradient leaf under ``KEPT`` are, bit for
+    bit, those under the parent's ``KEPT`` (which builds every plan twice):
+    under the router as initialised, and under one that scores the experts
+    held elsewhere a flat 0.5 (their gate columns zeroed; there is no
+    selection bias to lean on), so that the four held draw four fifths of
+    the choices and every expert layer runs in the worst-case buffer."""
+    model, batch = _kernel_model()
+    params = model.init(jax.random.key(2), batch)
+    if router == "collapsed":
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: leaf.at[:, 4:].set(0.0)
+            if path[-1].key == "gate" else leaf,
+            params,
+        )
+
+    def readings():
+        # (Jitted anew each time: the policy is read when the model is traced.)
+        return jax.jit(
+            jax.value_and_grad(lambda p: model.apply(p, batch), has_aux=True)
+        )(params)
+
+    kept = readings()
+    monkeypatch.setattr(laguna, "KEPT", KEPT_BEFORE_ROUTING)
+    jax.clear_caches()
+    parent = readings()
+    (_, counters), _ = kept
+    assert counters["moe_fallback"].tolist() == [int(router == "collapsed")] * 4
+    assert counters["moe_dropped"].tolist() == [0] * 4
+    leaves = jax.tree.leaves(kept)
+    assert len(leaves) == 1 + 3 + len(jax.tree.leaves(params))
+    for got, want in zip(leaves, jax.tree.leaves(parent)):
+        assert np.isfinite(got).all() and np.array_equal(got, want)
+
+
+# (published index of the first layer, layers) -> the layers whose attention
+# residuals are kept, the expert layers whose routing and plan are.
+KEPT_BY_CUT = {
+    "the whole cut": (0, 5, 5, 4),
+    "the period: three sliding layers and a full one, each + experts": (1, 4, 4, 4),
+    "sliding attention + experts": (1, 1, 1, 1),
+    "full attention + dense FFN": (0, 1, 1, 0),
+}
+
+
+@pytest.mark.parametrize("cut", sorted(KEPT_BY_CUT))
+def test_step_build_counts_the_layers_whose_residuals_are_kept(monkeypatch, cut):
+    """Facts of the traced step, recorded when it is built (nothing is
+    compiled here): ``routing_kept`` 0 for a cut without expert layers."""
+    from ray_shuffling_data_loader_tpu import telemetry
+    from ray_shuffling_data_loader_tpu.parallel import make_train_step
+
+    first, count, attention, routing = KEPT_BY_CUT[cut]
+    model = LagunaLM(
+        _model_config(toy_config(first_layer=first, num_hidden_layers=count))
+    )
+    with _tracing(monkeypatch):
+        mesh = make_mesh(devices=jax.devices()[:1])
+        make_train_step(model, optax.adam(1e-5), mesh, None)
+        spans = telemetry.local_spans()
+    (build,) = [s["args"] for s in spans if s["name"] == "step:build"]
+    assert build["layers"] == count and build["attention_kept"] == attention
+    assert build["routing_kept"] == routing
 
 
 def test_the_family_s_tree_carries_every_leaf_there_and_back(family):

@@ -7,6 +7,7 @@ import collections
 import contextlib
 import json
 import os
+import re
 import sys
 
 import jax
@@ -26,6 +27,10 @@ from ray_shuffling_data_loader_tpu.models.lfm2_moe import (  # noqa: E402
     Lfm2MoeLM,
 )
 from ray_shuffling_data_loader_tpu.ops import flash_attention, moe  # noqa: E402
+from ray_shuffling_data_loader_tpu.ops.flash_attention import (  # noqa: E402
+    ATTENTION_OUT,
+    ATTENTION_STATS,
+)
 from ray_shuffling_data_loader_tpu.ops.short_conv import (  # noqa: E402
     causal_depthwise_conv1d,
 )
@@ -433,6 +438,120 @@ def test_a_chip_that_holds_half_the_experts_or_more_runs_the_parent_s_layer(rout
     }
 
 
+# -- the routing and the plan under recomputation (ISSUE 33) -------------------------
+
+
+def _routed_layer(bias):
+    """``route`` + ``experts_ffn`` on the XLA path, a function of what is
+    differentiated: ``-> (scalar, (y, load, dropped, fallback))``."""
+
+    def layer(x, gate, w1, w3, w2):
+        experts, weights = moe.route(x, gate, bias, TOP_K)
+        y, *counts = moe.experts_ffn(
+            x, experts, weights, w1, w3, w2, 0, ROUTED, tile=TILE, use_pallas=False
+        )
+        return _weighed(y), (y, *counts)
+
+    return layer
+
+
+def _routed_inputs():
+    x, _, w1, w3, w2 = _expert_inputs()
+    gate = jax.random.normal(jax.random.key(6), (HIDDEN, ROUTED))
+    return x, gate, w1, w3, w2
+
+
+# What the recomputation keeps -> plans built (one stable sort each) in the
+# compiled gradient.
+RECOMPUTED = {
+    "the routing named": (
+        jax.checkpoint_policies.save_only_these_names(moe.ROUTING), 1
+    ),
+    "the model's own": (lfm2_moe.KEPT, 1),
+    "nothing kept": (None, 2),
+    "the matmuls' outputs alone": (REMAT["policy"], 2),
+}
+# A selection bias towards the four experts held sends every token to them:
+# the load outgrows the bounded buffer and the layer falls back.
+ROUTERS = {
+    "fits": (None, 0),
+    "falls back": (jnp.where(jnp.arange(ROUTED) < HELD, 10.0, 0.0), 1),
+}
+
+
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+@pytest.mark.parametrize("kept", sorted(RECOMPUTED))
+def test_a_recomputed_layer_builds_its_plan_once_where_the_routing_is_kept(
+    kept, router
+):
+    """The backward pass of a recomputed layer reads the experts chosen and
+    the plan. Under a policy that lists ``moe.ROUTING`` they stay from the
+    forward pass and the second build is dead code; under any other the
+    compiled gradient sorts twice and scatters eight times for six. Counted
+    in the optimised HLO: the jaxpr keeps the dead equations in ``remat2``
+    until compilation. The numbers are the same either way ..."""
+    policy, builds = RECOMPUTED[kept]
+    bias, falls_back = ROUTERS[router]
+    inputs = _routed_inputs()
+
+    def gradient(layer):
+        return jax.jit(
+            jax.value_and_grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True)
+        )
+
+    layer = _routed_layer(bias)
+    compiled = gradient(jax.checkpoint(layer, policy=policy)).lower(*inputs).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" sort\(", text)) == builds
+    assert len(re.findall(r" scatter\(", text)) == 4 + 2 * builds
+    (_, (y, load, dropped, fallback)), grads = got = compiled(*inputs)
+    assert int(fallback) == falls_back and int(dropped) == 0
+    assert 0 < int(load.sum()) <= ASSIGNMENTS
+    assert (int(load.sum()) == ASSIGNMENTS) == bool(falls_back)
+    # ... as without any recomputation.
+    want = gradient(layer)(*inputs)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.array_equal(a, b)
+    assert all(float(jnp.abs(g).max()) > 0 for g in grads)
+
+
+@pytest.mark.parametrize("load", ["fits", "one tile over"])
+def test_without_a_policy_the_names_are_identities(monkeypatch, load):
+    """A bare caller (no recomputation, no policy): value, ``load``,
+    ``dropped``, ``fallback`` and every gradient of ``experts_ffn``, and
+    ``route``'s two results, are bit for bit those of the module with
+    ``checkpoint_name`` patched to the identity."""
+    loads, _, falls_back = LOADS[load]
+    experts = _choices(loads)
+    inputs = _expert_inputs()
+    x, gate, *_ = _routed_inputs()
+
+    def readings():
+        layer = _layer(experts, ROUTED, _kernel_options("ragged_dot"))
+        out = jax.jit(jax.value_and_grad(
+            layer, argnums=(0, 1, 2, 3, 4), has_aux=True
+        ))(*inputs)
+        return out, jax.jit(lambda x, gate: moe.route(x, gate, None, TOP_K))(x, gate)
+
+    named = readings()
+    assert any(
+        eqn.primitive.name == "name" and eqn.params["name"] == moe.ROUTING
+        for eqn, _ in _equations(
+            jax.make_jaxpr(lambda *a: moe.plan_dispatch(*a, 0, HELD, TILE))(
+                experts, inputs[1]
+            ).jaxpr
+        )
+    )
+    monkeypatch.setattr(moe, "checkpoint_name", lambda value, name: value)
+    jax.clear_caches()
+    bare = readings()
+    assert int(named[0][0][1][3]) == falls_back
+    leaves = jax.tree.leaves(named)
+    assert len(leaves) == 1 + 4 + 5 + 2
+    for got, want in zip(leaves, jax.tree.leaves(bare)):
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+
+
 def test_the_router_chooses_by_the_bias_and_weighs_without_it():
     x = jnp.eye(4)
     gate = jnp.array([[2.0, 1.0, 0.0, -1.0]] * 4)
@@ -561,6 +680,17 @@ def _tracing(monkeypatch):
         trace.reset_state()
 
 
+def _biased_towards_the_held(params):
+    """``params`` with every expert layer's selection bias at 10 for the four
+    experts held and 0 for the twelve others: every token chooses the held.
+    (A buffer of its own a layer: a step donates its state.)"""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: jnp.where(jnp.arange(16) < 4, 10.0, 0.0)
+        if path[-1].key == "expert_bias" else leaf,
+        params,
+    )
+
+
 @pytest.mark.parametrize("router", ["even", "collapsed"])
 def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
     monkeypatch, router
@@ -584,12 +714,7 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
     with _tracing(monkeypatch):
         state, shardings = init_state(model, optimizer, mesh, batch)
         if router == "collapsed":
-            # (A buffer of its own a layer: the step donates its state.)
-            state = state._replace(params=jax.tree_util.tree_map_with_path(
-                lambda path, leaf: jnp.where(jnp.arange(16) < 4, 10.0, 0.0)
-                if path[-1].key == "expert_bias" else leaf,
-                state.params,
-            ))
+            state = state._replace(params=_biased_towards_the_held(state.params))
         step = make_train_step(model, optimizer, mesh, shardings)
         losses = []
         for _ in range(3):
@@ -603,8 +728,10 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
     build = [s for s in spans if s["name"] == "step:build"]
     assert build and build[-1]["args"]["model"] == "lfm2_moe"
     assert build[-1]["args"]["experts_held"] == 4 and build[-1]["args"]["layers"] == 5
-    # The cut's one attention layer keeps its kernel's residuals.
+    # The cut's one attention layer keeps its kernel's residuals, its four
+    # expert layers their routing and plan.
     assert build[-1]["args"]["attention_kept"] == 1
+    assert build[-1]["args"]["routing_kept"] == 4
     loads = [s["args"] for s in spans if s["name"] == "moe:load"]
     assert len(loads) == 3 and all(a["dropped"] == 0 for a in loads)
     # 4 sequences x 64 tokens x 4 choices, a quarter of the experts held:
@@ -693,18 +820,67 @@ def test_what_is_kept_changes_no_number(monkeypatch):
         assert np.isfinite(got).all() and np.array_equal(got, want)
 
 
-@pytest.mark.parametrize(
-    "cut,kept", [("the whole cut", 1), ("attention + experts", 1), ("conv + experts", 0)]
+# ``KEPT`` as it stood before ISSUE 33: the matmuls' outputs and the attention
+# kernel's residuals, the routing and the plan built again.
+KEPT_BEFORE_ROUTING = jax.checkpoint_policies.save_from_both_policies(
+    REMAT["policy"],
+    jax.checkpoint_policies.save_only_these_names(ATTENTION_OUT, ATTENTION_STATS),
 )
+
+
+@pytest.mark.parametrize("router", ["even", "collapsed"])
+def test_keeping_the_routing_and_the_plan_changes_no_number(monkeypatch, router):
+    """Loss, counters and every gradient leaf under ``KEPT`` are, bit for
+    bit, those under the parent's ``KEPT`` (which builds every plan twice):
+    under the router as initialised, and under one whose selection bias
+    sends every token to the experts held, so that every expert layer runs
+    in the worst-case buffer."""
+    model, params, batch = _kernel_model()
+    if router == "collapsed":
+        params = _biased_towards_the_held(params)
+
+    def readings():
+        # (Jitted anew each time: the policy is read when the model is traced.)
+        return jax.jit(
+            jax.value_and_grad(lambda p: model.apply(p, batch), has_aux=True)
+        )(params)
+
+    assert lfm2_moe.KEPT is not KEPT_BEFORE_ROUTING
+    kept = readings()
+    monkeypatch.setattr(lfm2_moe, "KEPT", KEPT_BEFORE_ROUTING)
+    jax.clear_caches()
+    parent = readings()
+    (_, counters), _ = kept
+    assert counters["moe_fallback"].tolist() == [int(router == "collapsed")] * 4
+    assert counters["moe_dropped"].tolist() == [0] * 4
+    leaves = jax.tree.leaves(kept)
+    assert len(leaves) == 1 + 3 + len(jax.tree.leaves(params))
+    for got, want in zip(leaves, jax.tree.leaves(parent)):
+        assert np.isfinite(got).all() and np.array_equal(got, want)
+
+
+# (published index of the first layer, layers) -> the attention layers whose
+# kernel's residuals are kept, the expert layers whose routing and plan are.
+KEPT_BY_CUT = {
+    "the whole cut": (1, 5, 1, 4),
+    "the period: attention, conv, conv, conv, each + experts": (2, 4, 1, 4),
+    "attention + experts": (2, 1, 1, 1),
+    "conv + experts": (3, 1, 0, 1),
+    "conv + dense FFN": (1, 1, 0, 0),
+}
+
+
+@pytest.mark.parametrize("cut", sorted(KEPT_BY_CUT))
 def test_step_build_counts_the_attention_layers_whose_residuals_are_kept(
-    monkeypatch, cut, kept
+    monkeypatch, cut
 ):
-    """A fact of the traced step, recorded when it is built (nothing is
-    compiled here): 0 for a cut without attention."""
+    """Facts of the traced step, recorded when it is built (nothing is
+    compiled here): ``attention_kept`` 0 for a cut without attention,
+    ``routing_kept`` 0 for one without expert layers."""
     from ray_shuffling_data_loader_tpu import telemetry
     from ray_shuffling_data_loader_tpu.parallel import make_train_step
 
-    first, count = CUTS[cut]
+    first, count, attention, routing = KEPT_BY_CUT[cut]
     cfg = toy_config(first_layer=first, num_hidden_layers=count)
     model = Lfm2MoeLM(
         Lfm2MoeConfig.from_dict(harness.load_family(cfg).program.model_config(cfg))
@@ -714,7 +890,8 @@ def test_step_build_counts_the_attention_layers_whose_residuals_are_kept(
         make_train_step(model, optax.adam(1e-3), mesh, None)
         spans = telemetry.local_spans()
     (build,) = [s["args"] for s in spans if s["name"] == "step:build"]
-    assert build["attention_kept"] == kept and build["layers"] == count
+    assert build["attention_kept"] == attention and build["layers"] == count
+    assert build["routing_kept"] == routing
 
 
 def test_the_family_s_tree_carries_every_leaf_there_and_back(family):
