@@ -1,8 +1,8 @@
-"""What the sparse sequence models share (``lfm2_moe.py``, ``laguna.py``):
-the parts of a pre-norm residual layer, the language model around a stack
-of recomputed layers, and its loss.
+"""What the sequence models share (``lfm2_moe.py``, ``laguna.py``,
+``phi4flash.py``): the parts of a pre-norm residual layer, the language
+model around a stack of recomputed layers, and its loss.
 
-* :class:`RMSNorm`; :class:`Rope` and :func:`rotary` (the half-split
+* :class:`RMSNorm`, :class:`LayerNorm`; :class:`Rope` and :func:`rotary` (the half-split
   convention, plain or YaRN frequencies, on all or on the first dimensions
   of a head);
 * :class:`Attention`: grouped-query causal attention through
@@ -10,9 +10,11 @@ of recomputed layers, and its loss.
 * :class:`DenseFFN` (the gated three-matrix form) and :class:`ExpertFFN`
   (the experts ONE chip holds of a routed layer, ``ops/moe.py``);
 * :class:`SequenceLM`: embedding, the layers (each recomputed in the
-  backward pass but for what its model's policy keeps), final norm, the
-  head over the vocabulary rows held, :func:`next_token_loss`, and the
-  step's counters of the expert layers' load.
+  backward pass but for what its model's policy keeps; tensors that a layer
+  hands on to later ones pass from layer to layer beside the stream), final
+  norm, the head over the vocabulary rows held (a matrix of its own, or the
+  embedding's where the model ties them), :func:`next_token_loss`, and,
+  where the model has expert layers, the step's counters of their load.
 
 Parameters are float32, compute is ``dtype`` (bfloat16 on the chip).
 """
@@ -46,6 +48,24 @@ class RMSNorm(nn.Module):
         x32 = x.astype(jnp.float32)
         inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (x32 * inv * scale).astype(self.dtype)
+
+
+class LayerNorm(nn.Module):
+    """Mean and variance over the last axis, a learned scale and bias."""
+
+    eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        inv = jax.lax.rsqrt(
+            jnp.mean(centred * centred, axis=-1, keepdims=True) + self.eps
+        )
+        return (centred * inv * scale + bias).astype(self.dtype)
 
 
 # -- rotary positions --------------------------------------------------------------
@@ -265,10 +285,11 @@ def moe_load_counts(load, dropped, fallback) -> dict:
     that ran in the worst-case buffer: the fullest expert, the mean, the
     assignments dropped (the layer is built to drop none; this is the
     count that says so), the expert layers, and those of them whose load
-    outgrew the bounded buffer."""
+    outgrew the bounded buffer. A step without an expert layer (a cut that
+    keeps none) counts zeros."""
     return {
-        "max": int(load.max()),
-        "mean": float(load.mean()),
+        "max": int(load.max()) if load.size else 0,
+        "mean": float(load.mean()) if load.size else 0.0,
         "dropped": int(dropped.sum()),
         "layers": int(load.shape[0]),
         "fallback": int(fallback.sum()),
@@ -284,12 +305,15 @@ class SequenceLM(nn.Module):
     ``{"moe_load": [expert layers, experts_held], "moe_dropped": [expert
     layers], "moe_fallback": [expert layers]}``: the tokens routed to each
     held expert, the assignments left out, and 1 where the layer ran in the
-    worst-case buffer. ``logits=True`` returns the logits instead (float32
-    ``[batch, seq, vocab]``: a test's size only).
+    worst-case buffer; ``{}`` from a model that holds no experts
+    (``cfg.experts_held`` 0). ``logits=True`` returns the logits instead
+    (float32 ``[batch, seq, vocab]``: a test's size only).
 
     A model gives ``cfg`` (``vocab_size``, ``hidden_size``, ``norm_eps``,
     ``experts_held``, ``layers()``: what each layer kept is, its published
-    index first), :meth:`recomputed_layer` and ``build_facts``.
+    index first; ``tie_word_embeddings`` where the head is the embedding's
+    own matrix), :meth:`recomputed_layer`, ``build_facts`` and, where its
+    final norm is not RMS, :meth:`final_norm`.
     ``use_pallas`` / ``interpret`` go to the attention and expert kernels
     (None: the kernels on a TPU backend)."""
 
@@ -303,21 +327,35 @@ class SequenceLM(nn.Module):
 
     # How ``parallel/train.py`` drives a model that brings its own loss:
     # one step input (the features, no labels), and the step's counters
-    # beside the loss: ``{span name: (metrics keys, what the span carries
-    # of their values)}``.
+    # beside the loss.
     batch_inputs = 1
-    step_counters = {
-        "moe:load": (
-            ("moe_load", "moe_dropped", "moe_fallback"), moe_load_counts
-        )
-    }
+
+    @property
+    def step_counters(self) -> dict:
+        """``{span name: (metrics keys, what the span carries of their
+        values)}``: the expert layers' load; nothing from a model that
+        holds no experts, whose step returns no such metrics."""
+        if not self.cfg.experts_held:
+            return {}
+        return {
+            "moe:load": (
+                ("moe_load", "moe_dropped", "moe_fallback"), moe_load_counts
+            )
+        }
 
     def recomputed_layer(self, *of_layer) -> nn.Module:
         """The layer that one entry of ``cfg.layers()`` describes, under
         ``nn.remat`` with the model's policy of what is kept. It maps ``x``
         to ``(x, counts)``: an expert layer's ``{"load", "dropped",
-        "fallback"}``, a dense layer's ``{}``."""
+        "fallback"}``, a dense layer's ``{}``. A model whose layers hand
+        tensors on to later layers maps ``(x, *handed)`` to ``(x, counts,
+        *handed)``: what a layer returns after its counts is what the next
+        one is called with, and being a recomputed layer's input it is
+        kept for the backward pass, not computed again."""
         raise NotImplementedError
+
+    def final_norm(self, dtype) -> nn.Module:
+        return RMSNorm(self.cfg.norm_eps, dtype, name="final_norm")
 
     def loss_fn(self, params, features):
         """``(loss, counters)`` of one batch of features."""
@@ -332,24 +370,28 @@ class SequenceLM(nn.Module):
             "embed", fan_in((cfg.vocab_size, cfg.hidden_size), -1),
             (cfg.vocab_size, cfg.hidden_size),
         )
-        head = self.param(
-            "head", fan_in((cfg.hidden_size, cfg.vocab_size)),
-            (cfg.hidden_size, cfg.vocab_size),
-        )
+        if getattr(cfg, "tie_word_embeddings", False):
+            # One matrix: its gradient is the embedding's plus the head's.
+            head = embed.T
+        else:
+            head = self.param(
+                "head", fan_in((cfg.hidden_size, cfg.vocab_size)),
+                (cfg.hidden_size, cfg.vocab_size),
+            )
         with jax.named_scope("embed"):
             x = jnp.take(embed, tokens, axis=0).astype(dt)
-        counts = []
+        counts, handed = [], ()
         for of_layer in cfg.layers():
-            x, layer_counts = self.recomputed_layer(*of_layer)(x)
+            x, layer_counts, *handed = self.recomputed_layer(*of_layer)(x, *handed)
             if layer_counts:
                 counts.append(layer_counts)
-        x = RMSNorm(cfg.norm_eps, dt, name="final_norm")(x)
+        x = self.final_norm(dt)(x)
         none = {"load": (0, cfg.experts_held), "dropped": (0,), "fallback": (0,)}
         counters = {
             f"moe_{name}": jnp.stack([c[name] for c in counts])
             if counts else jnp.zeros(shape, jnp.int32)
             for name, shape in none.items()
-        }
+        } if cfg.experts_held else {}
         with jax.named_scope("head"):
             head = head.astype(dt)
             if logits:

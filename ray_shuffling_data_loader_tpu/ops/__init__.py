@@ -13,6 +13,10 @@ from ray_shuffling_data_loader_tpu.ops.embedding import (  # noqa: F401
 from ray_shuffling_data_loader_tpu.ops.flash_attention import (  # noqa: F401
     flash_attention,
 )
+from ray_shuffling_data_loader_tpu.ops.selective_scan import (  # noqa: F401
+    selective_scan,
+    selective_scan_reference,
+)
 from ray_shuffling_data_loader_tpu.ops.ring_attention import (  # noqa: F401
     attention_reference,
     blockwise_attention,
@@ -34,4 +38,6 @@ __all__ = [
     "make_ring_attention",
     "make_ulysses_attention",
     "ring_attention",
+    "selective_scan",
+    "selective_scan_reference",
 ]

@@ -12,6 +12,10 @@ normalizer).
 
 Scope: attention over ``[batch, seq, heads, head_dim]`` (split over batch
 and heads on multi-device meshes with ``shard_map``, see :mod:`.placement`).
+The values may be wider (or narrower) than the queries and keys: ``v [..,
+value_dim]`` gives an output of that width (a differential head multiplies
+maps of 64-dimensional heads into values of 128), plain and windowed alike;
+with ``value_dim == head_dim`` the traced kernels are what they were.
 Grouped-query attention: ``k`` and ``v`` may carry fewer heads than ``q``
 (``heads % kv_heads == 0``); a query head reads its group's key/value
 blocks through the block index map, so the repeat never exists in HBM,
@@ -285,6 +289,7 @@ def _flash_forward(
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
+    dv = v.shape[-1]  # the values' width, and the output's
     kv_of = _kv_head_map(h, k.shape[2])
     scale = 1.0 / math.sqrt(d)
     bq = min(block_q, t)
@@ -316,23 +321,23 @@ def _flash_forward(
                 (1, bk, d), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
             ),
             pl.BlockSpec(
-                (1, bk, d), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
+                (1, bk, dv), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
             ),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, tq_pad, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, tq_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((b * h, tq_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),  # running max
             pltpu.VMEM((bq, 128), jnp.float32),  # normalizer
-            pltpu.VMEM((bq, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -342,7 +347,7 @@ def _flash_forward(
         # function that holds it.
         name=_kernel_name(window, "fwd"),
     )(qb, kb, vb)
-    out = out[:, :t].reshape(b, h, t, d)
+    out = out[:, :t].reshape(b, h, t, dv)
     out = jnp.transpose(out, (0, 2, 1, 3))
     if not return_stats:
         return out
@@ -554,6 +559,7 @@ def _flash_backward_pallas(
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     hk = k.shape[2]
     group = h // hk
     kv_of = _kv_head_map(h, hk)
@@ -611,10 +617,14 @@ def _flash_backward_pallas(
         """The query row of this kv head's group that ``inner`` is at."""
         return (bkv // hk) * h + (bkv % hk) * group + inner // q_steps
 
-    q_spec = pl.BlockSpec(
-        (1, bq, d), lambda bkv, j, i: (q_of(bkv, i), q_block(j, i), 0)
-    )
-    kv_spec = pl.BlockSpec((1, bk, d), lambda bkv, j, i: (bkv, j, 0))
+    def q_rows(width):  # q [.., d] and dO [.., dv], a query head's block
+        return pl.BlockSpec(
+            (1, bq, width), lambda bkv, j, i: (q_of(bkv, i), q_block(j, i), 0)
+        )
+
+    def kv_rows(width):  # k, dk [.., d] and v, dv [.., dv]
+        return pl.BlockSpec((1, bk, width), lambda bkv, j, i: (bkv, j, 0))
+
     row_spec = pl.BlockSpec(
         (1, bq, 1), lambda bkv, j, i: (q_of(bkv, i), q_block(j, i), 0)
     )
@@ -630,16 +640,16 @@ def _flash_backward_pallas(
             **dkv_band,
         ),
         grid=(b * hk, tk_pad // bk, group * q_steps),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
-                  row_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_rows(d), kv_rows(d), kv_rows(dv), q_rows(dv), row_spec,
+                  row_spec, row_spec],
+        out_specs=[kv_rows(d), kv_rows(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((b * hk, tk_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hk, tk_pad, d), v.dtype),
+            jax.ShapeDtypeStruct((b * hk, tk_pad, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -650,10 +660,14 @@ def _flash_backward_pallas(
     dkb, dvb = dkv
 
     k_steps, key_block, dq_band = _key_steps(nq, tk_pad // bk, bq, bk, window)
-    q_spec2 = pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0))
-    kv_spec2 = pl.BlockSpec(
-        (1, bk, d), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
-    )
+    def q_rows2(width):
+        return pl.BlockSpec((1, bq, width), lambda bh, i, j: (bh, i, 0))
+
+    def kv_rows2(width):
+        return pl.BlockSpec(
+            (1, bk, width), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
+        )
+
     row_spec2 = pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0))
     dqb = pl.pallas_call(
         functools.partial(
@@ -666,8 +680,8 @@ def _flash_backward_pallas(
             **dq_band,
         ),
         grid=(b * h, tq_pad // bq, k_steps),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2,
-                  row_spec2, row_spec2],
+        in_specs=[q_rows2(d), kv_rows2(d), kv_rows2(dv), q_rows2(dv),
+                  row_spec2, row_spec2, row_spec2],
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, tq_pad, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -679,7 +693,7 @@ def _flash_backward_pallas(
     )(qb, kb, vb, dob, mb, lb, db)
 
     def from_bh(x):
-        x = x[:, :t].reshape(b, -1, t, d)
+        x = x[:, :t].reshape(b, -1, t, x.shape[-1])
         return jnp.transpose(x, (0, 2, 1, 3))
 
     return from_bh(dqb), from_bh(dkb), from_bh(dvb)
@@ -801,8 +815,9 @@ def flash_attention(
     interpret: bool = False,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Fused attention over ``q [batch, seq, heads, head_dim]`` and ``k``,
-    ``v`` ``[batch, seq, kv_heads, head_dim]``; ``kv_heads`` divides
+    """Fused attention over ``q [batch, seq, heads, head_dim]``, ``k
+    [batch, seq, kv_heads, head_dim]`` and ``v [batch, seq, kv_heads,
+    value_dim]``, to ``[batch, seq, heads, value_dim]``; ``kv_heads`` divides
     ``heads`` (grouped-query attention: query head ``i`` reads key/value
     head ``i // (heads // kv_heads)``).
 
