@@ -23,14 +23,17 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ray_shuffling_data_loader_tpu.ops import moe
-from ray_shuffling_data_loader_tpu.ops.flash_attention import flash_attention
+from ray_shuffling_data_loader_tpu.ops.flash_attention import (
+    flash_attention,
+    grid_steps,
+)
 
 
 def fan_in(shape, fan_in_axis=-2):
@@ -312,8 +315,9 @@ class SequenceLM(nn.Module):
     A model gives ``cfg`` (``vocab_size``, ``hidden_size``, ``norm_eps``,
     ``experts_held``, ``layers()``: what each layer kept is, its published
     index first; ``tie_word_embeddings`` where the head is the embedding's
-    own matrix), :meth:`recomputed_layer`, ``build_facts`` and, where its
-    final norm is not RMS, :meth:`final_norm`.
+    own matrix), :meth:`recomputed_layer`, ``build_facts``,
+    :meth:`attention_calls` and, where its final norm is not RMS,
+    :meth:`final_norm`.
     ``use_pallas`` / ``interpret`` go to the attention and expert kernels
     (None: the kernels on a TPU backend)."""
 
@@ -356,6 +360,24 @@ class SequenceLM(nn.Module):
 
     def final_norm(self, dtype) -> nn.Module:
         return RMSNorm(self.cfg.norm_eps, dtype, name="final_norm")
+
+    def attention_calls(self) -> Sequence[Tuple[int, Optional[int]]]:
+        """``(query heads, window)`` of the attention kernels' call in
+        each attention layer kept."""
+        raise NotImplementedError
+
+    def traced_facts(self, features) -> dict:
+        """What ``step:build`` can say only of a batch's shape (so when the
+        step is traced): the grid steps, and the blocks with work among
+        them, of one forward call of every attention layer kept, summed
+        (the kernels' grid, also where the XLA path runs in their place)."""
+        batch, seq = features["tokens"].shape
+        steps = blocks = 0
+        for heads, window in self.attention_calls():
+            of_head = grid_steps(seq, self.block_q, self.block_k, window=window)
+            steps += batch * heads * of_head[0]
+            blocks += batch * heads * of_head[1]
+        return {"attention_grid_steps": steps, "attention_blocks": blocks}
 
     def loss_fn(self, params, features):
         """``(loss, counters)`` of one batch of features."""

@@ -241,6 +241,13 @@ class LagunaLM(SequenceLM):
             "routing_kept": sum(not dense for *_, dense in cfg.layers()),
         }
 
+    def attention_calls(self):
+        window = self.cfg.sliding_window
+        return [
+            (heads, window if kind == SLIDING else None)
+            for _, kind, heads, _ in self.cfg.layers()
+        ]
+
     def recomputed_layer(self, index, kind, heads, dense) -> nn.Module:
         return nn.remat(Layer, policy=KEPT)(
             self.cfg, kind, heads, dense, self.compute_dtype, self.use_pallas,

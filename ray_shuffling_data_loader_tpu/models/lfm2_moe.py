@@ -232,6 +232,13 @@ class Lfm2MoeLM(SequenceLM):
             "routing_kept": sum(not dense for *_, dense in layers),
         }
 
+    def attention_calls(self):
+        heads = self.cfg.num_attention_heads
+        return [
+            (heads, None)
+            for _, kind, _ in self.cfg.layers() if kind == "full_attention"
+        ]
+
     def recomputed_layer(self, index, kind, dense) -> nn.Module:
         return nn.remat(Layer, policy=KEPT)(
             self.cfg, kind, dense, self.compute_dtype, self.use_pallas,

@@ -391,6 +391,13 @@ class Phi4FlashLM(SequenceLM):
             "memory_kept": int((cfg.memory_layer, MAMBA) in cfg.layers()),
         }
 
+    def attention_calls(self):
+        cfg = self.cfg
+        return [
+            (cfg.num_attention_heads, cfg.sliding_window if kind == WINDOW else None)
+            for _, kind in cfg.layers() if kind in (WINDOW, FULL, CROSS)
+        ]
+
     def final_norm(self, dtype) -> nn.Module:
         return LayerNorm(self.cfg.norm_eps, dtype, name="final_norm")
 

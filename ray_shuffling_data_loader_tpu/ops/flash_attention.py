@@ -27,15 +27,27 @@ interaction kernel (``ops/interaction.py``): Pallas on TPU backends, the
 XLA reference on backends Mosaic cannot target, interpret mode by
 explicit argument in CPU tests.
 
-Sliding window: with ``window`` a causal query at position ``i`` sees the
-keys ``i - window < j <= i``. The three kernels then run on a grid of the
-band alone: a query block's inner steps are the key blocks that intersect
-its band (:func:`_key_blocks`; a key block's, for dK/dV, the query blocks
-that intersect its own, :func:`_query_blocks`), the blocks outside are
-neither fetched nor computed, and the mask is applied only in the blocks
-that the band's two edges cut. Those Pallas calls are named
-``flash_attention_window_*``. Without a window nothing here differs from
-the plain kernels.
+The grid: below its first axis (batch · heads) a kernel steps through the
+(query block, key block) pairs that hold work and through no other.
+:func:`_blocks_with_work` says in which pairs the mask admits a score
+(every pair without ``causal``; on or under the diagonal with it; with
+``window``, a causal query at position ``i`` seeing the keys ``i - window <
+j <= i``, those the band touches), and :class:`_Steps` lays them out in the
+order a kernel accumulates in: all of a query block's key blocks in a row
+for the forward and dQ, for dK/dV a key block's query blocks, a query head
+of the group after the other. Where some pairs hold none, the steps are
+tables handed to the kernel as scalar-prefetch operands, which the block
+index maps and the body read, so that no step fetches or computes a block
+without work: a causal head of ``n`` blocks a side takes ``n (n + 1) / 2``
+steps, not ``n * n``. Tables too long for the scalar memory
+(:data:`MAX_TABLE_ENTRIES`) hold runs of up to 2, 4, .. blocks an entry
+instead, whose last may fall short: those steps stay on the run's last
+block and compute nothing. Where every pair holds work (no ``causal``, or
+one block) the grid stays the rectangle of the blocks, with no table: an
+index map that reads one costs a step about 0.02 µs an operand. The mask is
+applied in every block of a plain call and, with a window, only in the
+blocks that the band's two edges cut; the windowed Pallas calls are named
+``flash_attention_window_*``.
 
 Differentiability: the kernel carries an exact, memory-safe custom VJP.
 The forward emits its softmax row statistics (m, l) as outputs; the
@@ -73,43 +85,163 @@ from ray_shuffling_data_loader_tpu.ops.ring_attention import (
 )
 
 
-def _key_blocks(qi, block_q, block_k, window, k_blocks, xp=jnp):
-    """``(first, last)`` key block that the band of query block ``qi``
-    touches: the keys ``q - window < k <= q`` of its queries."""
-    first = xp.maximum(qi * block_q - (window - 1), 0) // block_k
-    last = xp.minimum(((qi + 1) * block_q - 1) // block_k, k_blocks - 1)
-    return first, last
+# The longest table of steps a kernel is handed: four int32 tables of this
+# length are half of a v5e's 1 MiB of scalar memory (its compiler takes four
+# of 32,896 entries and refuses four of 65,792). 8,192 tokens in blocks of 512
+# are 136 entries a head, 1,088 in dK/dV with eight query heads to a key head.
+MAX_TABLE_ENTRIES = 32768
 
 
-def _query_blocks(ki, block_q, block_k, window, q_blocks, xp=jnp):
-    """``(first, last)`` query block whose band touches key block ``ki``:
-    the queries ``k <= q < k + window`` of its keys."""
-    first = (ki * block_k) // block_q
-    last = xp.minimum(
-        ((ki + 1) * block_k + window - 2) // block_q, q_blocks - 1
+def _blocks_with_work(nq, nk, block_q, block_k, causal, window) -> np.ndarray:
+    """``[nq, nk]``: whether the mask admits a score of query block ``qi``
+    against key block ``ki`` (``q - window < k <= q`` for some query and
+    key of theirs)."""
+    q0 = np.arange(nq)[:, None] * block_q
+    k0 = np.arange(nk)[None, :] * block_k
+    work = np.ones((nq, nk), bool)
+    if causal:
+        work &= q0 + block_q - 1 >= k0
+    if window is not None:
+        work &= q0 - (k0 + block_k - 1) < window
+    return work
+
+
+class _Steps:
+    """A kernel's grid below its first axis (batch · heads): ``work[o, i]``
+    says whether inner block ``i`` holds work against outer block ``o``,
+    whose accumulator the kernel revisits; it does so once for each member
+    of the ``group``. The steps go outer block by outer block, within one
+    member by member, within a member through its blocks with work in
+    rising order (a band: one run a row).
+
+    Where every block holds work the grid is the rectangle ``(outer blocks,
+    group · inner blocks)`` and there is no table. Otherwise it is one axis
+    of ``length`` steps laid out in four int32 ``tables``, which the index
+    maps and the kernel read: an entry is a run of ``count <= width`` inner
+    blocks from ``start`` on, against ``outer`` for ``member``, and takes
+    ``width`` grid steps; ``width`` is 1 (a step a block with work)
+    wherever the tables then fit ``MAX_TABLE_ENTRIES``."""
+
+    def __init__(self, work: np.ndarray, group: int = 1):
+        self.group = group
+        self.inner = work.shape[1]
+        self.tables = ()
+        self.width = 1
+        if work.all():
+            self.grid = (work.shape[0], group * self.inner)
+            self.semantics = ("parallel", "arbitrary")
+            return
+        first, count = work.argmax(axis=1), work.sum(axis=1)
+        # A run a row and member is the shortest a table gets.
+        while (
+            self.width < count.max()
+            and group * (-(-count // self.width)).sum() > MAX_TABLE_ENTRIES
+        ):
+            self.width *= 2
+        entries = [
+            (o, g, s, min(self.width, first[o] + count[o] - s))
+            for o in range(len(work))
+            for g in range(group)
+            for s in range(first[o], first[o] + count[o], self.width)
+        ]
+        self.tables = tuple(np.asarray(entries, np.int32).T)
+        self.grid = (len(entries) * self.width,)
+        self.semantics = ("arbitrary",)
+
+    @property
+    def length(self) -> int:
+        """Grid steps a batch · head in all."""
+        return int(np.prod(self.grid))
+
+    def call(self, rows: int, **specs) -> dict:
+        """``pallas_call``'s ``grid_spec`` and ``compiler_params`` for
+        ``rows`` batch · heads: ``specs`` are the grid spec's own
+        (``in_specs``, ``out_specs``, ``scratch_shapes``)."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        return dict(
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(self.tables),
+                grid=(rows, *self.grid),
+                **specs,
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", *self.semantics),
+            ),
+        )
+
+    def here(self, *table_refs):
+        """Where a kernel is: its grid indices below the first and the
+        tables, as :meth:`blocks` and :meth:`edges` take them (an index map
+        is handed the same after its first argument)."""
+        from jax.experimental import pallas as pl
+
+        return *(pl.program_id(1 + a) for a in range(len(self.grid))), *table_refs
+
+    def _entry(self, step):
+        if self.width == 1:
+            return step, 0
+        return step // self.width, step % self.width
+
+    def blocks(self, *at):
+        """``(outer block, member, inner block)`` of a grid step. Past a
+        run's last block the step stays on it: nothing is fetched."""
+        if not self.tables:
+            o, j = at
+            return (o, 0, j) if self.group == 1 else (o, j // self.inner, j % self.inner)
+        step, outer, member, start, count = at
+        e, j = self._entry(step)
+        inner = start[e]
+        if self.width > 1:
+            inner = inner + jnp.minimum(j, count[e] - 1)
+        return outer[e], member[e], inner
+
+    def edges(self, *at):
+        """``(first, last, runs)``: whether a grid step is the first or the
+        last of its outer block, and whether it holds a block at all
+        (``True`` itself where every step does)."""
+        if not self.tables:
+            return at[1] == 0, at[1] == self.grid[1] - 1, True
+        step, outer, _, _, count = at
+        e, j = self._entry(step)
+        n, w = len(self.tables[0]), self.width
+        here = outer[e]
+        first = (j == 0) & ((e == 0) | (outer[jnp.maximum(e - 1, 0)] != here))
+        last = (j == w - 1) & (
+            (e == n - 1) | (outer[jnp.minimum(e + 1, n - 1)] != here)
+        )
+        return first, last, True if w == 1 else j < count[e]
+
+
+def grid_steps(seq_len, block_q, block_k, causal=True, window=None):
+    """``(grid steps, blocks with work)`` a head of one forward call: equal
+    unless the tables hold runs."""
+    bq, bk = min(block_q, seq_len), min(block_k, seq_len)
+    work = _blocks_with_work(
+        -(-seq_len // bq), -(-seq_len // bk), bq, bk, causal, window
     )
-    return first, last
+    return _Steps(work).length, int(work.sum())
 
 
-def _band_steps(blocks_of, blocks, *sizes) -> int:
-    """Inner grid steps of a windowed kernel: the most blocks that
-    ``blocks_of`` gives any of the ``blocks`` outer ones."""
-    first, last = blocks_of(np.arange(blocks), *sizes, xp=np)
-    return int((last - first + 1).max())
-
-
-def _on_band(run, qi, ki, block_q, block_k, window, update):
-    """``update(masked)`` where ``run``: unmasked in a block that lies whole
-    inside the band, masked in one that the diagonal or the window's far
-    edge cuts (a padded key lies past every real query, so the diagonal's
-    mask covers it)."""
+def _update_block(runs, qi, ki, block_q, block_k, window, update):
+    """``update(masked)`` in a step that holds a block (``runs``; ``True``
+    itself where every step does). A plain call masks every block; a
+    windowed one only a block that the diagonal or the window's far edge
+    cuts, not one that lies whole inside the band (a padded key lies past
+    every real query, so the diagonal's mask covers it)."""
     from jax.experimental import pallas as pl
 
+    if window is None:
+        if runs is True:
+            update()
+        else:
+            pl.when(runs)(update)
+        return
     inside = (ki * block_k + block_k - 1 <= qi * block_q) & (
         (qi + 1) * block_q - 1 - ki * block_k < window
     )
-    pl.when(run & inside)(functools.partial(update, False))
-    pl.when(run & jnp.logical_not(inside))(functools.partial(update, True))
+    pl.when(runs & inside)(functools.partial(update, False))
+    pl.when(runs & jnp.logical_not(inside))(functools.partial(update, True))
 
 
 def _kernel_name(window, which: str) -> str:
@@ -119,46 +251,35 @@ def _kernel_name(window, which: str) -> str:
 
 
 def _flash_kernel(
-    q_ref,
-    k_ref,
-    v_ref,
-    o_ref,
-    m_ref,
-    l_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
-    *,
+    *refs,
+    steps: _Steps,
     scale: float,
     causal: bool,
     block_q: int,
     block_k: int,
     seq_len: int,
     window: Optional[int] = None,
-    k_blocks: int = 0,
 ):
-    """One (batch·head, q-block, kv-block) grid cell.
+    """One grid cell: a query block against one of its key blocks with work
+    (``steps``: a query block's in a row). ``refs``: the steps' tables if
+    any, ``q, k, v``, the outputs ``o, m, l``, the scratch.
 
-    The kv dimension is the innermost grid axis; the output block is
-    revisited across it, carrying (running max, normalizer, accumulator)
-    in VMEM scratch. The softmax statistics (row max ``m`` and
-    normalizer ``l``) are emitted as outputs: the backward kernels and
-    the ring schedule's stats merge consume them. With ``window`` the
-    innermost axis steps through the query block's band of ``k_blocks``
-    key blocks, from its first.
+    The output block is revisited across a query block's steps, carrying
+    (running max, normalizer, accumulator) in VMEM scratch. The softmax
+    statistics (row max ``m`` and normalizer ``l``) are emitted as
+    outputs: the backward kernels and the ring schedule's stats merge
+    consume them.
     """
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    steps = pl.num_programs(2)
-    if window is None:
-        ki = step
-    else:
-        first, last = _key_blocks(qi, block_q, block_k, window, k_blocks)
-        ki = first + step
+    (
+        *tables, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr
+    ) = refs
+    at = steps.here(*tables)
+    qi, _, ki = steps.blocks(*at)
+    first, last, runs = steps.edges(*at)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr[...], NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr[...])
@@ -208,44 +329,15 @@ def _flash_kernel(
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if window is not None:
-        # The band's own steps; a query block near the start has fewer.
-        _on_band(ki <= last, qi, ki, block_q, block_k, window, _update)
-    elif causal:
-        # Skip fully-masked (strictly upper-right) blocks: the first
-        # valid kv block for q-block qi always exists at ki == 0, so the
-        # ki == 0 initialization above is never the skipped cell.
-        pl.when((qi + 1) * block_q > ki * block_k)(_update)
-    else:
-        _update()
+    _update_block(runs, qi, ki, block_q, block_k, window, _update)
 
-    @pl.when(step == steps - 1)
+    @pl.when(last)
     def _fin():
         o_ref[0] = (
             acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
         ).astype(o_ref.dtype)
         m_ref[0] = m_scr[:, :1]
         l_ref[0] = l_scr[:, :1]
-
-
-def _key_steps(nq, nk, bq, bk, window):
-    """The inner grid axis of the forward and dQ kernels: ``(steps,
-    key_block(i, j), kernel keywords)``. Every key block without a window;
-    with one, query block ``i``'s band, and past its last block that block
-    again, so that nothing is fetched for a step that does not run."""
-    if window is None:
-        return nk, (lambda i, j: j), {}
-    band = (bq, bk, window, nk)
-
-    def key_block(i, j):
-        first, last = _key_blocks(i, *band)
-        return jnp.minimum(first + j, last)
-
-    return (
-        _band_steps(_key_blocks, nq, *band),
-        key_block,
-        {"window": window, "k_blocks": nk},
-    )
 
 
 def _to_bh(x, t_pad):
@@ -270,6 +362,28 @@ def _kv_head_map(h: int, hk: int):
     return lambda bh: (bh // h) * hk + (bh % h) // group
 
 
+def _specs_by_query(steps: _Steps, bq: int, bk: int, kv_of):
+    """Block specs of the forward and dQ kernels, whose steps go query
+    block by query block: ``q_rows(width)`` for what a query head's rows
+    hold (q, out, dO, dq, the statistics), ``kv_rows(width)`` for k and v,
+    read at the group's head."""
+    from jax.experimental import pallas as pl
+
+    def q_rows(width):
+        return pl.BlockSpec(
+            (1, bq, width),
+            lambda bh, *at: (bh, steps.blocks(*at)[0], 0),
+        )
+
+    def kv_rows(width):
+        return pl.BlockSpec(
+            (1, bk, width),
+            lambda bh, *at: (kv_of(bh), steps.blocks(*at)[2], 0),
+        )
+
+    return q_rows, kv_rows
+
+
 def _flash_forward(
     q: jax.Array,
     k: jax.Array,
@@ -283,8 +397,7 @@ def _flash_forward(
 ):
     """Fused forward. With ``return_stats`` also returns the softmax row
     statistics ``(m, l)`` as float32 ``[b, h, t]`` — residuals for the
-    fused backward and merge inputs for the ring schedule. With ``window``
-    (causal only) the grid's inner axis is the band's key blocks."""
+    fused backward and merge inputs for the ring schedule."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -301,52 +414,41 @@ def _flash_forward(
     kb = _to_bh(k, tk_pad)
     vb = _to_bh(v, tk_pad)
 
-    nk = tk_pad // bk
-    steps, key_block, of_band = _key_steps(tq_pad // bq, nk, bq, bk, window)
-    kernel = functools.partial(
-        _flash_kernel,
-        scale=scale,
-        causal=causal,
-        block_q=bq,
-        block_k=bk,
-        seq_len=t,
-        **of_band,
+    steps = _Steps(
+        _blocks_with_work(tq_pad // bq, tk_pad // bk, bq, bk, causal, window)
     )
+    q_rows, kv_rows = _specs_by_query(steps, bq, bk, kv_of)
     out, m, l = pl.pallas_call(
-        kernel,
-        grid=(b * h, tq_pad // bq, steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec(
-                (1, bk, d), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
-            ),
-            pl.BlockSpec(
-                (1, bk, dv), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, dv), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
-        ],
+        functools.partial(
+            _flash_kernel,
+            steps=steps,
+            scale=scale,
+            causal=causal,
+            block_q=bq,
+            block_k=bk,
+            seq_len=t,
+            window=window,
+        ),
+        **steps.call(
+            b * h,
+            in_specs=[q_rows(d), kv_rows(d), kv_rows(dv)],
+            out_specs=[q_rows(dv), q_rows(1), q_rows(1)],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),  # running max
+                pltpu.VMEM((bq, 128), jnp.float32),  # normalizer
+                pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tq_pad, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, tq_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((b * h, tq_pad, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),  # running max
-            pltpu.VMEM((bq, 128), jnp.float32),  # normalizer
-            pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
         interpret=interpret,
         # The kernel's own name in the trace, whatever jit calls the
         # function that holds it.
         name=_kernel_name(window, "fwd"),
-    )(qb, kb, vb)
+    )(*steps.tables, qb, kb, vb)
     out = out[:, :t].reshape(b, h, t, dv)
     out = jnp.transpose(out, (0, 2, 1, 3))
     if not return_stats:
@@ -386,32 +488,20 @@ def _bwd_probs(q, k, m, l, ki, scale, causal, block_q, block_k, seq_len, qi,
 
 
 def _flash_bwd_dkv_kernel(
-    q_ref,
-    k_ref,
-    v_ref,
-    do_ref,
-    m_ref,
-    l_ref,
-    d_ref,
-    dk_ref,
-    dv_ref,
-    dk_scr,
-    dv_scr,
-    *,
+    *refs,
+    steps: _Steps,
     scale: float,
     causal: bool,
     block_q: int,
     block_k: int,
     seq_len: int,
-    q_blocks: int,
     window: Optional[int] = None,
-    band_steps: int = 0,
 ):
-    """dK/dV: grid (batch·kv-head, kv-block, group·q-block) with the
-    group's query heads and their q blocks innermost; the dk/dv
-    accumulators live in VMEM and are revisited across all of them. With
-    ``window`` a query head's inner steps are the ``band_steps`` query
-    blocks from the first whose band touches this key block.
+    """dK/dV: grid (batch·kv-head, ..), a key block against one query
+    block with work of one query head of its group (``steps``: a key
+    block's in a row, head after head); the dk/dv accumulators live in
+    VMEM and are revisited across all of them. ``refs``: the steps' tables
+    if any, ``q, k, v, dO, m, l, D``, the outputs ``dk, dv``, the scratch.
 
         p  = softmax block recomputed from (m, l)
         dv += pᵀ @ dO
@@ -420,15 +510,15 @@ def _flash_bwd_dkv_kernel(
     """
     from jax.experimental import pallas as pl
 
-    ki = pl.program_id(1)
-    inner = pl.program_id(2)
-    if window is None:
-        qi = inner % q_blocks
-    else:
-        first, last = _query_blocks(ki, block_q, block_k, window, q_blocks)
-        qi = first + inner % band_steps
+    (
+        *tables, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, d_ref, dk_ref, dv_ref,
+        dk_scr, dv_scr,
+    ) = refs
+    at = steps.here(*tables)
+    ki, _, qi = steps.blocks(*at)
+    first, last, runs = steps.edges(*at)
 
-    @pl.when(inner == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
@@ -462,54 +552,35 @@ def _flash_bwd_dkv_kernel(
             preferred_element_type=jnp.float32,
         ) * scale
 
-    if window is not None:
-        _on_band(qi <= last, qi, ki, block_q, block_k, window, _update)
-    elif causal:
-        # q blocks strictly above the diagonal see only masked scores.
-        pl.when((qi + 1) * block_q > ki * block_k)(_update)
-    else:
-        _update()
+    _update_block(runs, qi, ki, block_q, block_k, window, _update)
 
-    @pl.when(inner == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _fin():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(
-    q_ref,
-    k_ref,
-    v_ref,
-    do_ref,
-    m_ref,
-    l_ref,
-    d_ref,
-    dq_ref,
-    dq_scr,
-    *,
+    *refs,
+    steps: _Steps,
     scale: float,
     causal: bool,
     block_q: int,
     block_k: int,
     seq_len: int,
     window: Optional[int] = None,
-    k_blocks: int = 0,
 ):
-    """dQ: grid (batch·head, q-block, kv-block) with kv innermost;
-    ``dq += ds @ k · scale`` accumulates in VMEM across kv blocks (with
-    ``window``: across the band's, as in the forward)."""
+    """dQ: the forward's grid and ``refs`` but for ``dO, m, l, D`` after
+    ``v`` and the one output; ``dq += ds @ k · scale`` accumulates in VMEM
+    across a query block's key blocks."""
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    steps = pl.num_programs(2)
-    if window is None:
-        ki = step
-    else:
-        first, last = _key_blocks(qi, block_q, block_k, window, k_blocks)
-        ki = first + step
+    *tables, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, d_ref, dq_ref, dq_scr = refs
+    at = steps.here(*tables)
+    qi, _, ki = steps.blocks(*at)
+    first, last, runs = steps.edges(*at)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
 
@@ -535,14 +606,9 @@ def _flash_bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         ) * scale
 
-    if window is not None:
-        _on_band(ki <= last, qi, ki, block_q, block_k, window, _update)
-    elif causal:
-        pl.when((qi + 1) * block_q > ki * block_k)(_update)
-    else:
-        _update()
+    _update_block(runs, qi, ki, block_q, block_k, window, _update)
 
-    @pl.when(step == steps - 1)
+    @pl.when(last)
     def _fin():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -550,9 +616,10 @@ def _flash_bwd_dq_kernel(
 def _flash_backward_pallas(
     q, k, v, out, m, l, ct, causal, block_q, block_k, interpret, window=None
 ):
-    """Fused flash backward: two Pallas kernels (dK/dV with q innermost,
-    dQ with kv innermost) consuming the forward's saved statistics — no
-    stats-recompute pass and no ``[T, T]`` block in HBM. ``D`` (the
+    """Fused flash backward: two Pallas kernels (dK/dV, a key block's
+    query blocks in a row, and dQ, a query block's key blocks) consuming
+    the forward's saved statistics — no stats-recompute pass and no
+    ``[T, T]`` block in HBM. ``D`` (the
     softmax-jacobian diagonal term rowsum(ct ⊙ out)) is a cheap XLA
     elementwise-reduce."""
     from jax.experimental import pallas as pl
@@ -596,101 +663,64 @@ def _flash_backward_pallas(
     )
     db = rows_bh(big_d, tq_pad)
 
-    # dK/dV's inner axis: a query head's steps, every query block or (with
-    # a window) those whose band touches the key block.
-    if window is None:
-        q_steps, dkv_band = nq, {}
+    work = _blocks_with_work(nq, tk_pad // bk, bq, bk, causal, window)
+    of_kernel = dict(
+        scale=scale, causal=causal, block_q=bq, block_k=bk, seq_len=t,
+        window=window,
+    )
 
-        def q_block(j, i):
-            return i % nq
+    # dK/dV: a key block's query blocks, for each query head of its group.
+    by_key = _Steps(work.T, group)
 
-    else:
-        band = (bq, bk, window, nq)
-        q_steps = _band_steps(_query_blocks, tk_pad // bk, *band)
-        dkv_band = {"window": window, "band_steps": q_steps}
+    def q_rows(width):  # q, dO and the statistics: a query head's block
+        def index(bkv, *at):
+            _, member, qi = by_key.blocks(*at)
+            return (bkv // hk) * h + (bkv % hk) * group + member, qi, 0
 
-        def q_block(j, i):
-            first, last = _query_blocks(j, *band)
-            return jnp.minimum(first + i % q_steps, last)
-
-    def q_of(bkv, inner):
-        """The query row of this kv head's group that ``inner`` is at."""
-        return (bkv // hk) * h + (bkv % hk) * group + inner // q_steps
-
-    def q_rows(width):  # q [.., d] and dO [.., dv], a query head's block
-        return pl.BlockSpec(
-            (1, bq, width), lambda bkv, j, i: (q_of(bkv, i), q_block(j, i), 0)
-        )
+        return pl.BlockSpec((1, bq, width), index)
 
     def kv_rows(width):  # k, dk [.., d] and v, dv [.., dv]
-        return pl.BlockSpec((1, bk, width), lambda bkv, j, i: (bkv, j, 0))
+        return pl.BlockSpec(
+            (1, bk, width),
+            lambda bkv, *at: (bkv, by_key.blocks(*at)[0], 0),
+        )
 
-    row_spec = pl.BlockSpec(
-        (1, bq, 1), lambda bkv, j, i: (q_of(bkv, i), q_block(j, i), 0)
-    )
-    dkv = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_kernel,
-            scale=scale,
-            causal=causal,
-            block_q=bq,
-            block_k=bk,
-            seq_len=t,
-            q_blocks=nq,
-            **dkv_band,
+    dkb, dvb = pl.pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, steps=by_key, **of_kernel),
+        **by_key.call(
+            b * hk,
+            in_specs=[q_rows(d), kv_rows(d), kv_rows(dv), q_rows(dv),
+                      q_rows(1), q_rows(1), q_rows(1)],
+            out_specs=[kv_rows(d), kv_rows(dv)],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, dv), jnp.float32),
+            ],
         ),
-        grid=(b * hk, tk_pad // bk, group * q_steps),
-        in_specs=[q_rows(d), kv_rows(d), kv_rows(dv), q_rows(dv), row_spec,
-                  row_spec, row_spec],
-        out_specs=[kv_rows(d), kv_rows(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((b * hk, tk_pad, d), k.dtype),
             jax.ShapeDtypeStruct((b * hk, tk_pad, dv), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, dv), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
         interpret=interpret,
         name=_kernel_name(window, "bwd_dkv"),
-    )(qb, kb, vb, dob, mb, lb, db)
-    dkb, dvb = dkv
+    )(*by_key.tables, qb, kb, vb, dob, mb, lb, db)
 
-    k_steps, key_block, dq_band = _key_steps(nq, tk_pad // bk, bq, bk, window)
-    def q_rows2(width):
-        return pl.BlockSpec((1, bq, width), lambda bh, i, j: (bh, i, 0))
-
-    def kv_rows2(width):
-        return pl.BlockSpec(
-            (1, bk, width), lambda bh, i, j: (kv_of(bh), key_block(i, j), 0)
-        )
-
-    row_spec2 = pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0))
+    # dQ: the forward's steps.
+    by_query = _Steps(work)
+    q_rows2, kv_rows2 = _specs_by_query(by_query, bq, bk, kv_of)
     dqb = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel,
-            scale=scale,
-            causal=causal,
-            block_q=bq,
-            block_k=bk,
-            seq_len=t,
-            **dq_band,
+        functools.partial(_flash_bwd_dq_kernel, steps=by_query, **of_kernel),
+        **by_query.call(
+            b * h,
+            in_specs=[q_rows2(d), kv_rows2(d), kv_rows2(dv), q_rows2(dv),
+                      q_rows2(1), q_rows2(1), q_rows2(1)],
+            out_specs=q_rows2(d),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         ),
-        grid=(b * h, tq_pad // bq, k_steps),
-        in_specs=[q_rows2(d), kv_rows2(d), kv_rows2(dv), q_rows2(dv),
-                  row_spec2, row_spec2, row_spec2],
-        out_specs=pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, tq_pad, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
         interpret=interpret,
         name=_kernel_name(window, "bwd_dq"),
-    )(qb, kb, vb, dob, mb, lb, db)
+    )(*by_query.tables, qb, kb, vb, dob, mb, lb, db)
 
     def from_bh(x):
         x = x[:, :t].reshape(b, -1, t, x.shape[-1])

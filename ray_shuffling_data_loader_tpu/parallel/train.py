@@ -190,8 +190,12 @@ def make_train_step(
             tables, model.embed_dim, mesh
         )
     _, batch_inputs = model_loss(model)
+    body = make_step_body(model, optimizer)
+    traced_facts = getattr(model, "traced_facts", None)
+    if traced_facts is not None:
+        body = _saying_what_it_traced(body, built, traced_facts)
     with trace_span("step:build", **built):
-        step_fn = traced_in_mesh(mesh, make_step_body(model, optimizer))
+        step_fn = traced_in_mesh(mesh, body)
         batch_in = (
             None,  # features dict: let jax use committed input shardings
             batch_sharding(mesh, 1),
@@ -204,6 +208,21 @@ def make_train_step(
         )
     counters = getattr(model, "step_counters", None)
     return _with_counter_spans(step, counters) if counters else step
+
+
+def _saying_what_it_traced(
+    body: Callable, built: dict, traced_facts: Callable
+) -> Callable:
+    """``body`` under a ``step:build`` span of its own whenever it is
+    traced (once a batch shape): ``built`` and what the model can say only
+    of that shape, ``traced_facts(*batch)``."""
+
+    @functools.wraps(body)
+    def traced(state, *batch):
+        with trace_span("step:build", **built, **traced_facts(*batch)):
+            return body(state, *batch)
+
+    return traced
 
 
 def _with_counter_spans(step: Callable, counters: Dict[str, Tuple]) -> Callable:

@@ -60,6 +60,197 @@ def test_matches_dense_reference(causal, shape, blocks):
     )
 
 
+# -- the grid: the blocks that hold work, each once, in the kernels' order ---------
+
+# (seq, block_q, block_k): equal blocks; unequal ones either way round over a
+# sequence that is a multiple of neither; one block.
+_GRIDS = [(64, 16, 16), (75, 32, 16), (90, 16, 32), (40, 128, 128)]
+# None; under a block, a block, several blocks, over the sequence.
+_WINDOWS = [None, 7, 16, 40, 1000]
+
+
+def _walk(steps):
+    """``[(outer, member, inner, first, last, runs)]`` of every grid step
+    in the grid's order, read as the index maps and the kernels read them."""
+    return [
+        tuple(
+            int(x) for x in
+            (*steps.blocks(*at, *steps.tables), *steps.edges(*at, *steps.tables))
+        )
+        for at in np.ndindex(*steps.grid)
+    ]
+
+
+@pytest.mark.parametrize("limit", [flash_module.MAX_TABLE_ENTRIES, 6])
+@pytest.mark.parametrize("group", [1, 2, 4])
+@pytest.mark.parametrize("causal,window", [(False, None)] + [(True, w) for w in _WINDOWS])
+@pytest.mark.parametrize("seq,bq,bk", _GRIDS)
+def test_the_steps_are_the_blocks_the_mask_admits_a_score_in(
+    monkeypatch, seq, bq, bk, causal, window, group, limit
+):
+    """Each block with work once for each member of the group, in the order
+    a kernel accumulates in; a table held to ``limit`` entries keeps that
+    order in runs whose spare steps stay on the run's last block."""
+    monkeypatch.setattr(flash_module, "MAX_TABLE_ENTRIES", limit)
+    bq, bk = min(bq, seq), min(bk, seq)
+    nq, nk = -(-seq // bq), -(-seq // bk)
+    # The kernels' own mask over the padded square, block by block.
+    q_pos = np.arange(nq * bq)[:, None]
+    k_pos = np.arange(nk * bk)[None, :]
+    valid = np.broadcast_to(k_pos < seq, (nq * bq, nk * bk)).copy()
+    if causal:
+        valid &= q_pos >= k_pos
+    if window is not None:
+        valid &= q_pos - k_pos < window
+    want = valid.reshape(nq, bq, nk, bk).any(axis=(1, 3))
+    work = flash_module._blocks_with_work(nq, nk, bq, bk, causal, window)
+    assert np.array_equal(work, want)
+
+    for table, by_key in ((work, False), (work.T, True)):
+        members = group if by_key else 1
+        steps = flash_module._Steps(table, members)
+        walked = _walk(steps)
+        assert len(walked) == steps.length
+        ran = [(o, g, i) for o, g, i, _, _, runs in walked if runs]
+        # Outer block after outer block, member after member, inner blocks
+        # rising: every pair once for every member.
+        assert ran == sorted(
+            (o, g, i) for o, i in np.argwhere(table) for g in range(members)
+        )
+        if want.all():  # nothing to leave out: the rectangle, and no table
+            assert steps.tables == () and len(steps.grid) == 2
+        else:
+            assert len(steps.grid) == 1
+            assert len(steps.tables[0]) <= max(limit, members * len(table))
+        if want.all() or limit >= len(ran):
+            assert len(walked) == len(ran)
+        for at, (o, g, i, first, last, runs) in enumerate(walked):
+            before = walked[at - 1] if at else None
+            after = walked[at + 1] if at + 1 < len(walked) else None
+            assert first == (before is None or before[0] != o)
+            assert last == (after is None or after[0] != o)
+            if not runs:  # a spare step: the block before it, not fetched again
+                assert (o, g, i) == before[:3]
+    assert flash_module.grid_steps(seq, bq, bk, causal, window) == (
+        flash_module._Steps(work).length, int(want.sum())
+    )
+
+
+def _causal_loss(window, bq, bk):
+    def loss(q, k, v):
+        out = flash_attention(
+            q, k, v, causal=True, use_pallas=True, interpret=True,
+            block_q=bq, block_k=bk, window=window,
+        )
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    return loss
+
+
+@pytest.mark.parametrize("limit", [flash_module.MAX_TABLE_ENTRIES, 6])
+@pytest.mark.parametrize("window", _WINDOWS)
+@pytest.mark.parametrize("seq,bq,bk,group", [(75, 32, 16, 2), (90, 16, 32, 4)])
+def test_wide_values_over_any_grid_match_the_dense_reference(
+    monkeypatch, seq, bq, bk, group, window, limit
+):
+    """Forward and gradients with ``value_dim != head_dim``, grouped heads,
+    unequal blocks over a sequence that is a multiple of neither, every
+    kind of window, the tables whole and in runs."""
+    monkeypatch.setattr(flash_module, "MAX_TABLE_ENTRIES", limit)
+    rng = np.random.default_rng(seq + (window or 0))
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    q, k, v = normal(2, seq, 4, 8), normal(2, seq, 4 // group, 8), normal(2, seq, 4 // group, 16)
+
+    def dense(q, k, v):
+        out = attention_reference(
+            q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+            causal=True, window=None if window is None or window >= seq else window,
+        )
+        return jnp.sum(out ** 2)
+
+    got = jax.value_and_grad(_causal_loss(window, bq, bk), (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-4)
+
+
+def _pallas_grids(jaxpr):
+    """``{name: grid}`` of every Pallas call under ``jaxpr``."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.update(_pallas_grids(sub))
+    return found
+
+
+def test_a_causal_head_takes_a_step_a_block_with_work_and_the_step_says_so(
+    monkeypatch,
+):
+    """The traced calls' grids at a sequence cell's shape, and what
+    ``step:build`` says of Laguna's step at its rehearsal sizes once it is
+    traced (nothing is compiled or run)."""
+    import optax
+
+    from chipbench import harness
+    from ray_shuffling_data_loader_tpu.models.laguna import LagunaConfig, LagunaLM
+    from ray_shuffling_data_loader_tpu.parallel import (
+        TrainState, make_mesh, make_train_step,
+    )
+    from ray_shuffling_data_loader_tpu.telemetry import trace
+
+    q = jax.ShapeDtypeStruct((1, 8192, 48, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
+    grad = jax.grad(_causal_loss(None, 512, 512), (0, 1, 2))
+    nq = 8192 // 512
+    assert _pallas_grids(jax.make_jaxpr(grad)(q, kv, kv).jaxpr) == {
+        "flash_attention_fwd": (48, nq * (nq + 1) // 2),
+        "flash_attention_bwd_dkv": (8, 6 * nq * (nq + 1) // 2),
+        "flash_attention_bwd_dq": (48, nq * (nq + 1) // 2),
+    }
+
+    _, cfg, _ = harness.load_cell(harness.load_benchmark(), "laguna-seq8k-train")
+    cfg = {**cfg, **cfg["rehearsal"]}
+    model = LagunaLM(
+        LagunaConfig.from_dict(harness.load_family(cfg).program.model_config(cfg)),
+        use_pallas=True, interpret=True,
+        block_q=cfg["kernels"]["attention_block_q"],
+        block_k=cfg["kernels"]["attention_block_k"],
+    )
+    batch = {"tokens": jnp.zeros((2, 64), jnp.int32)}
+    optimizer = optax.adam(1e-5)
+    state = jax.eval_shape(
+        lambda: TrainState(
+            jnp.zeros((), jnp.int32),
+            (params := model.init(jax.random.key(0), batch)),
+            optimizer.init(params),
+        )
+    )
+    monkeypatch.setenv("RSDL_TRACE", "1")
+    trace.refresh_from_env()
+    trace.reset_state()
+    try:
+        mesh = make_mesh(devices=jax.devices()[:1])
+        make_train_step(model, optimizer, mesh, None).lower(state, batch)
+        spans = trace.local_spans()
+    finally:
+        monkeypatch.delenv("RSDL_TRACE")
+        trace.refresh_from_env()
+        trace.reset_state()
+    built, traced = [s["args"] for s in spans if s["name"] == "step:build"]
+    assert "attention_blocks" not in built
+    assert built["attention_kept"] == traced["attention_kept"] == 5
+    # 64 positions, query blocks of 32 and key blocks of 16, two sequences:
+    # a full layer's head (6 of them, layers 0 and 4) has 2 + 4 blocks with
+    # work, a head of the three layers between (8, window 16) 2 + 3 (keys
+    # 0-31, then 17-63).
+    blocks = 2 * (2 * 6 * 6 + 3 * 8 * 5)
+    assert traced["attention_blocks"] == traced["attention_grid_steps"] == blocks
+    assert blocks < 2 * 5 * 8 * 2 * 4  # the rectangles'
+
+
 def test_bfloat16(seed=3):
     q, k, v = _qkv((2, 32, 2, 8), seed=seed, dtype=jnp.bfloat16)
     got = flash_attention(

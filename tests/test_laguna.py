@@ -126,20 +126,22 @@ def _pallas_calls(jaxpr):
 
 
 @pytest.mark.parametrize(
-    "window,names,inner",
+    "window,names,blocks",
     [
-        # 8,192 positions in blocks of 512: 16 key blocks a query block
-        # without a window, 2 with one of 512, 3 with 700 or 1,024.
-        (None, "flash_attention_", (16, 8 * 16, 16)),
-        (512, "flash_attention_window_", (2, 8 * 2, 2)),
-        (700, "flash_attention_window_", (3, 8 * 3, 3)),
-        (1024, "flash_attention_window_", (3, 8 * 3, 3)),
-        (8192, "flash_attention_", (16, 8 * 16, 16)),
+        # 8,192 positions in blocks of 512: a query block sees the key blocks
+        # up to its own without a window (136 pairs), its own and the one
+        # before with one of 512 (31), two before with 700 or 1,024 (45).
+        (None, "flash_attention_", 136),
+        (512, "flash_attention_window_", 31),
+        (700, "flash_attention_window_", 45),
+        (1024, "flash_attention_window_", 45),
+        (8192, "flash_attention_", 136),
     ],
 )
-def test_a_window_s_grid_visits_the_band_s_blocks_only(window, names, inner):
+def test_a_window_s_grid_visits_the_band_s_blocks_only(window, names, blocks):
     """At the cell's own shapes (traced, nothing runs): forward, dK/dV
-    (a key head's 8 query heads times the band), dQ."""
+    (a key head's 8 query heads times the band), dQ: a step a block with
+    work."""
     q = jax.ShapeDtypeStruct((1, 8192, 64, 128), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
 
@@ -154,7 +156,7 @@ def test_a_window_s_grid_visits_the_band_s_blocks_only(window, names, inner):
         names + which for which in ("fwd", "bwd_dkv", "bwd_dq")
     ]
     assert [grid for _, grid in calls] == [
-        (64, 16, inner[0]), (8, 16, inner[1]), (64, 16, inner[2]),
+        (64, blocks), (8, 8 * blocks), (64, blocks),
     ]
 
 
@@ -169,7 +171,7 @@ def test_a_window_is_a_causal_query_s_and_at_least_one_key():
     assert np.allclose(got, _masked_softmax_attention(q, k, v, 4), atol=1e-5)
 
 
-# -- (g) without a window LFM2's traced call and step are the parent's ------------------
+# -- (g) LFM2's traced call and step are the ones pinned -------------------------------
 
 
 def _traced(jaxpr) -> str:
@@ -179,18 +181,21 @@ def _traced(jaxpr) -> str:
 
 
 @pytest.fixture(scope="module")
-def parent_traces():
-    """Digests of the traced text at commit 01a534d (PR 31), before
-    ``window`` existed and before ``models/blocks.py``: the same code below
-    run on that tree. A jaxpr's text belongs to one jax version."""
-    with open(os.path.join(ROOT, "tests", "fixtures", "lfm2_traced_01a534d.json")) as f:
+def pinned_traces():
+    """Digests of the traced text as ISSUE 35 left it (the attention
+    kernels' grids over the blocks with work; before it the pins were
+    those of ``01a534d`` and of ISSUE 33). A change to ``models/blocks.py``
+    or ``ops/flash_attention.py`` that is not meant to move LFM2's step
+    leaves them; one that is pins again and says why. A jaxpr's text
+    belongs to one jax version."""
+    with open(os.path.join(ROOT, "tests", "fixtures", "lfm2_traced_grid_of_work.json")) as f:
         pinned = json.load(f)
     if pinned["jax"] != jax.__version__:
         pytest.skip(f"pinned under jax {pinned['jax']}, this is {jax.__version__}")
     return pinned
 
 
-def test_without_a_window_lfm2_s_attention_call_is_the_parent_s(parent_traces):
+def test_without_a_window_lfm2_s_attention_call_is_the_pinned_one(pinned_traces):
     """Forward and backward of ``lfm2-seq8k-train``'s call, at its shapes:
     kernels, names, grids, index maps, the named residuals."""
     q = jax.ShapeDtypeStruct((4, 8192, 32, 64), jnp.bfloat16)
@@ -202,7 +207,7 @@ def test_without_a_window_lfm2_s_attention_call_is_the_parent_s(parent_traces):
         ).astype(jnp.float32).sum()
 
     traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv)
-    assert _traced(traced) == parent_traces["attention"]
+    assert _traced(traced) == pinned_traces["attention"]
     assert "flash_attention_window" not in str(traced)
 
 
@@ -233,26 +238,16 @@ def _lfm2_step_traced():
     return jax.make_jaxpr(make_step_body(model, optimizer))(state, batch)
 
 
-def test_lfm2_s_traced_step_is_the_parent_s(parent_traces, monkeypatch):
-    """Moving LFM2's layer parts to ``models/blocks.py`` and giving the
-    attention kernels a window changed no equation of its step (ISSUE 32).
-    ISSUE 33 names the routing and the plan in ``ops/moe.py``: the step
-    differs from the one pinned at ``01a534d`` by those ``name`` equations
-    (nine an expert layer) and by nothing else. So the step is pinned
-    again as it stands (``lfm2_traced_routing_named.json``), and with
-    ``checkpoint_name`` patched to the identity in ``ops/moe.py`` it still
-    is, equation for equation, the step of ``01a534d``."""
-    with open(
-        os.path.join(ROOT, "tests", "fixtures", "lfm2_traced_routing_named.json")
-    ) as f:
-        pinned = json.load(f)
-    assert pinned["jax"] == parent_traces["jax"]
+def test_lfm2_s_traced_step_is_the_pinned_one(pinned_traces):
+    """Moving LFM2's layer parts to ``models/blocks.py``, a window and a
+    value width in the attention kernels changed no equation of its step
+    (ISSUE 32, 34); ISSUE 33 named the routing and the plan in
+    ``ops/moe.py`` (nine ``name`` equations an expert layer); ISSUE 35
+    gave the three attention kernels their tables and a grid of two axes,
+    and pinned the step again as it then stood."""
     traced = _lfm2_step_traced()
-    assert str(traced).count(f"name[name={moe.ROUTING}]") == pinned["names"] == 9 * 4
-    assert _traced(traced) == pinned["step"]
-    monkeypatch.setattr(moe, "checkpoint_name", lambda value, name: value)
-    jax.clear_caches()
-    assert _traced(_lfm2_step_traced()) == parent_traces["step"]
+    assert str(traced).count(f"name[name={moe.ROUTING}]") == pinned_traces["names"] == 9 * 4
+    assert _traced(traced) == pinned_traces["step"]
 
 
 # -- (d) the rotary tables ---------------------------------------------------------------
@@ -551,12 +546,21 @@ def test_the_step_says_what_it_was_built_for_and_names_its_scopes(monkeypatch):
         lowered = step.lower(state, batch).as_text(debug_info=True)
         state, metrics = step(state, batch)
         spans = telemetry.local_spans()
-    (build,) = [s["args"] for s in spans if s["name"] == "step:build"]
+    build, *traced = [s["args"] for s in spans if s["name"] == "step:build"]
     assert build == {
         "model": "laguna", "experts_held": 4, "layers": 5, "window": 16,
         "heads_full": 6, "heads_window": 8, "attention_kept": 5,
         "routing_kept": 4,
     }
+    # Each trace of the step (lowered, then called) says it again with what
+    # hangs on the batch's shape: 64 positions in query blocks of 32 and key
+    # blocks of 16 are 6 blocks with work a full head (two layers of 6
+    # heads) and 5 a windowed one (three of 8), a grid step each.
+    blocks = 2 * 6 * 6 + 3 * 8 * 5
+    assert traced and all(
+        t == {**build, "attention_grid_steps": blocks, "attention_blocks": blocks}
+        for t in traced
+    )
     (load,) = [s["args"] for s in spans if s["name"] == "moe:load"]
     assert set(load) == {"max", "mean", "dropped", "layers", "fallback"}
     assert load["layers"] == 4 and load["dropped"] == 0

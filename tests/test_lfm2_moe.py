@@ -732,6 +732,11 @@ def test_the_step_takes_the_model_s_loss_and_hands_its_counters_to_the_trace(
     # expert layers their routing and plan.
     assert build[-1]["args"]["attention_kept"] == 1
     assert build[-1]["args"]["routing_kept"] == 4
+    # The span of the step's trace adds what hangs on the batch's shape: 4
+    # sequences of 64 positions, under one block, are a block a head.
+    assert "attention_blocks" not in build[0]["args"]
+    assert build[-1]["args"]["attention_grid_steps"] == 4 * 4
+    assert build[-1]["args"]["attention_blocks"] == 4 * 4
     loads = [s["args"] for s in spans if s["name"] == "moe:load"]
     assert len(loads) == 3 and all(a["dropped"] == 0 for a in loads)
     # 4 sequences x 64 tokens x 4 choices, a quarter of the experts held:

@@ -390,12 +390,20 @@ def test_the_step_says_what_it_was_built_for_and_names_its_scopes(monkeypatch):
         lowered = step.lower(state, batch).as_text(debug_info=True)
         state, metrics = step(state, batch)
         spans = telemetry.local_spans()
-    (build,) = [s["args"] for s in spans if s["name"] == "step:build"]
+    build, *traced = [s["args"] for s in spans if s["name"] == "step:build"]
     assert build == {
         "model": "phi4flash", "layers": 6, "ssm_layers": 2, "memory_units": 1,
         "cross_layers": 1, "window": 16, "shared_from": [16, 17],
         "attention_kept": 3, "memory_kept": 1,
     }
+    # Each trace of the step says it again with what hangs on the batch's
+    # shape: 8 heads a layer, 6 blocks with work a head of the full and the
+    # cross layer, 5 of the windowed one, a grid step each.
+    blocks = 8 * (6 + 6 + 5)
+    assert traced and all(
+        t == {**build, "attention_grid_steps": blocks, "attention_blocks": blocks}
+        for t in traced
+    )
     # A model without experts: the loss alone, and no ``moe:load`` span.
     assert set(metrics) == {"loss"} and np.isfinite(float(metrics["loss"]))
     assert not [s for s in spans if s["name"] == "moe:load"]
