@@ -26,8 +26,9 @@ https://ui.perfetto.dev): the spans of the driver, the pool workers and
 the actors, and the device's programs and operations, all on the wall
 clock (``telemetry.trace_export(path, xplane=...)``). It then checks the
 order that file has to show: an epoch's first ``reduce`` ends before its
-first ``stage:h2d`` begins, and every batch's ``stage:transfer`` ends
-before the ``jit_step_fn`` that consumes it does.
+first ``stage:h2d`` begins, every batch's ``stage:transfer`` ends before
+the ``jit_step_fn`` that consumes it does, and a device operation of the
+step carries the ``op_name`` the step's ``step:ops`` table gives it.
 
 All ``jax`` imports sit inside ``main()``: ``runtime.init()`` spawns
 workers that re-import ``__main__``.
@@ -113,6 +114,17 @@ def check_merged_trace(path: str, say) -> None:
     say(
         "  one clock: every epoch's first reduce ended before its first "
         "stage:h2d began, every stage:transfer before its step ended"
+    )
+    # The step said what is in the program it compiled (``step:ops``), so
+    # a device operation shows its scope.
+    scoped = [
+        e for e in events
+        if e.get("cat") == "xplane" and e["args"].get("op_name")
+    ]
+    assert scoped, "no device operation carries its op_name"
+    say(
+        f"  {len(scoped)} device operations carry their op_name: "
+        f"{scoped[0]['name']} is {scoped[0]['args']['op_name']}"
     )
 
 

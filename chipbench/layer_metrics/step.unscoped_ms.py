@@ -1,0 +1,19 @@
+"""Device milliseconds a step that the program's table cannot place: events
+whose own name the compiled step's text gives no ``op_name`` (copies and
+slices the compiler put in), or one under neither ``loss`` nor ``optimizer``
+(the step counter; an operation the compiler rebuilt inside a branch under a
+bare name such as ``gather``). Lower is better: it is what the split cannot
+see.
+
+One of four (``step.forward_ms``, ``step.backward_ms``,
+``step.optimizer_ms``, ``step.unscoped_ms``) that add up to the self time
+of every operation inside the window's train-step programs, a step: what
+``step.device_ms`` is the median of, less the device's gaps inside a step.
+``chipbench/scope_time.py`` says how an event's self time and its phase
+are found. A program that hands over no ``step:ops`` table: nothing to read."""
+
+from chipbench import scope_time
+
+
+def read(ctx):
+    return scope_time.phase_ms(ctx, "unscoped")

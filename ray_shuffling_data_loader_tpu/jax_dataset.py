@@ -156,7 +156,8 @@ def layer_counts(spans) -> Dict[str, Dict[str, Any]]:
 
     * ``runtime.by_fn[<fn>]``: ``tasks``, ``wait_s`` (submit to the
       worker's start), ``run_s`` of the ``pool:<fn>`` spans
-    * ``shuffle.epoch_s``: seconds of each ``shuffle:epoch``, in order
+    * ``shuffle.epoch_s``: seconds of each ``shuffle:epoch``, in order;
+      ``shuffle.schedules``: the schedule each of them ran, in that order
     * ``delivery``: ``gets``, ``get_wait_s`` of the ``queue:get`` spans
     * ``staging``: ``stager_s`` (``stage:epoch``, the stager thread's
       life), ``ring_put_s`` (``stage:ring-put``, the loader's slack),
@@ -164,10 +165,13 @@ def layer_counts(spans) -> Dict[str, Dict[str, Any]]:
     * ``train step[<name>]``: of the spans of the category ``train`` (the
       counters a model's step hands over, under the names and with the
       numbers the model chose: ``parallel/train.py``), ``spans`` and
-      ``sum``, every number they carry added up; a reader divides
+      ``sum``, every number they carry added up; a reader divides. Of
+      ``step:ops`` also ``table`` and ``program``, the newest span's: what
+      maps a device operation's own name to its scope
     """
     out: Dict[str, Dict[str, Any]] = {}
-    epochs: List[Tuple[float, float]] = []
+    epochs: List[Tuple[float, float, Any]] = []
+    ops = None  # the newest ``step:ops`` span
     for span in spans:
         name = span["name"]
         dur_s = span["dur"] / 1e6
@@ -180,7 +184,7 @@ def layer_counts(spans) -> Dict[str, Dict[str, Any]]:
             fn["wait_s"] += wait_s
             fn["run_s"] += dur_s - wait_s
         elif name == "shuffle:epoch":
-            epochs.append((span["ts"], dur_s))
+            epochs.append((span["ts"], dur_s, span["args"].get("schedule")))
         elif name == "queue:get":
             delivery = out.setdefault(
                 "delivery", {"gets": 0, "get_wait_s": 0.0}
@@ -195,6 +199,8 @@ def layer_counts(spans) -> Dict[str, Dict[str, Any]]:
             for key, value in span["args"].items():
                 if isinstance(value, (int, float)):
                     counter["sum"][key] = counter["sum"].get(key, 0) + value
+            if name == "step:ops" and (ops is None or span["ts"] >= ops["ts"]):
+                ops = span
         elif name in ("stage:epoch", "stage:ring-put", "stage:transfer"):
             staging = out.setdefault(
                 "staging",
@@ -211,7 +217,15 @@ def layer_counts(spans) -> Dict[str, Dict[str, Any]]:
                     staging["max_transfer_s"], dur_s
                 )
     if epochs:
-        out["shuffle"] = {"epoch_s": [dur_s for _, dur_s in sorted(epochs)]}
+        epochs.sort(key=lambda e: e[0])
+        out["shuffle"] = {
+            "epoch_s": [dur_s for _, dur_s, _ in epochs],
+            "schedules": [schedule for _, _, schedule in epochs],
+        }
+    if ops is not None:
+        out["train step"]["step:ops"].update(
+            table=ops["args"]["table"], program=ops["args"]["program"]
+        )
     return out
 
 

@@ -24,8 +24,9 @@ workload honest).
 from __future__ import annotations
 
 import functools
+import re
 import time
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -179,7 +180,9 @@ def make_train_step(
     from a model that brings its own loss (:func:`model_loss`). Counters
     such a model's step returns beside the loss (``model.step_counters``)
     stay on the device; while tracing is active each step's are handed to
-    the span buffer, which fetches them when it is read.
+    the span buffer, which fetches them when it is read. The step keeps
+    the program it compiles for each batch shape and tells a trace what is
+    in it (:class:`_KeptStep`); ``.lower`` is the jitted function's.
     """
     # How the step's embedding tables will be read is fixed by the shapes
     # and the mesh when it is traced: a count on the span, not a rate.
@@ -190,61 +193,176 @@ def make_train_step(
             tables, model.embed_dim, mesh
         )
     _, batch_inputs = model_loss(model)
-    body = make_step_body(model, optimizer)
-    traced_facts = getattr(model, "traced_facts", None)
-    if traced_facts is not None:
-        body = _saying_what_it_traced(body, built, traced_facts)
     with trace_span("step:build", **built):
-        step_fn = traced_in_mesh(mesh, body)
+        step_fn = traced_in_mesh(mesh, make_step_body(model, optimizer))
         batch_in = (
             None,  # features dict: let jax use committed input shardings
             batch_sharding(mesh, 1),
         )[:batch_inputs]
-        step = jax.jit(
+        jitted = jax.jit(
             step_fn,
             in_shardings=(state_shardings, *batch_in),
             out_shardings=(state_shardings, None),
             donate_argnums=(0,) if donate_state else (),
         )
-    counters = getattr(model, "step_counters", None)
-    return _with_counter_spans(step, counters) if counters else step
+    return _KeptStep(
+        jitted,
+        built,
+        getattr(model, "traced_facts", None),
+        getattr(model, "step_counters", None) or {},
+    )
 
 
-def _saying_what_it_traced(
-    body: Callable, built: dict, traced_facts: Callable
-) -> Callable:
-    """``body`` under a ``step:build`` span of its own whenever it is
-    traced (once a batch shape): ``built`` and what the model can say only
-    of that shape, ``traced_facts(*batch)``."""
-
-    @functools.wraps(body)
-    def traced(state, *batch):
-        with trace_span("step:build", **built, **traced_facts(*batch)):
-            return body(state, *batch)
-
-    return traced
+# Operations the device runs no program of its own for: none is ever an
+# event of a trace's ``XLA Ops`` line.
+_NEVER_EVENTS = frozenset(
+    ("parameter", "constant", "tuple", "get-tuple-element", "bitcast")
+)
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([^\s(]+) \(.*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%?([^\s=]+) = (?:\(.*?\)|\S+) ([\w\-]+)\("
+)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"\b(calls|to_apply)=%?([\w.\-]+)")
 
 
-def _with_counter_spans(step: Callable, counters: Dict[str, Tuple]) -> Callable:
-    """``step`` whose counters (``{span name: (metrics keys, fold)}``) are
-    recorded as spans of the category ``train`` while tracing is active,
-    each carrying ``fold(*values)``: the values stay on the device until
-    the span buffer is read (``telemetry.defer_span``), so no step ever
-    waits for one."""
+def program_ops(text: str) -> Dict[str, Any]:
+    """``{"program", "table"}`` of a compiled program's text
+    (``jax.stages.Compiled.as_text()``): the module's name, which the
+    trace's ``XLA Modules`` line shows before the program's number, and
+    for every instruction that can be an event of its own on the ``XLA
+    Ops`` line, its own name -> its ``op_name``, whole. Left out: the
+    instructions of fused computations and of the computations a reduce,
+    sort or scatter applies (the device runs the operation that holds
+    them), those the device runs nothing for (:data:`_NEVER_EVENTS`), and
+    those without an ``op_name`` (copies the compiler put in)."""
+    program = text.split(None, 2)[1].rstrip(",") if text else ""
+    found: List[Tuple[str, str, str]] = []  # computation, own name, op_name
+    held = set()  # computations that one operation holds
+    computation = None
+    for line in text.splitlines():
+        if not line:
+            continue
+        if line[0] == "}":
+            computation = None
+        elif line[0] != " ":
+            head = _COMPUTATION.match(line)
+            computation = head.group(1) if head else None
+        elif computation is not None:
+            instruction = _INSTRUCTION.match(line)
+            if instruction is None:
+                continue
+            own, opcode = instruction.groups()
+            for how, callee in _CALLED.findall(line):
+                # A ``call``'s computation and an async operation's run
+                # as operations of their own.
+                if (opcode == "fusion") if how == "calls" else (opcode != "call"):
+                    held.add(callee)
+            op_name = _OP_NAME.search(line)
+            if op_name and opcode not in _NEVER_EVENTS:
+                found.append((computation, own, op_name.group(1)))
+    table = {own: op for inside, own, op in found if inside not in held}
+    return {"program": program, "table": table}
 
-    @functools.wraps(step)
-    def counted(state, *batch):
-        state, metrics = step(state, *batch)
+
+class _Program:
+    """One batch shape of the step, as the step compiled it: the program,
+    what the model could say only of that shape, and whether a trace has
+    been told of it yet."""
+
+    __slots__ = ("compiled", "facts", "said")
+
+    def __init__(self, compiled, facts: dict):
+        self.compiled = compiled
+        self.facts = facts
+        self.said = False
+
+    def build(self) -> dict:
+        """``step:build``: the facts, and how many bytes the program
+        takes of the device beside its arguments."""
+        out = dict(self.facts)
+        memory = self.compiled.memory_analysis()
+        if memory is not None:
+            out.update(
+                temp_bytes=int(memory.temp_size_in_bytes),
+                argument_bytes=int(memory.argument_size_in_bytes),
+                output_bytes=int(memory.output_size_in_bytes),
+                alias_bytes=int(memory.alias_size_in_bytes),
+                code_bytes=int(memory.generated_code_size_in_bytes),
+            )
+        return out
+
+    def ops(self) -> dict:
+        """``step:ops``: :func:`program_ops` of the program's text."""
+        return program_ops(self.compiled.as_text())
+
+
+class _KeptStep:
+    """The jitted step, dispatching through the programs it compiled.
+
+    The first call with a batch shape lowers and compiles the step (the
+    one compile the jitted call would have made: same shardings, same
+    donation) and keeps the ``jax.stages.Compiled``; later calls go
+    straight to it, so the program that runs can be asked what is in it
+    (``as_text()``, ``memory_analysis()``) at no second compile and no
+    second copy on the device. Another shape (a loader's short last
+    batch) compiles another program, kept beside the first. Which program
+    a call takes is found by calling the one used last: it refuses other
+    shapes (``TypeError``) and other shardings (``ValueError``) before
+    anything runs, so a step walks no tree to choose.
+
+    While tracing is active each step hands the span buffer, deferred
+    (``telemetry.defer_span``: nothing is computed or fetched until the
+    buffer is read), the model's counters (``{span name: (metrics keys,
+    fold)}``) and, once a compiled shape, ``step:build`` and ``step:ops``;
+    all of the category ``train``."""
+
+    def __init__(
+        self,
+        jitted,
+        built: dict,
+        traced_facts: Optional[Callable],
+        counters: Dict[str, Tuple],
+    ):
+        self.lower = jitted.lower
+        self._jitted = jitted
+        self._built = built
+        self._traced_facts = traced_facts
+        self._counters = counters
+        self._programs: List[_Program] = []  # the one used last first
+
+    def __call__(self, state, *batch):
+        for at, program in enumerate(self._programs):
+            try:
+                out = program.compiled(state, *batch)
+            except (TypeError, ValueError):
+                continue
+            if at:
+                self._programs.insert(0, self._programs.pop(at))
+            break
+        else:
+            program = self._compile(state, *batch)
+            out = program.compiled(state, *batch)
         if tracing_active():
-            now = time.time()
-            for name, (keys, fold) in counters.items():
-                defer_span(
-                    name, now, [metrics[k] for k in keys], fold, cat="train"
-                )
-        return state, metrics
+            self._say(program, out[1])
+        return out
 
-    counted.lower = step.lower
-    return counted
+    def _compile(self, state, *batch) -> _Program:
+        facts = dict(self._built)
+        if self._traced_facts is not None:
+            facts.update(self._traced_facts(*batch))
+        program = _Program(self._jitted.lower(state, *batch).compile(), facts)
+        self._programs.insert(0, program)
+        return program
+
+    def _say(self, program: _Program, metrics) -> None:
+        now = time.time()
+        if not program.said:
+            program.said = True
+            defer_span("step:build", now, (), program.build, cat="train")
+            defer_span("step:ops", now, (), program.ops, cat="train")
+        for name, (keys, fold) in self._counters.items():
+            defer_span(name, now, [metrics[k] for k in keys], fold, cat="train")
 
 
 def _tree_dot(a, b) -> jax.Array:

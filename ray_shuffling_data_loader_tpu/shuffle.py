@@ -3968,7 +3968,7 @@ def shuffle_epoch(
                     telemetry.record_span(
                         "shuffle:epoch", epoch_t0, time.time() - epoch_t0,
                         cat="shuffle", reducers=num_reducers,
-                        maps=len(filenames),
+                        maps=len(filenames), schedule=schedule,
                     )
                 if journal is not None and not getattr(
                     thread, "suspended", False

@@ -704,14 +704,18 @@ def _mark_offset_ns(planes) -> Optional[int]:
     return max(offsets, default=None)
 
 
-def _xplane_events(path: str, span_names: set) -> List[dict]:
+def _xplane_events(
+    path: str, span_names: set, op_names: Dict[str, str]
+) -> List[dict]:
     """The xplane's device programs and operations and its host
     annotations as Chrome-trace events on the wall clock: every event is
     shifted by ``wall_ns - start_ns`` of the file's ``clock.sync`` marks
     (:func:`clock_sync`). Of the host plane, the lines (threads) that
     hold a span named in ``span_names`` or a mark are kept, whole: the
     profiler's own runtime events on those threads come with them, the
-    backend's thread pools do not."""
+    backend's thread pools do not. A device operation whose own name is in
+    ``op_names`` (a train step's ``step:ops`` table) carries that
+    ``op_name`` in its ``args``: the scope of ``fusion.661``."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -756,18 +760,20 @@ def _xplane_events(path: str, span_names: set) -> List[dict]:
                 }
             )
             for ev in line_events:
+                # An operation's name is its whole HLO instruction; keep
+                # what stands before " = ".
+                own = ev.name.split(" = ")[0].lstrip("%")
+                op_name = op_names.get(own) if device else None
                 events.append(
                     {
-                        # An operation's name is its whole HLO
-                        # instruction; keep what stands before " = ".
-                        "name": ev.name.split(" = ")[0].lstrip("%")[:120],
+                        "name": own[:120],
                         "cat": "xplane",
                         "ph": "X",
                         "ts": (int(ev.start_ns) + offset_ns) / 1e3,
                         "dur": int(ev.duration_ns) / 1e3,
                         "pid": pid,
                         "tid": tid,
-                        "args": {},
+                        "args": {"op_name": op_name} if op_name else {},
                     }
                 )
     return events
@@ -780,7 +786,8 @@ def trace_export(path: str, xplane: Optional[str] = None) -> str:
     profiler wrote while :func:`refresh_active` saw its session), the
     device's ``XLA Modules`` / ``XLA Ops`` lines and the host plane's
     annotations go into the same file, shifted onto the wall clock by the
-    ``clock.sync`` mark. Returns ``path``."""
+    ``clock.sync`` mark, and a device operation that a ``step:ops`` span of
+    the buffer names carries its ``op_name``. Returns ``path``."""
     _resolve_deferred()
     flush()
     events: List[dict] = []
@@ -793,7 +800,13 @@ def trace_export(path: str, xplane: Optional[str] = None) -> str:
         events.extend(_events)  # no-spool mode: the local buffer
     if xplane:
         span_names = {e["name"] for e in events if e.get("ph") == "X"}
-        events.extend(_xplane_events(xplane, span_names | {"clock.sync"}))
+        op_names: Dict[str, str] = {}
+        for e in events:
+            if e["name"] == "step:ops" and e.get("ph") == "X":
+                op_names.update(e["args"]["table"])
+        events.extend(
+            _xplane_events(xplane, span_names | {"clock.sync"}, op_names)
+        )
     # Metadata first, then chronological — what the viewers expect.
     events.sort(key=lambda e: (e.get("ph") != "M", e.get("ts", 0)))
     payload = {"traceEvents": events, "displayTimeUnit": "ms"}

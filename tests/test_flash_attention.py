@@ -189,15 +189,16 @@ def _pallas_grids(jaxpr):
 def test_a_causal_head_takes_a_step_a_block_with_work_and_the_step_says_so(
     monkeypatch,
 ):
-    """The traced calls' grids at a sequence cell's shape, and what
-    ``step:build`` says of Laguna's step at its rehearsal sizes once it is
-    traced (nothing is compiled or run)."""
+    """The traced calls' grids at a sequence cell's shape (nothing is
+    compiled or run), and what ``step:build`` says of Laguna's step at its
+    rehearsal sizes once it has compiled a batch's shape: the grid is the
+    kernels', also where the XLA path runs in their place, as here."""
     import optax
 
     from chipbench import harness
     from ray_shuffling_data_loader_tpu.models.laguna import LagunaConfig, LagunaLM
     from ray_shuffling_data_loader_tpu.parallel import (
-        TrainState, make_mesh, make_train_step,
+        init_state, make_mesh, make_train_step,
     )
     from ray_shuffling_data_loader_tpu.telemetry import trace
 
@@ -215,25 +216,22 @@ def test_a_causal_head_takes_a_step_a_block_with_work_and_the_step_says_so(
     cfg = {**cfg, **cfg["rehearsal"]}
     model = LagunaLM(
         LagunaConfig.from_dict(harness.load_family(cfg).program.model_config(cfg)),
-        use_pallas=True, interpret=True,
+        use_pallas=False,
         block_q=cfg["kernels"]["attention_block_q"],
         block_k=cfg["kernels"]["attention_block_k"],
     )
     batch = {"tokens": jnp.zeros((2, 64), jnp.int32)}
     optimizer = optax.adam(1e-5)
-    state = jax.eval_shape(
-        lambda: TrainState(
-            jnp.zeros((), jnp.int32),
-            (params := model.init(jax.random.key(0), batch)),
-            optimizer.init(params),
-        )
-    )
     monkeypatch.setenv("RSDL_TRACE", "1")
     trace.refresh_from_env()
     trace.reset_state()
     try:
         mesh = make_mesh(devices=jax.devices()[:1])
-        make_train_step(model, optimizer, mesh, None).lower(state, batch)
+        state, shardings = init_state(model, optimizer, mesh, batch)
+        step = make_train_step(model, optimizer, mesh, shardings)
+        step.lower(state, batch)  # lowering alone says nothing
+        state, _ = step(state, batch)
+        step(state, batch)  # a second step of the shape says nothing more
         spans = trace.local_spans()
     finally:
         monkeypatch.delenv("RSDL_TRACE")

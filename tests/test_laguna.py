@@ -532,6 +532,10 @@ def test_each_attention_kernel_s_forward_runs_once_a_step():
     assert calls["moe_experts_fwd"] == 4 * 2 * 3 * 2
 
 
+# What ``step:build`` says of the program the step compiled and kept.
+BYTES = ("temp_bytes", "argument_bytes", "output_bytes", "alias_bytes", "code_bytes")
+
+
 def test_the_step_says_what_it_was_built_for_and_names_its_scopes(monkeypatch):
     from ray_shuffling_data_loader_tpu import telemetry
     from ray_shuffling_data_loader_tpu.parallel import init_state, make_train_step
@@ -552,15 +556,19 @@ def test_the_step_says_what_it_was_built_for_and_names_its_scopes(monkeypatch):
         "heads_full": 6, "heads_window": 8, "attention_kept": 5,
         "routing_kept": 4,
     }
-    # Each trace of the step (lowered, then called) says it again with what
-    # hangs on the batch's shape: 64 positions in query blocks of 32 and key
+    # The first step that ran while tracing was on says it again, once for
+    # the shape it compiled (lowering alone says nothing), with what hangs
+    # on the batch's shape: 64 positions in query blocks of 32 and key
     # blocks of 16 are 6 blocks with work a full head (two layers of 6
-    # heads) and 5 a windowed one (three of 8), a grid step each.
+    # heads) and 5 a windowed one (three of 8), a grid step each; and with
+    # the bytes the compiled program takes.
     blocks = 2 * 6 * 6 + 3 * 8 * 5
-    assert traced and all(
-        t == {**build, "attention_grid_steps": blocks, "attention_blocks": blocks}
-        for t in traced
-    )
+    (traced,) = traced
+    sizes = {k: traced.pop(k) for k in BYTES}
+    assert traced == {
+        **build, "attention_grid_steps": blocks, "attention_blocks": blocks,
+    }
+    assert sizes["argument_bytes"] > 0 and sizes["temp_bytes"] > 0
     (load,) = [s["args"] for s in spans if s["name"] == "moe:load"]
     assert set(load) == {"max", "mean", "dropped", "layers", "fallback"}
     assert load["layers"] == 4 and load["dropped"] == 0

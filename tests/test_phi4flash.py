@@ -376,6 +376,10 @@ def _sizes_outside_kernels(jaxpr):
     return sizes
 
 
+# What ``step:build`` says of the program the step compiled and kept.
+BYTES = ("temp_bytes", "argument_bytes", "output_bytes", "alias_bytes", "code_bytes")
+
+
 def test_the_step_says_what_it_was_built_for_and_names_its_scopes(monkeypatch):
     from ray_shuffling_data_loader_tpu import telemetry
     from ray_shuffling_data_loader_tpu.parallel import init_state, make_train_step
@@ -396,14 +400,17 @@ def test_the_step_says_what_it_was_built_for_and_names_its_scopes(monkeypatch):
         "cross_layers": 1, "window": 16, "shared_from": [16, 17],
         "attention_kept": 3, "memory_kept": 1,
     }
-    # Each trace of the step says it again with what hangs on the batch's
-    # shape: 8 heads a layer, 6 blocks with work a head of the full and the
-    # cross layer, 5 of the windowed one, a grid step each.
+    # The first step that ran while tracing was on says it again, once for
+    # the shape it compiled, with what hangs on the batch's shape (8 heads
+    # a layer, 6 blocks with work a head of the full and the cross layer, 5
+    # of the windowed one, a grid step each) and the compiled program's bytes.
     blocks = 8 * (6 + 6 + 5)
-    assert traced and all(
-        t == {**build, "attention_grid_steps": blocks, "attention_blocks": blocks}
-        for t in traced
-    )
+    (traced,) = traced
+    sizes = {k: traced.pop(k) for k in BYTES}
+    assert traced == {
+        **build, "attention_grid_steps": blocks, "attention_blocks": blocks,
+    }
+    assert sizes["argument_bytes"] > 0 and sizes["temp_bytes"] > 0
     # A model without experts: the loss alone, and no ``moe:load`` span.
     assert set(metrics) == {"loss"} and np.isfinite(float(metrics["loss"]))
     assert not [s for s in spans if s["name"] == "moe:load"]

@@ -1,8 +1,9 @@
 """The program's own spans (ISSUE 25): the switch that follows a profiler
 session, the spans of every layer in the profiler's trace and on its clock,
 the ``layers`` counts folded from them, the names the trace readers go by,
-and the runtime's ``pool:`` spans. All on the CPU; what the spans cost and
-read on the chip is PERF.md's."""
+the runtime's ``pool:`` spans, and (ISSUE 36) the train step that keeps the
+program it compiled and says what is in it. All on the CPU; what the spans
+cost and read on the chip is PERF.md's."""
 
 import asyncio
 import json
@@ -201,6 +202,10 @@ def test_spans_land_in_the_profilers_trace_on_its_clock(files, tmp_path):
     # after.
     assert len(layers["shuffle"]["epoch_s"]) in (1, 2)
     assert all(s > 0 for s in layers["shuffle"]["epoch_s"])
+    # Which schedule made each of them, in the same order: the span says it.
+    schedules = layers["shuffle"]["schedules"]
+    assert len(schedules) == len(layers["shuffle"]["epoch_s"])
+    assert set(schedules) <= {"index", "selective", "mapreduce"}, schedules
     by_fn = layers["runtime"]["by_fn"]
     assert sum(c["tasks"] for c in by_fn.values()) >= 4
     assert any(fn.startswith("shuffle_") for fn in by_fn), sorted(by_fn)
@@ -324,9 +329,9 @@ SYNTHETIC = [
     _span("pool:shuffle_reduce", 300, 400, tid=4, wait_ns=100_000_000),
     _span("pool:generate_file", 0, 10, tid=4, wait_ns=0),
     # The shuffle driver's threads.
-    _span("shuffle:epoch", 0, 3000, tid=5, epoch=4),
-    _span("shuffle:epoch", 2000, 5000, tid=6, epoch=5),
-    _span("shuffle:epoch", 6000, 4000, tid=5, epoch=6),
+    _span("shuffle:epoch", 0, 3000, tid=5, epoch=4, schedule="mapreduce"),
+    _span("shuffle:epoch", 6000, 4000, tid=5, epoch=6, schedule="index"),
+    _span("shuffle:epoch", 2000, 5000, tid=6, epoch=5, schedule="index"),
     _span("epoch:admission", 1990, 10, tid=7, epoch=5),
     # The resident loader.
     _span("resident:handover", 0, 100, tid=8, epoch=1),
@@ -353,7 +358,10 @@ def test_layers_arithmetic():
         "run_s": pytest.approx(0.300),
     }
     # In the order they began, whatever thread recorded them.
-    assert got["shuffle"] == {"epoch_s": [3.0, 5.0, 4.0]}
+    assert got["shuffle"] == {
+        "epoch_s": [3.0, 5.0, 4.0],
+        "schedules": ["mapreduce", "index", "index"],
+    }
     assert got["delivery"] == {
         "gets": 1, "get_wait_s": pytest.approx(0.100),
     }
@@ -610,3 +618,305 @@ def test_pool_keeps_nothing_with_tracing_off(local_runtime, tmp_path):
     assert not pool._traced
     assert fut.result(timeout=60) == "second attempt"
     assert trace.local_spans() == []
+
+
+# -- (g) the train step keeps the program it compiled (ISSUE 36) ---------------------
+
+
+class _OneMatrix:
+    """The smallest model ``make_train_step`` takes: ``logits = x @ w``,
+    with something to say when the step is built and of a batch's shape."""
+
+    build_facts = {"model": "one_matrix"}
+
+    def init(self, rng, features):
+        return {"w": jnp.full((features["x"].shape[1],), 0.5, jnp.float32)}
+
+    def apply(self, params, features):
+        return features["x"] @ params["w"]
+
+    def traced_facts(self, features, labels):
+        return {"rows": int(features["x"].shape[0])}
+
+
+def _batch(rows):
+    return {"x": jnp.ones((rows, 4), jnp.float32)}, jnp.zeros((rows,), jnp.float32)
+
+
+def _kept_step():
+    import optax
+
+    from ray_shuffling_data_loader_tpu.parallel import (
+        init_state,
+        make_mesh,
+        make_train_step,
+    )
+
+    mesh = make_mesh(devices=jax.devices()[:1])
+    model, optimizer = _OneMatrix(), optax.adam(1e-3)
+    state, shardings = init_state(model, optimizer, mesh, _batch(8)[0])
+    return make_train_step(model, optimizer, mesh, shardings), state
+
+
+class _Compiles:
+    """The backend compilations JAX reports while the block runs."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __enter__(self):
+        self.count = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        return self
+
+    def _on(self, event, duration, **_):
+        self.count += event == self.EVENT
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._on)
+
+
+def _train_spans(name):
+    return [
+        s for s in trace.local_spans()
+        if s["name"] == name and s.get("cat") == "train"
+    ]
+
+
+def test_the_step_compiles_once_a_shape_and_keeps_each_program(monkeypatch):
+    monkeypatch.setenv("RSDL_TRACE", "1")
+    trace.refresh_from_env()
+    step, state = _kept_step()
+    assert callable(step.lower)  # the jitted function's, for an abstract batch
+    whole, short = _batch(8), _batch(3)  # a loader's short last batch
+    with _Compiles() as first:
+        for _ in range(4):
+            state, metrics = step(state, *whole)
+    assert first.count == 1 and np.isfinite(float(metrics["loss"]))
+    (eight,) = step._programs
+    with _Compiles() as second:
+        state, _ = step(state, *short)
+        state, _ = step(state, *whole)
+        state, _ = step(state, *short)
+    assert second.count == 1
+    # Kept beside the first, which is still the program of its shape.
+    assert len(step._programs) == 2 and eight in step._programs
+    assert step._programs[0] is not eight  # the one used last comes first
+    with _Compiles() as reading:
+        builds, ops = _train_spans("step:build"), _train_spans("step:ops")
+    # Saying what is in the programs compiles nothing.
+    assert reading.count == 0
+    assert [b["args"]["rows"] for b in builds] == [8, 3]
+    assert len(ops) == 2
+    # A mistaken call is still the jitted function's error, and costs no
+    # program.
+    with pytest.raises(TypeError):
+        step(state)
+    assert len(step._programs) == 2
+
+
+def test_a_step_first_called_untraced_says_its_program_once_tracing_is_on(
+    monkeypatch,
+):
+    step, state = _kept_step()
+    for _ in range(2):
+        state, _ = step(state, *_batch(8))
+    assert trace.local_spans() == []
+    # A profiler session that began after warm-up, or the switch: the flag.
+    monkeypatch.setenv("RSDL_TRACE", "1")
+    trace.refresh_from_env()
+    assert trace.local_spans() == []  # nothing until a step runs
+    for _ in range(3):
+        state, _ = step(state, *_batch(8))
+    (build,) = _train_spans("step:build")
+    (ops,) = _train_spans("step:ops")
+    (program,) = step._programs
+    memory = program.compiled.memory_analysis()
+    assert build["args"]["model"] == "one_matrix" and build["args"]["rows"] == 8
+    assert {
+        k: build["args"][k]
+        for k in ("temp_bytes", "argument_bytes", "output_bytes",
+                  "alias_bytes", "code_bytes")
+    } == {
+        "temp_bytes": memory.temp_size_in_bytes,
+        "argument_bytes": memory.argument_size_in_bytes,
+        "output_bytes": memory.output_size_in_bytes,
+        "alias_bytes": memory.alias_size_in_bytes,
+        "code_bytes": memory.generated_code_size_in_bytes,
+    }
+    assert build["args"]["argument_bytes"] > 0
+    # ``step:ops``: the module's name, and the program's own instructions.
+    text = program.compiled.as_text()
+    assert ops["args"]["program"] == "jit_step_fn"
+    assert text.startswith("HloModule jit_step_fn")
+    table = ops["args"]["table"]
+    entry = text[text.index("\nENTRY "):]
+    in_entry = [own for own in table if f"%{own} = " in entry]
+    assert in_entry, table
+    for own, op_name in table.items():
+        # The names are those of the compiled text, and so a trace's.
+        assert f"%{own} = " in text, own
+        assert f'op_name="{op_name}"' in text, own
+    unscoped = {
+        own: table[own] for own in in_entry
+        if "jvp(loss)" not in table[own]
+        and "/optimizer/" not in table[own]
+    }
+    # All under ``loss`` or ``optimizer`` but the step counter's ``add``.
+    assert set(unscoped.values()) <= {"jit(step_fn)/add"}, unscoped
+    assert any("transpose(jvp(loss))" in v for v in table.values())
+    # No parameter, and nothing of a fused computation.
+    assert not any(own.startswith(("param", "state", "Arg_")) for own in table)
+    # The loader carries the table through and adds up the build's numbers.
+    folded = layer_counts(trace.local_spans())["train step"]
+    assert folded["step:ops"]["table"] == table
+    assert folded["step:ops"]["program"] == "jit_step_fn"
+    assert folded["step:ops"]["spans"] == 1
+    assert folded["step:build"]["spans"] == 1
+    assert folded["step:build"]["sum"]["temp_bytes"] == memory.temp_size_in_bytes
+
+
+def test_a_step_never_traced_says_nothing_and_reads_no_text(monkeypatch):
+    from ray_shuffling_data_loader_tpu.parallel import train
+
+    asked = []
+    monkeypatch.setattr(
+        train._Program, "ops", lambda self: asked.append("ops") or {}
+    )
+    monkeypatch.setattr(
+        train._Program, "build", lambda self: asked.append("build") or {}
+    )
+    step, state = _kept_step()
+    for _ in range(3):
+        state, _ = step(state, *_batch(8))
+    assert trace.local_spans() == [] and asked == []
+    assert layer_counts(trace.local_spans()) == {}
+    assert not step._programs[0].said
+
+
+def test_the_newest_table_is_the_one_the_layers_keep():
+    def ops(ts_ms, table):
+        return {**_span("step:ops", ts_ms, 0, table=table, program="jit_step_fn"),
+                "cat": "train"}
+
+    def build(ts_ms, temp):
+        return {**_span("step:build", ts_ms, 0, model="m", temp_bytes=temp,
+                        shared_from=[1, 2]), "cat": "train"}
+
+    got = layer_counts([
+        ops(20, {"fusion.1": "jit(step_fn)/optimizer/add"}),
+        ops(10, {"fusion.1": "jit(step_fn)/jvp(loss)/mul"}),
+        build(10, 100), build(20, 300),
+        # The span of the build itself (``RSDL_TRACE``) is no counter.
+        _span("step:build", 0, 5, model="m"),
+    ])
+    assert set(got) == {"train step"}
+    assert got["train step"]["step:ops"] == {
+        "spans": 2, "sum": {}, "program": "jit_step_fn",
+        "table": {"fusion.1": "jit(step_fn)/optimizer/add"},
+    }
+    assert got["train step"]["step:build"] == {
+        "spans": 2, "sum": {"temp_bytes": 400},
+    }
+
+
+HLO_TEXT = """\
+HloModule jit_step_fn, is_scheduled=true, entry_computation_layout={()->f32[]}
+
+%fused_computation (param_0: f32[4]) -> f32[4] {
+  %param_0 = f32[4]{0} parameter(0)
+  ROOT %add.9 = f32[4]{0} add(%param_0, %param_0), metadata={op_name="jit(step_fn)/optimizer/add"}
+}
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0), metadata={op_name="reduce_sum"}
+  %b = f32[] parameter(1), metadata={op_name="reduce_sum"}
+  ROOT %add.5 = f32[] add(%a, %b), metadata={op_name="jit(step_fn)/jvp(loss)/reduce_sum"}
+}
+
+%branch_1 (arg_tuple.0: (f32[4]{0:T(128)}, s32[])) -> f32[4] {
+  %arg_tuple.0 = (f32[4]{0:T(128)}, s32[]) parameter(0)
+  %get-tuple-element.3 = f32[4]{0:T(128)} get-tuple-element(%arg_tuple.0), index=0, metadata={op_name="jit(step_fn)/jvp(loss)/m/experts/cond/mul"}
+  %copy-start.1 = (f32[4]{0:T(128)}, f32[4]{0:T(128)S(1)}, u32[]) copy-start(%get-tuple-element.3)
+  %copy-done.1 = f32[4]{0:T(128)S(1)} copy-done(%copy-start.1)
+  ROOT %fusion.7 = f32[4]{0:T(128)} fusion(%copy-done.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step_fn)/jvp(loss)/m/experts/cond/mul"}
+}
+
+ENTRY %main.9 (Arg_0.1: f32[4]) -> f32[] {
+  %Arg_0.1 = f32[4]{0:T(128)} parameter(0), metadata={op_name="state.params[\\'w\\']"}
+  %constant.2 = s32[] constant(1), metadata={op_name="jit(step_fn)/jvp(loss)/m/experts/cond"}
+  %tuple.3 = (f32[4]{0:T(128)}, s32[]) tuple(%Arg_0.1, %constant.2)
+  %cond.4 = f32[4]{0:T(128)} conditional(%constant.2, %tuple.3, %tuple.3), branch_computations={%branch_1, %branch_1}, metadata={op_name="jit(step_fn)/jvp(loss)/m/experts/cond"}
+  %call.6 = f32[4]{0:T(128)} call(%cond.4), to_apply=%branch_1, metadata={op_name="jit(step_fn)/jvp(loss)/m/call"}
+  ROOT %reduce.8 = f32[] reduce(%call.6, %constant.2), dimensions={0}, to_apply=%region_0.1, metadata={op_name="jit(step_fn)/transpose(jvp(loss))/m/reduce_sum"}
+}
+"""
+
+
+def test_program_ops_keeps_what_can_be_an_event_of_its_own():
+    """On a text as the TPU's compiler writes it: tiled layouts, a tuple
+    type, a branch's computation, a fusion's and a reducer's."""
+    from ray_shuffling_data_loader_tpu.parallel.train import program_ops
+
+    got = program_ops(HLO_TEXT)
+    assert got["program"] == "jit_step_fn"
+    assert got["table"] == {
+        # A branch's operations are events of their own, the fused and
+        # the reducer's are not; copies carry no ``op_name``.
+        "fusion.7": "jit(step_fn)/jvp(loss)/m/experts/cond/mul",
+        "cond.4": "jit(step_fn)/jvp(loss)/m/experts/cond",
+        "call.6": "jit(step_fn)/jvp(loss)/m/call",
+        "reduce.8": "jit(step_fn)/transpose(jvp(loss))/m/reduce_sum",
+    }
+    assert program_ops("") == {"program": "", "table": {}}
+
+
+def test_the_merged_trace_puts_the_op_name_on_a_device_operation(
+    monkeypatch, tmp_path
+):
+    """``trace_export(xplane=...)``: an operation of the device's ``XLA
+    Ops`` line whose own name a ``step:ops`` span of the buffer holds
+    carries that ``op_name``; nothing else changes."""
+    import types
+
+    monkeypatch.setenv("RSDL_TRACE", "1")
+    trace.refresh_from_env()
+    trace.record_span(
+        "step:ops", 1.0, 0.0, cat="train", program="jit_step_fn",
+        table={"fusion.661": "jit(step_fn)/jvp(loss)/m/layer_2/attention/mul"},
+    )
+
+    def event(name, start, dur, **stats):
+        return types.SimpleNamespace(
+            name=name, start_ns=start, duration_ns=dur, stats=list(stats.items())
+        )
+
+    def line(name, *events):
+        return types.SimpleNamespace(name=name, events=list(events))
+
+    planes = [
+        types.SimpleNamespace(name="/device:TPU:0", lines=[
+            line("XLA Modules", event("jit_step_fn(123)", 1000, 500)),
+            line("XLA Ops",
+                 event("%fusion.661 = f32[4]{0} fusion(..)", 1000, 200),
+                 event("%copy.3 = f32[4]{0} copy(..)", 1200, 100)),
+        ]),
+        types.SimpleNamespace(name="/host:CPU", lines=[
+            line("main", event("clock.sync", 900, 1, wall_ns=5_000_000_900)),
+        ]),
+    ]
+    monkeypatch.setattr(
+        jax.profiler.ProfileData, "from_file",
+        staticmethod(lambda path: types.SimpleNamespace(planes=planes)),
+    )
+    out = trace.trace_export(str(tmp_path / "merged.json"), xplane="an.xplane.pb")
+    with open(out) as f:
+        events = {
+            e["name"]: e for e in json.load(f)["traceEvents"]
+            if e.get("cat") == "xplane"
+        }
+    assert events["fusion.661"]["args"] == {
+        "op_name": "jit(step_fn)/jvp(loss)/m/layer_2/attention/mul"
+    }
+    assert events["copy.3"]["args"] == {}
+    assert events["jit_step_fn(123)"]["args"] == {}
+    assert events["fusion.661"]["ts"] == pytest.approx(5_000_001.0)
