@@ -292,13 +292,16 @@ def main(argv) -> int:
         for name, g, w in zip(("fwd", "grad"), got, want):
             errs[f"interaction {name}"] = rel_err(g, w)
         # Flash attention at the head widths the models use; 300 is not a
-        # multiple of the 128 block, so padded query and key blocks run.
-        for d in (32, 128):
+        # multiple of the 128 block, so padded query and key blocks run. The
+        # toy models' blocks of 32 over 64 tokens are under a vreg's lanes.
+        for d, t, blocks in ((32, 300, (128, 128)), (128, 300, (128, 128)),
+                             (64, 64, (32, 32))):
             for causal in (False, True):
-                q, k, v, ct = (bf16(2, 300, 2, d) for _ in range(4))
+                q, k, v, ct = (bf16(2, t, 2, d) for _ in range(4))
                 got = with_grads(
                     lambda q, k, v: flash_attention(
                         q, k, v, causal=causal, use_pallas=True,
+                        block_q=blocks[0], block_k=blocks[1],
                         interpret=rehearse,
                     ),
                     q, k, v, ct=ct,
@@ -310,7 +313,9 @@ def main(argv) -> int:
                     q, k, v, ct=ct,
                 )
                 for name, g, w in zip(("fwd", "dQ", "dK", "dV"), got, want):
-                    errs[f"flash d={d} causal={causal} {name}"] = rel_err(g, w)
+                    errs[f"flash d={d} t={t} causal={causal} {name}"] = (
+                        rel_err(g, w)
+                    )
         for name, err in errs.items():
             say(f"  {name}: rel err {err:.2e} (limit {TOL:.2e})")
             assert err < TOL, (name, err)

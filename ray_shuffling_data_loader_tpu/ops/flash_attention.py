@@ -54,9 +54,17 @@ The forward emits its softmax row statistics (m, l) as outputs; the
 backward is two fused Pallas kernels — dK/dV (q innermost, VMEM
 accumulators) and dQ (kv innermost) — that recompute probability blocks
 from those statistics, so no ``[T, T]`` block materializes in the
-gradient and no stats-recompute pass is paid. ``RSDL_FLASH_BWD=xla``
-falls back to the chunked-XLA exact backward (shared with
-``blockwise_attention``).
+gradient and no stats-recompute pass is paid. They read the statistics as
+two lane-dense float32 rows a query head, the log-sum-exp ``lse = m + log
+l`` (``+inf`` on a row with no key, so ``p = exp(s − lse)`` is 0 there) and
+``D = rowsum(dO ⊙ out)``, not as ``[t, 1]`` columns, which the (8, 128)
+tile pads 128-fold and XLA lays out anew. dK/dV runs key-major (the block
+``[bk, bq]``, keys on the sublanes), so its two accumulating products
+``pᵀ @ dO`` and ``dsᵀ @ q`` contract the block's minor dimension and no
+block is transposed; dQ's ``ds @ k`` contracts the keys, so dQ stays
+query-major and turns a query block's rows into columns once, at its first
+step. ``RSDL_FLASH_BWD=xla`` falls back to the chunked-XLA exact backward
+(shared with ``blockwise_attention``).
 """
 
 from __future__ import annotations
@@ -362,18 +370,34 @@ def _kv_head_map(h: int, hk: int):
     return lambda bh: (bh // h) * hk + (bh % h) // group
 
 
-def _specs_by_query(steps: _Steps, bq: int, bk: int, kv_of):
-    """Block specs of the forward and dQ kernels, whose steps go query
-    block by query block: ``q_rows(width)`` for what a query head's rows
-    hold (q, out, dO, dq, the statistics), ``kv_rows(width)`` for k and v,
-    read at the group's head."""
+def _stat_rows(bq: int, nq: int, index):
+    """Block spec of the backward's ``lse`` and ``D`` rows, ``[b·h·nq, 1,
+    bq]``: the row of the query block that ``index``, a query-rows index
+    map, names. The block is the array's last two dimensions whole, which
+    Mosaic takes at any ``bq``: a ``(1, bq)`` block of a ``[1, t]`` row
+    would need ``bq`` a multiple of 128 or the whole padded sequence."""
     from jax.experimental import pallas as pl
 
+    def at(*a):
+        row, qi, _ = index(*a)
+        return row * nq + qi, 0, 0
+
+    return pl.BlockSpec((1, 1, bq), at)
+
+
+def _specs_by_query(steps: _Steps, bq: int, bk: int, nq: int, kv_of):
+    """Block specs of the forward and dQ kernels, whose steps go query
+    block by query block: ``q_rows(width)`` for what a query head's rows
+    hold (q, out, dO, dq, the forward's m and l), ``kv_rows(width)`` for k
+    and v, read at the group's head, and ``stat_rows`` for the backward's
+    statistics."""
+    from jax.experimental import pallas as pl
+
+    def index(bh, *at):
+        return bh, steps.blocks(*at)[0], 0
+
     def q_rows(width):
-        return pl.BlockSpec(
-            (1, bq, width),
-            lambda bh, *at: (bh, steps.blocks(*at)[0], 0),
-        )
+        return pl.BlockSpec((1, bq, width), index)
 
     def kv_rows(width):
         return pl.BlockSpec(
@@ -381,7 +405,7 @@ def _specs_by_query(steps: _Steps, bq: int, bk: int, kv_of):
             lambda bh, *at: (kv_of(bh), steps.blocks(*at)[2], 0),
         )
 
-    return q_rows, kv_rows
+    return q_rows, kv_rows, _stat_rows(bq, nq, index)
 
 
 def _flash_forward(
@@ -417,7 +441,7 @@ def _flash_forward(
     steps = _Steps(
         _blocks_with_work(tq_pad // bq, tk_pad // bk, bq, bk, causal, window)
     )
-    q_rows, kv_rows = _specs_by_query(steps, bq, bk, kv_of)
+    q_rows, kv_rows, _ = _specs_by_query(steps, bq, bk, tq_pad // bq, kv_of)
     out, m, l = pl.pallas_call(
         functools.partial(
             _flash_kernel,
@@ -456,35 +480,37 @@ def _flash_forward(
     return out, m[:, :t, 0].reshape(b, h, t), l[:, :t, 0].reshape(b, h, t)
 
 
-def _bwd_probs(q, k, m, l, ki, scale, causal, block_q, block_k, seq_len, qi,
-               window=None, masked=True):
-    """Shared backward-kernel algebra: recompute the normalized
-    probability block from the saved statistics."""
+def _bwd_probs(a, b, lse, scale, masked, keys_axis, qi, ki, block_q, block_k,
+               seq_len, causal, window=None):
+    """Shared backward-kernel algebra: the probability block ``exp(a @ bᵀ ·
+    scale − lse)`` recomputed from the saved log-sum-exp, whose keys lie
+    along ``keys_axis``: 1 for a query-major block (``a`` the queries,
+    ``lse`` a column), 0 for a key-major one (``a`` the keys, ``lse`` a
+    row). ``lse`` is ``+inf`` on a row with no admitted key, padded rows
+    among them, so that it is 0 there with no guard."""
     s = (
         jax.lax.dot_general(
-            q,
-            k,
+            a,
+            b,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         * scale
-    )  # [bq, bk]
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (q.shape[0], k.shape[0]), 1
     )
     if (causal or seq_len % block_k != 0) and masked:
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, keys_axis
+        )
         valid = k_pos < seq_len
         if causal:
             q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (q.shape[0], k.shape[0]), 0
+                jnp.int32, s.shape, 1 - keys_axis
             )
             valid = valid & (q_pos >= k_pos)
             if window is not None:
                 valid = valid & (q_pos - k_pos < window)
         s = jnp.where(valid, s, NEG_INF)
-    p = jnp.exp(s - m) / jnp.maximum(l, 1e-30)
-    # Fully-masked rows kept m at NEG_INF and must contribute nothing.
-    return jnp.where(m > NEG_INF / 2, p, 0.0)
+    return jnp.exp(s - lse)
 
 
 def _flash_bwd_dkv_kernel(
@@ -501,17 +527,23 @@ def _flash_bwd_dkv_kernel(
     block with work of one query head of its group (``steps``: a key
     block's in a row, head after head); the dk/dv accumulators live in
     VMEM and are revisited across all of them. ``refs``: the steps' tables
-    if any, ``q, k, v, dO, m, l, D``, the outputs ``dk, dv``, the scratch.
+    if any, ``q, k, v, dO``, the ``lse`` and ``D`` rows, the outputs ``dk,
+    dv``, the scratch.
 
-        p  = softmax block recomputed from (m, l)
+    Key-major: the block is ``[bk, bq]``, keys on the sublanes and queries
+    on the lanes, so both accumulating products contract the block's minor
+    dimension and the statistics are ``[1, bq]`` rows broadcast down the
+    sublanes::
+
+        pᵀ  = exp(k @ qᵀ · scale − lse)
         dv += pᵀ @ dO
-        dp = dO @ vᵀ ; ds = p ⊙ (dp - D)
+        dsᵀ = pᵀ ⊙ (v @ dOᵀ − D)
         dk += dsᵀ @ q · scale
     """
     from jax.experimental import pallas as pl
 
     (
-        *tables, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, d_ref, dk_ref, dv_ref,
+        *tables, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref, dv_ref,
         dk_scr, dv_scr,
     ) = refs
     at = steps.here(*tables)
@@ -529,27 +561,21 @@ def _flash_bwd_dkv_kernel(
         v = v_ref[0]
         do = do_ref[0].astype(jnp.float32)
         p = _bwd_probs(
-            q, k, m_ref[0], l_ref[0], ki, scale, causal, block_q,
-            block_k, seq_len, qi, window, masked,
-        )
-        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
-            p,
-            do,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            k, q, lse_ref[0], scale, masked, 0, qi, ki, block_q, block_k,
+            seq_len, causal, window,
+        )  # [bk, bq]
+        dv_scr[...] = dv_scr[...] + jax.lax.dot(
+            p, do, preferred_element_type=jnp.float32
         )
         dp = jax.lax.dot_general(
-            do,
             v,
+            do,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         ds = p * (dp - d_ref[0])
-        dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
-            ds,
-            q,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        dk_scr[...] = dk_scr[...] + jax.lax.dot(
+            ds, q, preferred_element_type=jnp.float32
         ) * scale
 
     _update_block(runs, qi, ki, block_q, block_k, window, _update)
@@ -570,12 +596,21 @@ def _flash_bwd_dq_kernel(
     seq_len: int,
     window: Optional[int] = None,
 ):
-    """dQ: the forward's grid and ``refs`` but for ``dO, m, l, D`` after
-    ``v`` and the one output; ``dq += ds @ k · scale`` accumulates in VMEM
-    across a query block's key blocks."""
+    """dQ: the forward's grid and ``refs`` but for ``dO`` and the ``lse``
+    and ``D`` rows after ``v`` and the one output; ``dq += ds @ k · scale``
+    accumulates in VMEM across a query block's key blocks.
+
+    Query-major, since ``ds @ k`` contracts the keys: the block is ``[bq,
+    bk]`` and the statistics are wanted as columns. A query block's first
+    step turns its two rows into columns once, into scratch with the lanes
+    replicated (as the forward keeps its running max); the inner steps read
+    them there."""
     from jax.experimental import pallas as pl
 
-    *tables, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, d_ref, dq_ref, dq_scr = refs
+    (
+        *tables, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
+        lse_scr, d_scr, dq_scr,
+    ) = refs
     at = steps.here(*tables)
     qi, _, ki = steps.blocks(*at)
     first, last, runs = steps.edges(*at)
@@ -583,6 +618,9 @@ def _flash_bwd_dq_kernel(
     @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
+        lanes = (lse_scr.shape[1], block_q)
+        lse_scr[...] = jnp.broadcast_to(lse_ref[0], lanes).T
+        d_scr[...] = jnp.broadcast_to(d_ref[0], lanes).T
 
     def _update(masked=True):
         q = q_ref[0]
@@ -590,16 +628,16 @@ def _flash_bwd_dq_kernel(
         v = v_ref[0]
         do = do_ref[0].astype(jnp.float32)
         p = _bwd_probs(
-            q, k, m_ref[0], l_ref[0], ki, scale, causal, block_q,
-            block_k, seq_len, qi, window, masked,
-        )
+            q, k, lse_scr[:, :1], scale, masked, 1, qi, ki, block_q, block_k,
+            seq_len, causal, window,
+        )  # [bq, bk]
         dp = jax.lax.dot_general(
             do,
             v,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        ds = p * (dp - d_ref[0])
+        ds = p * (dp - d_scr[:, :1])
         dq_scr[...] = dq_scr[...] + jax.lax.dot(
             ds,
             k.astype(jnp.float32),
@@ -619,9 +657,20 @@ def _flash_backward_pallas(
     """Fused flash backward: two Pallas kernels (dK/dV, a key block's
     query blocks in a row, and dQ, a query block's key blocks) consuming
     the forward's saved statistics — no stats-recompute pass and no
-    ``[T, T]`` block in HBM. ``D`` (the
-    softmax-jacobian diagonal term rowsum(ct ⊙ out)) is a cheap XLA
-    elementwise-reduce."""
+    ``[T, T]`` block in HBM.
+
+    The kernels read two lane-dense float32 rows a query head, ``[b·h·nq,
+    1, bq]`` (plain reshapes of ``[b, h, t]``, so no relayout; a query
+    block's row is an array's last two dimensions whole, which Mosaic takes
+    at any ``bq``, under 128 lanes too): the
+    log-sum-exp ``lse = m + log l``, ``+inf`` where ``l`` is 0 (a padded
+    row, or one that admits no key), so that ``p = exp(s − lse)`` needs no
+    divide and no guard, and ``D = rowsum(ct ⊙ out)`` (the softmax
+    jacobian's diagonal term, one XLA reduce). dK/dV runs key-major on
+    them (keys on the sublanes: no ``[bq, bk]`` block is transposed for its
+    two products, and a statistic's block is a row of ``bq``); dQ contracts
+    the keys, so it stays query-major and turns a query block's rows into
+    columns once, at the block's first step."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -637,13 +686,12 @@ def _flash_backward_pallas(
     tk_pad = -(-t // bk) * bk
     nq = tq_pad // bq
 
-    def rows_bh(x, t_pad, fill=0.0):  # [b, h, t] -> [bh, t_pad, 1]
-        x = x.reshape(b * h, t, 1)
-        if t_pad != t:
+    def row_bh(x, fill):  # [b, h, t] -> [bh * nq, 1, bq]
+        if tq_pad != t:
             x = jnp.pad(
-                x, ((0, 0), (0, t_pad - t), (0, 0)), constant_values=fill
+                x, ((0, 0), (0, 0), (0, tq_pad - t)), constant_values=fill
             )
-        return x
+        return x.reshape(b * h * nq, 1, bq)
 
     qb = _to_bh(q, tq_pad)
     kb = _to_bh(k, tk_pad)
@@ -651,17 +699,15 @@ def _flash_backward_pallas(
     # Native dtype: the kernels cast each dO block to f32 on load, so a
     # host-side f32 copy would only double dO's HBM traffic.
     dob = _to_bh(ct, tq_pad)
-    # Padded q rows carry m = -inf so the kernels' live-row guard
-    # (m > NEG_INF/2) zeroes them directly, rather than relying on the
-    # zero-padded q/dO rows keeping exp(0)/1e-30 products finite*0.
-    mb = rows_bh(m, tq_pad, fill=NEG_INF)
-    lb = rows_bh(l, tq_pad)
-    big_d = jnp.einsum(
-        "bqhd,bqhd->bhq",
-        ct.astype(jnp.float32),
-        out.astype(jnp.float32),
+    lse = row_bh(jnp.where(l > 0, m + jnp.log(l), jnp.inf), jnp.inf)
+    big_d = row_bh(
+        jnp.einsum(
+            "bqhd,bqhd->bhq",
+            ct.astype(jnp.float32),
+            out.astype(jnp.float32),
+        ),
+        0.0,
     )
-    db = rows_bh(big_d, tq_pad)
 
     work = _blocks_with_work(nq, tk_pad // bk, bq, bk, causal, window)
     of_kernel = dict(
@@ -672,11 +718,11 @@ def _flash_backward_pallas(
     # dK/dV: a key block's query blocks, for each query head of its group.
     by_key = _Steps(work.T, group)
 
-    def q_rows(width):  # q, dO and the statistics: a query head's block
-        def index(bkv, *at):
-            _, member, qi = by_key.blocks(*at)
-            return (bkv // hk) * h + (bkv % hk) * group + member, qi, 0
+    def index(bkv, *at):  # q, dO and the statistics: a query head's block
+        _, member, qi = by_key.blocks(*at)
+        return (bkv // hk) * h + (bkv % hk) * group + member, qi, 0
 
+    def q_rows(width):
         return pl.BlockSpec((1, bq, width), index)
 
     def kv_rows(width):  # k, dk [.., d] and v, dv [.., dv]
@@ -685,12 +731,13 @@ def _flash_backward_pallas(
             lambda bkv, *at: (bkv, by_key.blocks(*at)[0], 0),
         )
 
+    stat_rows = _stat_rows(bq, nq, index)
     dkb, dvb = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, steps=by_key, **of_kernel),
         **by_key.call(
             b * hk,
             in_specs=[q_rows(d), kv_rows(d), kv_rows(dv), q_rows(dv),
-                      q_rows(1), q_rows(1), q_rows(1)],
+                      stat_rows, stat_rows],
             out_specs=[kv_rows(d), kv_rows(dv)],
             scratch_shapes=[
                 pltpu.VMEM((bk, d), jnp.float32),
@@ -703,24 +750,30 @@ def _flash_backward_pallas(
         ],
         interpret=interpret,
         name=_kernel_name(window, "bwd_dkv"),
-    )(*by_key.tables, qb, kb, vb, dob, mb, lb, db)
+    )(*by_key.tables, qb, kb, vb, dob, lse, big_d)
 
     # dQ: the forward's steps.
     by_query = _Steps(work)
-    q_rows2, kv_rows2 = _specs_by_query(by_query, bq, bk, kv_of)
+    q_rows2, kv_rows2, stat_rows2 = _specs_by_query(
+        by_query, bq, bk, nq, kv_of
+    )
     dqb = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, steps=by_query, **of_kernel),
         **by_query.call(
             b * h,
             in_specs=[q_rows2(d), kv_rows2(d), kv_rows2(dv), q_rows2(dv),
-                      q_rows2(1), q_rows2(1), q_rows2(1)],
+                      stat_rows2, stat_rows2],
             out_specs=q_rows2(d),
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 128), jnp.float32),  # lse, lanes replicated
+                pltpu.VMEM((bq, 128), jnp.float32),  # D, lanes replicated
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, tq_pad, d), q.dtype),
         interpret=interpret,
         name=_kernel_name(window, "bwd_dq"),
-    )(*by_query.tables, qb, kb, vb, dob, mb, lb, db)
+    )(*by_query.tables, qb, kb, vb, dob, lse, big_d)
 
     def from_bh(x):
         x = x[:, :t].reshape(b, -1, t, x.shape[-1])

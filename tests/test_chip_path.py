@@ -47,13 +47,19 @@ def test_interaction_kernel_lowers_for_tpu():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("head_dim", [32, 128])
-def test_flash_kernels_lower_for_tpu(head_dim, causal):
+@pytest.mark.parametrize(
+    "head_dim,seq,blocks", [(32, 300, (128, 128)), (128, 300, (128, 128)),
+                            (64, 64, (32, 32)), (64, 64, (32, 16))],
+)
+def test_flash_kernels_lower_for_tpu(head_dim, seq, blocks, causal):
     """Forward, dK/dV and dQ kernels at a sequence length (300) that is
-    not a multiple of the 128 block."""
-    q = jax.ShapeDtypeStruct((2, 300, 2, head_dim), jnp.bfloat16)
+    not a multiple of the 128 block, and at the toy models' blocks of 32
+    over 64 tokens, under a vreg's 128 lanes and shorter than the
+    sequence."""
+    q = jax.ShapeDtypeStruct((2, seq, 2, head_dim), jnp.bfloat16)
     attn = lambda q, k, v: flash_attention(
-        q, k, v, causal=causal, use_pallas=True
+        q, k, v, causal=causal, use_pallas=True,
+        block_q=blocks[0], block_k=blocks[1],
     )
     _lowers_for_tpu(attn, q, q, q)
     text = _lowers_for_tpu(

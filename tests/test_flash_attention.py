@@ -136,10 +136,27 @@ def test_the_steps_are_the_blocks_the_mask_admits_a_score_in(
     )
 
 
-def _causal_loss(window, bq, bk):
+# (seq, block_q, block_k, group, value_dim), heads of 8 dimensions: blocks that
+# divide the sequence; the rehearsal's unequal blocks either way round over a
+# sequence that is a multiple of neither (padded rows), values wider than the
+# heads; eight query heads to a key head; the default blocks over a ragged tail.
+_BACKWARD_SHAPES = [
+    (32, 16, 16, 1, 8),
+    (75, 32, 16, 2, 16),
+    (90, 16, 32, 4, 16),
+    (64, 32, 16, 8, 8),
+    (300, 128, 128, 1, 8),
+]
+# Without ``causal`` every block holds work: no table, so no runs either.
+_BACKWARD_MASKS = [(False, None, flash_module.MAX_TABLE_ENTRIES)] + [
+    (True, w, limit) for w in _WINDOWS for limit in (flash_module.MAX_TABLE_ENTRIES, 6)
+]
+
+
+def _loss(causal, window, bq, bk):
     def loss(q, k, v):
         out = flash_attention(
-            q, k, v, causal=True, use_pallas=True, interpret=True,
+            q, k, v, causal=causal, use_pallas=True, interpret=True,
             block_q=bq, block_k=bk, window=window,
         )
         return jnp.sum(out.astype(jnp.float32) ** 2)
@@ -147,43 +164,83 @@ def _causal_loss(window, bq, bk):
     return loss
 
 
-@pytest.mark.parametrize("limit", [flash_module.MAX_TABLE_ENTRIES, 6])
-@pytest.mark.parametrize("window", _WINDOWS)
-@pytest.mark.parametrize("seq,bq,bk,group", [(75, 32, 16, 2), (90, 16, 32, 4)])
-def test_wide_values_over_any_grid_match_the_dense_reference(
-    monkeypatch, seq, bq, bk, group, window, limit
+@pytest.mark.parametrize("causal,window,limit", _BACKWARD_MASKS)
+@pytest.mark.parametrize("seq,bq,bk,group,dv", _BACKWARD_SHAPES)
+def test_the_backward_is_the_dense_reference_s_gradient(
+    monkeypatch, seq, bq, bk, group, dv, causal, window, limit
 ):
-    """Forward and gradients with ``value_dim != head_dim``, grouped heads,
-    unequal blocks over a sequence that is a multiple of neither, every
-    kind of window, the tables whole and in runs."""
+    """Value and gradients of the fused kernels against ``jax.grad`` of the
+    dense reference: causal or not, every kind of window, groups of 1 to 8,
+    values wider than the heads, padded rows, unequal blocks, the tables
+    whole and in runs."""
     monkeypatch.setattr(flash_module, "MAX_TABLE_ENTRIES", limit)
-    rng = np.random.default_rng(seq + (window or 0))
+    heads = max(group, 4)
+    rng = np.random.default_rng(seq + group + (window or 0))
     normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    q, k, v = normal(2, seq, 4, 8), normal(2, seq, 4 // group, 8), normal(2, seq, 4 // group, 16)
+    q = normal(2, seq, heads, 8)
+    k, v = normal(2, seq, heads // group, 8), normal(2, seq, heads // group, dv)
 
     def dense(q, k, v):
         out = attention_reference(
             q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
-            causal=True, window=None if window is None or window >= seq else window,
+            causal=causal, window=None if window is None or window >= seq else window,
         )
         return jnp.sum(out ** 2)
 
-    got = jax.value_and_grad(_causal_loss(window, bq, bk), (0, 1, 2))(q, k, v)
+    got = jax.value_and_grad(_loss(causal, window, bq, bk), (0, 1, 2))(q, k, v)
     want = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4, atol=1e-4)
+
+
+def _pallas_calls(jaxpr):
+    """``{name: equation}`` of every Pallas call under ``jaxpr``."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = eqn
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.update(_pallas_calls(sub))
+    return found
 
 
 def _pallas_grids(jaxpr):
     """``{name: grid}`` of every Pallas call under ``jaxpr``."""
-    found = {}
+    return {
+        name: tuple(eqn.params["grid_mapping"].grid)
+        for name, eqn in _pallas_calls(jaxpr).items()
+    }
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` under ``jaxpr``, into its ``cond``s' branches."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found[eqn.params["name"]] = tuple(eqn.params["grid_mapping"].grid)
-            continue
+        if eqn.primitive.name == "dot_general":
+            yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found.update(_pallas_grids(sub))
-    return found
+            yield from _dots(sub)
+
+
+@pytest.mark.parametrize("window", [None, 16])
+def test_the_backward_reads_lane_dense_rows_and_runs_dkv_key_major(window):
+    """No operand of a backward kernel is a column (``[.., t, 1]``: the
+    statistics are ``lse`` and ``D`` rows), and no product of dK/dV
+    contracts its left operand's first dimension (a ``[bq, bk]`` block
+    transposed): both accumulate ``[bk, bq] @ [bq, d]``. A windowed call
+    traces the body twice, for blocks inside the band and on its edges."""
+    q, k, v = _qkv((1, 64, 4, 8), seed=13)
+    k, v = k[:, :, :2], v[:, :, :2]
+    grad = jax.grad(_loss(True, window, 32, 16), (0, 1, 2))
+    calls = _pallas_calls(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+    dkv, dq = (flash_module._kernel_name(window, w) for w in ("bwd_dkv", "bwd_dq"))
+    assert sorted(n for n in calls if "_bwd_" in n) == sorted([dkv, dq])
+    for name in (dkv, dq):
+        shapes = [x.aval.shape for x in calls[name].invars]
+        assert [s for s in shapes if s[-1:] == (1,)] == [], (name, shapes)
+    contracted = [e.params["dimension_numbers"][0][0] for e in _dots(calls[dkv].params["jaxpr"])]
+    assert len(contracted) == (4 if window is None else 8)
+    assert all(0 not in c for c in contracted), contracted
 
 
 def test_a_causal_head_takes_a_step_a_block_with_work_and_the_step_says_so(
@@ -204,7 +261,7 @@ def test_a_causal_head_takes_a_step_a_block_with_work_and_the_step_says_so(
 
     q = jax.ShapeDtypeStruct((1, 8192, 48, 128), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
-    grad = jax.grad(_causal_loss(None, 512, 512), (0, 1, 2))
+    grad = jax.grad(_loss(True, None, 512, 512), (0, 1, 2))
     nq = 8192 // 512
     assert _pallas_grids(jax.make_jaxpr(grad)(q, kv, kv).jaxpr) == {
         "flash_attention_fwd": (48, nq * (nq + 1) // 2),
@@ -262,56 +319,6 @@ def test_bfloat16(seed=3):
         rtol=5e-2,
         atol=5e-2,
     )
-
-
-def test_gradients_exact():
-    """The custom VJP is the dense reference's gradient — exact."""
-    q, k, v = _qkv((1, 32, 2, 8), seed=4)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(
-            flash_attention(
-                q, k, v, causal=True, use_pallas=True,
-                block_q=16, block_k=16, interpret=True,
-            )
-            ** 2
-        )
-
-    def loss_dense(q, k, v):
-        return jnp.sum(attention_reference(q, k, v, causal=True) ** 2)
-
-    g_f = jax.grad(loss_flash, (0, 1, 2))(q, k, v)
-    g_d = jax.grad(loss_dense, (0, 1, 2))(q, k, v)
-    for gf, gd in zip(g_f, g_d):
-        np.testing.assert_allclose(
-            np.asarray(gf), np.asarray(gd), rtol=1e-4, atol=1e-4
-        )
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_gradients_multi_chunk_ragged(causal):
-    """Backward with several KV chunks and a ragged tail (T=300 over
-    128-wide chunks) — the chunked-VJP path the single-chunk test
-    misses."""
-    q, k, v = _qkv((1, 300, 2, 8), seed=6)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(
-            flash_attention(
-                q, k, v, causal=causal, use_pallas=True, interpret=True
-            )
-            ** 2
-        )
-
-    def loss_dense(q, k, v):
-        return jnp.sum(attention_reference(q, k, v, causal=causal) ** 2)
-
-    g_f = jax.grad(loss_flash, (0, 1, 2))(q, k, v)
-    g_d = jax.grad(loss_dense, (0, 1, 2))(q, k, v)
-    for gf, gd in zip(g_f, g_d):
-        np.testing.assert_allclose(
-            np.asarray(gf), np.asarray(gd), rtol=1e-3, atol=1e-4
-        )
 
 
 def test_gradients_sharded_mesh():

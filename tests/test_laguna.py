@@ -182,9 +182,10 @@ def _traced(jaxpr) -> str:
 
 @pytest.fixture(scope="module")
 def pinned_traces():
-    """Digests of the traced text as ISSUE 35 left it (the attention
-    kernels' grids over the blocks with work; before it the pins were
-    those of ``01a534d`` and of ISSUE 33). A change to ``models/blocks.py``
+    """Digests of the traced text as ISSUE 37 left it (the fused backward
+    on lane-dense ``lse`` and ``D`` rows, dK/dV key-major; before it the
+    pins were ISSUE 35's, the kernels' grids over the blocks with work, and
+    before that ``01a534d``'s and ISSUE 33's). A change to ``models/blocks.py``
     or ``ops/flash_attention.py`` that is not meant to move LFM2's step
     leaves them; one that is pins again and says why. A jaxpr's text
     belongs to one jax version."""
@@ -244,7 +245,8 @@ def test_lfm2_s_traced_step_is_the_pinned_one(pinned_traces):
     (ISSUE 32, 34); ISSUE 33 named the routing and the plan in
     ``ops/moe.py`` (nine ``name`` equations an expert layer); ISSUE 35
     gave the three attention kernels their tables and a grid of two axes,
-    and pinned the step again as it then stood."""
+    and ISSUE 37 the backward kernels their statistics as rows: each pinned
+    the step again as it then stood."""
     traced = _lfm2_step_traced()
     assert str(traced).count(f"name[name={moe.ROUTING}]") == pinned_traces["names"] == 9 * 4
     assert _traced(traced) == pinned_traces["step"]
