@@ -254,30 +254,30 @@ def main(argv) -> int:
     # accumulating in bfloat16 over 32 or more terms would miss it.
     TOL = 4 * 2.0**-8
 
+    def with_grads(fn, *args, ct):
+        """``fn``'s value and its cotangents for ``ct``, in one jit."""
+
+        def run(*args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out, *vjp(ct.astype(out.dtype)))
+
+        return jax.jit(run)(*args)
+
+    def reference(fn, *args, ct):
+        """The same from ``fn`` in float32 at full matmul precision (a
+        TPU runs a float32 matmul in bfloat16 passes unless told)."""
+        with jax.default_matmul_precision("highest"):
+            return with_grads(
+                lambda *a: fn(*(x.astype(jnp.float32) for x in a)),
+                *args,
+                ct=ct,
+            )
+
+    def bf16(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
     @phase("kernels")
     def _kernels():
-        def with_grads(fn, *args, ct):
-            """``fn``'s value and its cotangents for ``ct``, in one jit."""
-
-            def run(*args):
-                out, vjp = jax.vjp(fn, *args)
-                return (out, *vjp(ct.astype(out.dtype)))
-
-            return jax.jit(run)(*args)
-
-        def reference(fn, *args, ct):
-            """The same from ``fn`` in float32 at full matmul precision (a
-            TPU runs a float32 matmul in bfloat16 passes unless told)."""
-            with jax.default_matmul_precision("highest"):
-                return with_grads(
-                    lambda *a: fn(*(x.astype(jnp.float32) for x in a)),
-                    *args,
-                    ct=ct,
-                )
-
-        def bf16(*shape):
-            return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-
         errs = {}
         # DLRM interaction at the model's shape; kernel_batch + 100 rows is
         # not a multiple of the 256-row tile, so the padded tail tile runs.
@@ -316,6 +316,61 @@ def main(argv) -> int:
                     errs[f"flash d={d} t={t} causal={causal} {name}"] = (
                         rel_err(g, w)
                     )
+        for name, err in errs.items():
+            say(f"  {name}: rel err {err:.2e} (limit {TOL:.2e})")
+            assert err < TOL, (name, err)
+
+    # Learned sparse attention at small blocks (query blocks of 256, key
+    # blocks of 128: the words' block is 8 sublanes and the key blocks a
+    # lane tile), 16 indexer heads of 64 and 8 query heads over 2 of 128,
+    # each kernel against its XLA path on the chip.
+    @phase("sparse kernels")
+    def _sparse_kernels():
+        from ray_shuffling_data_loader_tpu.ops import sparse_attention as sa
+
+        t, bq, bk, topk = (128, 32, 16, 24) if rehearse else (512, 256, 128, 96)
+        f32 = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)  # noqa: E731
+        qi, ki, wi = f32(1, t, 16, 64), f32(1, t, 64), f32(1, t, 16)
+        kernel = dict(block_q=bq, block_k=bk, use_pallas=True, interpret=rehearse)
+        oracle = dict(block_q=bq, block_k=bk, use_pallas=False)
+        words, lse_i = jax.jit(
+            lambda *a: sa.index_select(*a, topk, **kernel))(qi, ki, wi)
+        want_words, want_lse = jax.jit(
+            lambda *a: sa.index_select(*a, topk, **oracle))(qi, ki, wi)
+        # Both score in float32 at the highest precision, in other orders:
+        # a pair of scores within a rounding of each other at the k-th place
+        # may fall either way; more than one pair in 10,000 moved is a fault.
+        got_sel, want_sel = (np.asarray(sa.unpack(w)) for w in (words, want_words))
+        moved = float(np.mean(got_sel != want_sel)) * t / topk
+        say(f"  sparse_index_fwd: {moved:.2e} of the selected pairs moved (limit 1e-4)")
+        assert moved <= 1e-4, moved
+        assert int(got_sel.sum()) == sa.selected_pairs(t, topk)
+        q, k, v, ct = bf16(1, t, 8, 128), bf16(1, t, 2, 128), bf16(1, t, 2, 128), bf16(1, t, 8, 128)
+        errs = {}
+        got = with_grads(
+            lambda q, k, v: flash_attention(q, k, v, causal=True, selected=want_words,
+                                            **kernel)[0],
+            q, k, v, ct=ct,
+        )
+        want = reference(
+            lambda q, k, v: flash_attention(q, k, v, causal=True, selected=want_words,
+                                            **oracle)[0],
+            q, k, v, ct=ct,
+        )
+        for name, g, w in zip(("fwd", "dQ", "dK", "dV"), got, want):
+            errs[f"flash_attention_sparse {name}"] = rel_err(g, w)
+        _, lse = flash_attention(q, k, v, causal=True, selected=want_words, **oracle)
+
+        def loss(how, lse_i):
+            return jax.jit(jax.value_and_grad(
+                lambda a, b, c: sa.index_loss(a, b, c, q, k, lse, lse_i, want_words, **how),
+                argnums=(0, 1, 2),
+            ))(qi, ki, wi)
+
+        (got_l, got_g), (want_l, want_g) = loss(kernel, want_lse), loss(oracle, want_lse)
+        errs["sparse_index_bwd loss"] = abs(float(got_l) - float(want_l)) / abs(float(want_l))
+        for name, g, w in zip(("dq", "dk", "dw"), got_g, want_g):
+            errs[f"sparse_index_bwd {name}"] = rel_err(g, w)
         for name, err in errs.items():
             say(f"  {name}: rel err {err:.2e} (limit {TOL:.2e})")
             assert err < TOL, (name, err)

@@ -160,7 +160,13 @@ class Attention(nn.Module):
     k (after an RMS norm on each head's q and k where ``qk_norm_eps`` is
     given), every key up to the query's own or the last ``window`` of
     them, an output projection. ``scope_name`` names its operations in a
-    trace."""
+    trace.
+
+    Called with ``selected`` (``ops/sparse_attention.py``'s words), a query
+    sees only the causal keys the selection keeps, and the call returns
+    ``(y, q, k, lse)``: beside the output, the heads' q and k as the
+    kernels read them and the log-sum-exp of each query's scores (``[b, h,
+    t]``), for an indexer's loss."""
 
     heads: int
     kv_heads: int
@@ -176,7 +182,7 @@ class Attention(nn.Module):
     scope_name: str = "attention"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, selected=None):
         h, d = x.shape[-1], self.head_dim
         nq, nkv = self.heads, self.kv_heads
         wq = self.param("q_proj", fan_in((h, nq * d)), (h, nq * d))
@@ -197,9 +203,12 @@ class Attention(nn.Module):
                 q, k, v, causal=True, use_pallas=self.use_pallas,
                 interpret=self.interpret,
                 block_q=self.block_q, block_k=self.block_k,
-                window=self.window,
+                window=self.window, selected=selected,
             )
-            return jnp.dot(out.reshape(b, t, nq * d), wo.astype(self.dtype))
+            if selected is not None:
+                out, lse = out
+            y = jnp.dot(out.reshape(b, t, nq * d), wo.astype(self.dtype))
+            return y if selected is None else (y, q, k, lse)
 
 
 class DenseFFN(nn.Module):
@@ -238,6 +247,7 @@ class Experts:
     selection_bias: bool
     norm_topk: bool = True
     scaling: float = 1.0
+    scoring: str = "sigmoid"
 
 
 class ExpertFFN(nn.Module):
@@ -270,6 +280,7 @@ class ExpertFFN(nn.Module):
         with jax.named_scope("router"):
             experts, weights = moe.route(
                 tokens, gate, bias, spec.top_k, spec.norm_topk, spec.scaling,
+                spec.scoring,
             )
         with jax.named_scope("experts"):
             y, load, dropped, fallback = moe.experts_ffn(
@@ -309,8 +320,12 @@ class SequenceLM(nn.Module):
     layers], "moe_fallback": [expert layers]}``: the tokens routed to each
     held expert, the assignments left out, and 1 where the layer ran in the
     worst-case buffer; ``{}`` from a model that holds no experts
-    (``cfg.experts_held`` 0). ``logits=True`` returns the logits instead
-    (float32 ``[batch, seq, vocab]``: a test's size only).
+    (``cfg.experts_held`` 0). A layer whose counts carry a ``"loss"``
+    (a scalar: an indexer's loss) adds it to the loss, and one whose counts
+    carry a ``"select"`` (``ops/sparse_attention.py`` ``select_counts``)
+    adds the layers' sum under ``"sparse_select"``. ``logits=True``
+    returns the logits instead (float32 ``[batch, seq, vocab]``: a test's
+    size only).
 
     A model gives ``cfg`` (``vocab_size``, ``hidden_size``, ``norm_eps``,
     ``experts_held``, ``layers()``: what each layer kept is, its published
@@ -402,9 +417,12 @@ class SequenceLM(nn.Module):
             )
         with jax.named_scope("embed"):
             x = jnp.take(embed, tokens, axis=0).astype(dt)
-        counts, handed = [], ()
+        counts, handed, terms = [], (), []
         for of_layer in cfg.layers():
             x, layer_counts, *handed = self.recomputed_layer(*of_layer)(x, *handed)
+            if "loss" in layer_counts:
+                layer_counts = dict(layer_counts)
+                terms.append(layer_counts.pop("loss"))
             if layer_counts:
                 counts.append(layer_counts)
         x = self.final_norm(dt)(x)
@@ -414,11 +432,15 @@ class SequenceLM(nn.Module):
             if counts else jnp.zeros(shape, jnp.int32)
             for name, shape in none.items()
         } if cfg.experts_held else {}
+        selects = [c["select"] for c in counts if "select" in c]
+        if selects:
+            counters["sparse_select"] = sum(selects)
         with jax.named_scope("head"):
             head = head.astype(dt)
             if logits:
                 return jnp.dot(x, head, preferred_element_type=jnp.float32)
-            return next_token_loss(x, head, tokens), counters
+            loss = next_token_loss(x, head, tokens)
+        return loss + sum(terms) if terms else loss, counters
 
 
 def next_token_loss(x: jax.Array, head: jax.Array, tokens: jax.Array):

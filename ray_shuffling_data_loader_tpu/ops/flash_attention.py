@@ -47,7 +47,12 @@ one block) the grid stays the rectangle of the blocks, with no table: an
 index map that reads one costs a step about 0.02 µs an operand. The mask is
 applied in every block of a plain call and, with a window, only in the
 blocks that the band's two edges cut; the windowed Pallas calls are named
-``flash_attention_window_*``.
+``flash_attention_window_*``. A call with a learned selection (``selected``,
+the packed words of :mod:`.sparse_attention`) masks every block by it too
+and steps through the causal blocks that hold a selected pair, from tables
+made on the device each batch (:class:`_SelectedSteps`); its Pallas calls
+are named ``flash_attention_sparse_*``. Without one the kernels are traced
+as they were.
 
 Differentiability: the kernel carries an exact, memory-safe custom VJP.
 The forward emits its softmax row statistics (m, l) as outputs; the
@@ -85,6 +90,13 @@ from ray_shuffling_data_loader_tpu.ops.placement import (
     MODEL_AXIS,
     auto_pallas,
     over_mesh,
+)
+from ray_shuffling_data_loader_tpu.ops.sparse_attention import (
+    WORD,
+    block_work,
+    check_blocks,
+    expand,
+    unpack,
 )
 from ray_shuffling_data_loader_tpu.ops.ring_attention import (
     NEG_INF,
@@ -186,6 +198,12 @@ class _Steps:
 
         return *(pl.program_id(1 + a) for a in range(len(self.grid))), *table_refs
 
+    def mapped(self, bh, at):
+        """What :meth:`blocks` takes of an index map's arguments ``(bh,
+        *at)``: ``at`` (the batch · head row matters to a table that differs
+        by batch, :class:`_SelectedSteps`)."""
+        return at
+
     def _entry(self, step):
         if self.width == 1:
             return step, 0
@@ -231,6 +249,78 @@ def grid_steps(seq_len, block_q, block_k, causal=True, window=None):
     return _Steps(work).length, int(work.sum())
 
 
+class _SelectedSteps(_Steps):
+    """The steps of a call with a selection (``selected``): of the blocks
+    the causal mask admits a score in (``work [outer, inner]``, fixed at
+    trace time), those that hold a selected pair of a batch (``live [batch,
+    outer, inner]``, on the device), in :class:`_Steps`'s order, each
+    batch's its own. The tables are made on the device each batch, one
+    entry a step, ``length`` entries a batch: the kept ones first, then the
+    last kept one repeated with ``count`` 0, which fetches nothing new and
+    computes nothing. An outer block's first entry is always kept, so that
+    every output block is written (with no selected pair it is zero).
+    ``rows`` is the grid's rows a batch (its heads)."""
+
+    def __init__(self, work: np.ndarray, live: jax.Array, rows: int,
+                 group: int = 1):
+        self.group, self.inner, self.width, self.rows = group, work.shape[1], 1, rows
+        outer, member, inner = (
+            np.asarray(x, np.int32)
+            for x in zip(*[
+                (o, g, i)
+                for o in range(len(work))
+                for g in range(group)
+                for i in np.flatnonzero(work[o])
+            ])
+        )
+        steps = len(outer)
+        first = np.r_[True, outer[1:] != outer[:-1]]
+        keep = live[:, outer, inner] | first  # [batch, steps]
+        order = jnp.argsort(jnp.logical_not(keep), axis=1, stable=True)
+        kept = jnp.sum(keep, axis=1, keepdims=True, dtype=jnp.int32)
+        e = jnp.arange(steps, dtype=jnp.int32)[None]
+        src = jnp.where(e < kept, order, jnp.take_along_axis(order, kept - 1, axis=1))
+        self.tables = (
+            *(jnp.asarray(x)[src].reshape(-1) for x in (outer, member, inner)),
+            (e < kept).astype(jnp.int32).reshape(-1),
+        )
+        self.grid = (steps,)
+        self.semantics = ("arbitrary",)
+
+    def here(self, *table_refs):
+        from jax.experimental import pallas as pl
+
+        return pl.program_id(0), pl.program_id(1), *table_refs
+
+    def mapped(self, bh, at):
+        return (bh, *at)
+
+    def _at(self, bh, step):
+        return (bh // self.rows) * self.grid[0] + step
+
+    def blocks(self, bh, step, outer, member, start, count):
+        e = self._at(bh, step)
+        return outer[e], member[e], start[e]
+
+    def edges(self, bh, step, outer, member, start, count):
+        e = self._at(bh, step)
+        after = jnp.minimum(e + 1, count.shape[0] - 1)
+        first = (step == 0) | (outer[jnp.maximum(e - 1, 0)] != outer[e])
+        last = (step == self.grid[0] - 1) | (count[after] == 0) | (
+            outer[after] != outer[e]
+        )
+        return first, last, count[e] > 0
+
+
+def _selected_block(sel_ref, block_q, key_major):
+    """A kernel's block of the selection from its words (bool, ``[bq,
+    bk]``, or ``[bk, bq]`` key-major)."""
+    block = expand(sel_ref[0], block_q)
+    if not key_major:
+        return block
+    return block.astype(jnp.int32).T == 1
+
+
 def _update_block(runs, qi, ki, block_q, block_k, window, update):
     """``update(masked)`` in a step that holds a block (``runs``; ``True``
     itself where every step does). A plain call masks every block; a
@@ -252,10 +342,11 @@ def _update_block(runs, qi, ki, block_q, block_k, window, update):
     pl.when(runs & jnp.logical_not(inside))(functools.partial(update, True))
 
 
-def _kernel_name(window, which: str) -> str:
-    """The Pallas call's name in a trace: the windowed kernels are a
-    population of their own."""
-    return "flash_attention_" + ("" if window is None else "window_") + which
+def _kernel_name(window, which: str, sparse: bool = False) -> str:
+    """The Pallas call's name in a trace: the windowed kernels, and those of
+    a call with a selection, are populations of their own."""
+    kind = "sparse_" if sparse else "" if window is None else "window_"
+    return "flash_attention_" + kind + which
 
 
 def _flash_kernel(
@@ -267,10 +358,12 @@ def _flash_kernel(
     block_k: int,
     seq_len: int,
     window: Optional[int] = None,
+    sparse: bool = False,
 ):
     """One grid cell: a query block against one of its key blocks with work
     (``steps``: a query block's in a row). ``refs``: the steps' tables if
-    any, ``q, k, v``, the outputs ``o, m, l``, the scratch.
+    any, ``q, k, v``, the selection's words where ``sparse``, the outputs
+    ``o, m, l``, the scratch.
 
     The output block is revisited across a query block's steps, carrying
     (running max, normalizer, accumulator) in VMEM scratch. The softmax
@@ -280,9 +373,12 @@ def _flash_kernel(
     """
     from jax.experimental import pallas as pl
 
-    (
-        *tables, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr
-    ) = refs
+    tables, refs = refs[: len(steps.tables)], refs[len(steps.tables):]
+    if sparse:
+        q_ref, k_ref, v_ref, sel_ref, *refs = refs
+    else:
+        (q_ref, k_ref, v_ref, *refs), sel_ref = refs, None
+    o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
     at = steps.here(*tables)
     qi, _, ki = steps.blocks(*at)
     first, last, runs = steps.edges(*at)
@@ -319,6 +415,8 @@ def _flash_kernel(
                 valid = valid & (q_pos >= k_pos)
                 if window is not None:
                     valid = valid & (q_pos - k_pos < window)
+            if sel_ref is not None:
+                valid = valid & _selected_block(sel_ref, block_q, False)
             s = jnp.where(valid, s, NEG_INF)
         m_prev = m_scr[:, :1]  # [bq, 1] (lanes replicated)
         l_prev = l_scr[:, :1]
@@ -394,7 +492,7 @@ def _specs_by_query(steps: _Steps, bq: int, bk: int, nq: int, kv_of):
     from jax.experimental import pallas as pl
 
     def index(bh, *at):
-        return bh, steps.blocks(*at)[0], 0
+        return bh, steps.blocks(*steps.mapped(bh, at))[0], 0
 
     def q_rows(width):
         return pl.BlockSpec((1, bq, width), index)
@@ -402,10 +500,25 @@ def _specs_by_query(steps: _Steps, bq: int, bk: int, nq: int, kv_of):
     def kv_rows(width):
         return pl.BlockSpec(
             (1, bk, width),
-            lambda bh, *at: (kv_of(bh), steps.blocks(*at)[2], 0),
+            lambda bh, *at: (kv_of(bh), steps.blocks(*steps.mapped(bh, at))[2], 0),
         )
 
     return q_rows, kv_rows, _stat_rows(bq, nq, index)
+
+
+def _selection_rows(steps: _Steps, bq: int, bk: int, nq: int, rows: int,
+                    key_major: bool):
+    """Block spec of the selection's words, ``[b·nq, R, t]``: the ``[R,
+    bk]`` words of a step's (query block, key block); ``rows`` grid rows a
+    batch."""
+    from jax.experimental import pallas as pl
+
+    def at(bh, *a):
+        outer, _, inner = steps.blocks(*steps.mapped(bh, a))
+        qi, ki = (inner, outer) if key_major else (outer, inner)
+        return (bh // rows) * nq + qi, 0, ki
+
+    return pl.BlockSpec((1, bq // WORD, bk), at)
 
 
 def _flash_forward(
@@ -418,10 +531,13 @@ def _flash_forward(
     interpret: bool,
     return_stats: bool = False,
     window: Optional[int] = None,
+    selected: Optional[jax.Array] = None,
 ):
     """Fused forward. With ``return_stats`` also returns the softmax row
     statistics ``(m, l)`` as float32 ``[b, h, t]`` — residuals for the
-    fused backward and merge inputs for the ring schedule."""
+    fused backward and merge inputs for the ring schedule. ``selected``
+    (the words of ``ops/sparse_attention.py``) admits a score only where
+    the selection does too, and the steps skip the blocks without one."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -438,9 +554,15 @@ def _flash_forward(
     kb = _to_bh(k, tk_pad)
     vb = _to_bh(v, tk_pad)
 
-    steps = _Steps(
-        _blocks_with_work(tq_pad // bq, tk_pad // bk, bq, bk, causal, window)
-    )
+    work = _blocks_with_work(tq_pad // bq, tk_pad // bk, bq, bk, causal, window)
+    sparse = selected is not None
+    in_specs, operands = [], ()
+    if sparse:
+        steps = _SelectedSteps(work, block_work(selected, bk), h)
+        in_specs = [_selection_rows(steps, bq, bk, tq_pad // bq, h, False)]
+        operands = (selected.reshape(-1, *selected.shape[2:]),)
+    else:
+        steps = _Steps(work)
     q_rows, kv_rows, _ = _specs_by_query(steps, bq, bk, tq_pad // bq, kv_of)
     out, m, l = pl.pallas_call(
         functools.partial(
@@ -452,10 +574,11 @@ def _flash_forward(
             block_k=bk,
             seq_len=t,
             window=window,
+            sparse=sparse,
         ),
         **steps.call(
             b * h,
-            in_specs=[q_rows(d), kv_rows(d), kv_rows(dv)],
+            in_specs=[q_rows(d), kv_rows(d), kv_rows(dv), *in_specs],
             out_specs=[q_rows(dv), q_rows(1), q_rows(1)],
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),  # running max
@@ -471,8 +594,8 @@ def _flash_forward(
         interpret=interpret,
         # The kernel's own name in the trace, whatever jit calls the
         # function that holds it.
-        name=_kernel_name(window, "fwd"),
-    )(*steps.tables, qb, kb, vb)
+        name=_kernel_name(window, "fwd", sparse),
+    )(*steps.tables, qb, kb, vb, *operands)
     out = out[:, :t].reshape(b, h, t, dv)
     out = jnp.transpose(out, (0, 2, 1, 3))
     if not return_stats:
@@ -481,7 +604,7 @@ def _flash_forward(
 
 
 def _bwd_probs(a, b, lse, scale, masked, keys_axis, qi, ki, block_q, block_k,
-               seq_len, causal, window=None):
+               seq_len, causal, window=None, selected=None):
     """Shared backward-kernel algebra: the probability block ``exp(a @ bᵀ ·
     scale − lse)`` recomputed from the saved log-sum-exp, whose keys lie
     along ``keys_axis``: 1 for a query-major block (``a`` the queries,
@@ -509,6 +632,8 @@ def _bwd_probs(a, b, lse, scale, masked, keys_axis, qi, ki, block_q, block_k,
             valid = valid & (q_pos >= k_pos)
             if window is not None:
                 valid = valid & (q_pos - k_pos < window)
+        if selected is not None:
+            valid = valid & selected
         s = jnp.where(valid, s, NEG_INF)
     return jnp.exp(s - lse)
 
@@ -522,13 +647,14 @@ def _flash_bwd_dkv_kernel(
     block_k: int,
     seq_len: int,
     window: Optional[int] = None,
+    sparse: bool = False,
 ):
     """dK/dV: grid (batch·kv-head, ..), a key block against one query
     block with work of one query head of its group (``steps``: a key
     block's in a row, head after head); the dk/dv accumulators live in
     VMEM and are revisited across all of them. ``refs``: the steps' tables
-    if any, ``q, k, v, dO``, the ``lse`` and ``D`` rows, the outputs ``dk,
-    dv``, the scratch.
+    if any, ``q, k, v, dO``, the ``lse`` and ``D`` rows, the selection's
+    words where ``sparse``, the outputs ``dk, dv``, the scratch.
 
     Key-major: the block is ``[bk, bq]``, keys on the sublanes and queries
     on the lanes, so both accumulating products contract the block's minor
@@ -542,10 +668,10 @@ def _flash_bwd_dkv_kernel(
     """
     from jax.experimental import pallas as pl
 
-    (
-        *tables, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dk_ref, dv_ref,
-        dk_scr, dv_scr,
-    ) = refs
+    tables, refs = refs[: len(steps.tables)], refs[len(steps.tables):]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, *refs = refs
+    sel_ref = refs.pop(0) if sparse else None
+    dk_ref, dv_ref, dk_scr, dv_scr = refs
     at = steps.here(*tables)
     ki, _, qi = steps.blocks(*at)
     first, last, runs = steps.edges(*at)
@@ -563,6 +689,7 @@ def _flash_bwd_dkv_kernel(
         p = _bwd_probs(
             k, q, lse_ref[0], scale, masked, 0, qi, ki, block_q, block_k,
             seq_len, causal, window,
+            None if sel_ref is None else _selected_block(sel_ref, block_q, True),
         )  # [bk, bq]
         dv_scr[...] = dv_scr[...] + jax.lax.dot(
             p, do, preferred_element_type=jnp.float32
@@ -595,9 +722,10 @@ def _flash_bwd_dq_kernel(
     block_k: int,
     seq_len: int,
     window: Optional[int] = None,
+    sparse: bool = False,
 ):
     """dQ: the forward's grid and ``refs`` but for ``dO`` and the ``lse``
-    and ``D`` rows after ``v`` and the one output; ``dq += ds @ k · scale``
+    and ``D`` rows after ``v`` (the words after them) and the one output; ``dq += ds @ k · scale``
     accumulates in VMEM across a query block's key blocks.
 
     Query-major, since ``ds @ k`` contracts the keys: the block is ``[bq,
@@ -607,10 +735,10 @@ def _flash_bwd_dq_kernel(
     them there."""
     from jax.experimental import pallas as pl
 
-    (
-        *tables, q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, dq_ref,
-        lse_scr, d_scr, dq_scr,
-    ) = refs
+    tables, refs = refs[: len(steps.tables)], refs[len(steps.tables):]
+    q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, *refs = refs
+    sel_ref = refs.pop(0) if sparse else None
+    dq_ref, lse_scr, d_scr, dq_scr = refs
     at = steps.here(*tables)
     qi, _, ki = steps.blocks(*at)
     first, last, runs = steps.edges(*at)
@@ -630,6 +758,7 @@ def _flash_bwd_dq_kernel(
         p = _bwd_probs(
             q, k, lse_scr[:, :1], scale, masked, 1, qi, ki, block_q, block_k,
             seq_len, causal, window,
+            None if sel_ref is None else _selected_block(sel_ref, block_q, False),
         )  # [bq, bk]
         dp = jax.lax.dot_general(
             do,
@@ -652,7 +781,8 @@ def _flash_bwd_dq_kernel(
 
 
 def _flash_backward_pallas(
-    q, k, v, out, m, l, ct, causal, block_q, block_k, interpret, window=None
+    q, k, v, out, m, l, ct, causal, block_q, block_k, interpret, window=None,
+    selected=None,
 ):
     """Fused flash backward: two Pallas kernels (dK/dV, a key block's
     query blocks in a row, and dQ, a query block's key blocks) consuming
@@ -710,16 +840,25 @@ def _flash_backward_pallas(
     )
 
     work = _blocks_with_work(nq, tk_pad // bk, bq, bk, causal, window)
+    sparse = selected is not None
     of_kernel = dict(
         scale=scale, causal=causal, block_q=bq, block_k=bk, seq_len=t,
         window=window,
     )
-
-    # dK/dV: a key block's query blocks, for each query head of its group.
-    by_key = _Steps(work.T, group)
+    if sparse:
+        of_kernel["sparse"] = True
+        live = block_work(selected, bk)
+        words = (selected.reshape(-1, *selected.shape[2:]),)
+        by_key = _SelectedSteps(work.T, jnp.swapaxes(live, 1, 2), hk, group)
+        by_query = _SelectedSteps(work, live, h)
+    else:
+        words = ()
+        # dK/dV: a key block's query blocks, for each query head of its group.
+        by_key = _Steps(work.T, group)
+        by_query = _Steps(work)
 
     def index(bkv, *at):  # q, dO and the statistics: a query head's block
-        _, member, qi = by_key.blocks(*at)
+        _, member, qi = by_key.blocks(*by_key.mapped(bkv, at))
         return (bkv // hk) * h + (bkv % hk) * group + member, qi, 0
 
     def q_rows(width):
@@ -728,16 +867,17 @@ def _flash_backward_pallas(
     def kv_rows(width):  # k, dk [.., d] and v, dv [.., dv]
         return pl.BlockSpec(
             (1, bk, width),
-            lambda bkv, *at: (bkv, by_key.blocks(*at)[0], 0),
+            lambda bkv, *at: (bkv, by_key.blocks(*by_key.mapped(bkv, at))[0], 0),
         )
 
     stat_rows = _stat_rows(bq, nq, index)
+    sel_rows = [_selection_rows(by_key, bq, bk, nq, hk, True)] if sparse else []
     dkb, dvb = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, steps=by_key, **of_kernel),
         **by_key.call(
             b * hk,
             in_specs=[q_rows(d), kv_rows(d), kv_rows(dv), q_rows(dv),
-                      stat_rows, stat_rows],
+                      stat_rows, stat_rows, *sel_rows],
             out_specs=[kv_rows(d), kv_rows(dv)],
             scratch_shapes=[
                 pltpu.VMEM((bk, d), jnp.float32),
@@ -749,20 +889,21 @@ def _flash_backward_pallas(
             jax.ShapeDtypeStruct((b * hk, tk_pad, dv), v.dtype),
         ],
         interpret=interpret,
-        name=_kernel_name(window, "bwd_dkv"),
-    )(*by_key.tables, qb, kb, vb, dob, lse, big_d)
+        name=_kernel_name(window, "bwd_dkv", sparse),
+    )(*by_key.tables, qb, kb, vb, dob, lse, big_d, *words)
 
     # dQ: the forward's steps.
-    by_query = _Steps(work)
     q_rows2, kv_rows2, stat_rows2 = _specs_by_query(
         by_query, bq, bk, nq, kv_of
     )
+    if sparse:
+        sel_rows = [_selection_rows(by_query, bq, bk, nq, h, False)]
     dqb = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, steps=by_query, **of_kernel),
         **by_query.call(
             b * h,
             in_specs=[q_rows2(d), kv_rows2(d), kv_rows2(dv), q_rows2(dv),
-                      stat_rows2, stat_rows2],
+                      stat_rows2, stat_rows2, *sel_rows],
             out_specs=q_rows2(d),
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),  # lse, lanes replicated
@@ -772,8 +913,8 @@ def _flash_backward_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, tq_pad, d), q.dtype),
         interpret=interpret,
-        name=_kernel_name(window, "bwd_dq"),
-    )(*by_query.tables, qb, kb, vb, dob, lse, big_d)
+        name=_kernel_name(window, "bwd_dq", sparse),
+    )(*by_query.tables, qb, kb, vb, dob, lse, big_d, *words)
 
     def from_bh(x):
         x = x[:, :t].reshape(b, -1, t, x.shape[-1])
@@ -873,6 +1014,73 @@ def _bwd(causal, block_q, block_k, interpret, window, res, ct):
 
 _flash_vjp.defvjp(_fwd, _bwd)
 
+_WORD_DIMS = (DATA_AXIS, None, None, None)  # the selection's [b, nq, R, t]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _sparse_vjp(q, k, v, selected, block_q, block_k, interpret):
+    return _sparse_fwd(q, k, v, selected, block_q, block_k, interpret)[0]
+
+
+def _sparse_fwd(q, k, v, selected, block_q, block_k, interpret):
+    """``(out, m, l)``, the residuals named as :func:`_fwd` names them. The
+    statistics are outputs for the caller's indexer loss, which reads them
+    without a gradient: their cotangents are dropped."""
+
+    def run(q, k, v, selected):
+        return _flash_forward(
+            q, k, v, True, block_q, block_k, interpret, return_stats=True,
+            selected=selected,
+        )
+
+    out, m, l = over_mesh(
+        run, in_dims=[_QKV_DIMS] * 3 + [_WORD_DIMS],
+        out_dims=[_QKV_DIMS, _STAT_DIMS, _STAT_DIMS],
+    )(q, k, v, selected)
+    out = checkpoint_name(out, ATTENTION_OUT)
+    m = checkpoint_name(m, ATTENTION_STATS)
+    l = checkpoint_name(l, ATTENTION_STATS)
+    return (out, m, l), (q, k, v, out, m, l, selected)
+
+
+def _sparse_bwd(block_q, block_k, interpret, res, ct):
+    q, k, v, out, m, l, selected = res
+
+    def run(q, k, v, out, m, l, ct, selected):
+        return _flash_backward_pallas(
+            q, k, v, out, m, l, ct, True, block_q, block_k, interpret,
+            selected=selected,
+        )
+
+    dq, dk, dv = over_mesh(
+        run,
+        in_dims=[_QKV_DIMS] * 4 + [_STAT_DIMS] * 2 + [_QKV_DIMS, _WORD_DIMS],
+        out_dims=[_QKV_DIMS] * 3,
+    )(q, k, v, out, m, l, ct[0], selected)
+    return dq, dk, dv, None
+
+
+_sparse_vjp.defvjp(_sparse_fwd, _sparse_bwd)
+
+
+def _sparse_reference(q, k, v, selected):
+    """Dense softmax attention over the causal keys the selection keeps:
+    ``(out, lse [b, h, t])``, float32 inside."""
+    b, t, h, d = q.shape
+    group = h // k.shape[2]
+    admitted = unpack(selected) & (jnp.arange(t)[:, None] >= jnp.arange(t)[None, :])
+    s = jnp.einsum(
+        "bqhd,bkhd->bhqk", q.astype(jnp.float32),
+        _repeat_kv(k, group).astype(jnp.float32),
+    ) / math.sqrt(d)
+    s = jnp.where(admitted[:, None], s, NEG_INF)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = jnp.einsum(
+        "bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]),
+        _repeat_kv(v, group).astype(jnp.float32),
+    )
+    return out.astype(q.dtype), lse
+
 
 def _repeat_kv(x: jax.Array, group: int) -> jax.Array:
     """Key/value heads repeated to the query heads (the XLA paths only:
@@ -897,7 +1105,8 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool = False,
     window: Optional[int] = None,
-) -> jax.Array:
+    selected: Optional[jax.Array] = None,
+):
     """Fused attention over ``q [batch, seq, heads, head_dim]``, ``k
     [batch, seq, kv_heads, head_dim]`` and ``v [batch, seq, kv_heads,
     value_dim]``, to ``[batch, seq, heads, value_dim]``; ``kv_heads`` divides
@@ -909,6 +1118,15 @@ def flash_attention(
     blocks of that band only. Any positive width: one that is no multiple
     of the blocks is masked where it ends, one of the sequence's length or
     more is plain causal attention and runs as it.
+
+    ``selected`` (with ``causal``, no ``window``): the ``[batch, seq /
+    block_q, block_q / 32, seq]`` int32 words of ``ops/sparse_attention.py``
+    (query blocks of this call's ``block_q``); a causal query sees only the
+    keys its words keep, the kernels (``flash_attention_sparse_*``) visit
+    only the blocks that hold a kept pair, from tables made on the device,
+    and the call returns ``(out, lse)``: ``lse [batch, heads, seq]`` float32,
+    the log-sum-exp of each query's scores over its keys, read without a
+    gradient.
 
     ``use_pallas=None`` auto-selects the kernel on any TPU backend (split
     batch/head-wise over the context mesh — same policy as
@@ -928,6 +1146,14 @@ def flash_attention(
             window = None
     if use_pallas is None:
         use_pallas = auto_pallas()
+    if selected is not None:
+        if not causal or window is not None:
+            raise ValueError("a selection is of causal attention without a window")
+        check_blocks(q.shape[1], block_q, block_k)
+        if not use_pallas:
+            return _sparse_reference(q, k, v, selected)
+        out, m, l = _sparse_vjp(q, k, v, selected, block_q, block_k, interpret)
+        return out, jnp.where(l > 0, m + jnp.log(l), jnp.inf)
     if not use_pallas:
         group = q.shape[2] // k.shape[2]
         return attention_reference(

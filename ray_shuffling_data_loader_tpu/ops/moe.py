@@ -94,11 +94,14 @@ def route(
     top_k: int,
     norm_topk_prob: bool = True,
     scaling: float = 1.0,
+    scoring: str = "sigmoid",
 ):
     """Sigmoid routing with a selection bias: ``s = sigmoid(x @ gate)``;
     the ``top_k`` experts with the largest ``s + expert_bias`` are chosen;
     their weights are their own ``s`` (without the bias), divided by
-    their sum where ``norm_topk_prob``, times ``scaling``.
+    their sum where ``norm_topk_prob``, times ``scaling``. ``scoring``
+    ``"softmax"`` (Qwen3-MoE's) takes ``s = softmax(x @ gate)`` over all the
+    experts routed over instead, the rest alike.
 
     ``x`` ``[tokens, hidden]``, ``gate`` ``[hidden, experts]``. Scores are
     computed in float32 at the highest matmul precision: a near tie
@@ -114,7 +117,12 @@ def route(
         gate.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    scores = jax.nn.sigmoid(logits)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown scoring {scoring!r}")
     chosen_by = scores if expert_bias is None else scores + expert_bias
     _, experts = jax.lax.top_k(chosen_by, top_k)
     experts = _kept(experts.astype(jnp.int32))

@@ -17,7 +17,11 @@ benchmark's ``paths``, by their full node ids and strictly: if one passes
 (the pin was brought up to date in place) the mark fails loudly and has to
 go. ``tests/chipbench/test_laguna_family.py`` holds what each one guards,
 stated so that it stays true when a family, a cell or a metric is appended.
-A ``benchmark`` PR can fold these marks, and the three of
+A sixth is ``test_phi4flash_family.py``'s list of the entries that grew
+since its parent (``keye-seq16k-train`` is appended to ``moe.*`` too);
+``tests/chipbench/test_keye_family.py`` keeps what it guards, that every
+accepted entry keeps its cells and what grew grew at the end. A
+``benchmark`` PR can fold these marks, and the three of
 ``tests/chipbench/conftest.py`` (ISSUE 28), back into the pinned tests.
 """
 
@@ -56,6 +60,11 @@ OUTDATED = {
     _LFM2_FAMILY + "test_pr_25_s_entries_keep_their_place_keys_and_cells":
         "pins PR 28's three metrics as all that follows PR 25's and "
         "lfm2-seq8k-train as the one cell appended; ISSUE 32 appends to both",
+    "tests/chipbench/test_phi4flash_family.py::"
+    "test_what_this_pr_appended_follows_what_was_there":
+        "pins the per-layer entries that grew since phi4flash's parent to the "
+        "twelve its cell was appended to; keye-seq16k-train is appended to "
+        "those and to moe.*, which phi4flash left",
 }
 
 
