@@ -34,10 +34,11 @@ at 16,384 tokens.
   with ``pbar`` the main attention's probabilities over ``S_t`` summed over
   the heads and normalised (no gradient). Its kernel ``sparse_index_bwd``
   takes one pass over a query block's causal key blocks: the main scores
-  again from the kept log-sum-exp of every head, ``pbar`` in VMEM, ``dL/dI
-  = softmax_{S_t}(I) - pbar`` on ``S_t``, and that back into the indexer's
-  ``q``, ``k`` and ``w``; the loss and the three gradients come out
-  together (a custom VJP hands the gradients over).
+  again from the kept log-sum-exp of every head, ``pbar`` in VMEM, each
+  index head's ``z = q^I . k^I`` once into VMEM for the scores and read back
+  for the gradient, ``dL/dI = softmax_{S_t}(I) - pbar`` on ``S_t``, and that
+  back into the indexer's ``q``, ``k`` and ``w``; the loss and the three
+  gradients come out together (a custom VJP hands the gradients over).
 
 The XLA paths (``use_pallas=False``) are dense, for the CPU and as the
 kernels' oracle. Everything a layer keeps of the two (the bitmask, the
@@ -212,6 +213,13 @@ def _columns(row, lanes: int = 128):
     return jnp.broadcast_to(row, (lanes, row.shape[1])).T
 
 
+def _vmem_bytes(shape, itemsize: int = 4) -> int:
+    """Bytes a VMEM array of ``shape`` takes, its last two dimensions padded
+    to the (8, 128) tile."""
+    *lead, rows, lanes = shape
+    return math.prod(lead) * -(-rows // 8) * 8 * -(-lanes // 128) * 128 * itemsize
+
+
 def _lane_sum(blk, acc):
     """``acc [n, 128] + blk [n, m]`` summed 128 lanes at a time."""
     for c in range(blk.shape[1] // 128):
@@ -350,13 +358,14 @@ def _index_fwd_pallas(qh, kt, wr, topk, block_q, block_k, interpret):
 def _index_bwd_kernel(
     q_ref, k_ref, lse_ref, qi_ref, qit_ref, kt_ref, w_ref, lsei_ref, words_ref,
     loss_ref, dq_ref, dw_ref, dkt_ref,
-    lse_scr, w_scr, lsei_scr, loss_scr, dq_scr, dw_scr,
+    lse_scr, w_scr, lsei_scr, loss_scr, dq_scr, dw_scr, z_scr,
     *, heads, group, index_heads, scale, inv_count, block_q, block_k,
 ):
     """One (query block, causal key block) pair of the indexer's loss:
     ``pbar`` over the main heads, ``dI``, and its gradients; a query block's
     loss, ``dq`` and ``dw`` accumulate across its key blocks, ``dk`` is this
-    pair's part."""
+    pair's part. Each index head's ``z = q^I @ k^Iᵀ`` is computed once a
+    pair, into ``z_scr``, and read back by the gradient loop."""
     from jax.experimental import pallas as pl
 
     i, kb = pl.program_id(1), pl.program_id(2)
@@ -390,14 +399,12 @@ def _index_bwd_kernel(
             pbar = pbar + jnp.exp(s - lse_scr[h][:, :1])
         pbar = jnp.where(sel, pbar * (1.0 / heads), 0.0)
         kt = kt_ref[0]  # [dim, bk]
-
-        def z_of(j):
-            return jax.lax.dot(qi_ref[0, j], kt, precision=_HIGHEST,
-                               preferred_element_type=jnp.float32)
-
         scores = jnp.zeros(rows, jnp.float32)
         for j in range(index_heads):
-            scores = scores + w_scr[j][:, :1] * jnp.maximum(z_of(j), 0.0)
+            z = jax.lax.dot(qi_ref[0, j], kt, precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
+            z_scr[j] = z
+            scores = scores + w_scr[j][:, :1] * jnp.maximum(z, 0.0)
         log_soft = scores - lsei_scr[:, :1]
         soft = jnp.where(sel, jnp.exp(log_soft), 0.0)
         safe = jnp.where(pbar > 0, pbar, 1.0)
@@ -406,7 +413,7 @@ def _index_bwd_kernel(
         d_scores = (soft - pbar) * inv_count
         dkt = jnp.zeros(dkt_ref.shape[2:], jnp.float32)
         for j in range(index_heads):
-            z = z_of(j)
+            z = z_scr[j]
             dw_scr[j] = dw_scr[j] + jnp.sum(d_scores * jnp.maximum(z, 0.0),
                                             axis=1, keepdims=True)
             dz = jnp.where(z > 0, d_scores * w_scr[j][:, :1], 0.0)
@@ -450,6 +457,19 @@ def _index_bwd_pallas(qi, ki, w, q, k, lse, lse_i, words, block_q, block_k,
     def kv_block(bi, i, kb):  # a causal key block; past the diagonal the last
         return jnp.minimum(kb, last(i))
 
+    # lse, w and lse_i as lane-replicated columns; the loss, dq and dw
+    # accumulators; each index head's z of the pair.
+    scratch = [(heads, block_q, 128), (ih, block_q, 128), (block_q, 128),
+               (block_q, 1), (ih, block_q, idim), (ih, block_q, 1),
+               (ih, block_q, block_k)]
+    need = sum(_vmem_bytes(s) for s in scratch)
+    if need > _VMEM_LIMIT:
+        raise ValueError(
+            f"sparse_index_bwd's scratch takes {need} B of VMEM, over the limit "
+            f"of {_VMEM_LIMIT}: {heads} heads, {ih} index heads of {idim}, "
+            f"blocks {block_q} / {block_k} (the index heads' z alone "
+            f"{_vmem_bytes(scratch[-1])} B)"
+        )
     qh = jnp.transpose(qi, (0, 2, 1, 3))  # [b, ih, t, id]
     rows = lambda x: x.reshape(b, -1, nq, block_q).transpose(0, 2, 1, 3)  # noqa: E731
     loss, dq, dw, dkt = pl.pallas_call(
@@ -486,14 +506,7 @@ def _index_bwd_pallas(qi, ki, w, q, k, lse, lse_i, words, block_q, block_k,
             jax.ShapeDtypeStruct((b, nq, ih, block_q), jnp.float32),
             jax.ShapeDtypeStruct((b, nq, idim, t), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((heads, block_q, 128), jnp.float32),
-            pltpu.VMEM((ih, block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((ih, block_q, idim), jnp.float32),
-            pltpu.VMEM((ih, block_q, 1), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in scratch],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,

@@ -197,10 +197,39 @@ def _loss_inputs(bq=32, bk=16, seed=2):
     return (qi, ki, wi), (q, k, lse, lse_i, words)
 
 
-@pytest.mark.parametrize("blocks_qk", [(32, 32), (32, 16)])
-def test_the_indexer_loss_kernel_is_the_formula_and_its_gradient(blocks_qk):
-    """``L = mean_t sum_S pbar (log pbar - log softmax_S(I))`` by hand, and
-    the kernel's loss and three gradients against the XLA path's autodiff."""
+class _ZAgain:
+    """Stands in for the loss kernel's ``z_scr``: a write is dropped, a read
+    of head ``j`` is its product ``q^I_j @ k^Iᵀ`` again."""
+
+    def __init__(self, qi_ref, kt_ref):
+        self.qi_ref, self.kt_ref = qi_ref, kt_ref
+
+    def __setitem__(self, j, z):
+        pass
+
+    def __getitem__(self, j):
+        return jax.lax.dot(self.qi_ref[0, j], self.kt_ref[0], precision="highest",
+                           preferred_element_type=jnp.float32)
+
+
+def _recomputing_z(monkeypatch):
+    """The loss kernel as it was before it kept ``z``: every read of the
+    scratch computes the product again."""
+    kernel = sa._index_bwd_kernel
+
+    def recomputing(*refs, **kw):
+        return kernel(*refs[:-1], _ZAgain(refs[3], refs[5]), **kw)
+
+    monkeypatch.setattr(sa, "_index_bwd_kernel", recomputing)
+
+
+@pytest.mark.parametrize("blocks_qk", [(32, 32), (32, 16), (32, 8)])
+def test_the_indexer_loss_kernel_is_the_formula_and_its_gradient(blocks_qk, monkeypatch):
+    """``L = mean_t sum_S pbar (log pbar - log softmax_S(I))`` by hand, the
+    kernel's loss and three gradients against the XLA path's autodiff, and
+    equal to the bit to the kernel that computes each ``z`` again in its
+    gradient loop: a ``z`` left in the scratch by another key block (up to
+    8 a query block at blocks of 32 / 8) would show."""
     bq, bk = blocks_qk
     (qi, ki, wi), (q, k, lse, lse_i, words) = _loss_inputs(bq, bk)
     sel = np.asarray(sa.unpack(words))
@@ -224,6 +253,76 @@ def test_the_indexer_loss_kernel_is_the_formula_and_its_gradient(blocks_qk):
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for g, w in zip(grads, want_grads):
         assert np.allclose(g, w, atol=1e-5 * float(jnp.abs(w).max())), float(jnp.abs(g - w).max())
+    _recomputing_z(monkeypatch)
+    again, again_grads = of(True)
+    for x, y in zip((got, *grads), (again, *again_grads)):
+        assert np.array_equal(x, y), float(jnp.abs(x - y).max())
+
+
+def _pallas_eqns(jaxpr):
+    """Every Pallas call's equation under ``jaxpr``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_eqns(sub)
+    return found
+
+
+def _loss_kernel_dots():
+    """``{(precision, operand dtype): count}`` of the ``dot_general``s in
+    ``sparse_index_bwd``'s kernel, into its ``pl.when`` bodies: 4 main heads
+    in bfloat16 over 2 key heads, 4 index heads of 8, traced only."""
+    (qi, ki, wi), (q, k, lse, lse_i, words) = _loss_inputs()
+    q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
+    grad = jax.grad(
+        lambda a, b, c: sa.index_loss(a, b, c, q, k, lse, lse_i, words, 32, 16,
+                                      use_pallas=True, interpret=True),
+        argnums=(0, 1, 2),
+    )
+    (call,) = [e for e in _pallas_eqns(jax.make_jaxpr(grad)(qi, ki, wi).jaxpr)
+               if e.params["name"] == "sparse_index_bwd"]
+
+    def dots(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from dots(sub)
+
+    return collections.Counter(
+        (str(e.params["precision"]), str(e.invars[0].aval.dtype))
+        for e in dots(call.params["jaxpr"])
+    )
+
+
+def test_the_indexer_loss_kernel_computes_each_index_head_s_z_once_a_pair(monkeypatch):
+    """Three float32 products at ``highest`` an index head (``z``, ``dq``,
+    ``dkᵀ``) and one bfloat16 product a main head; computing ``z`` again in
+    the gradient loop, as the kernel once did, makes it four."""
+    highest = str((jax.lax.Precision.HIGHEST,) * 2)
+    assert _loss_kernel_dots() == {(highest, "float32"): 3 * 4, ("None", "bfloat16"): 4}
+    _recomputing_z(monkeypatch)
+    assert _loss_kernel_dots() == {(highest, "float32"): 4 * 4, ("None", "bfloat16"): 4}
+
+
+def test_the_indexer_loss_kernel_refuses_a_scratch_over_its_vmem_limit():
+    """DSA's own indexer, 64 index heads of 128 beside 128 heads, at blocks
+    of 512: its ``z`` alone is 64 MiB of VMEM."""
+    b, t = 1, 1024
+    f32 = jnp.float32
+    args = (
+        jax.ShapeDtypeStruct((b, t, 64, 128), f32), jax.ShapeDtypeStruct((b, t, 128), f32),
+        jax.ShapeDtypeStruct((b, t, 64), f32),
+        jax.ShapeDtypeStruct((b, t, 128, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((b, t, 1, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((b, 128, t), f32), jax.ShapeDtypeStruct((b, t), f32),
+        jax.ShapeDtypeStruct((b, 2, 16, t), jnp.int32),
+    )
+    with pytest.raises(ValueError, match=r"sparse_index_bwd's scratch .* 64 index heads of 128"):
+        jax.eval_shape(lambda *a: sa.index_loss(*a, 512, 512, use_pallas=True), *args)
 
 
 def test_the_indexer_loss_trains_the_indexer_alone():
@@ -426,14 +525,8 @@ def test_in_bfloat16_the_program_is_inside_the_limits_and_float8_is_not(family):
 
 def _pallas_calls(jaxpr):
     """``[(name, grid)]`` of every Pallas call under ``jaxpr``."""
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append((eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)))
-            continue
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _pallas_calls(sub)
-    return found
+    return [(e.params["name"], tuple(e.params["grid_mapping"].grid))
+            for e in _pallas_eqns(jaxpr)]
 
 
 def _kernel_model(layers=2):
