@@ -375,6 +375,31 @@ def main(argv) -> int:
             say(f"  {name}: rel err {err:.2e} (limit {TOL:.2e})")
             assert err < TOL, (name, err)
 
+    # Latent attention's split key at small blocks (query blocks of 256, key
+    # blocks of 128 over 512 tokens), 4 heads of 128 + 64 over one shared
+    # rotary key of 64 and values of 128: the three flash_attention_latent_*
+    # kernels against the XLA path, which repeats the shared part to the
+    # heads. TOL holds for all five: dK_shared sums the heads' rows in
+    # float32 and is rounded to bfloat16 once, as every other output is.
+    @phase("latent kernels")
+    def _latent_kernels():
+        t, bq, bk = (64, 32, 16) if rehearse else (512, 256, 128)
+        q, k, v, ct = bf16(1, t, 4, 192), bf16(1, t, 4, 128), bf16(1, t, 4, 128), bf16(1, t, 4, 128)
+        k_rope = bf16(1, t, 1, 64)
+
+        def attend(use_pallas):
+            kw = dict(block_q=bq, block_k=bk, interpret=rehearse) if use_pallas else {}
+            return lambda q, k, v, kr: flash_attention(
+                q, k, v, causal=True, use_pallas=use_pallas, k_shared=kr, **kw
+            )
+
+        got = with_grads(attend(True), q, k, v, k_rope, ct=ct)
+        want = reference(attend(False), q, k, v, k_rope, ct=ct)
+        for name, g, w in zip(("fwd", "dQ", "dK", "dV", "dK_shared"), got, want):
+            err = rel_err(g, w)
+            say(f"  flash_attention_latent {name}: rel err {err:.2e} (limit {TOL:.2e})")
+            assert err < TOL, (name, err)
+
     # -- data and model -----------------------------------------------------
     model_columns = [c for c in DATA_SPEC if c != LABEL_COLUMN]
     # The key rides along for the exactly-once checks; the model never

@@ -1,12 +1,15 @@
 """What the sequence models share (``lfm2_moe.py``, ``laguna.py``,
-``phi4flash.py``): the parts of a pre-norm residual layer, the language
-model around a stack of recomputed layers, and its loss.
+``phi4flash.py``, ``keye.py``, ``kimi.py``): the parts of a pre-norm
+residual layer, the language model around a stack of recomputed layers,
+and its loss.
 
 * :class:`RMSNorm`, :class:`LayerNorm`; :class:`Rope` and :func:`rotary` (the half-split
   convention, plain or YaRN frequencies, on all or on the first dimensions
   of a head);
 * :class:`Attention`: grouped-query causal attention through
   ``ops/flash_attention.py``, full or over a sliding window;
+* :class:`LatentAttention`: keys and values through a low-rank latent, a
+  rotary key part shared by every head;
 * :class:`DenseFFN` (the gated three-matrix form) and :class:`ExpertFFN`
   (the experts ONE chip holds of a routed layer, ``ops/moe.py``);
 * :class:`SequenceLM`: embedding, the layers (each recomputed in the
@@ -209,6 +212,64 @@ class Attention(nn.Module):
                 out, lse = out
             y = jnp.dot(out.reshape(b, t, nq * d), wo.astype(self.dtype))
             return y if selected is None else (y, q, k, lse)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2's, the query projected
+    straight from ``x``): ``heads`` query heads of ``nope_dim + rope_dim``;
+    ``[c ; k_rope] = W_kva x`` with ``c`` the ``kv_rank``-wide latent, RMS
+    normed, and ``k_rope`` ONE key of ``rope_dim`` that every head reads;
+    ``[k_nope ; v] = W_kvb c`` in heads of ``nope_dim`` and ``value_dim``;
+    rotary positions on the query's last ``rope_dim`` dimensions and on
+    ``k_rope`` only; causal softmax of ``(q_nope · k_nope + q_rope · k_rope)
+    / sqrt(nope_dim + rope_dim)``; an output projection.
+
+    Layout: a query head is ``[nope ; rope]`` (``q_proj``'s columns a head
+    at a time, as published), ``kv_a_proj``'s columns ``[latent ; rope]``,
+    ``kv_b_proj``'s ``[k_nope ; v]`` a head at a time. The kernels take the
+    query whole and the key in its two parts, ``k_rope`` as one head of
+    ``[b, t, 1, rope_dim]`` (``flash_attention``'s ``k_shared``): it is never
+    repeated to the heads. The low-rank path (``kv_a_proj``, the latent's
+    norm, ``kv_b_proj`` and ``k_rope``'s rotary) runs under the scope
+    ``latent``, inside ``scope_name``."""
+
+    heads: int
+    nope_dim: int
+    rope_dim: int
+    value_dim: int
+    kv_rank: int
+    rope: Rope
+    norm_eps: float
+    dtype: Any
+    use_pallas: Optional[bool]
+    interpret: bool
+    block_q: int
+    block_k: int
+    scope_name: str = "attention"
+
+    @nn.compact
+    def __call__(self, x):
+        h, n = x.shape[-1], self.heads
+        nope, rope_dim, dv, rank = self.nope_dim, self.rope_dim, self.value_dim, self.kv_rank
+        wq = self.param("q_proj", fan_in((h, n * (nope + rope_dim))), (h, n * (nope + rope_dim)))
+        wa = self.param("kv_a_proj", fan_in((h, rank + rope_dim)), (h, rank + rope_dim))
+        wb = self.param("kv_b_proj", fan_in((rank, n * (nope + dv))), (rank, n * (nope + dv)))
+        wo = self.param("out_proj", fan_in((n * dv, h)), (n * dv, h))
+        b, t, _ = x.shape
+        with jax.named_scope(self.scope_name):
+            q = jnp.dot(x, wq.astype(self.dtype)).reshape(b, t, n, nope + rope_dim)
+            q = jnp.concatenate([q[..., :nope], rotary(q[..., nope:], self.rope)], axis=-1)
+            with jax.named_scope("latent"):
+                ck = jnp.dot(x, wa.astype(self.dtype))
+                c = RMSNorm(self.norm_eps, self.dtype, name="kv_a_norm")(ck[..., :rank])
+                k_rope = rotary(ck[..., rank:].reshape(b, t, 1, rope_dim), self.rope)
+                kv = jnp.dot(c, wb.astype(self.dtype)).reshape(b, t, n, nope + dv)
+            out = flash_attention(
+                q, kv[..., :nope], kv[..., nope:], causal=True,
+                use_pallas=self.use_pallas, interpret=self.interpret,
+                block_q=self.block_q, block_k=self.block_k, k_shared=k_rope,
+            )
+            return jnp.dot(out.reshape(b, t, n * dv), wo.astype(self.dtype))
 
 
 class DenseFFN(nn.Module):

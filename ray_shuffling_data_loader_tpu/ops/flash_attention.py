@@ -54,6 +54,18 @@ made on the device each batch (:class:`_SelectedSteps`); its Pallas calls
 are named ``flash_attention_sparse_*``. Without one the kernels are traced
 as they were.
 
+A key may be split in two (``k_shared``, latent attention's decoupled
+rotary key): a part of each head's own, ``k [.., head_dim - d_s]``, and one
+head of ``d_s`` that every query head reads, ``k_shared [batch, seq, 1,
+d_s]``, against the last ``d_s`` dimensions of each query head. The kernels
+take the query's two parts as two operands (each lane-aligned as it is
+given: a head of 192 is 128 + 64) and add the two products of a score; the
+shared part's block index ignores the head, so it is fetched per key block
+and never repeated in HBM. The dK/dV kernel accumulates the shared key's
+gradient of a key head's query heads beside dK, and the heads' rows are
+summed after the call, as ``_sum_groups`` sums a group's. Its Pallas calls
+are named ``flash_attention_latent_*``.
+
 Differentiability: the kernel carries an exact, memory-safe custom VJP.
 The forward emits its softmax row statistics (m, l) as outputs; the
 backward is two fused Pallas kernels — dK/dV (q innermost, VMEM
@@ -69,7 +81,8 @@ tile pads 128-fold and XLA lays out anew. dK/dV runs key-major (the block
 block is transposed; dQ's ``ds @ k`` contracts the keys, so dQ stays
 query-major and turns a query block's rows into columns once, at its first
 step. ``RSDL_FLASH_BWD=xla`` falls back to the chunked-XLA exact backward
-(shared with ``blockwise_attention``).
+(shared with ``blockwise_attention``) in a call without a selection or a
+shared key part.
 """
 
 from __future__ import annotations
@@ -342,11 +355,35 @@ def _update_block(runs, qi, ki, block_q, block_k, window, update):
     pl.when(runs & jnp.logical_not(inside))(functools.partial(update, True))
 
 
-def _kernel_name(window, which: str, sparse: bool = False) -> str:
+def _kernel_name(window, which: str, sparse: bool = False,
+                 latent: bool = False) -> str:
     """The Pallas call's name in a trace: the windowed kernels, and those of
-    a call with a selection, are populations of their own."""
-    kind = "sparse_" if sparse else "" if window is None else "window_"
+    a call with a selection or a shared key part, are populations of their
+    own."""
+    kind = (
+        "sparse_" if sparse else "latent_" if latent
+        else "" if window is None else "window_"
+    )
     return "flash_attention_" + kind + which
+
+
+def _dot_t(a, b):
+    """``a @ bᵀ`` in float32 (the two blocks' minor dimensions contracted)."""
+    return jax.lax.dot_general(
+        a,
+        b,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _scores(a, b, shared):
+    """``a @ bᵀ``, plus ``a₂ @ b₂ᵀ`` where ``shared`` is the pair of refs
+    ``(a₂, b₂)`` of a split key's shared part (None: no such part)."""
+    s = _dot_t(a, b)
+    if shared is not None:
+        s = s + _dot_t(shared[0][0], shared[1][0])
+    return s
 
 
 def _flash_kernel(
@@ -359,11 +396,13 @@ def _flash_kernel(
     seq_len: int,
     window: Optional[int] = None,
     sparse: bool = False,
+    latent: bool = False,
 ):
     """One grid cell: a query block against one of its key blocks with work
     (``steps``: a query block's in a row). ``refs``: the steps' tables if
-    any, ``q, k, v``, the selection's words where ``sparse``, the outputs
-    ``o, m, l``, the scratch.
+    any, ``q, k, v``, the query's and the key's shared parts where
+    ``latent``, the selection's words where ``sparse``, the outputs ``o, m,
+    l``, the scratch.
 
     The output block is revisited across a query block's steps, carrying
     (running max, normalizer, accumulator) in VMEM scratch. The softmax
@@ -378,6 +417,7 @@ def _flash_kernel(
         q_ref, k_ref, v_ref, sel_ref, *refs = refs
     else:
         (q_ref, k_ref, v_ref, *refs), sel_ref = refs, None
+    shared = (refs.pop(0), refs.pop(0)) if latent else None
     o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
     at = steps.here(*tables)
     qi, _, ki = steps.blocks(*at)
@@ -393,15 +433,7 @@ def _flash_kernel(
         q = q_ref[0]  # [bq, d]
         k = k_ref[0]  # [bk, d]
         v = v_ref[0]
-        s = (
-            jax.lax.dot_general(
-                q,
-                k,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [bq, bk]
+        s = _scores(q, k, shared) * scale  # [bq, bk]
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
@@ -532,12 +564,15 @@ def _flash_forward(
     return_stats: bool = False,
     window: Optional[int] = None,
     selected: Optional[jax.Array] = None,
+    k_shared: Optional[jax.Array] = None,
 ):
     """Fused forward. With ``return_stats`` also returns the softmax row
     statistics ``(m, l)`` as float32 ``[b, h, t]`` — residuals for the
     fused backward and merge inputs for the ring schedule. ``selected``
     (the words of ``ops/sparse_attention.py``) admits a score only where
-    the selection does too, and the steps skip the blocks without one."""
+    the selection does too, and the steps skip the blocks without one.
+    ``k_shared`` (``[b, t, 1, d_s]``) is the key's part that every query
+    head reads against its last ``d_s`` dimensions."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -550,7 +585,12 @@ def _flash_forward(
     tq_pad = -(-t // bq) * bq
     tk_pad = -(-t // bk) * bk
 
-    qb = _to_bh(q, tq_pad)
+    latent = k_shared is not None
+    dn = k.shape[-1]  # the width of the part of a key that is its head's own
+    if latent:
+        qb, qsb = _to_bh(q[..., :dn], tq_pad), _to_bh(q[..., dn:], tq_pad)
+    else:
+        qb = _to_bh(q, tq_pad)
     kb = _to_bh(k, tk_pad)
     vb = _to_bh(v, tk_pad)
 
@@ -564,6 +604,11 @@ def _flash_forward(
     else:
         steps = _Steps(work)
     q_rows, kv_rows, _ = _specs_by_query(steps, bq, bk, tq_pad // bq, kv_of)
+    if latent:
+        ds = d - dn
+        shared_rows = _specs_by_query(steps, bq, bk, tq_pad // bq, _kv_head_map(h, 1))[1]
+        in_specs = [q_rows(ds), shared_rows(ds)]
+        operands = (qsb, _to_bh(k_shared, tk_pad))
     out, m, l = pl.pallas_call(
         functools.partial(
             _flash_kernel,
@@ -575,10 +620,11 @@ def _flash_forward(
             seq_len=t,
             window=window,
             sparse=sparse,
+            latent=latent,
         ),
         **steps.call(
             b * h,
-            in_specs=[q_rows(d), kv_rows(d), kv_rows(dv), *in_specs],
+            in_specs=[q_rows(dn), kv_rows(dn), kv_rows(dv), *in_specs],
             out_specs=[q_rows(dv), q_rows(1), q_rows(1)],
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),  # running max
@@ -594,7 +640,7 @@ def _flash_forward(
         interpret=interpret,
         # The kernel's own name in the trace, whatever jit calls the
         # function that holds it.
-        name=_kernel_name(window, "fwd", sparse),
+        name=_kernel_name(window, "fwd", sparse, latent),
     )(*steps.tables, qb, kb, vb, *operands)
     out = out[:, :t].reshape(b, h, t, dv)
     out = jnp.transpose(out, (0, 2, 1, 3))
@@ -604,22 +650,15 @@ def _flash_forward(
 
 
 def _bwd_probs(a, b, lse, scale, masked, keys_axis, qi, ki, block_q, block_k,
-               seq_len, causal, window=None, selected=None):
+               seq_len, causal, window=None, selected=None, shared=None):
     """Shared backward-kernel algebra: the probability block ``exp(a @ bᵀ ·
     scale − lse)`` recomputed from the saved log-sum-exp, whose keys lie
     along ``keys_axis``: 1 for a query-major block (``a`` the queries,
     ``lse`` a column), 0 for a key-major one (``a`` the keys, ``lse`` a
     row). ``lse`` is ``+inf`` on a row with no admitted key, padded rows
-    among them, so that it is 0 there with no guard."""
-    s = (
-        jax.lax.dot_general(
-            a,
-            b,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        * scale
-    )
+    among them, so that it is 0 there with no guard. ``shared``: the refs
+    of a split key's shared parts in ``a``'s and ``b``'s order."""
+    s = _scores(a, b, shared) * scale
     if (causal or seq_len % block_k != 0) and masked:
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, keys_axis
@@ -648,13 +687,16 @@ def _flash_bwd_dkv_kernel(
     seq_len: int,
     window: Optional[int] = None,
     sparse: bool = False,
+    latent: bool = False,
 ):
     """dK/dV: grid (batch·kv-head, ..), a key block against one query
     block with work of one query head of its group (``steps``: a key
     block's in a row, head after head); the dk/dv accumulators live in
     VMEM and are revisited across all of them. ``refs``: the steps' tables
-    if any, ``q, k, v, dO``, the ``lse`` and ``D`` rows, the selection's
-    words where ``sparse``, the outputs ``dk, dv``, the scratch.
+    if any, ``q, k, v, dO``, the ``lse`` and ``D`` rows, the query's and
+    the key's shared parts where ``latent``, the selection's words where
+    ``sparse``, the outputs ``dk, dv`` and, where ``latent``, the shared
+    key's gradient from this key head's query heads, the scratch.
 
     Key-major: the block is ``[bk, bq]``, keys on the sublanes and queries
     on the lanes, so both accumulating products contract the block's minor
@@ -670,8 +712,12 @@ def _flash_bwd_dkv_kernel(
 
     tables, refs = refs[: len(steps.tables)], refs[len(steps.tables):]
     q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, *refs = refs
+    qs_ref, ks_ref = (refs.pop(0), refs.pop(0)) if latent else (None, None)
     sel_ref = refs.pop(0) if sparse else None
-    dk_ref, dv_ref, dk_scr, dv_scr = refs
+    if latent:
+        dk_ref, dv_ref, dks_ref, dk_scr, dv_scr, dks_scr = refs
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = refs
     at = steps.here(*tables)
     ki, _, qi = steps.blocks(*at)
     first, last, runs = steps.edges(*at)
@@ -680,6 +726,8 @@ def _flash_bwd_dkv_kernel(
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
+        if latent:
+            dks_scr[...] = jnp.zeros_like(dks_scr[...])
 
     def _update(masked=True):
         q = q_ref[0]
@@ -690,6 +738,7 @@ def _flash_bwd_dkv_kernel(
             k, q, lse_ref[0], scale, masked, 0, qi, ki, block_q, block_k,
             seq_len, causal, window,
             None if sel_ref is None else _selected_block(sel_ref, block_q, True),
+            None if qs_ref is None else (ks_ref, qs_ref),
         )  # [bk, bq]
         dv_scr[...] = dv_scr[...] + jax.lax.dot(
             p, do, preferred_element_type=jnp.float32
@@ -704,6 +753,10 @@ def _flash_bwd_dkv_kernel(
         dk_scr[...] = dk_scr[...] + jax.lax.dot(
             ds, q, preferred_element_type=jnp.float32
         ) * scale
+        if latent:
+            dks_scr[...] = dks_scr[...] + jax.lax.dot(
+                ds, qs_ref[0], preferred_element_type=jnp.float32
+            ) * scale
 
     _update_block(runs, qi, ki, block_q, block_k, window, _update)
 
@@ -711,6 +764,8 @@ def _flash_bwd_dkv_kernel(
     def _fin():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        if latent:
+            dks_ref[0] = dks_scr[...]
 
 
 def _flash_bwd_dq_kernel(
@@ -723,10 +778,13 @@ def _flash_bwd_dq_kernel(
     seq_len: int,
     window: Optional[int] = None,
     sparse: bool = False,
+    latent: bool = False,
 ):
     """dQ: the forward's grid and ``refs`` but for ``dO`` and the ``lse``
-    and ``D`` rows after ``v`` (the words after them) and the one output; ``dq += ds @ k · scale``
-    accumulates in VMEM across a query block's key blocks.
+    and ``D`` rows after ``v`` (the shared parts and the words after them)
+    and the output, ``dq``, and where ``latent`` the query's shared part's
+    too; ``dq += ds @ k · scale`` accumulates in VMEM across a query
+    block's key blocks.
 
     Query-major, since ``ds @ k`` contracts the keys: the block is ``[bq,
     bk]`` and the statistics are wanted as columns. A query block's first
@@ -737,8 +795,12 @@ def _flash_bwd_dq_kernel(
 
     tables, refs = refs[: len(steps.tables)], refs[len(steps.tables):]
     q_ref, k_ref, v_ref, do_ref, lse_ref, d_ref, *refs = refs
+    qs_ref, ks_ref = (refs.pop(0), refs.pop(0)) if latent else (None, None)
     sel_ref = refs.pop(0) if sparse else None
-    dq_ref, lse_scr, d_scr, dq_scr = refs
+    if latent:
+        dq_ref, dqs_ref, lse_scr, d_scr, dq_scr, dqs_scr = refs
+    else:
+        dq_ref, lse_scr, d_scr, dq_scr = refs
     at = steps.here(*tables)
     qi, _, ki = steps.blocks(*at)
     first, last, runs = steps.edges(*at)
@@ -746,6 +808,8 @@ def _flash_bwd_dq_kernel(
     @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
+        if latent:
+            dqs_scr[...] = jnp.zeros_like(dqs_scr[...])
         lanes = (lse_scr.shape[1], block_q)
         lse_scr[...] = jnp.broadcast_to(lse_ref[0], lanes).T
         d_scr[...] = jnp.broadcast_to(d_ref[0], lanes).T
@@ -759,6 +823,7 @@ def _flash_bwd_dq_kernel(
             q, k, lse_scr[:, :1], scale, masked, 1, qi, ki, block_q, block_k,
             seq_len, causal, window,
             None if sel_ref is None else _selected_block(sel_ref, block_q, False),
+            None if qs_ref is None else (qs_ref, ks_ref),
         )  # [bq, bk]
         dp = jax.lax.dot_general(
             do,
@@ -772,17 +837,25 @@ def _flash_bwd_dq_kernel(
             k.astype(jnp.float32),
             preferred_element_type=jnp.float32,
         ) * scale
+        if latent:
+            dqs_scr[...] = dqs_scr[...] + jax.lax.dot(
+                ds,
+                ks_ref[0].astype(jnp.float32),
+                preferred_element_type=jnp.float32,
+            ) * scale
 
     _update_block(runs, qi, ki, block_q, block_k, window, _update)
 
     @pl.when(last)
     def _fin():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        if latent:
+            dqs_ref[0] = dqs_scr[...].astype(dqs_ref.dtype)
 
 
 def _flash_backward_pallas(
     q, k, v, out, m, l, ct, causal, block_q, block_k, interpret, window=None,
-    selected=None,
+    selected=None, k_shared=None,
 ):
     """Fused flash backward: two Pallas kernels (dK/dV, a key block's
     query blocks in a row, and dQ, a query block's key blocks) consuming
@@ -800,7 +873,11 @@ def _flash_backward_pallas(
     them (keys on the sublanes: no ``[bq, bk]`` block is transposed for its
     two products, and a statistic's block is a row of ``bq``); dQ contracts
     the keys, so it stays query-major and turns a query block's rows into
-    columns once, at the block's first step."""
+    columns once, at the block's first step.
+
+    With ``k_shared`` it returns a fourth gradient, the shared key's from
+    each key head's query heads, ``[b, t, kv_heads, d_s]`` float32, for the
+    caller to sum over the heads."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -823,7 +900,14 @@ def _flash_backward_pallas(
             )
         return x.reshape(b * h * nq, 1, bq)
 
-    qb = _to_bh(q, tq_pad)
+    latent = k_shared is not None
+    dn = k.shape[-1]  # the width of the part of a key that is its head's own
+    if latent:
+        ds = d - dn
+        qb, qsb = _to_bh(q[..., :dn], tq_pad), _to_bh(q[..., dn:], tq_pad)
+        shared = (qsb, _to_bh(k_shared, tk_pad))
+    else:
+        qb, shared = _to_bh(q, tq_pad), ()
     kb = _to_bh(k, tk_pad)
     vb = _to_bh(v, tk_pad)
     # Native dtype: the kernels cast each dO block to f32 on load, so a
@@ -845,6 +929,8 @@ def _flash_backward_pallas(
         scale=scale, causal=causal, block_q=bq, block_k=bk, seq_len=t,
         window=window,
     )
+    if latent:
+        of_kernel["latent"] = True
     if sparse:
         of_kernel["sparse"] = True
         live = block_work(selected, bk)
@@ -872,25 +958,42 @@ def _flash_backward_pallas(
 
     stat_rows = _stat_rows(bq, nq, index)
     sel_rows = [_selection_rows(by_key, bq, bk, nq, hk, True)] if sparse else []
-    dkb, dvb = pl.pallas_call(
+    in_shared = out_shared = scratch_shared = shape_shared = []
+    if latent:
+        # The shared key's block is its batch's: every head of a batch reads it.
+        in_shared = [
+            q_rows(ds),
+            pl.BlockSpec(
+                (1, bk, ds),
+                lambda bkv, *at: (
+                    bkv // hk, by_key.blocks(*by_key.mapped(bkv, at))[0], 0
+                ),
+            ),
+        ]
+        out_shared = [kv_rows(ds)]
+        scratch_shared = [pltpu.VMEM((bk, ds), jnp.float32)]
+        shape_shared = [jax.ShapeDtypeStruct((b * hk, tk_pad, ds), jnp.float32)]
+    dkb, dvb, *dksb = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, steps=by_key, **of_kernel),
         **by_key.call(
             b * hk,
-            in_specs=[q_rows(d), kv_rows(d), kv_rows(dv), q_rows(dv),
-                      stat_rows, stat_rows, *sel_rows],
-            out_specs=[kv_rows(d), kv_rows(dv)],
+            in_specs=[q_rows(dn), kv_rows(dn), kv_rows(dv), q_rows(dv),
+                      stat_rows, stat_rows, *in_shared, *sel_rows],
+            out_specs=[kv_rows(dn), kv_rows(dv), *out_shared],
             scratch_shapes=[
-                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, dn), jnp.float32),
                 pltpu.VMEM((bk, dv), jnp.float32),
+                *scratch_shared,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b * hk, tk_pad, d), k.dtype),
+            jax.ShapeDtypeStruct((b * hk, tk_pad, dn), k.dtype),
             jax.ShapeDtypeStruct((b * hk, tk_pad, dv), v.dtype),
+            *shape_shared,
         ],
         interpret=interpret,
-        name=_kernel_name(window, "bwd_dkv", sparse),
-    )(*by_key.tables, qb, kb, vb, dob, lse, big_d, *words)
+        name=_kernel_name(window, "bwd_dkv", sparse, latent),
+    )(*by_key.tables, qb, kb, vb, dob, lse, big_d, *shared, *words)
 
     # dQ: the forward's steps.
     q_rows2, kv_rows2, stat_rows2 = _specs_by_query(
@@ -898,29 +1001,41 @@ def _flash_backward_pallas(
     )
     if sparse:
         sel_rows = [_selection_rows(by_query, bq, bk, nq, h, False)]
+    out_specs = q_rows2(dn)
+    scratch = [pltpu.VMEM((bq, dn), jnp.float32)]
+    out_shape = jax.ShapeDtypeStruct((b * h, tq_pad, dn), q.dtype)
+    if latent:
+        shared_rows = _specs_by_query(by_query, bq, bk, nq, _kv_head_map(h, 1))[1]
+        in_shared = [q_rows2(ds), shared_rows(ds)]
+        out_specs = [out_specs, q_rows2(ds)]
+        scratch.append(pltpu.VMEM((bq, ds), jnp.float32))
+        out_shape = [out_shape, jax.ShapeDtypeStruct((b * h, tq_pad, ds), q.dtype)]
     dqb = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, steps=by_query, **of_kernel),
         **by_query.call(
             b * h,
-            in_specs=[q_rows2(d), kv_rows2(d), kv_rows2(dv), q_rows2(dv),
-                      stat_rows2, stat_rows2, *sel_rows],
-            out_specs=q_rows2(d),
+            in_specs=[q_rows2(dn), kv_rows2(dn), kv_rows2(dv), q_rows2(dv),
+                      stat_rows2, stat_rows2, *in_shared, *sel_rows],
+            out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),  # lse, lanes replicated
                 pltpu.VMEM((bq, 128), jnp.float32),  # D, lanes replicated
-                pltpu.VMEM((bq, d), jnp.float32),
+                *scratch,
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b * h, tq_pad, d), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
-        name=_kernel_name(window, "bwd_dq", sparse),
-    )(*by_query.tables, qb, kb, vb, dob, lse, big_d, *words)
+        name=_kernel_name(window, "bwd_dq", sparse, latent),
+    )(*by_query.tables, qb, kb, vb, dob, lse, big_d, *shared, *words)
 
     def from_bh(x):
         x = x[:, :t].reshape(b, -1, t, x.shape[-1])
         return jnp.transpose(x, (0, 2, 1, 3))
 
-    return from_bh(dqb), from_bh(dkb), from_bh(dvb)
+    if not latent:
+        return from_bh(dqb), from_bh(dkb), from_bh(dvb)
+    dq = jnp.concatenate([from_bh(dqb[0]), from_bh(dqb[1])], axis=-1)
+    return dq, from_bh(dkb), from_bh(dvb), from_bh(dksb[0])
 
 
 # What the fused backward reads of the forward kernel, under
@@ -1062,6 +1177,65 @@ def _sparse_bwd(block_q, block_k, interpret, res, ct):
 
 _sparse_vjp.defvjp(_sparse_fwd, _sparse_bwd)
 
+_SHARED_DIMS = (DATA_AXIS, None, None, None)  # a shared key part's [b, t, 1, d_s]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _latent_vjp(q, k, v, k_shared, causal, block_q, block_k, interpret):
+    return _latent_fwd(q, k, v, k_shared, causal, block_q, block_k, interpret)[0]
+
+
+def _latent_fwd(q, k, v, k_shared, causal, block_q, block_k, interpret):
+    """The forward with a shared key part; the residuals named as
+    :func:`_fwd` names them."""
+
+    def run(q, k, v, k_shared):
+        return _flash_forward(
+            q, k, v, causal, block_q, block_k, interpret, return_stats=True,
+            k_shared=k_shared,
+        )
+
+    out, m, l = over_mesh(
+        run, in_dims=[_QKV_DIMS] * 3 + [_SHARED_DIMS],
+        out_dims=[_QKV_DIMS, _STAT_DIMS, _STAT_DIMS],
+    )(q, k, v, k_shared)
+    out = checkpoint_name(out, ATTENTION_OUT)
+    m = checkpoint_name(m, ATTENTION_STATS)
+    l = checkpoint_name(l, ATTENTION_STATS)
+    return out, (q, k, v, k_shared, out, m, l)
+
+
+def _latent_bwd(causal, block_q, block_k, interpret, res, ct):
+    """dQ, dK, dV and the shared part's gradient: the kernels' rows of it, a
+    key head's query heads each, summed over the heads here (outside the
+    mesh's split, so that heads on other devices are summed too)."""
+    q, k, v, k_shared, out, m, l = res
+
+    def run(q, k, v, k_shared, out, m, l, ct):
+        return _flash_backward_pallas(
+            q, k, v, out, m, l, ct, causal, block_q, block_k, interpret,
+            k_shared=k_shared,
+        )
+
+    dq, dk, dv, dks = over_mesh(
+        run,
+        in_dims=[_QKV_DIMS] * 3 + [_SHARED_DIMS, _QKV_DIMS] + [_STAT_DIMS] * 2
+        + [_QKV_DIMS],
+        out_dims=[_QKV_DIMS] * 4,
+    )(q, k, v, k_shared, out, m, l, ct)
+    return dq, dk, dv, jnp.sum(dks, axis=2, keepdims=True).astype(k_shared.dtype)
+
+
+_latent_vjp.defvjp(_latent_fwd, _latent_bwd)
+
+
+def _with_shared(k: jax.Array, k_shared: jax.Array) -> jax.Array:
+    """Each key head with the shared part appended, repeated in HBM (the XLA
+    paths only)."""
+    return jnp.concatenate(
+        [k, jnp.broadcast_to(k_shared, (*k.shape[:3], k_shared.shape[-1]))], axis=-1
+    )
+
 
 def _sparse_reference(q, k, v, selected):
     """Dense softmax attention over the causal keys the selection keeps:
@@ -1106,12 +1280,19 @@ def flash_attention(
     interpret: bool = False,
     window: Optional[int] = None,
     selected: Optional[jax.Array] = None,
+    k_shared: Optional[jax.Array] = None,
 ):
     """Fused attention over ``q [batch, seq, heads, head_dim]``, ``k
     [batch, seq, kv_heads, head_dim]`` and ``v [batch, seq, kv_heads,
     value_dim]``, to ``[batch, seq, heads, value_dim]``; ``kv_heads`` divides
     ``heads`` (grouped-query attention: query head ``i`` reads key/value
     head ``i // (heads // kv_heads)``).
+
+    ``k_shared`` ``[batch, seq, 1, d_s]`` (no ``window``, no ``selected``):
+    a key part that every head reads, so ``k`` is ``[.., head_dim - d_s]``
+    and a score is ``q[..., :-d_s] · k + q[..., -d_s:] · k_shared``, scaled
+    by ``1 / sqrt(head_dim)``; the kernels are ``flash_attention_latent_*``,
+    and the shared part's gradient is summed over the heads.
 
     ``window`` (with ``causal``): position ``i`` sees the ``window`` keys
     ``i - window < j <= i``, itself among them, and the kernels visit the
@@ -1146,6 +1327,17 @@ def flash_attention(
             window = None
     if use_pallas is None:
         use_pallas = auto_pallas()
+    if k_shared is not None:
+        if window is not None or selected is not None:
+            raise ValueError("a shared key part is of attention without a window or a selection")
+        if k_shared.shape != (*k.shape[:2], 1, q.shape[-1] - k.shape[-1]):
+            raise ValueError(
+                f"a shared key part of {k_shared.shape} does not complete keys of "
+                f"{k.shape} to queries of {q.shape}"
+            )
+        if use_pallas:
+            return _latent_vjp(q, k, v, k_shared, causal, block_q, block_k, interpret)
+        k = _with_shared(k, k_shared)
     if selected is not None:
         if not causal or window is not None:
             raise ValueError("a selection is of causal attention without a window")
