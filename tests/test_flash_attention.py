@@ -12,6 +12,7 @@ import pytest
 
 
 from ray_shuffling_data_loader_tpu.ops import attention_reference
+from ray_shuffling_data_loader_tpu.ops import sparse_attention as sa
 from ray_shuffling_data_loader_tpu.ops.flash_attention import (
     ATTENTION_OUT,
     ATTENTION_STATS,
@@ -33,31 +34,106 @@ def _qkv(shape, seed=0, dtype=jnp.float32):
     )
 
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize(
-    "shape,blocks",
-    [
-        ((2, 64, 2, 8), (16, 16)),  # multiple kv blocks per q block
-        ((1, 56, 2, 8), (16, 24)),  # ragged: seq divides neither block
-        ((2, 8, 1, 4), (128, 128)),  # seq smaller than the block
-    ],
-)
-def test_matches_dense_reference(causal, shape, blocks):
-    q, k, v = _qkv(shape, seed=1)
-    got = flash_attention(
-        q,
-        k,
-        v,
-        causal=causal,
-        use_pallas=True,
-        block_q=blocks[0],
-        block_k=blocks[1],
-        interpret=True,
+def _dense_forward(q, k, v, admitted, k_shared=None):
+    """``(out, m, l)`` of dense float32 attention over the ``admitted [b, t,
+    t]`` pairs, ``m`` and ``l`` ``[b, h, t]``: the softmax statistics as the
+    kernel keeps them, ``NEG_INF`` and 0 (and an output of 0) on a query
+    that admits no key."""
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    if k_shared is not None:
+        k = jnp.concatenate([k, jnp.broadcast_to(k_shared, (*k.shape[:3], k_shared.shape[-1]))], -1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") / np.sqrt(q.shape[-1])
+    s = jnp.where(admitted[:, None], s, flash_module.NEG_INF)
+    m = jnp.max(s, axis=-1)
+    p = jnp.where(admitted[:, None], jnp.exp(s - m[..., None]), 0.0)
+    l = jnp.sum(p, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p / jnp.maximum(l, 1e-30)[..., None], v,
+                     precision="highest")
+    return out, m, l
+
+
+# The first three shapes, causal or not, through ``flash_attention`` as they
+# always were; then groups of 1 / 2 / 4, values of another width than the
+# heads, ragged sequences under unequal blocks, windows, tables in runs, the
+# latent split and the sparse selection, with the statistics (``more``: the
+# key heads, the values' width, the window, ``latent`` the shared key's
+# width, ``sparse`` a selection's share, ``limit`` the table entries).
+_DENSE_SHAPES = [
+    ((2, 64, 2, 8), (16, 16)),  # multiple kv blocks per q block
+    ((1, 56, 2, 8), (16, 24)),  # ragged: seq divides neither block
+    ((2, 8, 1, 4), (128, 128)),  # seq smaller than the block
+]
+_DENSE_CASES = [
+    pytest.param(causal, shape, blocks, {}, id=f"shape{i}-blocks{i}-{causal}")
+    for causal in (False, True) for i, (shape, blocks) in enumerate(_DENSE_SHAPES)
+] + [
+    pytest.param(True, (2, 64, 4, 8), (16, 16), {"kv": 2, "dv": 16}, id="group2-values16"),
+    pytest.param(True, (1, 75, 4, 8), (32, 16), {"kv": 1}, id="group4-ragged"),
+    pytest.param(False, (1, 90, 4, 8), (16, 32), {"kv": 2, "dv": 16}, id="noncausal-group2-ragged"),
+    pytest.param(True, (1, 64, 2, 8), (16, 16), {"window": 7}, id="window7"),
+    pytest.param(True, (1, 75, 4, 8), (32, 16), {"kv": 2, "dv": 16, "window": 16},
+                 id="window16-group2-ragged"),
+    pytest.param(True, (1, 64, 2, 8), (16, 32), {"window": 40}, id="window40"),
+    pytest.param(True, (1, 90, 2, 8), (16, 32), {"limit": 6}, id="tables-in-runs"),
+    pytest.param(True, (1, 75, 4, 8), (16, 16), {"kv": 2, "window": 16, "limit": 6},
+                 id="window16-tables-in-runs"),
+    pytest.param(True, (1, 64, 4, 12), (32, 16), {"latent": 4}, id="latent"),
+    pytest.param(False, (2, 48, 4, 12), (16, 16), {"kv": 2, "latent": 4, "dv": 16},
+                 id="latent-noncausal-group2"),
+    pytest.param(True, (1, 64, 4, 8), (32, 16), {"kv": 2, "sparse": 0.3}, id="sparse"),
+    pytest.param(True, (2, 64, 2, 8), (64, 32), {"sparse": 0.1, "dv": 16}, id="sparse-values16"),
+]
+
+
+@pytest.mark.parametrize("causal,shape,blocks,more", _DENSE_CASES)
+def test_matches_dense_reference(monkeypatch, causal, shape, blocks, more):
+    """The forward's output and its softmax statistics ``m``, ``l`` against
+    dense float32 attention, within float32 round-off; a query whose
+    selection keeps no key finishes as 0, with ``m`` at ``NEG_INF`` and
+    ``l`` 0."""
+    if not more:
+        q, k, v = _qkv(shape, seed=1)
+        got = flash_attention(
+            q, k, v, causal=causal, use_pallas=True, block_q=blocks[0],
+            block_k=blocks[1], interpret=True,
+        )
+        want = attention_reference(q, k, v, causal=causal)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
+        )
+    if "limit" in more:
+        monkeypatch.setattr(flash_module, "MAX_TABLE_ENTRIES", more["limit"])
+    b, t, h, d = shape
+    rng = np.random.default_rng(t + h + d)
+    normal = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    shared = more.get("latent", 0)
+    hk = more.get("kv", h)
+    q, k = normal(b, t, h, d), normal(b, t, hk, d - shared)
+    v = normal(b, t, hk, more.get("dv", d))
+    k_shared = normal(b, t, 1, shared) if shared else None
+    pos = np.arange(t)
+    admitted = np.ones((b, t, t), bool)
+    if causal:
+        admitted &= pos[:, None] >= pos[None, :]
+    window = more.get("window")
+    if window is not None:
+        admitted &= pos[:, None] - pos[None, :] < window
+    words = None
+    if "sparse" in more:
+        admitted &= rng.random((b, t, t)) < more["sparse"]
+        admitted[:, 5] = False  # a query that keeps no key
+        words = sa.pack(jnp.asarray(admitted), blocks[0])
+    out, m, l = flash_module._flash_forward(
+        q, k, v, causal, *blocks, True, return_stats=True, window=window,
+        selected=words, k_shared=k_shared,
     )
-    want = attention_reference(q, k, v, causal=causal)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
-    )
+    want = _dense_forward(q, k, v, jnp.asarray(admitted), k_shared)
+    for got, w in zip((out, m, l), want):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(w), rtol=2e-5, atol=2e-5)
+    if "sparse" in more:
+        assert not np.any(np.asarray(out)[:, 5]) and not np.any(np.asarray(l)[:, :, 5])
+        assert np.all(np.asarray(m)[:, :, 5] == flash_module.NEG_INF)
 
 
 # -- the grid: the blocks that hold work, each once, in the kernels' order ---------
@@ -213,13 +289,19 @@ def _pallas_grids(jaxpr):
     }
 
 
-def _dots(jaxpr):
-    """Every ``dot_general`` under ``jaxpr``, into its ``cond``s' branches."""
+def _eqns(jaxpr, names):
+    """Every equation of a primitive in ``names`` under ``jaxpr``, into its
+    ``cond``s' branches."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
+        if eqn.primitive.name in names:
             yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _dots(sub)
+            yield from _eqns(sub, names)
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` under ``jaxpr``, into its ``cond``s' branches."""
+    return _eqns(jaxpr, ("dot_general",))
 
 
 @pytest.mark.parametrize("window", [None, 16])
@@ -241,6 +323,47 @@ def test_the_backward_reads_lane_dense_rows_and_runs_dkv_key_major(window):
     contracted = [e.params["dimension_numbers"][0][0] for e in _dots(calls[dkv].params["jaxpr"])]
     assert len(contracted) == (4 if window is None else 8)
     assert all(0 not in c for c in contracted), contracted
+
+
+@pytest.mark.parametrize("kind", ["plain", "window", "latent", "sparse"])
+def test_the_forward_writes_lane_dense_rows_and_runs_key_major(kind):
+    """No operand or output of the forward kernel is a column (``[.., t,
+    1]``: ``m`` and ``l`` leave it as rows), every reduction in its body
+    runs over the keys down the sublanes (axis 0 of a ``[bk, bq]`` block),
+    and no product contracts a block's first dimension (a ``[bq, bk]`` or
+    ``[bk, bq]`` block transposed)."""
+    q, k, v = _qkv((1, 64, 4, 8), seed=13)
+    k, v = k[:, :, :2], v[:, :, :2]
+    kw = {}
+    if kind == "window":
+        kw["window"] = 16
+    elif kind == "latent":
+        k, kw["k_shared"] = k[..., :6], k[:, :, :1, 6:]
+    elif kind == "sparse":
+        kw["selected"] = sa.pack(jnp.ones((1, 64, 64), bool), 32)
+    fwd = jax.make_jaxpr(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, use_pallas=True, interpret=True,
+            block_q=32, block_k=16, **kw,
+        )
+    )
+    calls = _pallas_calls(fwd(q, k, v).jaxpr)
+    name = flash_module._kernel_name(
+        kw.get("window"), "fwd", kind == "sparse", kind == "latent"
+    )
+    assert list(calls) == [name]
+    call = calls[name]
+    shapes = [x.aval.shape for x in (*call.invars, *call.outvars)]
+    assert [s for s in shapes if s[-1:] == (1,)] == [], shapes
+    body = call.params["jaxpr"]
+    reduced = [e.params["axes"] for e in _eqns(body, ("reduce_max", "reduce_sum"))]
+    assert len(reduced) == (2 if kind != "window" else 4)
+    assert all(axes == (0,) for axes in reduced), reduced
+    blocks = {(32, 16), (16, 32)}
+    for dot in _dots(body):
+        lhs = dot.invars[0].aval.shape
+        (contracted, _), _ = dot.params["dimension_numbers"]
+        assert not (lhs in blocks and 0 in contracted), (lhs, contracted)
 
 
 def test_a_causal_head_takes_a_step_a_block_with_work_and_the_step_says_so(

@@ -2,8 +2,8 @@
 sparse attention kernels and the indexer's loss against plain formulas, the
 softmax router, text MRoPE, the expert shares against the uncut layer, the
 program against the benchmark's plain float32 reference, what the step keeps
-and says, and that the attention kernels without a selection are the
-parent's. CPU only, toy sizes, the kernels in the Pallas interpreter."""
+and says, and that the attention kernels without a selection trace to the
+pinned text. CPU only, toy sizes, the kernels in the Pallas interpreter."""
 
 import collections
 import contextlib
@@ -635,7 +635,7 @@ def test_the_family_s_tree_carries_every_leaf_there_and_back(family):
     assert all(back[k] is weights[k] for k in weights)
 
 
-# -- the sisters' attention kernels are the parent's --------------------------------------
+# -- the sisters' attention kernels trace to the pinned text ------------------------------
 
 
 CALLS = {
@@ -650,9 +650,11 @@ CALLS = {
 
 @pytest.mark.parametrize("call", sorted(CALLS))
 def test_without_a_selection_the_attention_call_is_the_parent_s(call):
-    """Forward and backward of the sister cells' calls trace to the text
-    their parent traced (digests in ``tests/fixtures``, taken from the
-    parent's own code; LFM2's call is ``tests/test_laguna.py``'s pin)."""
+    """Forward and backward of the sister cells' calls trace to the pinned
+    text (digests in ``tests/fixtures``: a selection moves none of them, and
+    a change to the plain kernels pins them again from its own trace, the
+    forward key-major on rows the last; LFM2's call is
+    ``tests/test_laguna.py``'s pin)."""
     with open(os.path.join(ROOT, "tests", "fixtures", "flash_traced_without_selection.json")) as f:
         pinned = json.load(f)
     if pinned["jax"] != jax.__version__:

@@ -4,7 +4,7 @@ the XLA path and against the plain kernels on the repeated key, the
 ``noaux_tc`` router, the expert shares against the uncut layer, the program
 against the benchmark's plain float32 reference, what the step keeps and
 says, that the rotary key reaches the kernels as one head, and that the
-attention kernels without a shared key lower to the parent's text. CPU
+attention kernels without a shared key lower to the pinned text. CPU
 only, toy sizes, the kernels in the Pallas interpreter."""
 
 import collections
@@ -445,7 +445,7 @@ def test_the_family_s_tree_carries_every_leaf_there_and_back(family):
     assert all(back[k] is weights[k] for k in weights)
 
 
-# -- the sisters' attention kernels lower to the parent's text --------------------------------
+# -- the sisters' attention kernels lower to the pinned text ----------------------------------
 
 
 LOWERED = {
@@ -463,7 +463,9 @@ LOWERED = {
 def test_without_a_shared_key_the_kernels_lower_to_the_parent_s_text(call):
     """Forward and backward of the sister cells' calls at their shapes,
     lowered for the TPU (Mosaic's kernels serialised in the text), against
-    the digests the parent's own code gave (``tests/fixtures``). Source
+    the digests pinned in ``tests/fixtures``: a shared key part moves none
+    of them, and a change to the plain kernels pins them again from its own
+    lowering (the forward key-major on rows was the last). Source
     locations are left out of the lowering on both sides: a kernel's text
     otherwise carries the line numbers of the file it was written in."""
     with open(os.path.join(ROOT, "tests", "fixtures", "flash_lowered_without_shared_key.json")) as f:

@@ -1,7 +1,7 @@
 """Laguna (ISSUE 32): the windowed attention kernels against plain masked
 softmax attention, the program's model against the benchmark's plain float32
 reference, the expert shares against the uncut layer, the rotary tables
-against their formulas, and that LFM2's traced step is the parent's. CPU
+against their formulas, and that LFM2's traced step is the pinned one. CPU
 only, toy sizes, the kernels in the Pallas interpreter."""
 
 import collections
@@ -182,10 +182,10 @@ def _traced(jaxpr) -> str:
 
 @pytest.fixture(scope="module")
 def pinned_traces():
-    """Digests of the traced text as ISSUE 37 left it (the fused backward
-    on lane-dense ``lse`` and ``D`` rows, dK/dV key-major; before it the
-    pins were ISSUE 35's, the kernels' grids over the blocks with work, and
-    before that ``01a534d``'s and ISSUE 33's). A change to ``models/blocks.py``
+    """Digests of the traced text as the key-major forward left it (its
+    ``m`` and ``l`` lane-dense rows, the values read as turned blocks), on
+    the fused backward's lane-dense ``lse`` and ``D`` rows with dK/dV
+    key-major and the kernels' grids over the blocks with work. A change to ``models/blocks.py``
     or ``ops/flash_attention.py`` that is not meant to move LFM2's step
     leaves them; one that is pins again and says why. A jaxpr's text
     belongs to one jax version."""
@@ -246,7 +246,7 @@ def test_lfm2_s_traced_step_is_the_pinned_one(pinned_traces):
     ``ops/moe.py`` (nine ``name`` equations an expert layer); ISSUE 35
     gave the three attention kernels their tables and a grid of two axes,
     and ISSUE 37 the backward kernels their statistics as rows: each pinned
-    the step again as it then stood."""
+    the step again as it then stood, and so did the key-major forward."""
     traced = _lfm2_step_traced()
     assert str(traced).count(f"name[name={moe.ROUTING}]") == pinned_traces["names"] == 9 * 4
     assert _traced(traced) == pinned_traces["step"]
