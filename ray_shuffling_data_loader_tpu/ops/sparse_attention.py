@@ -11,7 +11,13 @@ and each query ``t`` keeps exactly the ``min(topk, t + 1)`` keys with the
 largest ``I[t, s]``, ties going to the lower index (as ``lax.top_k`` breaks
 them). The scores that decide the selection are float32 at the highest
 matmul precision: the selection is discrete, and a near tie decided by
-bfloat16 rounding moves a key.
+bfloat16 rounding moves a key. In the kernels a float32 product (the
+scores' ``z``, and the loss's ``dq`` and ``dkᵀ``) is ``highest``'s six
+bfloat16 terms, each operand split into three bfloat16 parts (hi·hi,
+hi·mid, mid·hi, hi·lo, lo·hi, mid·mid, all kept), issued side by side
+along one contraction or one output (:func:`_lhs6`, :func:`_dq6`,
+:func:`_dkt6`), so that heads of 64 fill the matrix unit where six
+passes of depth 64 would each fill half of it.
 
 The selection is a packed bitmask, ``[batch, nq, R, seq]`` int32 for query
 blocks of ``block_q`` (``nq = seq / block_q``, ``R = block_q / 32``): bit
@@ -215,9 +221,97 @@ def _columns(row, lanes: int = 128):
 
 def _vmem_bytes(shape, itemsize: int = 4) -> int:
     """Bytes a VMEM array of ``shape`` takes, its last two dimensions padded
-    to the (8, 128) tile."""
+    to the tile: (8, 128) of 4-byte numbers, (16, 128) of 2-byte ones."""
     *lead, rows, lanes = shape
-    return math.prod(lead) * -(-rows // 8) * 8 * -(-lanes // 128) * 128 * itemsize
+    tile = 32 // itemsize
+    return math.prod(lead) * -(-rows // tile) * tile * -(-lanes // 128) * 128 * itemsize
+
+
+def _check_vmem(kernel: str, scratch, detail: str) -> None:
+    """Refuse, at trace time, a kernel whose scratch (``(shape, dtype)``
+    pairs) would not fit under :data:`_VMEM_LIMIT`."""
+    need = sum(_vmem_bytes(s, jnp.dtype(dt).itemsize) for s, dt in scratch)
+    if need > _VMEM_LIMIT:
+        raise ValueError(
+            f"{kernel}'s scratch takes {need} B of VMEM, over the limit of "
+            f"{_VMEM_LIMIT}: {detail}"
+        )
+
+
+def _split3(x):
+    """A float32 array as three bfloat16 parts ``hi + mid + lo``: 24 bits of
+    mantissa in three of 8, ``x`` again to float32's rounding."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _dot(a, b):
+    return jax.lax.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _lhs6(a):
+    """``a [m, k]`` float32 -> ``[m, 6k]`` bfloat16, ``[hi|hi|mid|hi|lo|mid]``;
+    against ``_rhs6(b)`` ONE product of contraction ``6k``, accumulated in
+    float32, is ``a @ b`` at ``highest``: its six bfloat16 terms (hi·hi,
+    hi·mid, mid·hi, hi·lo, lo·hi, mid·mid) side by side, so the matrix unit
+    runs full depth where six products of depth ``k`` = 64 fill half of it."""
+    hi, mid, lo = _split3(a)
+    return jnp.concatenate([hi, hi, mid, hi, lo, mid], axis=1)
+
+
+def _rhs6(b):
+    """``b [k, n]`` float32 -> ``[6k, n]`` bfloat16, ``[hi; mid; hi; lo; hi;
+    mid]``: the right side of :func:`_lhs6`'s product."""
+    hi, mid, lo = _split3(b)
+    return jnp.concatenate([hi, mid, hi, lo, hi, mid], axis=0)
+
+
+def _dq_parts(kt):
+    """``kᵀ [id, bk]`` float32 -> ``[2 id, 4 bk]`` bfloat16: ``[[hi, hi, hi,
+    0], [mid, mid, 0, lo]]``, the right side of :func:`_dq6`."""
+    hi, mid, lo = _split3(kt)
+    zero = jnp.zeros_like(hi)
+    return jnp.concatenate([
+        jnp.concatenate([hi, hi, hi, zero], axis=1),
+        jnp.concatenate([mid, mid, zero, lo], axis=1),
+    ], axis=0)
+
+
+def _dq6(dz3, kt4):
+    """``dz @ k`` at ``highest`` of ``dz`` split (:func:`_split3`) and ``kt4
+    = _dq_parts(kᵀ)``: ONE product of contraction ``4 bk`` whose ``[bq, 2
+    id]`` output holds dz·k_hi (dz's three parts) in its first ``id`` lanes
+    and hi·mid, mid·mid, hi·lo in the rest, the six terms in an output as
+    wide as the array where each term of 64 lanes would fill half of it;
+    the caller sums the halves."""
+    hi, mid, lo = dz3
+    return jax.lax.dot_general(
+        jnp.concatenate([hi, mid, lo, hi], axis=1), kt4,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _stack3(x):
+    """``x [m, n]`` float32 -> its three parts stacked down the rows, ``[3m,
+    n]`` bfloat16, the left side of :func:`_dkt6`."""
+    return jnp.concatenate(_split3(x), axis=0)
+
+
+def _dkt6(qit3, dz3):
+    """``q^Iᵀ @ dz`` at ``highest`` of ``qit3 = _stack3(q^Iᵀ)`` and ``dz``
+    split: three products, each part of ``dz`` latched once against the
+    parts of ``q^Iᵀ`` it meets (hi against hi, mid, lo; mid against hi, mid;
+    lo against hi), the six terms."""
+    hi, mid, lo = dz3
+    m = qit3.shape[0] // 3
+    p3 = _dot(qit3, hi)
+    p2 = _dot(qit3[: 2 * m], mid)
+    return (p3[:m] + p3[m : 2 * m] + p3[2 * m :] + p2[:m] + p2[m:]
+            + _dot(qit3[:m], lo))
 
 
 def _lane_sum(blk, acc):
@@ -228,9 +322,11 @@ def _lane_sum(blk, acc):
 
 
 def _index_fwd_kernel(q_ref, kt_ref, w_ref, words_ref, lse_ref, key_scr,
-                      wcol_scr, *, heads, topk, block_q, block_k, seq):
+                      wcol_scr, q6_scr, *, heads, topk, block_q, block_k, seq):
     """One query block: its row of scores as int32 keys in VMEM, the exact
-    top-k, the words and the selected scores' log-sum-exp."""
+    top-k, the words and the selected scores' log-sum-exp. Each head's
+    ``q`` is split for :func:`_lhs6`'s product once, into ``q6_scr``; a key
+    block's ``kᵀ`` once, for all the heads."""
     from jax.experimental import pallas as pl
 
     i = pl.program_id(1)
@@ -241,16 +337,16 @@ def _index_fwd_kernel(q_ref, kt_ref, w_ref, words_ref, lse_ref, key_scr,
     col = jax.lax.broadcasted_iota(jnp.int32, rows, 1)
     for j in range(heads):
         wcol_scr[j] = _columns(w_ref[0, 0, j : j + 1, :])
+        q6_scr[j] = _lhs6(q_ref[0, j])
 
     def at(kb):
         return pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
 
     def score(kb, top):
-        kt = kt_ref[0, :, at(kb)]  # [dim, bk]
+        kt6 = _rhs6(kt_ref[0, :, at(kb)])  # [6 dim, bk]
         acc = jnp.zeros(rows, jnp.float32)
         for j in range(heads):
-            z = jax.lax.dot(q_ref[0, j], kt, precision=_HIGHEST,
-                            preferred_element_type=jnp.float32)
+            z = _dot(q6_scr[j], kt6)
             acc = acc + wcol_scr[j][:, :1] * jnp.maximum(z, 0.0)
         valid = q_pos >= kb * block_k + col
         key_scr[:, at(kb)] = jnp.where(valid, _order_key(acc), INT_MIN)
@@ -323,6 +419,11 @@ def _index_fwd_pallas(qh, kt, wr, topk, block_q, block_k, interpret):
 
     b, heads, t, dim = qh.shape
     nq, r = t // block_q, block_q // WORD
+    # The row of keys, w as lane-replicated columns, each head's q split.
+    scratch = [((block_q, t), jnp.int32), ((heads, block_q, 128), jnp.float32),
+               ((heads, block_q, 6 * dim), jnp.bfloat16)]
+    _check_vmem("sparse_index_fwd", scratch,
+                f"{heads} index heads of {dim}, {t} keys, block_q {block_q}")
     return pl.pallas_call(
         functools.partial(
             _index_fwd_kernel, heads=heads, topk=topk, block_q=block_q,
@@ -342,10 +443,7 @@ def _index_fwd_pallas(qh, kt, wr, topk, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((b, nq, r, t), jnp.int32),
             jax.ShapeDtypeStruct((b, nq, 1, block_q), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, t), jnp.int32),
-            pltpu.VMEM((heads, block_q, 128), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM(s, dt) for s, dt in scratch],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT,
@@ -358,14 +456,20 @@ def _index_fwd_pallas(qh, kt, wr, topk, block_q, block_k, interpret):
 def _index_bwd_kernel(
     q_ref, k_ref, lse_ref, qi_ref, qit_ref, kt_ref, w_ref, lsei_ref, words_ref,
     loss_ref, dq_ref, dw_ref, dkt_ref,
-    lse_scr, w_scr, lsei_scr, loss_scr, dq_scr, dw_scr, z_scr,
+    lse_scr, w_scr, lsei_scr, loss_scr, dq_scr, dw_scr, q6_scr, qit3_scr, z_scr,
     *, heads, group, index_heads, scale, inv_count, block_q, block_k,
 ):
     """One (query block, causal key block) pair of the indexer's loss:
     ``pbar`` over the main heads, ``dI``, and its gradients; a query block's
     loss, ``dq`` and ``dw`` accumulate across its key blocks, ``dk`` is this
     pair's part. Each index head's ``z = q^I @ k^Iᵀ`` is computed once a
-    pair, into ``z_scr``, and read back by the gradient loop."""
+    pair as :func:`_lhs6`'s product (``q^I`` split at the query block's
+    first step, into ``q6_scr``; ``k^Iᵀ`` once a pair), into ``z_scr``, and
+    read back by the gradient loop. The gradient's two products take the same six
+    bfloat16 terms, ``dz`` split once a head and ``k^Iᵀ`` once a pair:
+    ``dq`` by :func:`_dq6` into ``dq_scr``'s two halves (summed at the query
+    block's last step), ``dkᵀ`` by :func:`_dkt6` against ``q^Iᵀ``'s parts
+    (``qit3_scr``, split at the query block's first step)."""
     from jax.experimental import pallas as pl
 
     i, kb = pl.program_id(1), pl.program_id(2)
@@ -377,6 +481,8 @@ def _index_bwd_kernel(
             lse_scr[h] = _columns(lse_ref[0, 0, h : h + 1, :])
         for j in range(index_heads):
             w_scr[j] = _columns(w_ref[0, 0, j : j + 1, :])
+            q6_scr[j] = _lhs6(qi_ref[0, j])
+            qit3_scr[j] = _stack3(qit_ref[0, j])
         lsei_scr[...] = _columns(lsei_ref[0, 0])
         loss_scr[...] = jnp.zeros_like(loss_scr)
         dq_scr[...] = jnp.zeros_like(dq_scr)
@@ -399,10 +505,10 @@ def _index_bwd_kernel(
             pbar = pbar + jnp.exp(s - lse_scr[h][:, :1])
         pbar = jnp.where(sel, pbar * (1.0 / heads), 0.0)
         kt = kt_ref[0]  # [dim, bk]
+        kt6 = _rhs6(kt)
         scores = jnp.zeros(rows, jnp.float32)
         for j in range(index_heads):
-            z = jax.lax.dot(qi_ref[0, j], kt, precision=_HIGHEST,
-                            preferred_element_type=jnp.float32)
+            z = _dot(q6_scr[j], kt6)
             z_scr[j] = z
             scores = scores + w_scr[j][:, :1] * jnp.maximum(z, 0.0)
         log_soft = scores - lsei_scr[:, :1]
@@ -411,18 +517,15 @@ def _index_bwd_kernel(
         terms = jnp.where(pbar > 0, pbar * (jnp.log(safe) - log_soft), 0.0)
         loss_scr[...] = loss_scr[...] + jnp.sum(terms, axis=1, keepdims=True)
         d_scores = (soft - pbar) * inv_count
+        kt4 = _dq_parts(kt)
         dkt = jnp.zeros(dkt_ref.shape[2:], jnp.float32)
         for j in range(index_heads):
             z = z_scr[j]
             dw_scr[j] = dw_scr[j] + jnp.sum(d_scores * jnp.maximum(z, 0.0),
                                             axis=1, keepdims=True)
-            dz = jnp.where(z > 0, d_scores * w_scr[j][:, :1], 0.0)
-            dq_scr[j] = dq_scr[j] + jax.lax.dot_general(
-                dz, kt, dimension_numbers=(((1,), (1,)), ((), ())),
-                precision=_HIGHEST, preferred_element_type=jnp.float32,
-            )
-            dkt = dkt + jax.lax.dot(qit_ref[0, j], dz, precision=_HIGHEST,
-                                    preferred_element_type=jnp.float32)
+            dz3 = _split3(jnp.where(z > 0, d_scores * w_scr[j][:, :1], 0.0))
+            dq_scr[j] = dq_scr[j] + _dq6(dz3, kt4)
+            dkt = dkt + _dkt6(qit3_scr[j], dz3)
         dkt_ref[0, 0] = dkt
 
     @pl.when(kb == pl.num_programs(2) - 1)
@@ -430,7 +533,8 @@ def _index_bwd_kernel(
         loss_ref[0, 0] = jnp.broadcast_to(
             jnp.sum(loss_scr[...], axis=0, keepdims=True), (1, 128)
         )
-        dq_ref[0] = dq_scr[...]
+        idim = dq_ref.shape[3]
+        dq_ref[0] = dq_scr[:, :, :idim] + dq_scr[:, :, idim:]
         for j in range(index_heads):
             dw_ref[0, 0, j : j + 1, :] = jnp.broadcast_to(
                 dw_scr[j], (block_q, 128)
@@ -457,19 +561,21 @@ def _index_bwd_pallas(qi, ki, w, q, k, lse, lse_i, words, block_q, block_k,
     def kv_block(bi, i, kb):  # a causal key block; past the diagonal the last
         return jnp.minimum(kb, last(i))
 
-    # lse, w and lse_i as lane-replicated columns; the loss, dq and dw
-    # accumulators; each index head's z of the pair.
-    scratch = [(heads, block_q, 128), (ih, block_q, 128), (block_q, 128),
-               (block_q, 1), (ih, block_q, idim), (ih, block_q, 1),
-               (ih, block_q, block_k)]
-    need = sum(_vmem_bytes(s) for s in scratch)
-    if need > _VMEM_LIMIT:
-        raise ValueError(
-            f"sparse_index_bwd's scratch takes {need} B of VMEM, over the limit "
-            f"of {_VMEM_LIMIT}: {heads} heads, {ih} index heads of {idim}, "
-            f"blocks {block_q} / {block_k} (the index heads' z alone "
-            f"{_vmem_bytes(scratch[-1])} B)"
-        )
+    # lse, w and lse_i as lane-replicated columns; the loss, dq (its two
+    # halves) and dw accumulators; each index head's q^I split for z and
+    # q^Iᵀ's parts for dk; each index head's z of the pair.
+    f32 = jnp.float32
+    scratch = [((heads, block_q, 128), f32), ((ih, block_q, 128), f32),
+               ((block_q, 128), f32), ((block_q, 1), f32),
+               ((ih, block_q, 2 * idim), f32), ((ih, block_q, 1), f32),
+               ((ih, block_q, 6 * idim), jnp.bfloat16),
+               ((ih, 3 * idim, block_q), jnp.bfloat16),
+               ((ih, block_q, block_k), f32)]
+    _check_vmem(
+        "sparse_index_bwd", scratch,
+        f"{heads} heads, {ih} index heads of {idim}, blocks {block_q} / "
+        f"{block_k} (the index heads' z alone {_vmem_bytes(scratch[-1][0])} B)",
+    )
     qh = jnp.transpose(qi, (0, 2, 1, 3))  # [b, ih, t, id]
     rows = lambda x: x.reshape(b, -1, nq, block_q).transpose(0, 2, 1, 3)  # noqa: E731
     loss, dq, dw, dkt = pl.pallas_call(
@@ -506,7 +612,7 @@ def _index_bwd_pallas(qi, ki, w, q, k, lse, lse_i, words, block_q, block_k,
             jax.ShapeDtypeStruct((b, nq, ih, block_q), jnp.float32),
             jax.ShapeDtypeStruct((b, nq, idim, t), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in scratch],
+        scratch_shapes=[pltpu.VMEM(s, dt) for s, dt in scratch],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,

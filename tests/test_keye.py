@@ -199,7 +199,8 @@ def _loss_inputs(bq=32, bk=16, seed=2):
 
 class _ZAgain:
     """Stands in for the loss kernel's ``z_scr``: a write is dropped, a read
-    of head ``j`` is its product ``q^I_j @ k^Iᵀ`` again."""
+    of head ``j`` is its product ``q^I_j @ k^Iᵀ`` again, in the kernel's
+    six bfloat16 terms."""
 
     def __init__(self, qi_ref, kt_ref):
         self.qi_ref, self.kt_ref = qi_ref, kt_ref
@@ -208,8 +209,7 @@ class _ZAgain:
         pass
 
     def __getitem__(self, j):
-        return jax.lax.dot(self.qi_ref[0, j], self.kt_ref[0], precision="highest",
-                           preferred_element_type=jnp.float32)
+        return sa._dot(sa._lhs6(self.qi_ref[0, j]), sa._rhs6(self.kt_ref[0]))
 
 
 def _recomputing_z(monkeypatch):
@@ -271,19 +271,12 @@ def _pallas_eqns(jaxpr):
     return found
 
 
-def _loss_kernel_dots():
-    """``{(precision, operand dtype): count}`` of the ``dot_general``s in
-    ``sparse_index_bwd``'s kernel, into its ``pl.when`` bodies: 4 main heads
-    in bfloat16 over 2 key heads, 4 index heads of 8, traced only."""
-    (qi, ki, wi), (q, k, lse, lse_i, words) = _loss_inputs()
-    q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
-    grad = jax.grad(
-        lambda a, b, c: sa.index_loss(a, b, c, q, k, lse, lse_i, words, 32, 16,
-                                      use_pallas=True, interpret=True),
-        argnums=(0, 1, 2),
-    )
-    (call,) = [e for e in _pallas_eqns(jax.make_jaxpr(grad)(qi, ki, wi).jaxpr)
-               if e.params["name"] == "sparse_index_bwd"]
+def _kernel_dots(fn, args, name):
+    """``{(precision, operand dtype, contraction): count}`` of the
+    ``dot_general``s in the kernel of the Pallas call ``name`` that ``fn``
+    traces, into its loops and ``pl.when`` bodies; traced only."""
+    (call,) = [e for e in _pallas_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+               if e.params["name"] == name]
 
     def dots(jaxpr):
         for eqn in jaxpr.eqns:
@@ -292,20 +285,104 @@ def _loss_kernel_dots():
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from dots(sub)
 
+    def contraction(e):
+        (lhs, _), _ = e.params["dimension_numbers"]
+        return math.prod(e.invars[0].aval.shape[i] for i in lhs)
+
     return collections.Counter(
-        (str(e.params["precision"]), str(e.invars[0].aval.dtype))
+        (str(e.params["precision"]), str(e.invars[0].aval.dtype), contraction(e))
         for e in dots(call.params["jaxpr"])
     )
 
 
+def _loss_kernel_dots():
+    """The dots of ``sparse_index_bwd``'s kernel: 4 main heads of 16 in
+    bfloat16 over 2 key heads, 4 index heads of 8, blocks 32 / 16."""
+    (qi, ki, wi), (q, k, lse, lse_i, words) = _loss_inputs()
+    q, k = q.astype(jnp.bfloat16), k.astype(jnp.bfloat16)
+    grad = jax.grad(
+        lambda a, b, c: sa.index_loss(a, b, c, q, k, lse, lse_i, words, 32, 16,
+                                      use_pallas=True, interpret=True),
+        argnums=(0, 1, 2),
+    )
+    return _kernel_dots(grad, (qi, ki, wi), "sparse_index_bwd")
+
+
 def test_the_indexer_loss_kernel_computes_each_index_head_s_z_once_a_pair(monkeypatch):
-    """Three float32 products at ``highest`` an index head (``z``, ``dq``,
-    ``dkᵀ``) and one bfloat16 product a main head; computing ``z`` again in
-    the gradient loop, as the kernel once did, makes it four."""
-    highest = str((jax.lax.Precision.HIGHEST,) * 2)
-    assert _loss_kernel_dots() == {(highest, "float32"): 3 * 4, ("None", "bfloat16"): 4}
+    """An index head's products a pair are bfloat16 and hold ``highest``'s
+    six terms: ``z`` one product of contraction ``6 id`` (48), ``dq`` one
+    of ``4 bk`` (64), ``dkᵀ`` three of ``bq`` (32); no float32 product at
+    ``highest`` is left; one bfloat16 product a main head (16). Computing
+    ``z`` again in the gradient loop, as the kernel once did, makes it two
+    ``z`` products a head."""
+    bf16 = ("None", "bfloat16")
+    kept = {(*bf16, 16): 4, (*bf16, 6 * 8): 4, (*bf16, 4 * 16): 4, (*bf16, 32): 3 * 4}
+    assert _loss_kernel_dots() == kept
     _recomputing_z(monkeypatch)
-    assert _loss_kernel_dots() == {(highest, "float32"): 4 * 4, ("None", "bfloat16"): 4}
+    assert _loss_kernel_dots() == {**kept, (*bf16, 6 * 8): 2 * 4}
+
+
+def test_the_indexer_select_kernel_computes_z_as_six_bfloat16_terms():
+    """``sparse_index_fwd``'s one product an index head a key block is
+    bfloat16 of contraction ``6 id``: ``z`` at ``highest`` in six terms, no
+    float32 product left."""
+    q, k, w = _indexer_inputs(128)
+    select = lambda q, k, w: sa.index_select(q, k, w, 24, 32, 16, use_pallas=True,  # noqa: E731
+                                             interpret=True)
+    assert _kernel_dots(select, (q, k, w), "sparse_index_fwd") == {
+        ("None", "bfloat16", 6 * 8): 4
+    }
+
+
+def _precision_operands(m, k, n, seed):
+    """float32 ``[m, k]``, ``[k, n]``: normal numbers times ``2**e``, ``e``
+    drawn in [-30, 30] for each entry, a tenth of the entries zero."""
+    rng = np.random.default_rng(seed)
+
+    def draw(shape):
+        x = rng.standard_normal(shape) * 2.0 ** rng.integers(-30, 31, shape)
+        x[rng.random(shape) < 0.1] = 0
+        return x.astype(np.float32)
+
+    return draw((m, k)), draw((k, n))
+
+
+def _three_terms(a, b):
+    """``bf16_3x``: each side split in two, hi·hi + hi·lo + lo·hi."""
+    def two(x):
+        hi = x.astype(jnp.bfloat16)
+        return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    (a_hi, a_lo), (b_hi, b_lo) = two(jnp.asarray(a)), two(jnp.asarray(b))
+    return sa._dot(a_hi, b_hi) + sa._dot(a_hi, b_lo) + sa._dot(a_lo, b_hi)
+
+
+def _six_terms(product, a, b):
+    """``a @ b`` as the loss and select kernels compute ``product``."""
+    a, b = jnp.asarray(a), jnp.asarray(b)
+    if product == "z":  # q^I @ kᵀ
+        return sa._dot(sa._lhs6(a), sa._rhs6(b))
+    if product == "dq":  # dz @ k, k handed over as kᵀ
+        halves = sa._dq6(sa._split3(a), sa._dq_parts(b.T))
+        return halves[:, : b.shape[1]] + halves[:, b.shape[1] :]
+    return sa._dkt6(sa._stack3(a), sa._split3(b))  # dkᵀ: q^Iᵀ @ dz
+
+
+@pytest.mark.parametrize("form", ["six_terms", "three_terms"])
+@pytest.mark.parametrize("product,shape", [("z", (512, 64, 512)), ("dq", (512, 512, 64)),
+                                           ("dkt", (64, 512, 512))])
+def test_the_indexer_s_bfloat16_products_keep_float32_s_precision(product, shape, form):
+    """The six bfloat16 terms against float64 on the kernels' shapes, the
+    error over ``|a| @ |b|`` entry by entry: within 1e-6, as float32 at
+    ``highest`` (about 2e-7); the three-term form (about 5e-6) is not, so
+    the bound tells the two apart."""
+    a, b = _precision_operands(*shape, seed=len(product))
+    exact = a.astype(np.float64) @ b.astype(np.float64)
+    scale = np.abs(a).astype(np.float64) @ np.abs(b).astype(np.float64)
+    got = _six_terms(product, a, b) if form == "six_terms" else _three_terms(a, b)
+    live = scale > 0
+    err = float((np.abs(np.asarray(got, np.float64) - exact)[live] / scale[live]).max())
+    assert (err <= 1e-6) == (form == "six_terms"), err
 
 
 def test_the_indexer_loss_kernel_refuses_a_scratch_over_its_vmem_limit():
@@ -323,6 +400,24 @@ def test_the_indexer_loss_kernel_refuses_a_scratch_over_its_vmem_limit():
     )
     with pytest.raises(ValueError, match=r"sparse_index_bwd's scratch .* 64 index heads of 128"):
         jax.eval_shape(lambda *a: sa.index_loss(*a, 512, 512, use_pallas=True), *args)
+
+
+@pytest.mark.parametrize("tokens", [16_384, 65_536])
+def test_the_indexer_select_kernel_refuses_a_scratch_over_its_vmem_limit(tokens):
+    """Keye's indexer, 16 heads of 64 at blocks of 512: at 16,384 tokens the
+    row of keys (32 MiB), the weights' columns and ``q``'s parts fit; at
+    65,536 the row alone is 128 MiB of VMEM."""
+    f32 = jnp.float32
+    args = (jax.ShapeDtypeStruct((1, tokens, 16, 64), f32),
+            jax.ShapeDtypeStruct((1, tokens, 64), f32),
+            jax.ShapeDtypeStruct((1, tokens, 16), f32))
+    select = lambda *a: sa.index_select(*a, 2048, 512, 512, use_pallas=True)  # noqa: E731
+    if tokens == 16_384:
+        words, _ = jax.eval_shape(select, *args)
+        assert words.shape == (1, 32, 16, tokens)
+        return
+    with pytest.raises(ValueError, match=r"sparse_index_fwd's scratch .* 65536 keys"):
+        jax.eval_shape(select, *args)
 
 
 def test_the_indexer_loss_trains_the_indexer_alone():
